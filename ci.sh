@@ -17,12 +17,12 @@ JAX_PLATFORMS=cpu python -m pytest tests/ -q
 # temp file, so CI itself stays green-on-clean.
 JAX_PLATFORMS=cpu python -m horovod_tpu.analysis \
     --baseline .hvdlint-baseline.json
-# Env-knob discipline beyond the package: bench/bench_daemon read
+# Env-knob discipline beyond the package: bench.py reads
 # HVD_* knobs too — HVD005 (only; bench's exception style is its own)
 # keeps them inside the runtime/config.py registry so the generated
 # troubleshooting table stays complete.
 JAX_PLATFORMS=cpu python -m horovod_tpu.analysis --rules HVD005 \
-    bench.py bench_daemon.py
+    bench.py
 
 # Runtime lock witness (docs/analysis.md "The runtime witness"): the
 # dynamic half of HVD007. Re-run the lock-heaviest suites (serving

@@ -19,8 +19,6 @@ Top-level API (parity with `horovod/tensorflow/__init__.py` and
     hvd.broadcast_global_variables(params, root_rank)
 """
 
-import horovod_tpu._jax_graft  # noqa: F401  (backfills jax.shard_map
-#                                on old jax BEFORE any module traces one)
 from horovod_tpu.runtime.bootstrap import (
     init,
     shutdown,

@@ -342,6 +342,27 @@ def make_global_batch(batch: Any, *, axis_name: Optional[str] = None) -> Any:
             sharding, np.asarray(x)), batch)
 
 
+def commit_step_state(mesh, tree):
+    """Commit a train step's carried state (params, optimizer state)
+    to ``mesh``, replicated, unless it already is.
+
+    The step returns its state committed to the mesh, and JAX types
+    an array by the mesh it is committed to. State fresh from
+    `model.init` / `tx.init` is committed to none, so without this the
+    step traced and compiled TWICE: once for the first call's
+    uncommitted state, once more for its own output — a second full
+    XLA compile inside "step 2". After the first call this is one
+    sharding comparison per leaf."""
+    from jax.sharding import NamedSharding
+    if mesh.is_multi_process:
+        return tree   # process-local state: jit assembles it as before
+    rep = NamedSharding(mesh, P())
+    if all(getattr(x, "sharding", None) == rep
+           for x in jax.tree.leaves(tree)):
+        return tree
+    return jax.device_put(tree, rep)
+
+
 def make_train_step(loss_fn: Callable, tx: optax.GradientTransformation,
                     *, mesh=None, axis_name: Optional[str] = None,
                     fusion_threshold: Optional[int] = None,
@@ -390,6 +411,17 @@ def make_train_step(loss_fn: Callable, tx: optax.GradientTransformation,
     )
     donate_argnums = (0, 1) if donate else ()
     from horovod_tpu.utils.timeline import step_bracket
-    return step_bracket(jax.jit(
+    jitted = jax.jit(
         sharded, donate_argnums=donate_argnums,
-        compiler_options=combiner_override_options() or None))
+        compiler_options=combiner_override_options() or None)
+
+    def placed(params, opt_state, batch):
+        params, opt_state = commit_step_state(
+            mesh, (params, opt_state))
+        return jitted(params, opt_state, batch)
+
+    stepped = step_bracket(placed)
+    # `__wrapped__` resolves to the innermost JITTED step
+    # (`step.__wrapped__.lower(...)`, models/train.py's convention).
+    stepped.__wrapped__ = jitted
+    return stepped

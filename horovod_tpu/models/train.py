@@ -108,10 +108,17 @@ def make_cnn_train_step(model, tx: optax.GradientTransformation,
         check_vma=False,
     )
     donate_argnums = (0,) if donate else ()
+    from horovod_tpu.jax import commit_step_state
     from horovod_tpu.utils.timeline import step_bracket
-    jitted = step_bracket(jax.jit(
+    compiled = jax.jit(
         sharded, donate_argnums=donate_argnums,
-        compiler_options=combiner_override_options() or None))
+        compiler_options=combiner_override_options() or None)
+
+    def placed(state, batch, rng):
+        return compiled(commit_step_state(mesh, state), batch, rng)
+
+    jitted = step_bracket(placed)
+    jitted.__wrapped__ = compiled
     return _obs_step(_chaos_step(jitted),
                      tokens_per_step=examples_per_step,
                      flops_per_step=flops_per_step)

@@ -107,12 +107,38 @@ def make_attn_fn(impl: str, *, causal: bool = True,
     if impl == "flash":
         from horovod_tpu.ops.flash_attention import flash_attention
 
+        kernel = functools.partial(
+            flash_attention, causal=causal, window=window,
+            block_q=flash_block_q, block_k=flash_block_k)
+
         def attn(q, k, v, m):
             _no_mask(m)
-            return flash_attention(q, k, v, causal=causal,
-                                   window=window,
-                                   block_q=flash_block_q,
-                                   block_k=flash_block_k)
+            # A Mosaic kernel is opaque to the GSPMD partitioner (on
+            # the chip: "Mosaic kernels cannot be automatically
+            # partitioned" — interpret mode on the CPU never says
+            # so). Under a mesh GSPMD still shards over, hand each
+            # device its (batch, heads) block through shard_map; the
+            # sequence stays whole (ring_flash / ulysses_flash are
+            # the impls that split it).
+            from horovod_tpu.parallel.mesh import (abstract_mesh,
+                                                   auto_axis_names)
+            mesh = abstract_mesh()
+            sizes = ({} if mesh.empty else
+                     {n: mesh.shape[n] for n in auto_axis_names(mesh)
+                      if mesh.shape[n] > 1})
+            if not sizes:
+                return kernel(q, k, v)
+
+            def fits(axis, *dims):
+                ok = axis in sizes and not any(d % sizes[axis]
+                                               for d in dims)
+                return axis if ok else None
+
+            spec = P(fits(AXIS_DATA, q.shape[0]), None,
+                     fits(AXIS_MODEL, q.shape[2], k.shape[2]), None)
+            return jax.shard_map(kernel, mesh=mesh,
+                                 in_specs=(spec, spec, spec),
+                                 out_specs=spec)(q, k, v)
         # The kernel consumes grouped K/V natively (index-mapped kv
         # heads); let ParallelSelfAttention skip the repeat.
         attn.native_gqa = True
@@ -612,7 +638,12 @@ def make_lm_train_step(model: TransformerLM,
             return jitted(params, opt_state, tokens)
 
     from horovod_tpu.utils.timeline import step_bracket
-    return step_bracket(wrapped)
+    stepped = step_bracket(wrapped)
+    # Same convention as models/train.py: `__wrapped__` resolves to
+    # the innermost JITTED step (`step.__wrapped__.lower(...)`, under
+    # `use(mesh)`, is how tests and chip_smoke.py read the program).
+    stepped.__wrapped__ = jitted
+    return stepped
 
 
 def _lm_data_loss(model, params, tokens, loss_chunk, mutable):

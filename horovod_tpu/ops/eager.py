@@ -287,7 +287,7 @@ def _mc_mesh2(st):
     cross-process psum runs over ``proc`` in k parallel chunk groups,
     and the full result reassembles with an intra-process all_gather
     over ``local`` — so the wire payload per process is its block
-    ONCE, not k times (VERDICT r2 next-#7). Cached on the state.
+    ONCE, not k times. Cached on the state.
     """
     cached = getattr(st, "mc_mesh2", None)
     if cached is not None:
@@ -838,7 +838,7 @@ def reducescatter(tensor, average: bool = False, name: Optional[str] = None):
             shard0 = stacked.shape[1] // st.size
             return out.reshape((st.size, shard0) + stacked.shape[2:])
         if _is_multicontroller(st):
-            # True MPMD path (VERDICT r3 next-#4): processes are the
+            # True MPMD path: processes are the
             # ranks; every local device holds this process's block, so
             # the device-axis reduction counts each process k times and
             # the sum is corrected by /k (exact for integers too: every

@@ -39,19 +39,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pallas TPU backend (absent on some CPU-only builds)
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-    _compiler_params = lambda: pltpu.CompilerParams(  # noqa: E731
+from jax.experimental.pallas import tpu as pltpu
+
+_VMEM = pltpu.VMEM
+
+
+def _compiler_params():
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel",
                              "arbitrary"))
-except (ImportError, AttributeError):  # pragma: no cover
-    # ImportError: no pallas TPU backend in this build; AttributeError:
-    # a build old enough to lack VMEM/CompilerParams. Anything else is
-    # a real bug and must surface.
-    pltpu = None
-    _VMEM = None
-    _compiler_params = lambda: None  # noqa: E731
 
 NEG_INF = float("-inf")
 
@@ -67,14 +63,14 @@ LSE_LANES = 128
 
 def _snap_tile(block: int, S: int) -> int:
     """Largest hardware-legal tile <= ``block`` for a length-``S``
-    grid axis. Real Mosaic (v5e/v5-lite captured it first —
-    BENCH_builder_r04's block-shape-divisibility failure) requires
-    the second-minor block dim to be a multiple of 8 OR equal to the
-    array dim: a single block equal to the (padded) axis always
-    qualifies, a multi-block tile must be 8-aligned — so a
+    grid axis. The tiling rule of real Mosaic (v5e/v5-lite): the
+    last two dims of a block must be multiples of (8, 128) or equal
+    to the array's dims. So the second-minor block dim is a multiple
+    of 8 OR the whole axis: a single block equal to the (padded)
+    axis always qualifies, a multi-block tile must be 8-aligned — a
     user-swept tile like 100 snaps to 96 instead of tracing a kernel
-    only interpret mode can run (the r4 lesson: interpret accepts
-    shapes real Mosaic rejects). Shared by the forward and both
+    only interpret mode can run (interpret accepts shapes real
+    Mosaic rejects). Shared by the forward and both
     backward grids so their tiles can never disagree."""
     b = min(block, max(S, 1))
     if b >= S:
@@ -275,8 +271,8 @@ def _flash_forward(q, k, v, *, causal, window, q_offset, k_offset,
     Sk = k.shape[1]
     group = _gqa_group(q, k, v)
     # Snapped tiles: multi-block tiles must be 8-aligned for real
-    # Mosaic (v5e/v5-lite divisibility; BENCH_builder_r04) — see
-    # `_snap_tile` / `flash_tile_check`.
+    # Mosaic (last two block dims multiples of (8, 128) or equal to
+    # the array dims) — see `_snap_tile` / `flash_tile_check`.
     bq = _snap_tile(block_q, Sq)
     bk = _snap_tile(block_k, Sk)
     nq = -(-Sq // bq)
@@ -351,8 +347,6 @@ def _flash_forward(q, k, v, *, causal, window, q_offset, k_offset,
 
 
 def _scratch(shape, dtype):
-    if _VMEM is None:  # pragma: no cover
-        raise RuntimeError("pallas TPU backend unavailable")
     return _VMEM(shape, dtype)
 
 
@@ -376,12 +370,7 @@ def _sds(shape, dtype, *like):
     `like` operands' — lets the pallas_calls sit inside `shard_map`
     with its default `check_vma=True` (ring/Ulysses SP pass this
     kernel as `attn_impl`)."""
-    vma = frozenset()
-    for x in like:
-        try:
-            vma |= jax.typeof(x).vma
-        except (AttributeError, TypeError):
-            pass   # older jax (no typeof/vma) / non-shard_map tracer
+    vma = frozenset().union(*(jax.typeof(x).vma for x in like))
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)

@@ -41,36 +41,6 @@ from horovod_tpu.runtime.config import config
 # re-merges; "all-reduce-combiner" is the OSS/GPU/TPU pass name).
 _COMBINER_PASSES = "all-reduce-combiner,cpu-all-reduce-combiner"
 
-# None = not probed yet; set by the first combiner_override_options()
-# call. Old jax/xla builds (observed: 0.4.37) cannot set
-# xla_disable_hlo_passes through compiler_options at all — the binding
-# drives protobuf reflection's SetString at a REPEATED field and every
-# jit carrying the option crashes at compile time — so the override
-# must be feature-probed, not assumed.
-_COMBINER_OVERRIDE_OK: Optional[bool] = None
-
-
-def _combiner_override_supported() -> bool:
-    global _COMBINER_OVERRIDE_OK
-    if _COMBINER_OVERRIDE_OK is None:
-        try:
-            # hvd: disable=HVD003(one-shot capability probe, cached in _COMBINER_OVERRIDE_OK for the process lifetime)
-            jax.jit(lambda x: x + 0,
-                    compiler_options={
-                        "xla_disable_hlo_passes": _COMBINER_PASSES,
-                    })(jnp.zeros(()))
-            _COMBINER_OVERRIDE_OK = True
-        # hvd: disable=HVD006(capability probe: any failure shape — TypeError, XlaRuntimeError, repeated-field crash — means no override; warned below)
-        except Exception:  # noqa: BLE001 — any failure means no override
-            import sys
-            sys.stderr.write(
-                "WARNING: this jax/xla build cannot disable the XLA "
-                "collective-combiner passes (xla_disable_hlo_passes "
-                "rejected); HOROVOD_FUSION_THRESHOLD buckets may be "
-                "re-merged by the backend.\n")
-            _COMBINER_OVERRIDE_OK = False
-    return _COMBINER_OVERRIDE_OK
-
 
 def combiner_override_options() -> dict:
     """jit `compiler_options` that pin HOROVOD_FUSION_THRESHOLD's
@@ -82,13 +52,12 @@ def combiner_override_options() -> dict:
     silently re-merges our buckets, so the env var's semantic — and
     the bucket-level backward/collective overlap — would stop at the
     IR. Returns {} when HOROVOD_XLA_COMBINER=xla (opt out: let XLA
-    choose granularity) or when the build cannot express the override
-    (degrade to XLA's granularity rather than crash every train
-    step — see `_combiner_override_supported`).
+    choose granularity). A compiler that rejected the option would
+    fail the step's compile loudly; the CPU and the TPU v5e compilers
+    of this installation both accept it (chip_smoke.py compiles the
+    ResNet and `make_train_step` steps with it).
     """
     if config.xla_combiner == "xla":
-        return {}
-    if not _combiner_override_supported():
         return {}
     return {"xla_disable_hlo_passes": _COMBINER_PASSES}
 

@@ -350,8 +350,6 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table,
     (pools unbatched). Un-quantized caches only — int8 KV keeps the
     lax walk's per-block dequant.
     """
-    if pltpu is None:
-        raise RuntimeError("pallas TPU backend unavailable")
     if interpret is None:
         interpret = _auto_interpret()
     fn = _make_paged_decode(bool(interpret))

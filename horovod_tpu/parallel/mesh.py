@@ -104,12 +104,8 @@ def mesh_axis_names(mesh: Mesh) -> Tuple[str, ...]:
 
 
 def axis_size(axis_name: str) -> int:
-    """Static size of a bound mesh axis (version-insulated:
-    `lax.axis_size` is jax ≥ 0.8)."""
-    try:
-        return jax.lax.axis_size(axis_name)
-    except (AttributeError, NameError):  # pragma: no cover
-        return jax.lax.psum(1, axis_name)
+    """Static size of a bound mesh axis."""
+    return jax.lax.axis_size(axis_name)
 
 
 def ring_perms(axis_name: str):
@@ -126,53 +122,21 @@ def ring_perms(axis_name: str):
 
 def use(mesh: Mesh):
     """Context manager installing `mesh` as the ambient mesh for
-    P(...)-spec sharding constraints (insulates the jax API rename:
-    `jax.set_mesh` ≥0.8, `jax.sharding.use_mesh` before, and on 0.4.x
-    the `Mesh` object itself — it is its own context manager there,
-    installing the thread-resources physical mesh)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh
+    P(...)-spec sharding constraints."""
+    return jax.set_mesh(mesh)
 
 
 def abstract_mesh():
-    """Version-insulated `jax.sharding.get_abstract_mesh()`: the
-    ambient mesh installed by `use()`, or None when off-mesh.
-
-    jax ≥0.5 exposes it directly; on 0.4.x the ambient mesh lives in
-    the thread-resources env (set by the `with mesh:` protocol `use()`
-    falls back to) and its `.abstract_mesh` view carries the same
-    axis_names/shape surface the callers consume.
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        return get()
-    from jax._src import mesh as mesh_lib
-    m = mesh_lib.thread_resources.env.physical_mesh
-    return None if m.empty else m.abstract_mesh
+    """The ambient mesh installed by `use()` (empty when off-mesh)."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def auto_axis_names(mesh) -> set:
     """The mesh axes GSPMD may still shard over (type Auto) — the only
-    ones a sharding constraint is allowed to mention.
-
-    jax ≥0.5 tags every mesh axis Auto/Manual/Explicit; on 0.4.x there
-    are no per-axis types, but axes bound in the current axis env
-    (i.e. inside an enclosing shard_map region) are exactly the Manual
-    ones, so everything else is Auto.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return {n for n, t in zip(mesh.axis_names, mesh.axis_types)
-                if t == axis_type.Auto}
-    from jax._src import core as _core
-    try:
-        manual = set(_core.get_axis_env().axis_sizes)
-    except (AttributeError, TypeError):  # pragma: no cover — API drift
-        manual = set()
-    return set(mesh.axis_names) - manual
+    ones a sharding constraint is allowed to mention; axes Manual
+    inside an enclosing shard_map region are out of its hands."""
+    return {n for n, t in zip(mesh.axis_names, mesh.axis_types)
+            if t == jax.sharding.AxisType.Auto}
 
 
 def sharding(mesh: Mesh, *spec) -> NamedSharding:
@@ -189,7 +153,10 @@ def safe_spec(mesh: Mesh, spec, shape) -> P:
     what makes KV-cache sharding GQA-aware: a heads dimension the
     model axis doesn't divide stays replicated instead of erroring."""
     sizes = dict(mesh.shape)
-    spec = tuple(spec) if isinstance(spec, (tuple, list)) else (spec,)
+    # A PartitionSpec is a sequence of entries but not a tuple
+    # subclass; a bare axis name (or None) is a one-entry spec.
+    spec = (tuple(spec) if isinstance(spec, (P, tuple, list))
+            else (spec,))
     assert len(spec) <= len(shape), (
         f"spec {spec} has more entries than array rank {len(shape)} "
         f"(shape {shape})")
@@ -255,10 +222,8 @@ def shard_batch(mesh: Mesh, batch,
     (`examples/keras_mnist_advanced.py:113-119` divides steps per epoch by
     `hvd.size()`): here one global batch is laid out across the data axis.
     """
-    # Single-axis: pass the bare name, not a 1-tuple — semantically
-    # identical, but old jax PartitionSpec __eq__ does not normalize
-    # (P(('data',)) != P('data')), and the bare form is what spec
-    # introspection everywhere else compares against.
+    # Single-axis: pass the bare name, the form spec introspection
+    # everywhere else compares against.
     sh = sharding(mesh, axes[0] if len(axes) == 1 else tuple(axes))
     return jax.tree.map(lambda x: _place(x, sh), batch)
 

@@ -635,9 +635,9 @@ class ParallelSelfAttention(nn.Module):
         The cache-wide-mask path reads (and masks against) all
         ``max_len`` K/V slots every tick, so per-tick HBM traffic
         scales with the cache ALLOCATION — at serving shapes that is
-        the dominant cost (VERDICT r4 weak #2: 10 ms/tick measured vs
-        a ~1.5 ms full-cache roofline, and most of the cache wasn't
-        even filled). Here the filled prefix [0, i+S) is consumed in
+        the dominant cost (10 ms/tick against a ~1.5 ms full-cache
+        roofline in the one 2026-07-31 v5e run, with most of the
+        cache not even filled). Here the filled prefix [0, i+S) is consumed in
         ``decode_prefix_block``-slot slices inside a `lax.fori_loop`
         with a data-dependent trip count; softmax is the standard
         online (flash) accumulation in f32 (Milakov & Gimelshein

@@ -102,8 +102,10 @@ def main(argv: List[str] | None = None) -> int:
     ap.add_argument("--platform", default="cpu",
                     choices=["cpu", "tpu", "auto"],
                     help="JAX platform forced in workers (cpu default: "
-                         "single-host TPU boxes have one chip, so "
-                         "multi-process means CPU devices)")
+                         "several workers on one host get virtual CPU "
+                         "devices; a host's TPU chips belong to ONE "
+                         "process, so `tpu` takes one worker per "
+                         "host)")
     ap.add_argument("--devices-per-proc", type=int, default=1,
                     help="virtual CPU devices per worker (cpu platform)")
     ap.add_argument("--elastic", action="store_true",
@@ -152,6 +154,15 @@ def main(argv: List[str] | None = None) -> int:
         total = local_n = args.num_proc
         rank_offset = 0
         head_host = "127.0.0.1"
+
+    if args.platform == "tpu" and local_n > 1:
+        # Every worker would see (and try to take) all of this host's
+        # chips; a chip belongs to one process at a time.
+        ap.error(f"--platform tpu with {local_n} workers on one host: "
+                 f"a host's TPU chips belong to ONE process. Run the "
+                 f"script directly (`python train.py`: one process "
+                 f"drives all local devices, README.md) "
+                 f"or launch one worker per host (-H host1:1,host2:1)")
 
     serve_here = args.rendezvous is None and args.host_index == 0
     if (args.hosts is not None and len(hosts) > 1

@@ -287,10 +287,6 @@ register_knob(
     "HOROVOD_COORDINATOR", "str", "(launcher)", "runtime/bootstrap.py",
     "jax.distributed coordinator address, set by hvdrun")
 register_knob(
-    "HVD_BENCH_PROBE_BUDGET_S", "float", "(unset)", "bench.py",
-    "Caps the benchmark's backend probe loop (seconds) before the "
-    "CPU fallback engages")
-register_knob(
     "HVD_METRICS_PORT", "int", "(unset)", "obs/exporter.py",
     "Serve Prometheus /metrics + /healthz + /metrics.json on this "
     "port (0 = ephemeral; binds 127.0.0.1 — wider exposure is a "

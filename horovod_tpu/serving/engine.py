@@ -320,6 +320,11 @@ class ServingEngine:
             raise ValueError(
                 f"eos_id must be in [0, vocab_size={model.vocab_size}"
                 f"), got {eos_id}")
+        # Before anything compiles (warmup below): a serving process
+        # that never calls hvd.init() still gets the compile cache.
+        from horovod_tpu.runtime.compile_cache import (
+            configure_compile_cache)
+        configure_compile_cache()
         # Sharded serving (docs/serving.md "Sharded serving"): the
         # engine owns mesh construction — None reads HVD_SERVE_MESH,
         # and ints/strs/MeshSpecs normalize to a built Mesh here so

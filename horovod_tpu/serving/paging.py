@@ -98,13 +98,7 @@ def _resolve_paged_kernel(mode: Optional[str],
     if mode == "pallas":
         # The pool aligns its decode model's walk granularity to the
         # block size (always legal — the spec guarantees block_size
-        # divides max_len), so only the backend can gate.
-        from horovod_tpu.ops.flash_attention import pltpu
-        if pltpu is None:
-            raise ValueError(
-                "paged kernel mode 'pallas' needs a pallas TPU "
-                "backend (interpret mode counts); set "
-                "HVD_PAGED_KERNEL=lax or off")
+        # divides max_len), so nothing gates it.
         return "pallas"
     blk = model.decode_prefix_block
     wb = min(int(blk), model.max_len) if blk else 0
@@ -1028,10 +1022,7 @@ class PagedSlotPool:
             # check, clamped to the allocated chain).
             self._est_fill[slot] += 1
         toks = self._toks
-        try:
-            toks.copy_to_host_async()
-        except AttributeError:   # older jax.Array without the method
-            pass
+        toks.copy_to_host_async()
         return TickHandle(toks)
 
     @staticmethod
@@ -1140,8 +1131,12 @@ class PagedSlotPool:
                 self._drf_cache = slot_reset(
                     self.drf_model, self._drf_cache, jnp.int32(slot))
         with self._ctx():
-            self._tables = self._tables.at[slot].set(
-                jnp.zeros((self.spec.blocks_per_seq,), jnp.int32))
+            # A host row, exactly as begin_prefill sets it: the same
+            # (warmed) scatter program. An eager jnp.zeros here would
+            # be a first-use XLA compile inside the serving window
+            # that the pool's own compile count never sees.
+            self._tables = self._tables.at[slot].set(jnp.asarray(
+                np.zeros((self.spec.blocks_per_seq,), np.int32)))
             self._fills = self._fills.at[slot].set(0)
             self._live = self._live.at[slot].set(False)
             self._done = self._done.at[slot].set(False)

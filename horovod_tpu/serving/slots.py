@@ -453,10 +453,7 @@ class SlotPool:
         finally:
             self.maybe_compiling = False
         toks = self._toks
-        try:
-            toks.copy_to_host_async()
-        except AttributeError:   # older jax.Array without the method
-            pass
+        toks.copy_to_host_async()
         return TickHandle(toks)
 
     @staticmethod

@@ -5,7 +5,7 @@ fraction α (the share of collective time NOT hidden under compute).
 The reference measured its 90/79 % efficiencies on hardware
 (`README.md:27-32` there); this module turns a `bench.py --profile DIR`
 capture into a *measured* α so the modeled numbers can be replaced the
-moment a chip window opens (VERDICT r3 weak #3).
+moment a chip window opens.
 
 Works on the Chrome-trace JSON (`*.trace.json.gz`) the profiler writes
 next to the xplane protobuf — dependency-free parsing. Device timelines
@@ -242,7 +242,7 @@ def analyze_op_breakdown(trace: Dict[str, Any],
     The r4 ResNet diagnosis (BN statistics = 37.8 % of the step,
     docs/mfu.md) was assembled by hand from a trace; this automates it
     so every `bench.py --profile` capture carries its own cost ranking
-    in the artifact (VERDICT r4 next-#5: the profiled configs must
+    in the artifact (the profiled configs must
     yield named top costs, not just a number).
 
     Category = the event's `hlo_category` arg when the profiler

@@ -1,6 +1,6 @@
 """Virtual-mesh scale: the full multi-axis dryrun beyond 8 devices.
 
-VERDICT r2 next-#6: the 8-device meshes the suite (and the driver)
+The 8-device meshes the suite (and the driver)
 exercise can hide factorization/divisibility bugs in `_split`, the
 interleaved pipeline placement, and eager negotiation that only appear
 at larger N. These tests run the SAME `dryrun_multichip` the driver
@@ -34,7 +34,7 @@ def _run_entry(expr, ok_marker, timeout=540):
 
 # 64 reaches axis degrees (e.g. model=4) the 8/16/32 meshes can't —
 # it found the kv_heads-vs-tp-degree divisibility bug on first run
-# (VERDICT r3 next-#8: be an order of magnitude past the reference's
+# (be an order of magnitude past the reference's
 # 2-rank CI scale).
 @pytest.mark.parametrize("n", [16, 32, 64])
 def test_dryrun_multichip_at_scale(n):

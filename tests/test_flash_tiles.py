@@ -1,13 +1,13 @@
 """v5e/v5-lite flash-attention tile-legality regression tests.
 
-BENCH_builder_r04 caught the Pallas block-shape-divisibility failure
-on real v5e Mosaic ("last two block dims divisible by (8, 128) or
-equal to the array dims") — a class of bug interpret mode happily
-hides, because the interpreter runs any block shape. The fix is
-two-sided and both sides are CPU-verifiable:
+The tiling rule of real v5e Mosaic: the last two block dims must be
+divisible by (8, 128) or equal to the array dims. Breaking it is a
+class of bug interpret mode happily hides, because the interpreter
+runs any block shape (tests/test_tpu_compile.py asks the TPU compiler
+itself). The rule is kept from two sides, both CPU-verifiable:
 
 * the lse/dvec operands ride lane-replicated rank-4 (LSE_LANES), so
-  the r04 offending spec (rank-3 lse with (1, 1, bq) blocks) no
+  the spec that once broke it (rank-3 lse with (1, 1, bq) blocks) no
   longer exists — `flash_tile_check` proves every block spec the
   fwd+bwd pallas_calls build at the captured shapes is legal;
 * user-swept tiles snap to hardware-legal sizes (`_snap_tile`:

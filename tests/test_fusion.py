@@ -120,8 +120,7 @@ def test_fusion_env_var(hvd, monkeypatch):
 
 
 class TestOverlapStructure:
-    """Pin the PRECONDITION for backward/allreduce overlap (VERDICT r2
-    next-#4): the IR handed to XLA must contain one INDEPENDENT
+    """Pin the PRECONDITION for backward/allreduce overlap: the IR handed to XLA must contain one INDEPENDENT
     all_reduce per gradient bucket — none chained through another
     collective — so the latency-hiding scheduler is free to issue each
     bucket's collective as soon as its grads exist, instead of one
@@ -181,7 +180,7 @@ class TestOverlapStructure:
         assert len(re.findall(r"stablehlo\.all_reduce", txt)) == 2
 
     def test_post_optimization_bucket_structure(self, hvd):
-        """Close the overlap-model loophole (VERDICT r3 next-#3): the
+        """Close the overlap-model loophole: the
         backend AllReduceCombiner re-merges our independent bucket
         all-reduces into one tuple all-reduce (the hazard
         docs/scaling.md flags), and `combiner_override_options()` —
@@ -194,13 +193,6 @@ class TestOverlapStructure:
         import jax
         import jax.numpy as jnp
         import optax
-
-        from horovod_tpu.ops.fusion import _combiner_override_supported
-        if not _combiner_override_supported():
-            pytest.skip("this jax/xla build cannot express "
-                        "xla_disable_hlo_passes via compiler_options; "
-                        "the combiner override degrades to a no-op "
-                        "(ops.fusion._combiner_override_supported)")
 
         from horovod_tpu import models
         from horovod_tpu.models import make_cnn_train_step

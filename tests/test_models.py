@@ -110,7 +110,7 @@ def test_resnet_bn_sample_trains(hvd):
 
 
 def test_s2d_stem_matches_plain_stem(hvd):
-    """Space-to-depth stem oracle (VERDICT r3 next-#2): with the SAME
+    """Space-to-depth stem oracle: with the SAME
     parameter tree (s2d is a pure compute-path flag), the s2d model's
     output equals the plain-stem model's on random input, fp32 — the
     MXU-friendly re-pack must be a numerical identity, not an

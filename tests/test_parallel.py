@@ -529,7 +529,7 @@ class TestPipelineParallel:
     def test_interleaved_matches_sequential(self, v):
         """Interleaved schedule (v chunks/device, S = v*P global
         stages) is numerically the same program as running the S
-        stages sequentially — GPipe-path oracle per VERDICT r1 #10."""
+        stages sequentially — the GPipe-path oracle."""
         P_, M, mb, d = 4, 8, 2, 6
         mesh = par.make_mesh(pipe=P_, data=2)
         per_stage, _ = self._make(v * P_, d)
@@ -582,8 +582,7 @@ class TestPipelineParallel:
         """remat=True: gradients are bit-compatible with the plain
         path, and the backward's per-tick residuals shrink from every
         stage INTERIOR intermediate to just the stage input — the
-        memory-bounding promise of `pipeline_apply(remat=)` (VERDICT
-        r2 next-#5). Measured structurally: the forward scan's
+        memory-bounding promise of `pipeline_apply(remat=)`. Measured structurally: the forward scan's
         stacked [ticks, ...] residual outputs in the grad jaxpr.
         v=2 additionally pins that the interleaved chunk-param
         indexing happens INSIDE the checkpoint (no [ticks, params]

@@ -191,7 +191,7 @@ def test_hvdrun_console_script():
         # runs from a plain checkout): pin the console-script CONTRACT
         # deterministically instead of skipping — pyproject must
         # declare hvdrun -> horovod_tpu.runner:main and that target
-        # must be an importable callable (VERDICT r4 weak #6: no
+        # must be an importable callable (no
         # silent environment-dependent skips). The full subprocess
         # contract below still runs wherever the package IS installed.
         try:

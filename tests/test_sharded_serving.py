@@ -133,6 +133,84 @@ class TestShardedBitwise:
             None, "model")
 
 
+class TestReallySharded:
+    """Replication must never again pass as sharding: a fully
+    replicated engine emits the same tokens, so the bitwise tests
+    above cannot tell. These assert what each device HOLDS."""
+
+    def test_safe_spec_reads_a_partition_spec(self, hvd):
+        # A PartitionSpec is a sequence, not a tuple subclass: it must
+        # be read entry by entry, never wrapped whole as one entry.
+        mesh = _mesh(4)
+        assert not isinstance(P(), tuple)
+        assert safe_spec(mesh, P(None, "model"), (8, 8)) == P(
+            None, "model")
+        assert safe_spec(mesh, P("model"), (8, 8)) == P("model")
+        assert safe_spec(mesh, "model", (8,)) == P("model")
+        assert safe_spec(mesh, P(("data", "model"), None),
+                         (8, 8)) == P(("data", "model"), None)
+
+    def test_place_with_specs_shards_params(self, lm):
+        from horovod_tpu.models.transformer import lm_param_specs
+        from horovod_tpu.parallel.mesh import place_with_specs
+        model, params = lm
+        mesh = _mesh(4)
+        specs = lm_param_specs(model, jax.random.PRNGKey(0),
+                               jnp.zeros((1, MAX_LEN), jnp.int32))
+        placed = place_with_specs(mesh, params, specs)
+        flat, _ = jax.tree_util.tree_flatten_with_path(placed)
+        split = 0
+        for path, leaf in flat:
+            name = jax.tree_util.keystr(path)
+            shards = leaf.addressable_shards
+            assert len({s.device for s in shards}) == 4
+            if "kernel" in name and ("attn" in name or "mlp" in name):
+                # Every attention / MLP matmul kernel: 1/4 per device.
+                assert shards[0].data.size * 4 == leaf.size, (
+                    name, leaf.shape, shards[0].data.shape)
+                split += 1
+        assert split >= 4 * model.num_layers
+        held = sum(s.data.nbytes for _, leaf in flat
+                   for s in leaf.addressable_shards[:1])
+        total = sum(leaf.nbytes for _, leaf in flat)
+        assert held < 0.6 * total, (held, total)
+
+    @pytest.mark.parametrize("paged", [False, True],
+                             ids=["fixed", "paged"])
+    def test_engine_kv_holds_a_quarter_per_device(self, lm, paged):
+        model, params = lm
+        kw = dict(paged=True, kv_block_size=8) if paged else {}
+        eng = ServingEngine(model, params, num_slots=2, mesh=_mesh(4),
+                            **kw)
+        try:
+            if paged:
+                leaves = list(eng.pool._pools)
+            else:
+                flat, _ = jax.tree_util.tree_flatten_with_path(
+                    eng.pool._cache)
+                leaves = [leaf for path, leaf in flat
+                          if "index" not in jax.tree_util.keystr(path)]
+            assert leaves
+            for leaf in leaves:
+                shard = leaf.addressable_shards[0].data
+                # [rows, 1, len, Hkv, D]: the heads axis splits 4 ways.
+                assert shard.shape[3] * 4 == leaf.shape[3], (
+                    leaf.shape, shard.shape)
+                assert len({s.device
+                            for s in leaf.addressable_shards}) == 4
+            kernels = [
+                leaf for path, leaf in
+                jax.tree_util.tree_flatten_with_path(
+                    eng.pool.params)[0]
+                if "kernel" in jax.tree_util.keystr(path)
+                and "attn" in jax.tree_util.keystr(path)]
+            assert kernels and all(
+                k.addressable_shards[0].data.size * 4 == k.size
+                for k in kernels)
+        finally:
+            eng.shutdown()
+
+
 class TestShardedSeams:
     """Where sharding could leak: prefix cache, spec decode,
     migration, accounting."""

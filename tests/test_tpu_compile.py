@@ -1,0 +1,229 @@
+"""Compile-for-TPU tests: the main path's Pallas kernels, at the
+flagship LM's real widths, through the TPU compiler for a DESCRIBED
+`v5e:2x2` chip (nothing is attached; nothing runs).
+
+Interpret mode accepts block shapes and VMEM budgets real Mosaic
+rejects, so every other test of these kernels can pass while the chip
+refuses them. Each test here lowers + compiles one program for the
+described chip and asserts the Mosaic custom call is in the compiled
+text. A compile that passes is a compile, not a chip run
+(`chip_smoke.py` is the chip run).
+
+Rules this file keeps (on-chip-measurement guide §2): the topology,
+shardings and shapes are built inside module-scoped fixtures — never
+at import, in a `skipif`/`parametrize` argument, `autouse`, or in
+conftest.py — because only ONE process may load the TPU's library and
+every xdist worker imports every test file; the compiles run in the
+test's own process; all of them live in this one file; and the
+persistent compile cache is off around them (an entry compiled for a
+described chip cannot be read back without one).
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+# The flagship LM's attention shape (bench.py / chip_smoke.py).
+B, S, H, D = 8, 2048, 8, 128
+LANES = 8
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means no compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def chip_config():
+    """JAX as a chip run has it, not as conftest.py sets it for the
+    CPU suite: x64 off (the default — under x64 the kernels' index
+    scalars trace as int64, which Mosaic does not lower), and the
+    persistent compile cache off (see module doc)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    cache_was = jax.config.jax_enable_compilation_cache
+    x64_was = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_enable_x64", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    jax.config.update("jax_enable_x64", x64_was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def sds(one_chip, chip_config):
+    """shape/dtype -> ShapeDtypeStruct placed on the described chip."""
+    def make(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return make
+
+
+def custom_calls(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text().count(
+        "tpu_custom_call")
+
+
+def test_described_chip_is_v5_lite(topo):
+    """The device_kind the peak table is keyed by."""
+    from horovod_tpu.utils.profile_analysis import PEAK_BF16_FLOPS
+    kind = topo.devices[0].device_kind
+    assert kind == "TPU v5 lite"
+    assert kind in PEAK_BF16_FLOPS
+
+
+def test_flash_forward(sds):
+    from horovod_tpu.ops.flash_attention import flash_attention
+    q = sds((B, S, H, D))
+    assert custom_calls(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        interpret=False), q, q, q) == 1
+
+
+def test_flash_fused_backward(sds):
+    """Forward + the two FlashAttention-2 style backward kernels."""
+    from horovod_tpu.ops.flash_attention import flash_attention
+    q = sds((B, S, H, D))
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False,
+                               bwd_impl="pallas").astype(
+                                   jnp.float32).mean()
+
+    assert custom_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, q) == 3
+
+
+def test_flash_forward_window_512(sds):
+    """The banded sliding-window grid."""
+    from horovod_tpu.ops.flash_attention import flash_attention
+    q = sds((B, S, H, D))
+    assert custom_calls(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        window=512, interpret=False),
+        q, q, q) == 1
+
+
+@pytest.mark.parametrize("hkv", [H, 2], ids=["mha", "gqa"])
+def test_flash_decode_attention(sds, hkv):
+    from horovod_tpu.ops.flash_attention import flash_decode_attention
+    q, kv = sds((B, 1, H, D)), sds((B, S, hkv, D))
+    assert custom_calls(
+        lambda q, k, v, n: flash_decode_attention(
+            q, k, v, n, block_k=256, interpret=False),
+        q, kv, kv, sds((), jnp.int32)) == 1
+
+
+@pytest.mark.parametrize("block_size", [16, 128])
+def test_paged_decode_attention_vmapped(sds, block_size):
+    """vmap over the lane axis dispatches ONE kernel with the lanes
+    as its leading grid dim (pools unbatched)."""
+    from horovod_tpu.ops.paged_attention import paged_decode_attention
+    per_seq = S // block_size
+    q = sds((LANES, 1, 1, H, D))
+    pool = sds((LANES * per_seq + 1, 1, block_size, H, D))
+
+    def tick(q, k_new, v_new, k_pool, v_pool, tables, fills):
+        return jax.vmap(
+            lambda q, kn, vn, t, f: paged_decode_attention(
+                q, kn, vn, k_pool, v_pool, t, f, interpret=False)
+        )(q, k_new, v_new, tables, fills)
+
+    assert custom_calls(
+        tick, q, q, q, pool, pool, sds((LANES, per_seq), jnp.int32),
+        sds((LANES,), jnp.int32)) == 1
+
+
+def test_paged_decode_tick_whole_program(sds, monkeypatch):
+    """The serving engine's Pallas-mode tick as `PagedSlotPool`
+    dispatches it: 8 lanes x max_len 2048, KV block 16, 12 layers —
+    one paged-decode kernel per layer inside vmap, sampling and the
+    block scatter around it."""
+    from horovod_tpu.models.transformer import (
+        TransformerLM, init_paged_pools, paged_cache_spec,
+        paged_decode_tick, serving_params, slot_decode_model)
+    from horovod_tpu.ops import flash_attention, paged_attention
+    from horovod_tpu.parallel.tensor import unbox
+
+    # The model reaches the kernels through `_auto_interpret()`, which
+    # asks for the default backend — the CPU here. Steer it in the
+    # test; the program grows no option for this.
+    monkeypatch.setattr(flash_attention, "_auto_interpret",
+                        lambda: False)
+    monkeypatch.setattr(paged_attention, "_auto_interpret",
+                        lambda: False)
+
+    layers, bs = 12, 16
+    model = TransformerLM(vocab_size=32768, num_layers=layers,
+                          num_heads=H, head_dim=D, max_len=S,
+                          dtype=jnp.bfloat16, attn_impl="flash")
+    dec = slot_decode_model(model).clone(decode_prefix_impl="pallas",
+                                         decode_prefix_block=bs)
+    spec = paged_cache_spec(model, bs)
+
+    def place(tree):
+        return jax.tree.map(lambda s: sds(s.shape, s.dtype), tree)
+
+    params = place(jax.eval_shape(
+        lambda r: serving_params(unbox(model.init(
+            r, jnp.zeros((1, 64), jnp.int32))["params"])),
+        jax.random.PRNGKey(0)))
+    pools = place(jax.eval_shape(
+        lambda: init_paged_pools(model, spec,
+                                 LANES * spec.blocks_per_seq + 1)))
+    vec = lambda dt: sds((LANES,), dt)  # noqa: E731
+    compiled = paged_decode_tick.lower(
+        dec, spec, pools, params,
+        sds((LANES, spec.blocks_per_seq), jnp.int32), vec(jnp.int32),
+        vec(jnp.int32), vec(jnp.float32), vec(jnp.float32),
+        sds((LANES, 2), jnp.uint32), vec(bool), vec(bool),
+        sds((), jnp.int32), fused=True).compile()
+    assert compiled.as_text().count("tpu_custom_call") == layers
+    mem = compiled.memory_analysis()
+    need = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert need < 16 * 2 ** 30, f"tick needs {need / 2**30:.1f} GiB"
+
+
+def test_flash_under_a_four_chip_data_mesh(topo, chip_config,
+                                           monkeypatch):
+    """The LM's `attn_impl="flash"` inside a GSPMD program over four
+    chips. A bare Mosaic kernel there is refused ("Mosaic kernels
+    cannot be automatically partitioned") — something interpret mode
+    on the CPU can never show — so `make_attn_fn` hands each device
+    its batch block through shard_map."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from horovod_tpu.models.transformer import make_attn_fn
+    from horovod_tpu.ops import flash_attention
+    from horovod_tpu.parallel.mesh import make_mesh, use
+
+    monkeypatch.setattr(flash_attention, "_auto_interpret",
+                        lambda: False)
+    mesh = make_mesh(devices=topo.devices, data=4)
+    q = jax.ShapeDtypeStruct(
+        (B, S, H, D), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("data")))
+    attn = make_attn_fn("flash", causal=True)
+
+    def loss(q, k, v):
+        return attn(q, k, v, None).astype(jnp.float32).mean()
+
+    with use(mesh):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, q, q).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
