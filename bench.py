@@ -886,7 +886,7 @@ def _overload_ab(model, params, args, prompts, rate, log):
 
 def _serving_trace_check(model, params, args, prompts, log):
     """Observability acceptance evidence: run a few requests with the
-    event log, the (Python-writer) Timeline and the shared metric
+    event log, the span recorder and the shared metric
     registry all live, then recover ONE request's ``trace_id`` from
     each subsystem — the proof that a request can be followed across
     the whole plane (docs/observability.md). Recorded in the bench
@@ -895,21 +895,15 @@ def _serving_trace_check(model, params, args, prompts, log):
     import tempfile
 
     from horovod_tpu.obs import events as obs_events
+    from horovod_tpu.obs import spans as obs_spans
     from horovod_tpu.obs.registry import registry as obs_registry
-    from horovod_tpu.runtime import state as _state
     from horovod_tpu.serving import ServingEngine
-    from horovod_tpu.utils.timeline import Timeline
 
     tmp = tempfile.mkdtemp(prefix="hvd_obs_trace_")
     ev_path = os.path.join(tmp, "events.jsonl")
-    tl_path = os.path.join(tmp, "timeline.json")
-    # Scoped swaps, both restored: a user-configured HVD_EVENTS_LOG
-    # must keep receiving events after the check.
+    # A scoped swap, restored: a user-configured HVD_EVENTS_LOG must
+    # keep receiving events after the check.
     prev_ev = obs_events.install(obs_events.EventLog(ev_path))
-    prev_tl = _state.global_state().timeline
-    # The Python writer explicitly: the native C++ writer drops span
-    # args, and args are the Timeline leg of the check.
-    _state.global_state().timeline = Timeline(tl_path, native=None)
     try:
         with ServingEngine(model, params,
                            num_slots=min(2, args.serving_slots),
@@ -918,8 +912,6 @@ def _serving_trace_check(model, params, args, prompts, log):
             for h in handles:
                 h.result(timeout=600)
     finally:
-        _state.global_state().timeline.close()
-        _state.global_state().timeline = prev_tl
         obs_events.install(prev_ev)
     # Subsystem 1: the shared registry's exemplar (the last retired
     # request's trace_id rides the e2e histogram).
@@ -927,23 +919,20 @@ def _serving_trace_check(model, params, args, prompts, log):
     ex = hist.samples()[0][1].exemplar if hist else None
     tid = (ex or {}).get("trace_id")
     in_exemplar = tid is not None
-    # Subsystems 2+3: the SAME id in the event log and span args.
-    in_events = in_timeline = False
+    # Subsystems 2+3: the SAME id in the event log and the span tree.
+    in_events = in_spans = False
     if tid:
         with open(ev_path) as f:
             in_events = any(
                 _json.loads(line).get("trace_id") == tid
                 for line in f)
-        with open(tl_path) as f:
-            in_timeline = any(
-                (e.get("args") or {}).get("trace_id") == tid
-                for e in _json.loads(f.read()))
-    n = sum((in_exemplar, in_events, in_timeline))
+        in_spans = bool(obs_spans.trace(tid))
+    n = sum((in_exemplar, in_events, in_spans))
     log(f"serving trace check: trace_id={tid} found in {n}/3 "
         f"subsystems (metrics exemplar={in_exemplar}, "
-        f"event log={in_events}, timeline args={in_timeline})")
+        f"event log={in_events}, span tree={in_spans})")
     return {"trace_id": tid, "in_metrics_exemplar": in_exemplar,
-            "in_event_log": in_events, "in_timeline_args": in_timeline,
+            "in_event_log": in_events, "in_span_tree": in_spans,
             "subsystems": n}
 
 
@@ -1251,7 +1240,7 @@ def run_serving(args, devices, n_chips, log):
            "pipeline_depth": depth, "prefill_chunk_budget": budget,
            "rates": per_rate,
            # One request followed across the observability plane
-           # (event log + Timeline span args + metric exemplar).
+           # (event log + span tree + metric exemplar).
            "trace_check": _serving_trace_check(
                model, params, args, prompts, log)}
     if slo_spec:

@@ -7,9 +7,10 @@ off the same names (`SPAN_PHASE`), so drift is worse than a missing
 doc row: an undeclared span is invisible to the critical-path
 anatomy. Two drift directions break the contract:
 
-* a ``spans.begin_span("name", ...)`` / ``spans.record_span(...)``
-  call (through any alias of the spans module, including
-  function-local imports) with a literal name not in the catalog
+* a ``spans.begin_span("name", ...)`` / ``spans.record_span(...)`` /
+  ``spans.loop_span(...)`` call (through any alias of the spans
+  module, including function-local imports) with a literal name not
+  in the catalog
   records a span no doc, waterfall legend or phase map knows
   (flagged at the call site);
 * a catalog entry whose name is never recorded anywhere is a dead
@@ -18,9 +19,7 @@ anatomy. Two drift directions break the contract:
 
 Dynamic names (a variable first argument) are out of scope for the
 literal scan; keep span names literal at call sites — that is what
-makes traces greppable in the first place. The Horovod `Timeline`'s
-``begin_span`` method is untouched: it is reached through a timeline
-handle, never through a spans-module alias.
+makes traces greppable in the first place.
 """
 
 from __future__ import annotations
@@ -34,14 +33,14 @@ RULE = RuleMeta(
     id="HVD012",
     name="span-catalog-drift",
     severity="error",
-    doc="spans.begin_span()/record_span() with a literal name not "
-        "declared in obs/spans.py SPAN_CATALOG (undocumented span, "
+    doc="spans.begin_span()/record_span()/loop_span() with a literal "
+        "name not declared in obs/spans.py SPAN_CATALOG (undocumented span, "
         "invisible to phase anatomy), or a catalog entry whose name "
         "is never recorded (dead promise).")
 
 _SPANS_MODULE = "obs/spans.py"
 _SPANS_DOTTED = "horovod_tpu.obs.spans"
-_RECORD_FNS = ("begin_span", "record_span")
+_RECORD_FNS = ("begin_span", "record_span", "loop_span")
 
 
 def _spans_module(project):
@@ -83,7 +82,8 @@ def _live_catalog() -> Dict[str, int]:
 
 def _span_aliases(mi) -> Tuple[Set[str], Set[str]]:
     """(module aliases of obs.spans, direct names bound to its
-    ``begin_span``/``record_span``) — scanned over the WHOLE tree,
+    ``begin_span``/``record_span``/``loop_span``) — scanned over the
+    WHOLE tree,
     because subsystems import the spans module function-locally."""
     mods: Set[str] = set()
     fns: Set[str] = set()

@@ -16,9 +16,11 @@ into, replacing per-subsystem silos:
 * `events` — bounded JSONL structured-event log for discrete events
   (restarts, requeues, sheds, chaos fires, stalls, compiles);
   ``HVD_EVENTS_LOG=/path`` persists it.
-* `tracing` — ``trace_id`` minted per serving request and carried
-  through queue → prefill → decode → (requeue), stamped into
-  Timeline span args, events and histogram exemplars.
+* `spans` — the one recorder: a ``trace_id`` minted per serving
+  request and carried through queue → prefill → decode → (requeue)
+  as a span tree (also stamped into events and histogram
+  exemplars), and the loop spans (``sched.*``, ``engine.*``,
+  ``train.*``) mirrored into the JAX profiler.
 * `profiling` — `profile_step` brackets + the opt-in `jax.profiler`
   session (``HVD_PROFILE_DIR``).
 * `aggregate` — the FLEET layer: a rank-0 collector pulling every
@@ -43,8 +45,8 @@ into, replacing per-subsystem silos:
 # `python -m horovod_tpu.obs.flightrec` CLI, and importing it from the
 # package __init__ would make runpy warn about the double import.
 # `from horovod_tpu.obs import flightrec` still works (submodule).
-from horovod_tpu.obs import (aggregate, catalog, events, slo,
-                             straggler, tracing)
+from horovod_tpu.obs import (aggregate, catalog, events, slo, spans,
+                             straggler)
 from horovod_tpu.obs.aggregate import FleetAggregator, rank_snapshot
 from horovod_tpu.obs.exporter import (MetricsServer, render_prometheus,
                                       start_exporter, stop_exporter)
@@ -56,7 +58,7 @@ from horovod_tpu.obs.slo import Objective, SLOMonitor
 
 __all__ = [
     "registry", "MetricRegistry", "Counter", "Gauge", "Histogram",
-    "catalog", "events", "tracing",
+    "catalog", "events", "spans",
     "aggregate", "straggler", "slo",
     "FleetAggregator", "rank_snapshot", "SLOMonitor", "Objective",
     "MetricsServer", "render_prometheus", "start_exporter",

@@ -28,11 +28,23 @@ Three consumers read the ring:
   ``hvd_request_phase_seconds{phase=}`` histograms, so "TTFT p95
   regressed" becomes "the admission phase regressed".
 
-Span NAMES are a contract: every ``begin_span``/``record_span``
-literal must appear in `SPAN_CATALOG` (hvdlint HVD012 pins both drift
-directions, the HVD010/011 pattern). Trace identity lives here too —
-`mint_trace_id` / `new_span_id` — with ``obs.tracing`` kept as a
-compat shim over this module.
+A second, smaller vocabulary covers the LOOPS every request and
+every train step pass through — the scheduler's step and its phases,
+the engine's per-iteration bookkeeping, the train step's dispatch.
+`loop_span` records those into a bounded ring of their own (so 15
+scheduler steps a second never evict a request's tree) and mirrors
+each into the JAX profiler as a ``TraceAnnotation``: while someone
+profiles (``HVD_PROFILE_DIR`` / `obs.profiler_session`), the
+``sched.*`` / ``engine.*`` / ``train.*`` rows sit in the trace's host
+plane on the same time axis as the device's ops. The ring's
+``t0_ns``/``t1_ns`` are ``time.time_ns()`` taken around the
+annotation — the profiler's host clock up to a per-session constant
+(an xplane's times count from the session's start).
+
+Span NAMES are a contract: every ``begin_span``/``record_span``/
+``loop_span`` literal must appear in `SPAN_CATALOG` (hvdlint HVD012
+pins both drift directions, the HVD010/011 pattern). Trace identity
+lives here too — `mint_trace_id` / `new_span_id`.
 
 Observability must never cost the workload: file faults
 warn-and-disable (the Timeline/EventLog contract), and recording is a
@@ -43,12 +55,15 @@ from __future__ import annotations
 
 import binascii
 import collections
+import itertools
 import json
 import os
 import sys
 import threading
 import time
 from typing import Dict, List, Optional
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from horovod_tpu.analysis import lockcheck
 
@@ -58,20 +73,27 @@ __all__ = [
     "configure", "install", "chrome_trace", "waterfall",
     "phase_anatomy", "observe_request", "flight_section",
     "span_table_md", "mint_trace_id", "new_trace_id", "new_span_id",
-    "span_args", "main",
+    "main", "loop_span", "loop_tail", "LOOP_RING",
 ]
 
 DEFAULT_RING = 4096
 
 # Every span name the subsystems may record, with the one-line
 # description an operator reads in docs/observability.md (hvdlint's
-# HVD012 pins both drift directions: a begin_span/record_span literal
-# not declared here, and a declared name nothing records). Keep names
+# HVD012 pins both drift directions: a begin_span/record_span/loop_span
+# literal not declared here, and a declared name nothing records). Keep names
 # literal at record sites — that is what makes a waterfall greppable.
 SPAN_CATALOG: Dict[str, str] = {
     "disagg.handoff":
         "Prefill-complete to decode-pool submit: the disaggregation "
         "seam (export + placement retries live inside it)",
+    "engine.bookkeeping":
+        "Loop span: the rest of one dispatch-loop iteration after "
+        "the scheduler's step (heartbeat, gauges, KV and swap "
+        "stats, brownout)",
+    "engine.idle_wait":
+        "Loop span: the dispatch thread parked on the admission "
+        "queue, waiting for a request",
     "router.attempt":
         "One placement of a request on one replica (submit to "
         "terminal answer from that engine)",
@@ -84,6 +106,33 @@ SPAN_CATALOG: Dict[str, str] = {
     "router.request":
         "Root span of a router-submitted request (client-observed "
         "latency through retries, hedges and migrations)",
+    "sched.admit":
+        "Loop span: one admission, queue-head pop to slot reserved "
+        "and reset (attrs slot, prompt_tokens, prefix_cached)",
+    "sched.first_token":
+        "Loop span: a drained prefill's first token sampled and read "
+        "(the one exposed host sync per request), the lane moved to "
+        "decoding (attr slot)",
+    "sched.housekeeping":
+        "Loop span: queue sweep, dead prefills, tenant preempts and "
+        "KV-block grafts at the top of a scheduler step",
+    "sched.prefill_chunk":
+        "Loop span: one prefill chunk's dispatch (attrs slot, "
+        "tokens) - the site that records serving.prefill_chunk",
+    "sched.spec_round":
+        "Loop span: one speculative draft-verify round over the "
+        "active lanes (attrs proposed, accepted)",
+    "sched.step":
+        "Loop span: one whole ContinuousBatchingScheduler.step "
+        "(attr tick, the tick counter at entry)",
+    "sched.tick_dispatch":
+        "Loop span: the decode tick's dispatch; attrs are the tick "
+        "record (lanes_decoding, lanes_prefilling, lanes_free, "
+        "queue_depth, context_sum, context_max)",
+    "sched.tick_sync":
+        "Loop span: reading the previous tick's tokens, appending "
+        "them and retiring the finished (attrs overlapped, tokens, "
+        "retired)",
     "serving.admission":
         "Queue-head pop to prefill schedule: slot+block admission, "
         "swap restore credit, prefix-cache match",
@@ -110,6 +159,12 @@ SPAN_CATALOG: Dict[str, str] = {
     "serving.spec_round":
         "One speculative draft-verify round's share of a lane "
         "(attrs carry proposed/accepted)",
+    "train.shard_batch":
+        "Loop span: a host batch placed onto the mesh "
+        "(parallel.shard_batch)",
+    "train.step":
+        "Loop span: one train step's dispatch (step_bracket; a "
+        "StepTraceAnnotation, attr step)",
     "transfer.export":
         "KV-block export from the source pool into a host "
         "BlockTransfer (chain digests stamped)",
@@ -167,13 +222,6 @@ new_trace_id = mint_trace_id
 def new_span_id() -> str:
     """8 hex chars; unique within one trace."""
     return binascii.hexlify(os.urandom(4)).decode()
-
-
-def span_args(trace_id: str, **extra) -> dict:
-    """The Timeline span ``args`` payload for a traced request."""
-    out = {"trace_id": trace_id}
-    out.update(extra)
-    return out
 
 
 def span_table_md() -> str:
@@ -490,6 +538,97 @@ def trace(trace_id: str) -> Optional[List[Dict]]:
 
 def tail(n: int = 200) -> List[Dict]:
     return get().tail(n)
+
+
+# ---------------------------------------------------------------------------
+# Loop spans: the serving loop and the train step, on the profiler's clock
+# ---------------------------------------------------------------------------
+
+# Records the loop ring keeps. A serving step leaves six or seven
+# (step, housekeeping, prefill chunk, tick dispatch, tick sync,
+# bookkeeping, now and then an admission), so at 15-50 steps a second
+# this is 10 to 5 minutes - long enough for a reader that runs a
+# minute or two after the window it asks about.
+LOOP_RING = 32768
+
+_LOOP: collections.deque = collections.deque(maxlen=LOOP_RING)
+_LOOP_SEQ = itertools.count(1)
+_LOOP_TLS = threading.local()
+
+
+class loop_span:
+    """``with spans.loop_span("sched.step", tick=n):`` - one span of a
+    loop, opened and closed at one call site on one thread.
+
+    Entering opens a ``jax.profiler.TraceAnnotation(name, **attrs)``
+    (an atomic load when no profiler session runs; with ``step_num``
+    a ``StepTraceAnnotation``, which the profiler's step analysis
+    reads) and stamps ``t0_ns``; leaving closes it, stamps ``t1_ns``
+    and appends ``(seq, name, t0_ns, t1_ns, parent, attrs)`` to the
+    loop ring - one deque append, no lock. ``parent`` is the ``seq``
+    of the enclosing loop span of this thread (0 at the top). `set`
+    adds attrs known only at the end. Keep ``name`` a literal from
+    `SPAN_CATALOG` (hvdlint HVD012)."""
+
+    __slots__ = ("name", "attrs", "seq", "parent", "t0_ns", "t1_ns",
+                 "_ann")
+
+    def __init__(self, name: str, *, step_num: Optional[int] = None,
+                 **attrs):
+        self.name = name
+        if step_num is None:
+            self._ann = TraceAnnotation(name, **attrs)
+        else:
+            self._ann = StepTraceAnnotation(name, step_num=step_num,
+                                            **attrs)
+            attrs["step"] = step_num
+        self.attrs = attrs
+        self.t1_ns = 0
+
+    def __enter__(self):
+        try:
+            stack = _LOOP_TLS.stack
+        except AttributeError:
+            stack = _LOOP_TLS.stack = []
+        self.parent = stack[-1] if stack else 0
+        self.seq = next(_LOOP_SEQ)
+        stack.append(self.seq)
+        self.t0_ns = time.time_ns()
+        self._ann.__enter__()
+        return self
+
+    def set(self, **attrs):
+        """Attach attrs to the open span (ring and profiler both)."""
+        self.attrs.update(attrs)
+        self._ann.set_metadata(**attrs)
+
+    def __exit__(self, exc_type, exc, tb):
+        self._ann.__exit__(exc_type, exc, tb)
+        self.t1_ns = time.time_ns()
+        _LOOP_TLS.stack.pop()
+        _LOOP.append((self.seq, self.name, self.t0_ns, self.t1_ns,
+                      self.parent, self.attrs))
+        return False
+
+
+def loop_tail(n: Optional[int] = None, *,
+              name: Optional[str] = None) -> List[Dict]:
+    """The newest ``n`` completed loop spans (all of the ring by
+    default), oldest first, optionally only those called ``name``:
+    ``{"seq", "name", "t0_ns", "t1_ns", "parent", "attrs"}``. A span
+    is appended when it closes, so children precede their parent."""
+    while True:
+        try:
+            recs = list(_LOOP)
+            break
+        except RuntimeError:    # an append raced the copy: again
+            continue
+    if name is not None:
+        recs = [r for r in recs if r[1] == name]
+    if n is not None:
+        recs = recs[-n:] if n > 0 else []
+    return [{"seq": r[0], "name": r[1], "t0_ns": r[2], "t1_ns": r[3],
+             "parent": r[4], "attrs": dict(r[5])} for r in recs]
 
 
 # ---------------------------------------------------------------------------

@@ -23,6 +23,8 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from horovod_tpu.obs import spans as _spans
+
 AXIS_DATA = "data"
 AXIS_SEQ = "seq"
 AXIS_MODEL = "model"
@@ -225,7 +227,8 @@ def shard_batch(mesh: Mesh, batch,
     # Single-axis: pass the bare name, the form spec introspection
     # everywhere else compares against.
     sh = sharding(mesh, axes[0] if len(axes) == 1 else tuple(axes))
-    return jax.tree.map(lambda x: _place(x, sh), batch)
+    with _spans.loop_span("train.shard_batch"):
+        return jax.tree.map(lambda x: _place(x, sh), batch)
 
 
 def replicate(mesh: Mesh, tree):

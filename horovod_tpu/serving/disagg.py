@@ -60,7 +60,6 @@ from horovod_tpu.obs import catalog as _obs_catalog
 from horovod_tpu.obs import events as _events
 from horovod_tpu.obs import reqlog as _reqlog
 from horovod_tpu.obs import spans as _spans
-from horovod_tpu.obs import tracing as _tracing
 from horovod_tpu.resilience import detector as _detector
 from horovod_tpu.serving.admission import (
     DeadlineExceededError, EngineClosedError, QueueFullError,
@@ -295,7 +294,7 @@ class DisaggRouter(ServingRouter):
             next(self._req_ids), prompt, max_new_tokens,
             temperature=temperature, top_p=top_p, seed=seed,
             deadline=None if timeout_s is None else now + timeout_s,
-            trace_id=_tracing.new_trace_id(), t_submit=now,
+            trace_id=_spans.new_trace_id(), t_submit=now,
             priority=priority, tenant=tenant)
         rr._disagg = True
         rr._transfer = None

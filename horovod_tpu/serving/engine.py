@@ -27,9 +27,10 @@ Usage::
         out = h.result(timeout=30)        # CompletedRequest
         print(out.tokens, out.finish_reason, out.ttft_s)
 
-With ``HOROVOD_TIMELINE`` set (or `start_timeline`), every request
-renders as its own trace process with QUEUE → PREFILL → DECODE spans
-in chrome://tracing.
+Every request leaves a span tree in `horovod_tpu.obs.spans`
+(queued → admission → prefill → decode); the dispatch loop and the
+scheduler's phases leave loop spans there, mirrored into the JAX
+profiler while a session runs (docs/observability.md).
 """
 
 from __future__ import annotations
@@ -51,7 +52,6 @@ from horovod_tpu.obs import events as _events
 from horovod_tpu.obs import flightrec as _flightrec
 from horovod_tpu.obs import reqlog as _reqlog
 from horovod_tpu.obs import spans as _spans
-from horovod_tpu.obs import tracing as _tracing
 from horovod_tpu.obs.registry import registry as _obs_registry
 from horovod_tpu.resilience import chaos
 from horovod_tpu.models.transformer import TransformerLM
@@ -61,7 +61,7 @@ from horovod_tpu.serving.admission import (
 )
 from horovod_tpu.serving.metrics import EngineMetrics
 from horovod_tpu.serving.scheduler import (
-    CompletedRequest, ContinuousBatchingScheduler, _span,
+    CompletedRequest, ContinuousBatchingScheduler,
 )
 from horovod_tpu.serving.slots import SlotPool
 from horovod_tpu.utils.stall import StallMonitor
@@ -156,7 +156,7 @@ class RequestHandle:
     @property
     def trace_id(self) -> str:
         """The request's observability id — the key into the event
-        log, the Timeline span args, and the histogram exemplars
+        log, the span recorder's tree, and the histogram exemplars
         (docs/observability.md); survives watchdog-restart requeues."""
         return self._req.trace_id
 
@@ -745,7 +745,7 @@ class ServingEngine:
             max_new_tokens=max_new_tokens, sampling=sampling,
             deadline=None if timeout_s is None else now + timeout_s,
             future=Future(),
-            trace_id=trace_id or _tracing.new_trace_id(),
+            trace_id=trace_id or _spans.new_trace_id(),
             t_submit=now, forced=forced, tokens=list(forced),
             parent_span=parent_span,
             priority=int(priority), tenant=str(tenant))
@@ -768,7 +768,6 @@ class ServingEngine:
                 self.brownout.touch(req.tenant)
             self._obs_tenant["requests"].inc(tenant=req.tenant,
                                              outcome="submitted")
-        _span("begin_span", req.id, "QUEUE", trace_id=req.trace_id)
         req.span_ids["queued"] = _spans.begin_span(
             "serving.queued", trace_id=req.trace_id,
             parent_id=req.parent_span or req.span_ids.get("root", ""),
@@ -781,7 +780,6 @@ class ServingEngine:
             if req.tenant:
                 self._obs_tenant["requests"].inc(tenant=req.tenant,
                                                  outcome="shed")
-            _span("end_span", req.id, "QUEUE")
             _spans.end_span(req.span_ids.pop("queued", ""),
                             status="shed")
             _spans.end_span(req.span_ids.pop("root", ""),
@@ -791,7 +789,6 @@ class ServingEngine:
                          queue_depth=len(self.queue))
             raise
         except EngineClosedError:
-            _span("end_span", req.id, "QUEUE")
             _spans.end_span(req.span_ids.pop("queued", ""),
                             status="closed")
             _spans.end_span(req.span_ids.pop("root", ""),
@@ -835,33 +832,35 @@ class ServingEngine:
                         "injected serving dispatch-thread crash "
                         "(site serving_dispatch_crash)")
                 progressed = scheduler.step()
-                with self._lock:
-                    if self._epoch != epoch:
-                        return   # superseded by a watchdog restart
-                    closing, drain = self._closing, self._drain
-                    # Heartbeat only AFTER the epoch check (a
-                    # superseded thread limping out of a hung call
-                    # must not refresh the live generation's stuck
-                    # timer), and under the lock — the watchdog reads
-                    # it against tick_deadline_s (hvdlint HVD004).
-                    self._heartbeat = time.time()
-                self.metrics.observe_gauges(
-                    len(queue), scheduler.pool.busy_slots,
-                    scheduler.pool.num_slots)
-                if self.paged:
-                    self.metrics.observe_kv(
-                        scheduler.pool.kv_stats())
-                # Brownout control loop: evaluated here on the
-                # dispatch thread (internally rate-limited) so the
-                # ladder's teeth — spec-k caps, tenant preemption
-                # mailbox — touch pool state only where jax work is
-                # allowed to happen.
-                if self.brownout is not None:
-                    self.brownout.step()
-                if (self._overload is not None
-                        and self._overload.swap is not None):
-                    self.metrics.observe_swap_store(
-                        self._overload.swap.stats())
+                with _spans.loop_span("engine.bookkeeping"):
+                    with self._lock:
+                        if self._epoch != epoch:
+                            return   # superseded by a watchdog restart
+                        closing, drain = self._closing, self._drain
+                        # Heartbeat only AFTER the epoch check (a
+                        # superseded thread limping out of a hung
+                        # call must not refresh the live generation's
+                        # stuck timer), and under the lock — the
+                        # watchdog reads it against tick_deadline_s
+                        # (hvdlint HVD004).
+                        self._heartbeat = time.time()
+                    self.metrics.observe_gauges(
+                        len(queue), scheduler.pool.busy_slots,
+                        scheduler.pool.num_slots)
+                    if self.paged:
+                        self.metrics.observe_kv(
+                            scheduler.pool.kv_stats())
+                    # Brownout control loop: evaluated here on the
+                    # dispatch thread (internally rate-limited) so the
+                    # ladder's teeth — spec-k caps, tenant preemption
+                    # mailbox — touch pool state only where jax work
+                    # is allowed to happen.
+                    if self.brownout is not None:
+                        self.brownout.step()
+                    if (self._overload is not None
+                            and self._overload.swap is not None):
+                        self.metrics.observe_swap_store(
+                            self._overload.swap.stats())
                 if closing:
                     if not drain:
                         scheduler.abort_active()
@@ -871,7 +870,8 @@ class ServingEngine:
                         return
                     continue
                 if not progressed and not scheduler.has_active():
-                    queue.wait(_IDLE_WAIT_S)
+                    with _spans.loop_span("engine.idle_wait"):
+                        queue.wait(_IDLE_WAIT_S)
         # hvd: disable=HVD006(THE containment boundary: any dispatch-thread fault must fail the in-flight futures, never leave callers hanging)
         except BaseException as e:  # noqa: BLE001 — fail futures, not hang
             # A dispatch-thread fault (a poison request, a compile

@@ -151,6 +151,16 @@ class EngineMetrics:
         # ~1 toward ~1/request.
         self.host_syncs = 0
         self.ticks_overlapped = 0
+        # The tick record, accumulated (scheduler `_tick_record`):
+        # at each decode tick's dispatch, how many lanes decoded, how
+        # many held a request still waiting for its first token, how
+        # many stood free, and the cached positions (prompt + emitted)
+        # of the decoding lanes. Over `ticks` they say why a lane did
+        # not decode, and what the ticks were asked to walk.
+        self.lane_ticks_decoding = 0
+        self.lane_ticks_prefilling = 0
+        self.lane_ticks_free = 0
+        self.tick_context_positions = 0
         # Self-healing counters (engine watchdog, docs/resilience.md).
         self.restarts = 0          # in-place engine restarts
         self.requeued = 0          # in-flight requests replayed
@@ -250,6 +260,15 @@ class EngineMetrics:
             self._obs_pre["tokens"].inc(n, kind="swapped_in")
         elif name == "preempt_swap_bytes":
             self._obs_pre["swap_bytes"].inc(n)
+
+    def observe_tick(self, tick: Dict[str, int]):
+        """One decode tick's record (the scheduler's `_tick_record`)
+        into the four lane/context counters: one lock, once a tick."""
+        with self._lock:
+            self.lane_ticks_decoding += tick["lanes_decoding"]
+            self.lane_ticks_prefilling += tick["lanes_prefilling"]
+            self.lane_ticks_free += tick["lanes_free"]
+            self.tick_context_positions += tick["context_sum"]
 
     def observe_admission(self, admitted: bool, *, tenant: str = ""):
         """One admission decision into the SLO shed-rate objective
@@ -430,6 +449,10 @@ class EngineMetrics:
                 "ticks": self.ticks,
                 "ticks_overlapped": self.ticks_overlapped,
                 "host_syncs": self.host_syncs,
+                "lane_ticks_decoding": self.lane_ticks_decoding,
+                "lane_ticks_prefilling": self.lane_ticks_prefilling,
+                "lane_ticks_free": self.lane_ticks_free,
+                "tick_context_positions": self.tick_context_positions,
                 "host_syncs_per_token": (
                     round(self.host_syncs / self.tokens_out, 4)
                     if self.tokens_out else None),

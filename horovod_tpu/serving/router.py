@@ -85,7 +85,6 @@ from horovod_tpu.obs import events as _events
 from horovod_tpu.obs import flightrec as _flightrec
 from horovod_tpu.obs import reqlog as _reqlog
 from horovod_tpu.obs import spans as _spans
-from horovod_tpu.obs import tracing as _tracing
 from horovod_tpu.resilience import chaos
 from horovod_tpu.resilience import detector as _detector
 from horovod_tpu.serving.admission import (
@@ -475,7 +474,7 @@ class ServingRouter:
             next(self._req_ids), prompt, max_new_tokens,
             temperature=temperature, top_p=top_p, seed=seed,
             deadline=None if timeout_s is None else now + timeout_s,
-            trace_id=_tracing.new_trace_id(), t_submit=now,
+            trace_id=_spans.new_trace_id(), t_submit=now,
             priority=priority, tenant=tenant)
         # The trace was minted HERE, so this is the client entry: mint
         # the causal root span (attempts, hedges and migration gaps

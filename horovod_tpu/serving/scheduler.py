@@ -116,32 +116,6 @@ class _PendingTick:
     snapshot: Dict[int, Request] = field(default_factory=dict)
 
 
-def _timeline():
-    """The process-global Horovod timeline, or None (spans are then
-    no-ops) — the same handle `utils.timeline.step_bracket` reads."""
-    try:
-        from horovod_tpu.runtime import state as _state
-        return _state.global_state().timeline
-    except (ImportError, AttributeError):
-        return None   # interpreter teardown / pre-init introspection
-
-
-def _span(method: str, request_id: int, name: str,
-          trace_id: str = ""):
-    """Emit a request-span Timeline verb; begin_span additionally
-    stamps the request's ``trace_id`` into the span ``args`` (the
-    Timeline leg of request tracing — one id follows the request
-    across QUEUE/PREFILL/DECODE and engine restarts)."""
-    tl = _timeline()
-    if tl is None:
-        return
-    if method == "begin_span" and trace_id:
-        tl.begin_span(f"request:{request_id}", name,
-                      args={"trace_id": trace_id})
-    else:
-        getattr(tl, method)(f"request:{request_id}", name)
-
-
 # Distinguishes stall-bracket names across scheduler generations: a
 # superseded thread's finally-end() must never cancel the successor's
 # identically-numbered pending tick (both count from shared metrics).
@@ -270,24 +244,34 @@ class ContinuousBatchingScheduler:
         hvdlint HVD001 for stray host syncs (docs/analysis.md)."""
         if self.abandoned:
             return False
-        now = time.time() if now is None else now
-        if chaos.fires("serving_deadline_storm"):
-            # Every queued deadline collapses at once — the sweep
-            # below must fail them all in one tick, never hang.
-            self.metrics.count("faults_injected")
-            self.queue.force_expire(now)
-        # Dead queued requests (cancelled / deadline-expired) resolve
-        # NOW, slot or no slot — with every slot busy, admission below
-        # never pops the queue, and a 100 ms deadline must not wait
-        # minutes for a slot to free.
-        self.queue.sweep(now, on_drop=self._queue_drop)
-        # Dead MID-PREFILL requests release their reserved blocks NOW
-        # too — a cancelled/hedge-lost prefill must not sit on
-        # reserved-but-unfilled blocks until the chunk loop next picks
-        # it (which, budget-starved, could be many steps away).
-        self._sweep_dead_prefills(now)
-        self._drain_tenant_preempts(now)
-        self._drain_grafts()
+        # The loop spans (obs/spans.py) sit where the work happens:
+        # this one is the whole iteration, its children the phases;
+        # under a profiler session they are rows beside the device's
+        # ops.
+        with _spans.loop_span("sched.step", tick=self.metrics.ticks):
+            return self._step(time.time() if now is None else now)
+
+    @hot_path
+    def _step(self, now: float) -> bool:
+        with _spans.loop_span("sched.housekeeping"):
+            if chaos.fires("serving_deadline_storm"):
+                # Every queued deadline collapses at once — the sweep
+                # below must fail them all in one tick, never hang.
+                self.metrics.count("faults_injected")
+                self.queue.force_expire(now)
+            # Dead queued requests (cancelled / deadline-expired)
+            # resolve NOW, slot or no slot — with every slot busy,
+            # admission below never pops the queue, and a 100 ms
+            # deadline must not wait minutes for a slot to free.
+            self.queue.sweep(now, on_drop=self._queue_drop)
+            # Dead MID-PREFILL requests release their reserved blocks
+            # NOW too — a cancelled/hedge-lost prefill must not sit on
+            # reserved-but-unfilled blocks until the chunk loop next
+            # picks it (which, budget-starved, could be many steps
+            # away).
+            self._sweep_dead_prefills(now)
+            self._drain_tenant_preempts(now)
+            self._drain_grafts()
         progressed = self._advance_prefills(now)
         # Watermark admission's collection point: reservations are
         # optimistic (BlockPool watermark), so every ticking lane's
@@ -328,7 +312,10 @@ class ContinuousBatchingScheduler:
                         "serving_tick_stall", 1.0)
                     while time.time() < t_end and not self.abandoned:
                         time.sleep(0.005)
-                handle = self.pool.tick_dispatch()
+                tick = self._tick_record()
+                self.metrics.observe_tick(tick)
+                with _spans.loop_span("sched.tick_dispatch", **tick):
+                    handle = self.pool.tick_dispatch()
             finally:
                 if self.stall is not None:
                     self.stall.end(tick_name)
@@ -348,6 +335,24 @@ class ContinuousBatchingScheduler:
                 self._sync_pending(overlapped=False)
         return progressed
 
+    def _tick_record(self) -> Dict[str, int]:
+        """What this tick is asked to do, from the scheduler's own
+        books (O(lanes), no device read): the lanes that decode, the
+        lanes a request holds without a first token yet, the free
+        ones, the queue behind them, and the cached positions (prompt
+        + emitted) the decoding lanes bring. The `sched.tick_dispatch`
+        span carries it; the engine's counters accumulate it."""
+        contexts = [len(r.prompt) + len(r.tokens)
+                    for r in self.active.values()]
+        decoding, prefilling = len(contexts), len(self.prefilling)
+        return {"lanes_decoding": decoding,
+                "lanes_prefilling": prefilling,
+                "lanes_free": max(
+                    0, self.pool.num_slots - decoding - prefilling),
+                "queue_depth": len(self.queue),
+                "context_sum": sum(contexts),
+                "context_max": max(contexts, default=0)}
+
     @hot_path
     def _spec_round(self):
         """One speculative draft-verify round over the active lanes:
@@ -360,35 +365,40 @@ class ContinuousBatchingScheduler:
         round retired."""
         tick_name = (f"serving_spec_{self._gen}."
                      f"{self.metrics.ticks}")
-        t_round0 = time.time()
-        if self.stall is not None:
-            self.stall.begin(tick_name)
-        try:
-            if chaos.fires("serving_tick_stall"):
-                # Same cooperative hung-tick injection as the tick
-                # path (watchdog food; ends early once abandoned).
-                self.metrics.count("faults_injected")
-                t_end = time.time() + chaos.delay_of(
-                    "serving_tick_stall", 1.0)
-                while time.time() < t_end and not self.abandoned:
-                    time.sleep(0.005)
-            emitted, counts, proposed = self.pool.spec_round()
-        finally:
+        with _spans.loop_span("sched.spec_round") as round_span:
             if self.stall is not None:
-                self.stall.end(tick_name)
-        round_dur = time.time() - t_round0
+                self.stall.begin(tick_name)
+            try:
+                if chaos.fires("serving_tick_stall"):
+                    # Same cooperative hung-tick injection as the tick
+                    # path (watchdog food; ends early once abandoned).
+                    self.metrics.count("faults_injected")
+                    t_end = time.time() + chaos.delay_of(
+                        "serving_tick_stall", 1.0)
+                    while time.time() < t_end and not self.abandoned:
+                        time.sleep(0.005)
+                emitted, counts, proposed = self.pool.spec_round()
+            finally:
+                if self.stall is not None:
+                    self.stall.end(tick_name)
+            prop = sum(int(proposed[slot]) for slot in self.active)
+            accepted = sum(max(0, int(counts[slot]) - 1)
+                           for slot in self.active
+                           if int(proposed[slot]) > 0)
+            round_span.set(proposed=prop, accepted=accepted)
+        # Each lane's share of the round is recorded into its
+        # request's tree with the round's own extent.
+        t_round0 = round_span.t0_ns * 1e-9
+        round_dur = (round_span.t1_ns - round_span.t0_ns) * 1e-9
         self.metrics.count("ticks")
         self.metrics.count("spec_rounds")
         self.metrics.count("host_syncs")
         if self.abandoned:
             return   # successor replays from prompts; drop the round
-        accepted = prop = 0
         multi = False
         for slot, req in list(self.active.items()):
             n = int(counts[slot])
             if int(proposed[slot]) > 0:
-                prop += int(proposed[slot])
-                accepted += max(0, n - 1)
                 _spans.record_span(
                     "serving.spec_round", trace_id=req.trace_id,
                     parent_id=req.span_ids.get("decode", ""),
@@ -417,6 +427,13 @@ class ContinuousBatchingScheduler:
         finished. ``overlapped`` records whether newer device work was
         already queued behind the read (the metric the tentpole
         moves: exposed host syncs per token)."""
+        with _spans.loop_span("sched.tick_sync",
+                              overlapped=overlapped) as sync_span:
+            tokens, retired = self._sync_tick(overlapped)
+            sync_span.set(tokens=tokens, retired=retired)
+
+    def _sync_tick(self, overlapped: bool):
+        """`_sync_pending`'s work; (tokens appended, lanes retired)."""
         # hvd: disable=HVD004(dispatch-thread-owned ring slot; a racing abandon() clears it too, and the snapshot re-check below tolerates that)
         pending, self._pending = self._pending, None
         sync_name = f"serving_sync_{self._gen}.{self.metrics.ticks}"
@@ -433,15 +450,19 @@ class ContinuousBatchingScheduler:
             # Superseded mid-pipeline: the successor owns these
             # requests now — appending this tick's tokens would
             # corrupt their replay-from-prompt.
-            return
+            return 0, 0
         t_tick = time.time()
+        tokens = retired = 0
         for slot, req in pending.snapshot.items():
             if self.active.get(slot) is not req:
                 continue   # retired (or slot re-assigned) since dispatch
             tok = int(toks[slot])
             req.tokens.append(tok)
+            tokens += 1
             self.metrics.count("tokens_out")
             self._maybe_retire(slot, req, tok, t_tick)
+            retired += self.active.get(slot) is not req
+        return tokens, retired
 
     # -- admission / chunked prefill ----------------------------------
 
@@ -495,108 +516,18 @@ class ContinuousBatchingScheduler:
                     if not self._try_preempt_for(head, now):
                         break
                     continue
-                req = self.queue.pop_ready(now, on_drop=self._queue_drop)
-                if req is None:
+                with _spans.loop_span("sched.admit") as adm_span:
+                    req = self.queue.pop_ready(
+                        now, on_drop=self._queue_drop)
+                    admitted = (None if req is None
+                                else self._admit(req))
+                    if admitted is not None:
+                        slot, job = admitted
+                        adm_span.set(slot=slot,
+                                     prompt_tokens=len(job.prompt),
+                                     prefix_cached=job.off)
+                if admitted is None:
                     break
-                # Causal spans: the queue wait (and any preemption
-                # pause) ends the moment the head is popped for
-                # admission; the admit/pin/reserve work is its own
-                # phase span.
-                _spans.end_span(req.span_ids.pop("queued", ""),
-                                status="admitted")
-                _spans.end_span(req.span_ids.pop("paused", ""),
-                                status="resumed")
-                adm_sid = _spans.begin_span(
-                    "serving.admission", trace_id=req.trace_id,
-                    parent_id=req.parent_span
-                    or req.span_ids.get("root", ""))
-                # Registration is the handoff-critical line: between
-                # pop_ready above and the prefilling registration the
-                # request is in neither the queue nor a scheduler dict,
-                # so a watchdog abandon landing in that window would
-                # strand its future. The lock forces an order: either
-                # the registration happens before the snapshot (the
-                # successor requeues it) or the abandon is visible here
-                # (we hand it straight back to the queue).
-                blocked = None
-                # The prefill stream: prompt plus any forced
-                # continuation prefix (token-exact migration) — the
-                # prefix matcher and the chunk schedule both see it.
-                full = req.full_prompt
-                with self._handoff:
-                    if self.abandoned:
-                        blocked = req
-                    else:
-                        # admit() pins matched prefix blocks and
-                        # reserves the rest; None only if the popped
-                        # request differs from the peeked head (a
-                        # cancel raced in between) AND doesn't fit.
-                        adm = self.pool.admit(full, req.remaining_new)
-                        if adm is None:
-                            blocked = req
-                        else:
-                            slot = adm.slot
-                            job = _PrefillJob(
-                                req=req, prompt=full,
-                                chunks=prefill_schedule(
-                                    int(full.shape[0])
-                                    - adm.skipped, self._max_chunk),
-                                off=adm.skipped)
-                            self.prefilling[slot] = job
-                            self._prefill_order.append(slot)
-                if blocked is not None:
-                    _spans.end_span(adm_sid, status="blocked")
-                    blocked.span_ids["queued"] = _spans.begin_span(
-                        "serving.queued",
-                        trace_id=blocked.trace_id,
-                        parent_id=blocked.parent_span
-                        or blocked.span_ids.get("root", ""),
-                        requeued=True)
-                    self.queue.requeue([blocked])
-                    break
-                req.prefix_cached = adm.skipped
-                if (self._ov is not None
-                        and self._ov.swap is not None
-                        and self._ov.swap.discard(req.id)):
-                    # A swap-preempted stream just resumed: its shelf
-                    # entry is spent. Credit the tokens the shelved
-                    # blocks served vs the sub-block tail that must
-                    # re-prefill anyway.
-                    self.metrics.count("preempt_tokens_swapped_in",
-                                       adm.skipped)
-                    tail = int(full.shape[0]) - adm.skipped
-                    if tail > 0:
-                        self.metrics.count(
-                            "preempt_tokens_recomputed", tail)
-                if adm.queried_blocks:
-                    self.metrics.count("prefix_hits",
-                                       adm.matched_blocks)
-                    self.metrics.count(
-                        "prefix_misses",
-                        adm.queried_blocks - adm.matched_blocks)
-                if adm.skipped:
-                    # The TTFT the cache just deleted: these prompt
-                    # tokens never touch a prefill chunk.
-                    self.metrics.count("prefill_tokens_skipped",
-                                       adm.skipped)
-                self.metrics.observe_peak(len(self.active)
-                                          + len(self.prefilling))
-                req.t_prefill = time.time()
-                _spans.end_span(adm_sid, prefix_cached=adm.skipped)
-                req.span_ids["prefill"] = _spans.begin_span(
-                    "serving.prefill", trace_id=req.trace_id,
-                    parent_id=req.parent_span
-                    or req.span_ids.get("root", ""),
-                    prompt_tokens=int(full.shape[0]),
-                    prefix_cached=adm.skipped)
-                _span("end_span", req.id, "QUEUE")
-                _span("begin_span", req.id, "PREFILL",
-                      trace_id=req.trace_id)
-                # Registered BEFORE any device work so a fault inside
-                # it (compile failure, OOM) leaves the request findable
-                # by the engine's crash containment — never a future
-                # in limbo.
-                self.pool.begin_prefill(slot)
                 progressed = True
             # Drop dead jobs before paying more device work for them.
             if job.req.cancelled or job.req.expired(now):
@@ -608,15 +539,19 @@ class ContinuousBatchingScheduler:
             while job.chunks and (left is None
                                   or job.chunks[0] <= left):
                 c = job.chunks.pop(0)
-                csid = _spans.begin_span(
-                    "serving.prefill_chunk",
-                    trace_id=job.req.trace_id,
-                    parent_id=job.req.span_ids.get("prefill", ""),
-                    tokens=c, off=job.off)
-                job.logits = self.pool.prefill_chunk(
-                    slot, job.prompt[job.off:job.off + c])
-                job.off += c
-                _spans.end_span(csid)
+                # One site, both records: the chunk in its request's
+                # tree and in the loop's.
+                with _spans.loop_span("sched.prefill_chunk",
+                                      slot=slot, tokens=c):
+                    csid = _spans.begin_span(
+                        "serving.prefill_chunk",
+                        trace_id=job.req.trace_id,
+                        parent_id=job.req.span_ids.get("prefill", ""),
+                        tokens=c, off=job.off)
+                    job.logits = self.pool.prefill_chunk(
+                        slot, job.prompt[job.off:job.off + c])
+                    job.off += c
+                    _spans.end_span(csid)
                 self.metrics.count("prefill_chunks")
                 self.metrics.count("prefill_tokens", c)
                 if left is not None:
@@ -624,11 +559,116 @@ class ContinuousBatchingScheduler:
                 progressed = True
             if job.chunks:
                 break    # budget spent mid-prompt; resume next step
-            self._finish_prefill(slot, job)
+            with _spans.loop_span("sched.first_token", slot=slot):
+                self._finish_prefill(slot, job)
             progressed = True
             if left is not None and left <= 0:
                 break
         return progressed
+
+    def _admit(self, req: Request):
+        """Give a popped request its lane: pin the prefix blocks it
+        matched, reserve the rest, register the prefill job and reset
+        the slot. Returns ``(slot, job)``, or None when the request
+        went back to the queue (an abandon or a cancel raced the
+        pop)."""
+        # Causal spans: the queue wait (and any preemption
+        # pause) ends the moment the head is popped for
+        # admission; the admit/pin/reserve work is its own
+        # phase span.
+        _spans.end_span(req.span_ids.pop("queued", ""),
+                        status="admitted")
+        _spans.end_span(req.span_ids.pop("paused", ""),
+                        status="resumed")
+        adm_sid = _spans.begin_span(
+            "serving.admission", trace_id=req.trace_id,
+            parent_id=req.parent_span
+            or req.span_ids.get("root", ""))
+        # Registration is the handoff-critical line: between
+        # pop_ready above and the prefilling registration the
+        # request is in neither the queue nor a scheduler dict,
+        # so a watchdog abandon landing in that window would
+        # strand its future. The lock forces an order: either
+        # the registration happens before the snapshot (the
+        # successor requeues it) or the abandon is visible here
+        # (we hand it straight back to the queue).
+        blocked = None
+        # The prefill stream: prompt plus any forced
+        # continuation prefix (token-exact migration) — the
+        # prefix matcher and the chunk schedule both see it.
+        full = req.full_prompt
+        with self._handoff:
+            if self.abandoned:
+                blocked = req
+            else:
+                # admit() pins matched prefix blocks and
+                # reserves the rest; None only if the popped
+                # request differs from the peeked head (a
+                # cancel raced in between) AND doesn't fit.
+                adm = self.pool.admit(full, req.remaining_new)
+                if adm is None:
+                    blocked = req
+                else:
+                    slot = adm.slot
+                    job = _PrefillJob(
+                        req=req, prompt=full,
+                        chunks=prefill_schedule(
+                            int(full.shape[0])
+                            - adm.skipped, self._max_chunk),
+                        off=adm.skipped)
+                    self.prefilling[slot] = job
+                    self._prefill_order.append(slot)
+        if blocked is not None:
+            _spans.end_span(adm_sid, status="blocked")
+            blocked.span_ids["queued"] = _spans.begin_span(
+                "serving.queued",
+                trace_id=blocked.trace_id,
+                parent_id=blocked.parent_span
+                or blocked.span_ids.get("root", ""),
+                requeued=True)
+            self.queue.requeue([blocked])
+            return None
+        req.prefix_cached = adm.skipped
+        if (self._ov is not None
+                and self._ov.swap is not None
+                and self._ov.swap.discard(req.id)):
+            # A swap-preempted stream just resumed: its shelf
+            # entry is spent. Credit the tokens the shelved
+            # blocks served vs the sub-block tail that must
+            # re-prefill anyway.
+            self.metrics.count("preempt_tokens_swapped_in",
+                               adm.skipped)
+            tail = int(full.shape[0]) - adm.skipped
+            if tail > 0:
+                self.metrics.count(
+                    "preempt_tokens_recomputed", tail)
+        if adm.queried_blocks:
+            self.metrics.count("prefix_hits",
+                               adm.matched_blocks)
+            self.metrics.count(
+                "prefix_misses",
+                adm.queried_blocks - adm.matched_blocks)
+        if adm.skipped:
+            # The TTFT the cache just deleted: these prompt
+            # tokens never touch a prefill chunk.
+            self.metrics.count("prefill_tokens_skipped",
+                               adm.skipped)
+        self.metrics.observe_peak(len(self.active)
+                                  + len(self.prefilling))
+        req.t_prefill = time.time()
+        _spans.end_span(adm_sid, prefix_cached=adm.skipped)
+        req.span_ids["prefill"] = _spans.begin_span(
+            "serving.prefill", trace_id=req.trace_id,
+            parent_id=req.parent_span
+            or req.span_ids.get("root", ""),
+            prompt_tokens=int(full.shape[0]),
+            prefix_cached=adm.skipped)
+        # Registered BEFORE any device work so a fault inside
+        # it (compile failure, OOM) leaves the request findable
+        # by the engine's crash containment — never a future
+        # in limbo.
+        self.pool.begin_prefill(slot)
+        return slot, job
 
     # -- preemption (the overload control plane) ----------------------
 
@@ -811,7 +851,6 @@ class ContinuousBatchingScheduler:
         self.pool.free(slot)
         # hvd: disable=HVD004(active is dispatch-thread-owned; the handoff lock only orders the container handoff, and abandon() snapshots wholesale)
         self.active.pop(slot, None)
-        _span("end_span", req.id, "DECODE")
         _spans.end_span(req.span_ids.pop("decode", ""),
                         status="preempted", mode=mode)
         # The pause span stays OPEN across the requeue — the resume's
@@ -920,9 +959,6 @@ class ContinuousBatchingScheduler:
         # Sampled by the prefill forward, not a decode tick — the
         # tokens_per_tick metric excludes it.
         self.metrics.count("prefill_first_tokens")
-        _span("end_span", req.id, "PREFILL")
-        _span("begin_span", req.id, "DECODE",
-              trace_id=req.trace_id)
         _spans.end_span(req.span_ids.pop("prefill", ""))
         req.span_ids["decode"] = _spans.begin_span(
             "serving.decode", trace_id=req.trace_id,
@@ -937,15 +973,11 @@ class ContinuousBatchingScheduler:
             self._ov.swap.discard(req.id)
         self.metrics.count("cancelled" if kind == "cancelled"
                            else "timed_out")
-        _span("end_span", req.id, "QUEUE")
         _spans.end_span(req.span_ids.pop("queued", ""),
                         status=kind)
         _spans.end_span(req.span_ids.pop("paused", ""),
                         status=kind)
         _spans.end_span(req.span_ids.pop("root", ""), status=kind)
-        tl = _timeline()
-        if tl is not None:
-            tl.mark(f"request:{req.id}", kind.upper())
         _events.emit("serving.queue_drop", request_id=req.id,
                      trace_id=req.trace_id, reason=kind)
 
@@ -984,7 +1016,6 @@ class ContinuousBatchingScheduler:
         self.pool.free(slot)
         # hvd: disable=HVD004(dispatch-thread-owned retire; abandon() clearing concurrently makes this a benign no-op, tolerated by _resolve)
         self.active.pop(slot, None)
-        _span("end_span", req.id, "DECODE")
         _spans.end_span(req.span_ids.pop("decode", ""),
                         status=reason)
         self._finalize(req, reason, now)
@@ -1004,7 +1035,6 @@ class ContinuousBatchingScheduler:
             self.prefilling.pop(slot, None)
             self._prefill_order.remove(slot)
         self.pool.free(slot)
-        _span("end_span", job.req.id, "PREFILL")
         _spans.end_span(job.req.span_ids.pop("prefill", ""),
                         status=reason)
         self._finalize(job.req, reason, time.time())
@@ -1016,9 +1046,6 @@ class ContinuousBatchingScheduler:
             # stayed resident so the entry was never spent — releases
             # the swap budget here.
             self._ov.swap.discard(req.id)
-        tl = _timeline()
-        if tl is not None:
-            tl.mark(f"request:{req.id}", reason.upper())
         _events.emit("serving.retire", request_id=req.id,
                      trace_id=req.trace_id, reason=reason,
                      tokens=len(req.tokens))

@@ -121,49 +121,6 @@ class Timeline:
                 self._states[tensor] = UNKNOWN
             self._maybe_flush()
 
-    def begin_span(self, process: str, name: str,
-                   args: Optional[dict] = None):
-        """Open a named B span on ``process`` (interned as its own
-        trace pid, like a tensor) — the request-level vocabulary the
-        serving engine emits (QUEUE / PREFILL / DECODE), so every
-        request renders as a distinct trace process in
-        chrome://tracing. Unlike `record` there is no per-tensor state
-        machine: spans pair by name via `end_span` and nest freely.
-        ``args`` lands in the Chrome-trace event's ``args`` payload —
-        the serving engine stamps each request's ``trace_id`` there,
-        so a span in chrome://tracing links to the same request's
-        event-log lines and metric exemplars (docs/observability.md).
-
-        The native C++ writer has no generic-span verb, so spans ride
-        its TOP_LEVEL/DONE tensor lifecycle (one outer process-named
-        bar wrapping each span's activity bar) — same trace, slightly
-        chattier nesting, and ``args`` are dropped (the Python writer
-        is the tracing-fidelity path)."""
-        if self._native is not None:
-            if not self._closed:
-                self._native.timeline_record(process, "TOP_LEVEL", name)
-            return
-        with self._lock:
-            if self._closed:
-                return
-            if args:
-                self._emit("B", name, self._pid(process), args=args)
-            else:
-                self._emit("B", name, self._pid(process))
-            self._maybe_flush()
-
-    def end_span(self, process: str, name: str):
-        """Close the matching `begin_span` (see its doc)."""
-        if self._native is not None:
-            if not self._closed:
-                self._native.timeline_record(process, "DONE", None)
-            return
-        with self._lock:
-            if self._closed:
-                return
-            self._emit("E", name, self._pid(process))
-            self._maybe_flush()
-
     def mark(self, tensor: str, name: str):
         """Instant event (`X`, timeline.cc:78-92)."""
         if self._native is not None:
@@ -251,8 +208,12 @@ def stop_timeline():
 
 
 def step_bracket(fn, name: str = "train_step"):
-    """Wrap a jitted train step so every invocation emits a host-side
-    B/E span on the HOROVOD_TIMELINE trace.
+    """Wrap a jitted train step so every invocation is seen from the
+    host: a ``train.step`` loop span (`obs.spans.loop_span`: the loop
+    ring, and under a `jax.profiler` session a ``StepTraceAnnotation``
+    numbered by this wrapper's own call count, on the same time axis
+    as the device's ops) and, inside it, a B/E span on the
+    HOROVOD_TIMELINE trace.
 
     Under SPMD the per-collective events the reference logs do not
     exist at runtime — collectives are compiled into the XLA program
@@ -260,22 +221,27 @@ def step_bracket(fn, name: str = "train_step"):
     `jax.profiler`, see docs/timeline.md). What the host CAN see, and
     what this bracket records, is the step cadence: dispatch duration,
     gaps between steps (input pipeline stalls), and how eager
-    collectives interleave with the jitted hot path — all in the same
-    Chrome trace. No-op overhead when no timeline is configured.
+    collectives interleave with the jitted hot path. With neither a
+    timeline nor a profiler session it costs one ring append a step.
     """
     import functools
+    import itertools
 
+    from horovod_tpu.obs import spans as _spans
     from horovod_tpu.runtime import state as _state
+
+    calls = itertools.count()
 
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
-        tl = _state.global_state().timeline
-        if tl is None:
-            return fn(*args, **kwargs)
-        tl.record(name, "TOP_LEVEL", "DISPATCH")
-        try:
-            return fn(*args, **kwargs)
-        finally:
-            tl.record(name, "DONE")
+        with _spans.loop_span("train.step", step_num=next(calls)):
+            tl = _state.global_state().timeline
+            if tl is None:
+                return fn(*args, **kwargs)
+            tl.record(name, "TOP_LEVEL", "DISPATCH")
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                tl.record(name, "DONE")
 
     return wrapper

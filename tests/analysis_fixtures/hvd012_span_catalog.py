@@ -24,6 +24,17 @@ def undeclared_direct_fn():
     begin_span("fixture.third_unknown", trace_id="t")           # EXPECT
 
 
+def undeclared_loop_span():
+    with spans.loop_span("fixture.unknown_loop", tick=1):       # EXPECT
+        pass
+
+
+def undeclared_loop_span_direct_fn():
+    from horovod_tpu.obs.spans import loop_span
+    with loop_span("fixture.other_unknown_loop"):               # EXPECT
+        pass
+
+
 def suppressed_prototype():
     # hvd: disable=HVD012(prototype span behind a flag; catalogued before the flag flips on - SUPPRESSED)
     spans.begin_span("fixture.experimental", trace_id="t")
@@ -34,12 +45,18 @@ def declared_ok():
     spans.begin_span("serving.prefill", trace_id="t")
 
 
+def declared_loop_ok():
+    # Clean negative: a loop span the real catalog declares.
+    with spans.loop_span("sched.step", tick=0):
+        pass
+
+
 def dynamic_ok(name):
     # Non-literal name: out of scope for the literal scan.
     spans.begin_span(name, trace_id="t")
 
 
-def timeline_ok(tl):
-    # Clean negative: the Horovod Timeline's begin_span METHOD is
-    # reached through a timeline handle, not a spans-module alias.
-    tl.begin_span("anything.goes")
+def other_handle_ok(rec):
+    # Clean negative: a begin_span METHOD reached through some other
+    # object's handle, not a spans-module alias.
+    rec.begin_span("anything.goes")

@@ -555,6 +555,33 @@ class TestDeadEntryDirections:
         assert active[0].path.endswith("obs/spans.py")
 
 
+    def test_loop_span_literals_are_pinned_both_ways(self, tmp_path):
+        """`loop_span` is a record verb like the others: its literal
+        keeps a catalog entry alive, and an undeclared one is flagged
+        at the call site."""
+        obs = tmp_path / "obs"
+        obs.mkdir()
+        (obs / "spans.py").write_text(textwrap.dedent("""\
+            SPAN_CATALOG = {
+                "mini.loop": "recorded through loop_span",
+            }
+            """))
+        (tmp_path / "consumer.py").write_text(textwrap.dedent("""\
+            from horovod_tpu.obs import spans
+
+
+            def loop():
+                with spans.loop_span("mini.loop", tick=1):
+                    with spans.loop_span("mini.undeclared"):
+                        pass
+            """))
+        files = collect_files([str(tmp_path)], str(tmp_path))
+        active, _ = run_rules(Project(files), [BY_ID["HVD012"]])
+        assert len(active) == 1
+        assert "mini.undeclared" in active[0].message
+        assert active[0].path.endswith("consumer.py")
+
+
 class TestChangedOnly:
     """--changed-only reporting scope: changed files plus their
     one-level importers; full-parse semantics stay (the CLI flag only

@@ -7,7 +7,7 @@ Three layers of proof:
   `/metrics` output (HELP/TYPE lines, label escaping, the histogram
   invariants: cumulative buckets monotonic, +Inf == `_count`).
 * **Cross-subsystem tracing** — one serving request's ``trace_id``
-  must appear in the event log, the Timeline span args, AND the
+  must appear in the event log, the span recorder's tree, AND the
   shared-registry histogram exemplars; and a watchdog-restart requeue
   must carry the ORIGINAL trace_id through recovery (continuity).
 * **Registrants** — the stall monitor, chaos sites, the training step
@@ -28,7 +28,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from horovod_tpu.obs import catalog, events, tracing
+from horovod_tpu.obs import catalog, events, spans
 from horovod_tpu.obs.exporter import MetricsServer, render_prometheus
 from horovod_tpu.obs.registry import (
     DEFAULT_BUCKETS, MetricRegistry, quantile_from_buckets, registry,
@@ -479,29 +479,21 @@ class TestSeries:
 
 class TestTracing:
     def test_trace_id_format(self):
-        a, b = tracing.new_trace_id(), tracing.new_trace_id()
+        a, b = spans.new_trace_id(), spans.new_trace_id()
         assert re.fullmatch(r"[0-9a-f]{16}", a)
         assert a != b
-        assert re.fullmatch(r"[0-9a-f]{8}", tracing.new_span_id())
+        assert re.fullmatch(r"[0-9a-f]{8}", spans.new_span_id())
 
     def test_trace_id_in_three_subsystems(self, lm, event_log,
                                           tmp_path):
         """The acceptance path: ONE request's trace_id recovered from
-        the event log, the Timeline span args, and the registry
+        the event log, the span recorder's tree, and the registry
         histogram exemplar — all for the same request."""
-        from horovod_tpu.runtime import state as _state
         from horovod_tpu.serving import ServingEngine
-        from horovod_tpu.utils.timeline import Timeline
         model, params = lm
-        tl_path = str(tmp_path / "tl.json")
-        _state.global_state().timeline = Timeline(tl_path, native=None)
-        try:
-            with ServingEngine(model, params, num_slots=2) as eng:
-                h = eng.submit(np.array([3, 5, 7]), 6)
-                out = h.result(timeout=300)
-        finally:
-            _state.global_state().timeline.close()
-            _state.global_state().timeline = None
+        with ServingEngine(model, params, num_slots=2) as eng:
+            h = eng.submit(np.array([3, 5, 7]), 6)
+            out = h.result(timeout=300)
         tid = h.trace_id
         assert re.fullmatch(r"[0-9a-f]{16}", tid)
         # 0) the result itself carries it
@@ -510,12 +502,11 @@ class TestTracing:
         recs = [json.loads(l) for l in open(event_log.path)]
         kinds = {r["kind"] for r in recs if r.get("trace_id") == tid}
         assert {"serving.submit", "serving.retire"} <= kinds, kinds
-        # 2) Timeline: span args on the request's B events
-        evs = json.loads(open(tl_path).read())
-        spans = [e for e in evs
-                 if (e.get("args") or {}).get("trace_id") == tid]
-        assert {e["name"] for e in spans} >= {"QUEUE", "PREFILL",
-                                              "DECODE"}
+        # 2) the span recorder: the request's tree under the same id
+        tree = spans.trace(tid) or []
+        assert {sp["name"] for sp in tree} >= {
+            "serving.request", "serving.queued", "serving.prefill",
+            "serving.decode"}
         # 3) registry histogram exemplar (the LAST finished request
         #    was this one — the only one submitted)
         ex = (registry().get("hvd_serving_e2e_seconds")

@@ -737,53 +737,6 @@ class TestPlumbing:
         assert snap["tokens_per_s"] > 0
         assert snap["num_slots"] == 2
 
-    def test_request_spans_in_timeline(self, lm, tmp_path):
-        """Serving spans land on the HOROVOD_TIMELINE trace as their
-        own request:<id> processes with QUEUE/PREFILL/DECODE B/E
-        pairs (the chrome://tracing rendering contract)."""
-        import json
-        from horovod_tpu.utils.timeline import (start_timeline,
-                                                stop_timeline)
-        model, params = lm
-        path = str(tmp_path / "serving_timeline.json")
-        start_timeline(path)
-        try:
-            with ServingEngine(model, params, num_slots=1) as eng:
-                eng.submit(_prompts(1, seed=90)[0], 4).result(
-                    timeout=300)
-        finally:
-            stop_timeline()
-        events = json.loads(open(path).read())
-        procs = {e["args"]["name"] for e in events
-                 if e.get("name") == "process_name"}
-        assert any(p.startswith("request:") for p in procs)
-        names = [(e.get("ph"), e.get("name")) for e in events]
-        # Every phase opens a B span; closes balance (the Python
-        # writer closes by name, the native writer by its TOP_LEVEL/
-        # DONE lifecycle — both yield a stack-balanced trace).
-        for span in ("QUEUE", "PREFILL", "DECODE"):
-            assert ("B", span) in names
-        assert (sum(1 for ph, _ in names if ph == "B")
-                == sum(1 for ph, _ in names if ph == "E"))
-
-    def test_timeline_span_api_direct(self, tmp_path):
-        """Unit: begin_span/end_span emit paired B/E on an interned
-        process pid without touching the tensor state machine."""
-        import json
-        from horovod_tpu.utils.timeline import Timeline
-        path = str(tmp_path / "spans.json")
-        tl = Timeline(path)
-        tl.begin_span("request:7", "QUEUE")
-        tl.end_span("request:7", "QUEUE")
-        tl.record("tensor_a", "NEGOTIATING")    # state machine intact
-        tl.record("tensor_a", "DONE")
-        tl.close()
-        events = json.loads(open(path).read())
-        assert ("B", "QUEUE") in [(e.get("ph"), e.get("name"))
-                                  for e in events]
-        assert ("E", "QUEUE") in [(e.get("ph"), e.get("name"))
-                                  for e in events]
-
 
 @pytest.mark.slow
 class TestSoak:
