@@ -1968,13 +1968,15 @@ def main():
                     help="LM architecture preset: gpt (LayerNorm/gelu/"
                          "tied head) or llama (RMSNorm/fused SwiGLU/"
                          "untied head, RoPE default)")
-    ap.add_argument("--flash-block-q", type=int, default=128,
+    ap.add_argument("--flash-block-q", type=int, default=None,
                     help="Pallas flash kernel q-tile (LM, "
-                         "--attn-impl flash only; sweep on hardware "
-                         "— VMEM vs grid-steps trade)")
-    ap.add_argument("--flash-block-k", type=int, default=128,
+                         "--attn-impl flash only; default: chosen "
+                         "from the shape by the kernel's plan; an "
+                         "integer to sweep on hardware)")
+    ap.add_argument("--flash-block-k", type=int, default=None,
                     help="Pallas flash kernel k-tile (LM, "
-                         "--attn-impl flash only)")
+                         "--attn-impl flash only; default: from the "
+                         "shape)")
     ap.add_argument("--profile", default=None, metavar="DIR",
                     help="capture a jax.profiler trace of the timed "
                          "steps into DIR (overlap/MFU analysis)")

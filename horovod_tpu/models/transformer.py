@@ -69,22 +69,24 @@ LLAMA_ARCH_KW = dict(norm="rmsnorm", mlp_impl="swiglu",
 def make_attn_fn(impl: str, *, causal: bool = True,
                  block_size: int = 512,
                  window: Optional[int] = None,
-                 flash_block_q: int = 128,
-                 flash_block_k: int = 128) -> Optional[Callable]:
+                 flash_block_q: Optional[int] = None,
+                 flash_block_k: Optional[int] = None
+                 ) -> Optional[Callable]:
     """attn_fn for `ParallelSelfAttention` (None = dot baseline, which
     consumes the explicit mask argument instead). ``window`` = sliding
     -window attention (last `window` positions only; requires causal).
-    ``flash_block_q``/``flash_block_k``: Pallas kernel grid tile sizes
-    (``impl="flash"``) — the VMEM-vs-grid-steps trade is shape- and
-    generation-dependent, so `bench.py --flash-block-q/-k` sweeps them
-    on hardware; defaults match the kernel's.
+    ``flash_block_q``/``flash_block_k``: Pallas kernel tile sizes
+    (``impl="flash"``). None (the default) lets the kernel choose them
+    from the shape (`ops.flash_attention._pick_tiles`); an integer is
+    honoured, so `bench.py --flash-block-q/-k` can still sweep them on
+    hardware.
     """
     from horovod_tpu.parallel.sequence import check_window
     check_window(window)
-    if (flash_block_q, flash_block_k) != (128, 128) and impl != "flash":
-        # ring_flash/ulysses_flash run the kernel at its defaults (the
-        # per-shard sequences are already small); silently ignoring the
-        # knob would make a hardware sweep measure identical kernels.
+    if (flash_block_q, flash_block_k) != (None, None) and impl != "flash":
+        # ring_flash/ulysses_flash run the kernel at its shape-chosen
+        # tiles; silently ignoring the knob would make a hardware
+        # sweep measure identical kernels.
         raise ValueError(
             f"flash_block_q/flash_block_k apply to attn_impl='flash' "
             f"only (got impl={impl!r})")
@@ -225,8 +227,8 @@ class TransformerBlock(nn.Module):
     causal: bool = True     # False = bidirectional (encoder / ViT)
     weight_quant: Optional[str] = None   # None | "int8" (block matmuls)
     kv_quant: Optional[str] = None       # None | "int8" (decode cache)
-    flash_block_q: int = 128             # Pallas flash tile sizes
-    flash_block_k: int = 128
+    flash_block_q: Optional[int] = None  # Pallas flash tile sizes;
+    flash_block_k: Optional[int] = None  # None = chosen from the shape
     attn_bias: bool = False              # GPT-2-family checkpoints
     attn_out_bias: Optional[bool] = None  # None = follow attn_bias
     ln_eps: float = 1e-6
@@ -353,8 +355,11 @@ class TransformerLM(nn.Module):
     # "int8": decode KV cache stored int8 with per-(position, head)
     # scales — 2x context length per byte of cache HBM.
     kv_quant: Optional[str] = None
-    flash_block_q: int = 128   # Pallas flash tile sizes (bench-sweepable)
-    flash_block_k: int = 128
+    # Pallas flash tile sizes: None = chosen from the shape by the
+    # kernel's plan (`flash_tile_check` shows it); an integer is
+    # honoured (bench.py --flash-block-q/-k sweeps them).
+    flash_block_q: Optional[int] = None
+    flash_block_k: Optional[int] = None
     attn_bias: bool = False    # attention projection biases (GPT-2)
     attn_out_bias: Optional[bool] = None  # Qwen2: qkv bias, no out bias
     ln_eps: float = 1e-6       # LayerNorm epsilon (GPT-2: 1e-5)

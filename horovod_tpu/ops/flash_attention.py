@@ -1,19 +1,35 @@
-"""Pallas TPU flash-attention kernel.
+"""Pallas TPU flash-attention kernels.
 
-The hot op of the flagship transformer, written for the hardware: one
-fused kernel per (batch, head, q-block) that streams K/V blocks through
-VMEM with online-softmax accumulation in float32 scratch — the [Sq, Sk]
-score matrix never touches HBM, Q·Kᵀ and P·V ride the MXU, and the
-rescale/exp traffic stays on the VPU.
+The hot op of the flagship transformer, written for the hardware:
+the [Sq, Sk] score matrix never touches HBM, the matmuls ride the MXU
+in the inputs' own dtype (bf16 operands for a bf16 model, float32
+accumulation), and the online-softmax rescale/exp traffic stays on the
+VPU in float32.
+
+How much one grid step does follows the shape (`_pick_tiles`), not a
+default: a step owns a 512-row tile where the sequence allows and,
+where the head's whole K and V fit the VMEM budget, holds them
+RESIDENT as one block — the k sweep is a `lax.fori_loop` inside the
+kernel whose bounds are the causal diagonal and the sliding-window
+band, so blocks above the diagonal or outside the band do not exist,
+and only the sub-blocks that straddle the diagonal, the band's edge
+or the zero-padded tail build a mask. Where `Sk * D` does not fit, the
+same kernels stream K/V one block a step over an innermost sequential
+grid axis (the float32 accumulators live in VMEM scratch across it)
+with the block index clamped to the band, so a skipped step re-uses
+the block already in VMEM instead of fetching one it will not read.
 
 No reference equivalent: Horovod v0.10 contains no attention at all
 (SURVEY §5.7); this is part of the TPU-native long-context extension.
 The backward is fused Pallas too (FlashAttention-2 style, the
 default): the forward saves only the row logsumexp, and two kernels
-rebuild each probability tile on the fly for dK/dV and dQ — O(S)
-residual memory, no scan-residual HBM traffic; under a sliding window
-both backward sweeps are banded like the forward grid. The same math
-in plain-XLA form lives in
+rebuild each probability tile on the fly — dQ sweeps k like the
+forward; dK/dV owns a k tile, holds the head group's Q, dO, lse and
+dvec resident and sweeps q FROM the diagonal. The forward and dK/dV
+compute the scores transposed (Sᵀ = K·Qᵀ, a query a lane), so the
+softmax state, lse and dvec are [1, block_q] rows and no score-sized
+tile is ever transposed on the XLU. O(S) residual memory, no scan-residual HBM
+traffic. The same math in plain-XLA form lives in
 `horovod_tpu.parallel.sequence.blockwise_attention`, the correctness
 oracle for both directions and the recompute-VJP fallback
 (HOROVOD_FLASH_BWD=recompute; banded for sliding-window training).
@@ -22,18 +38,14 @@ Layout is the framework-wide [batch, seq, heads, head_dim]; the kernel
 internally works head-major. `ulysses_attention(attn_impl=
 flash_attention)` composes this with sequence parallelism: all_to_all to
 head-sharded layout, flash kernel locally, all_to_all back.
-
-Grid iteration order puts the K/V-block dimension innermost (sequential
-on TPU), so the float32 accumulators live in VMEM scratch across the
-whole K sweep and results are written to HBM exactly once per q-block.
-Fully-masked causal blocks are skipped (compute guarded by `pl.when`,
-~2x step speedup for long causal sequences).
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import Optional
+import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -43,22 +55,37 @@ from jax.experimental.pallas import tpu as pltpu
 
 _VMEM = pltpu.VMEM
 
-
-def _compiler_params():
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel",
-                             "arbitrary"))
-
 NEG_INF = float("-inf")
 
-# The row-logsumexp rides between the fwd and bwd kernels lane-
-# replicated to a full 128-lane trailing dim: real Mosaic requires the
-# last two block dims to be (8k, 128m) or equal to the array dims, so a
-# rank-3 [B, H, S] lse with (1, 1, bq) blocks is UNLOWERABLE on
-# hardware (it only ever worked in interpret mode); and after (8, 128)
-# tile padding a narrower trailing dim would occupy the same HBM
-# anyway. Kernel-internal only — the public API still returns [B,H,S].
-LSE_LANES = 128
+# Mosaic's scoped-VMEM default on the chips this runs on (v5e: 16 MiB
+# of 128): a kernel whose plan asks for more says so through
+# `vmem_limit_bytes`, one that asks for less leaves the default alone.
+VMEM_SCOPED_DEFAULT = 16 * 2 ** 20
+# What the plan lets one kernel ask for before K/V (or the group's Q)
+# stop being resident and stream instead: a quarter of v5e's VMEM, and
+# under the smallest VMEM of the current generations (64 MiB).
+VMEM_BUDGET = 32 * 2 ** 20
+# Tiles the plan chooses from when the caller names none, each with
+# the kernels' time per unit of score area relative to the largest
+# (one v5e chip, B4 S1024 H16 D64 bf16, forward + backward: 0.94, 1.37
+# and 2.65 ms a layer; my chip run, PR 25): a bigger tile amortizes the
+# online-softmax bookkeeping and the MXU's weight loads over more
+# columns, a smaller one pads a ragged length less. (1024-row tiles
+# measured no faster than 512 at S 1024 and 2048.)
+AUTO_TILES = ((512, 1.0), (256, 1.45), (128, 2.8))
+
+_NT = (((1,), (1,)), ((), ()))      # a · bᵀ
+_NN = (((1,), (0,)), ((), ()))      # a · b
+_TN = (((0,), (0,)), ((), ()))      # aᵀ · b
+
+
+def _compiler_params(vmem_bytes: int):
+    kw = {}
+    if vmem_bytes > VMEM_SCOPED_DEFAULT:
+        kw["vmem_limit_bytes"] = int(vmem_bytes)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel",
+                             "arbitrary"), **kw)
 
 
 def _snap_tile(block: int, S: int) -> int:
@@ -89,260 +116,582 @@ def mosaic_block_ok(block_shape, array_shape) -> bool:
             and (b2 % 8 == 0 or b2 == a2))
 
 
+class _Tiles(NamedTuple):
+    """What `_pick_tiles` decides for one (Sq, Sk, D, dtype, group):
+    ``bq`` query rows are one online-softmax state and one grid step
+    of the forward and dQ, ``bk`` keys one sub-block of their sweep;
+    dK/dV owns ``bk`` keys a step and sweeps ``bq``-row sub-blocks."""
+    bq: int
+    bk: int
+    Sqp: int               # padded lengths (multiples of bq / bk)
+    Skp: int
+    kv_resident: bool      # fwd, dQ: the head's whole K and V one block
+    q_resident: bool       # dK/dV: the group's whole Q, dO, lse, dvec
+    vmem_fwd: int          # bytes each kernel's plan sums to
+    vmem_dq: int
+    vmem_dkv: int
+
+    @property
+    def nq(self) -> int:
+        return self.Sqp // self.bq
+
+    @property
+    def nk(self) -> int:
+        return self.Skp // self.bk
+
+    @property
+    def k_rows(self) -> int:
+        """Rows of K (and V) a forward or dQ grid step holds."""
+        return self.Skp if self.kv_resident else self.bk
+
+    @property
+    def q_rows(self) -> int:
+        """Rows of each group member's Q a dK/dV grid step holds."""
+        return self.Sqp if self.q_resident else self.bq
+
+    def sweep_steps(self, window: Optional[int]):
+        """Lengths of the innermost (sequential) grid axis of the
+        forward / dQ and of dK/dV: the blocks of the swept side a step
+        may need — one when resident; streamed, all of them, or under
+        a sliding window only those its band can touch."""
+        def steps(S, rows, own):
+            n = S // rows
+            if window is None:
+                return n
+            return min(n, -(-(own + window - 1) // rows) + 1)
+        return (steps(self.Skp, self.k_rows, self.bq),
+                steps(self.Sqp, self.q_rows, self.bk))
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _axis_tile(S: int, block: Optional[int], sublane: int):
+    """(tile, padded length) of one sequence axis."""
+    if block is not None:
+        b = _snap_tile(block, S)           # honoured as given
+        return b, _round_up(S, b)
+    if S <= AUTO_TILES[0][0]:
+        b = _round_up(S, sublane)          # one tile == the padded axis
+        return b, b
+    b = min(AUTO_TILES, key=lambda tc: _round_up(S, tc[0]) * tc[1])[0]
+    return b, _round_up(S, b)
+
+
+def _vmem_plan(bq, bk, q_rows, k_rows, D, itemsize, group):
+    """Bytes of VMEM the three kernels ask for: every pipelined block
+    twice (double-buffered), the float32 scratch, and the float32
+    temporaries of one [bq, bk] sub-block. ``q_rows``/``k_rows`` are
+    the rows of the SWEPT side one step holds (the whole padded axis
+    when resident, one tile when streamed). Minor dims count as
+    padded to 128 lanes."""
+    Dp = _round_up(D, 128)
+    row = 8 * _round_up(bq, 128) * 4          # one [1, bq] f32 row
+    temps = 6 * bq * _round_up(bk, 128) * 4   # s, p, dp, ds + casts
+    fwd = (2 * (2 * bq * Dp * itemsize + row)             # q, o, lse
+           + 2 * 2 * k_rows * Dp * itemsize               # k, v
+           + _round_up(D, 8) * _round_up(bq, 128) * 4     # acc (Oᵀ)
+           + 2 * row + temps)                             # m, l
+    dq = (2 * (3 * bq * Dp * itemsize + 2 * row)          # q, do, dq
+          + 2 * 2 * k_rows * Dp * itemsize                # + lse, dvec
+          + bq * Dp * 4 + 2 * bq * 128 * 4 + temps)       # acc, columns
+    dkv = (2 * group * (2 * q_rows * Dp * itemsize        # q, do
+                        + 2 * (q_rows // bq) * row)       # lse, dvec
+           + 2 * 4 * bk * Dp * itemsize                   # k, v, dk, dv
+           + 2 * bk * Dp * 4 + temps)
+    return fwd, dq, dkv
+
+
+def _pick_tiles(Sq: int, Sk: int, D: int, itemsize: int, group: int,
+                block_q: Optional[int] = None,
+                block_k: Optional[int] = None) -> _Tiles:
+    """Tiles from the shape. ``block_q``/``block_k`` given: that tile
+    (snapped to a legal size), as before. Left None: the tile of
+    `AUTO_TILES` that makes the padded axis cheapest, or the whole
+    axis where it is no longer than the largest. Either way the swept
+    side is RESIDENT where the kernels' VMEM sum stays inside
+    `VMEM_BUDGET`, and streams one tile a grid step where it does
+    not."""
+    sublane = 8 * max(1, 4 // itemsize)       # f32 8, bf16 16 rows
+    bq, Sqp = _axis_tile(Sq, block_q, sublane)
+    bk, Skp = _axis_tile(Sk, block_k, sublane)
+    plan = functools.partial(_vmem_plan, bq, bk, D=D,
+                             itemsize=itemsize, group=group)
+    fwd, dq, dkv = plan(q_rows=Sqp, k_rows=Skp)
+    kv_resident = max(fwd, dq) <= VMEM_BUDGET
+    q_resident = dkv <= VMEM_BUDGET
+    if not kv_resident:
+        fwd, dq, _ = plan(q_rows=bq, k_rows=bk)
+    if not q_resident:
+        _, _, dkv = plan(q_rows=bq, k_rows=bk)
+    return _Tiles(bq, bk, Sqp, Skp, kv_resident, q_resident,
+                  fwd, dq, dkv)
+
+
+@dataclasses.dataclass(frozen=True)
+class FlashPlan:
+    """What the fwd + bwd pallas_calls will do at one shape — the
+    record that says whether the shape-chosen tiling engages. Iterates
+    as the block list `(name, block shape, array shape, legal)`."""
+    block_q: int
+    block_k: int
+    kv_resident: bool
+    q_resident: bool
+    grid: dict              # kernel -> grid tuple (batch 1)
+    grid_steps: dict        # kernel -> steps a call, per batch row
+    vmem_bytes: dict        # kernel -> bytes the plan sums to
+    vmem_limit_bytes: dict  # kernel -> what is asked of Mosaic, or None
+    blocks: tuple
+
+    def __iter__(self):
+        return iter(self.blocks)
+
+
 def flash_tile_check(Sq: int, Sk: int, H: int, Hkv: int, D: int, *,
-                     block_q: int = 128, block_k: int = 128):
-    """Every (name, block shape, array shape, legal) the fwd + bwd
-    pallas_calls will use at these shapes after tile snapping — the
-    static half of the v5e regression test: a config is
+                     block_q: Optional[int] = None,
+                     block_k: Optional[int] = None,
+                     itemsize: int = 2,
+                     window: Optional[int] = None) -> FlashPlan:
+    """The plan the fwd + bwd pallas_calls will use at these shapes:
+    tiles, grid steps a call, resident or streamed, VMEM asked, and
+    every (name, block shape, array shape, legal) after tile snapping —
+    the static half of the v5e regression test: a config is
     hardware-lowerable iff every entry's ``legal`` bit is True, and
     that is checkable on CPU (interpret mode would happily run
     illegal tiles, which is exactly how the r04 failure shipped)."""
-    bq = _snap_tile(block_q, Sq)
-    bk = _snap_tile(block_k, Sk)
-    nq = -(-Sq // bq)
-    nk = -(-Sk // bk)
+    group = H // Hkv
+    t = _pick_tiles(Sq, Sk, D, itemsize, group, block_q, block_k)
     B = 1   # batch rides a leading grid dim, never a constrained one
+    q_arr, k_arr = (B, H, t.Sqp, D), (B, Hkv, t.Skp, D)
+    r_arr = (B, H, t.nq, 1, t.bq)
     entries = [
-        ("fwd.q", (1, 1, bq, D), (B, H, nq * bq, D)),
-        ("fwd.kv", (1, 1, bk, D), (B, Hkv, nk * bk, D)),
-        ("fwd.out", (1, 1, bq, D), (B, H, nq * bq, D)),
-        ("fwd.lse", (1, 1, bq, LSE_LANES), (B, H, nq * bq, LSE_LANES)),
-        ("bwd.dq.q", (1, 1, bq, D), (B, H, nq * bq, D)),
-        ("bwd.dq.lse", (1, 1, bq, LSE_LANES),
-         (B, H, nq * bq, LSE_LANES)),
-        ("bwd.dq.kv", (1, 1, bk, D), (B, Hkv, nk * bk, D)),
-        ("bwd.dkv.q", (1, 1, bq, D), (B, H, nq * bq, D)),
-        ("bwd.dkv.out", (1, 1, bk, D), (B, Hkv, nk * bk, D)),
+        ("fwd.q", (1, 1, t.bq, D), q_arr),
+        ("fwd.kv", (1, 1, t.k_rows, D), k_arr),
+        ("fwd.out", (1, 1, t.bq, D), q_arr),
+        ("fwd.lse", (1, 1, 1, 1, t.bq), r_arr),
+        ("bwd.dq.q", (1, 1, t.bq, D), q_arr),
+        ("bwd.dq.lse", (1, 1, 1, 1, t.bq), r_arr),
+        ("bwd.dq.kv", (1, 1, t.k_rows, D), k_arr),
+        ("bwd.dkv.q", (1, group, t.q_rows, D), q_arr),
+        ("bwd.dkv.lse", (1, group, t.q_rows // t.bq, 1, t.bq), r_arr),
+        ("bwd.dkv.out", (1, 1, t.bk, D), k_arr),
     ]
-    return [(name, blk, arr, mosaic_block_ok(blk, arr))
-            for name, blk, arr in entries]
+    fwd_sweep, dkv_sweep = t.sweep_steps(window)
+    grid = {"fwd": (B, H, t.nq, fwd_sweep),
+            "bwd.dq": (B, H, t.nq, fwd_sweep),
+            "bwd.dkv": (B, Hkv, t.nk, dkv_sweep)}
+    vmem = {"fwd": t.vmem_fwd, "bwd.dq": t.vmem_dq,
+            "bwd.dkv": t.vmem_dkv}
+    return FlashPlan(
+        block_q=t.bq, block_k=t.bk, kv_resident=t.kv_resident,
+        q_resident=t.q_resident, grid=grid,
+        grid_steps={k: math.prod(g) for k, g in grid.items()},
+        vmem_bytes=vmem,
+        vmem_limit_bytes={k: v if v > VMEM_SCOPED_DEFAULT else None
+                          for k, v in vmem.items()},
+        blocks=tuple((name, blk, arr, mosaic_block_ok(blk, arr))
+                     for name, blk, arr in entries))
+
+
+# Block-index arithmetic on traced int32 scalars, for the index maps
+# and the kernels' sweep bounds. Written on `lax` primitives: Mosaic
+# lowers each `//` or `%` of `jax.numpy` through a `sign` helper that
+# costs tens of milliseconds of lowering apiece — with a dozen of them
+# a layer that was 9 s of every process's set-up (my chip run, PR 25).
+# Python ints (the bounds of a non-causal sweep) pass straight through.
+
+def _traced(*xs) -> bool:
+    return any(not isinstance(x, int) for x in xs)
+
+
+def _fdiv(x, d: int):
+    """floor(x / d) for a positive Python int ``d``."""
+    if not _traced(x):
+        return x // d
+    if d == 1:
+        return x
+    # lax.div truncates toward zero; shift negatives down first
+    return jax.lax.div(x - jnp.where(x < 0, d - 1, 0).astype(x.dtype),
+                       jnp.asarray(d, x.dtype))
+
+
+def _imin(a, b):
+    return jnp.minimum(a, b) if _traced(a, b) else min(a, b)
+
+
+def _imax(a, b):
+    return jnp.maximum(a, b) if _traced(a, b) else max(a, b)
+
+
+def _iclip(x, lo, hi):
+    return _imin(_imax(x, lo), hi)
 
 
 def _band_j0(qi, *, window, q_offset, k_offset, block_q, block_k):
     """First k-block index that can intersect q-block ``qi``'s band —
-    the banded grid's offset (shared by index_map and kernel so the
-    DMA'd block and the in-kernel positions cannot disagree)."""
-    lo = (q_offset + qi * block_q - (window - 1) - k_offset) // block_k
-    return jnp.maximum(0, lo)
+    the lower bound of the k sweep (shared by index_map and kernel so
+    the DMA'd tile and the in-kernel positions cannot disagree)."""
+    return _imax(0, _fdiv(
+        q_offset + qi * block_q - (window - 1) - k_offset, block_k))
 
 
 def _band_i0(j, *, q_offset, k_offset, block_q, block_k):
     """First q-block index whose rows can see k-block ``j`` under the
-    causal band (q >= k) — the dK/dV banded grid's offset."""
-    lo = (k_offset + j * block_k - q_offset) // block_q
-    return jnp.maximum(0, lo)
+    causal band (q >= k) — the lower bound of dK/dV's q sweep."""
+    return _imax(0, _fdiv(k_offset + j * block_k - q_offset, block_q))
+
+
+def _k_sweep(qi, *, causal, window, q_offset, k_offset, kv_len,
+             block_q, block_k, nk):
+    """Bounds `(lo, a, b, hi)` of q-block ``qi``'s k sweep, in k-blocks:
+    blocks [lo, a) straddle the window's far edge, [a, b) lie wholly
+    inside the band and before the zero-padded tail (no mask), [b, hi)
+    straddle the diagonal or the tail. Blocks outside [lo, hi) cannot
+    be seen by any row of the q-block and are never visited."""
+    full = kv_len // block_k
+    if not causal:
+        return 0, 0, full, nk
+    q0 = q_offset + qi * block_q
+    hi = _iclip(_fdiv(q0 + block_q - 1 - k_offset, block_k) + 1, 0, nk)
+    b = _imin(_fdiv(q0 - k_offset + 1, block_k), _imin(hi, full))
+    if window is None:
+        return 0, 0, _imax(b, 0), hi
+    lo = _imin(_band_j0(qi, window=window, q_offset=q_offset,
+                        k_offset=k_offset, block_q=block_q,
+                        block_k=block_k), hi)
+    a = _iclip(_fdiv(q0 + block_q - 1 - window - k_offset, block_k) + 1,
+               lo, hi)
+    return lo, a, _imax(a, b), hi
+
+
+def _q_sweep(j, *, causal, window, q_offset, k_offset, block_q,
+             block_k, nq):
+    """dK/dV's mirror of `_k_sweep`: bounds `(lo, a, b, hi)` of
+    k-block ``j``'s q sweep, in q-blocks — [lo, a) straddle the
+    diagonal, [a, b) see the whole k-block unmasked, [b, hi) straddle
+    the window's far edge. (Zero-padded q rows need no mask: their dO
+    and dvec are zero, so they add nothing to dK or dV.)"""
+    if not causal:
+        return 0, 0, nq, nq
+    c0 = k_offset + j * block_k
+    lo = _imin(_band_i0(j, q_offset=q_offset, k_offset=k_offset,
+                        block_q=block_q, block_k=block_k), nq)
+    a = _iclip(_fdiv(c0 + block_k - 2 - q_offset, block_q) + 1, lo, nq)
+    if window is None:
+        return lo, a, nq, nq
+    hi = _iclip(_fdiv(window + c0 + block_k - 2 - q_offset, block_q) + 1,
+                lo, nq)
+    a = _imin(a, hi)
+    b = _iclip(_fdiv(window - block_q + c0 - q_offset, block_q) + 1,
+               a, hi)
+    return lo, a, b, hi
+
+
+def _streamed_block(own, step, *, sweep, n):
+    """Index of the swept block a streamed grid step holds in VMEM:
+    the ``step``-th of block ``own``'s band, clamped to the band's
+    last (so a step past the band re-uses the block already there;
+    `_swept` gives it an empty range) and to the axis's ``n`` blocks
+    (a band that lies wholly outside the sequence)."""
+    lo, _, _, hi = sweep(own)
+    return _iclip(_imin(lo + step, hi - 1), 0, n - 1)
 
 
 def _mask_block(q_start, k_start, *, causal, window, kv_len, k_local0,
-                block_q, block_k):
+                block_q, block_k, transposed=False):
     """The fwd/bwd-shared mask for one [block_q, block_k] tile, or None.
 
     `q_start`/`k_start` are GLOBAL positions (offset-aware, the
     `banded_causal_mask` band rule); `k_local0` is the block's LOCAL
-    key index origin for the zero-pad tail test.
-    """
+    key index origin for the zero-pad tail test (None: the caller's
+    padded keys need no mask). ``transposed``: the [block_k, block_q]
+    tile of dK/dV's Sᵀ."""
+    shape = (block_k, block_q) if transposed else (block_q, block_k)
+    qd, kd = (1, 0) if transposed else (0, 1)
     mask = None
     if causal:
-        rows = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        cols = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
+        rows = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, qd)
+        cols = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, kd)
         mask = rows >= cols
         if window is not None:
             mask = jnp.logical_and(mask, rows - cols < window)
-    if kv_len % block_k:
+    if k_local0 is not None and kv_len % block_k:
         local = k_local0 + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
+            jnp.int32, shape, kd)
         pad_ok = local < kv_len
         mask = pad_ok if mask is None else jnp.logical_and(mask, pad_ok)
     return mask
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
+def _sweep_loops(bounds, body, *, masked_head, masked_tail):
+    """Run ``body(masked)(j, carry)`` over the three ranges of a sweep:
+    masked [lo, a), unmasked [a, b), masked [b, hi). A range that
+    cannot exist for this configuration is not traced at all."""
+    lo, a, b, hi = bounds
+    if masked_head:
+        jax.lax.fori_loop(lo, a, body(True), 0)
+    jax.lax.fori_loop(a, b, body(False), 0)
+    if masked_tail:
+        jax.lax.fori_loop(b, hi, body(True), 0)
+
+
+def _fold_scale(D: int, dtype) -> bool:
+    """Whether the softmax scale goes onto the q (k) operand rather
+    than the float32 logits: only where that is exact or was always
+    done — a power of two (D 64: 0.125) in any dtype, any scale in
+    float32. Never rounded into bf16."""
+    return (jnp.dtype(dtype) == jnp.float32
+            or math.frexp(D ** -0.5)[0] == 0.5)
+
+
+def _kernel(fn, interpret: bool, **static):
+    """``fn`` with its static arguments bound, handed its position on
+    the two sequence grid axes `(index, sweep step, sweep steps)`.
+    Under the interpreter the whole body sits inside a traced,
+    trivially-true `pl.when`: the interpreter evaluates an unguarded
+    body's loads primitive by primitive, and under
+    `shard_map(check_vma=True)` those trip a varying-manual-axes
+    mismatch (ref operands vary, their indices do not); inside a cond
+    nothing is checked. Mosaic never sees the guard."""
+    fn = functools.partial(fn, **static)
+
+    def run(*refs):
+        pos = (pl.program_id(2), pl.program_id(3), pl.num_programs(3))
+        if interpret:
+            pl.when(pos[0] >= 0)(lambda: fn(pos, *refs))
+        else:
+            fn(pos, *refs)
+    return run
+
+
+def _scaled(x, scale):
+    """``x * scale`` in ``x``'s dtype, through float32 (v5e's VPU has
+    no bf16 multiply); exact wherever `_fold_scale` allows it."""
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
+def _swept(pos, own_sweep, streamed):
+    """(bounds, base, first, last) of one grid step's sweep: the block
+    ranges `(lo, a, b, hi)` to loop over, the index of the first swept
+    block in VMEM, and whether this is the first / last step of the
+    sequential axis. Resident: the whole band, all of it in VMEM.
+    Streamed: the band's ``step``-th block alone (an empty range when
+    the band has fewer)."""
+    own, step, steps = pos
+    bounds = own_sweep(own)
+    if not streamed:
+        return bounds, 0, True, True
+    base = bounds[0] + step
+    bounds = tuple(_iclip(x, base, base + 1) for x in bounds)
+    return bounds, base, step == 0, step == steps - 1
+
+
+def _when(cond, fn):
+    """``fn()`` now where ``cond`` is statically true, else guarded."""
+    fn() if cond is True else pl.when(cond)(fn)
+
+
+def _flash_kernel(pos, q_ref, k_ref, v_ref, o_ref, lse_ref,
                   acc_ref, m_ref, l_ref, *,
-                  scale: float, causal: bool, window: "int | None",
-                  banded: bool, nk_total: int,
-                  q_offset: int, k_offset: int,
-                  kv_len: int, block_q: int, block_k: int):
-    """One (batch, head, q-block, k-block) grid cell.
+                  scale: float, fold: bool, causal: bool,
+                  window: "int | None", q_offset: int, k_offset: int,
+                  kv_len: int, block_q: int, block_k: int, nk: int,
+                  streamed: bool):
+    """One (batch, head, q-block, sweep step) grid cell: the q-block
+    sweeps the k sub-blocks its band can see (`_k_sweep`).
 
-    ``banded``: the innermost grid axis runs over only the k-blocks
-    that can intersect the sliding-window band of this q-block
-    (index_map adds `_band_j0`); out-of-range logical blocks (clamped
-    duplicates at the sequence end) are skipped by the validity guard.
+    Resident K/V (``streamed`` False): the sweep axis has one step,
+    ``k_ref`` is the head's whole K, and the sweep is an in-kernel
+    loop. Streamed: the axis runs over the band's blocks, one in VMEM
+    a step (`_streamed_block` clamps the index, so a step past the
+    band re-uses the block already there and its sweep is empty).
 
-    Scratch (persistent across the innermost k-block sweep):
-      acc_ref [block_q, D] f32 — unnormalized output accumulator
-      m_ref   [block_q, 128] f32 — running row max (lane-replicated)
-      l_ref   [block_q, 128] f32 — running softmax denominator
+    The scores are computed TRANSPOSED, Sᵀ = K·Qᵀ [block_k, block_q]:
+    a query is a lane, so the online-softmax state is a [1, block_q]
+    row (4 vregs at 512, not the 64 of a lane-replicated column), the
+    max and the sum over keys run down the sublanes (elementwise
+    across vregs, no cross-lane reduce), and Oᵀ += Vᵀ·Pᵀ; the output
+    is transposed back once, when the sweep ends.
+
+    Scratch (persistent across the sweep axis):
+      acc_ref [D, block_q] f32 — unnormalized output accumulator, Oᵀ
+      m_ref   [1, block_q] f32 — running max of each query
+      l_ref   [1, block_q] f32 — running softmax denominator
     """
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
-    nk = pl.num_programs(3)
+    qi = pos[0]
+    bounds, base, first, last = _swept(
+        pos, functools.partial(
+            _k_sweep, causal=causal, window=window, q_offset=q_offset,
+            k_offset=k_offset, kv_len=kv_len, block_q=block_q,
+            block_k=block_k, nk=nk), streamed)
+    q_start = q_offset + qi * block_q
 
-    @pl.when(ki == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
+    _when(first, _init)
 
-    # Global positions of this block's rows/cols (for causal + pad masks).
-    q_start = q_offset + qi * block_q
-    if banded:
-        jl = _band_j0(qi, window=window, q_offset=q_offset,
-                      k_offset=k_offset, block_q=block_q,
-                      block_k=block_k) + ki
-        jc = jnp.minimum(jl, nk_total - 1)   # what the index_map DMA'd
-        in_range = jl <= nk_total - 1
-    else:
-        jl = jc = ki
-        in_range = True
-    k_start = k_offset + jc * block_k
+    q = q_ref[0, 0]                                          # [bq, D]
+    if fold:
+        q = _scaled(q, scale)
 
-    def _block():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # [bq, D]
-        k = k_ref[0, 0].astype(jnp.float32)                  # [bk, D]
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [bq, bk]
+    def body(masked):
+        def _block(j, carry):
+            off = pl.multiple_of((j - base) * block_k, block_k)
+            k = k_ref[0, 0, pl.ds(off, block_k), :]          # [bk, D]
+            st = jax.lax.dot_general(
+                k, q, _NT, preferred_element_type=jnp.float32)
+            if not fold:
+                st = st * scale                              # [bk, bq]
+            m_prev = m_ref[...]                              # [1, bq]
+            if masked:
+                mask = _mask_block(
+                    q_start, k_offset + j * block_k, causal=causal,
+                    window=window, kv_len=kv_len, k_local0=j * block_k,
+                    block_q=block_q, block_k=block_k, transposed=True)
+                st = jnp.where(mask, st, NEG_INF)
+            m_new = jnp.maximum(
+                m_prev, jnp.max(st, axis=0, keepdims=True))
+            if masked:
+                # Queries with every key masked so far keep m == -inf;
+                # shift by 0 there so exp(-inf - 0) = 0 instead of
+                # exp(-inf - -inf) = NaN.
+                shift = jnp.where(m_new == NEG_INF, 0.0, m_new)
+                corr = jnp.where(m_prev == NEG_INF, 0.0,
+                                 jnp.exp(m_prev - shift))
+            else:
+                # every score is finite, so m_new is, and
+                # exp(-inf - m_new) is the 0 a fresh query needs
+                shift = m_new
+                corr = jnp.exp(m_prev - m_new)
+            pt = jnp.exp(st - shift)                         # [bk, bq]
+            l_ref[...] = (l_ref[...] * corr
+                          + jnp.sum(pt, axis=0, keepdims=True))
+            v = v_ref[0, 0, pl.ds(off, block_k), :]          # [bk, D]
+            pvt = jax.lax.dot_general(
+                v, pt.astype(v.dtype), _TN,
+                preferred_element_type=jnp.float32)          # [D, bq]
+            acc_ref[...] = acc_ref[...] * corr + pvt
+            m_ref[...] = m_new
+            return carry
+        return _block
 
-        mask = _mask_block(q_start, k_start, causal=causal,
-                           window=window, kv_len=kv_len,
-                           k_local0=jc * block_k,
-                           block_q=block_q, block_k=block_k)
-        if mask is not None:
-            logits = jnp.where(mask, logits, NEG_INF)
+    _sweep_loops(bounds, body,
+                 masked_head=causal and window is not None,
+                 masked_tail=causal or kv_len % block_k != 0)
 
-        m_prev = m_ref[...]                                  # [bq, 128]
-        l_prev = l_ref[...]
-        m_cur = jnp.max(logits, axis=-1, keepdims=True)      # [bq, 1]
-        m_new = jnp.maximum(m_prev, m_cur)                   # [bq, 128]
-        # Rows with every key masked so far keep m == -inf; shift by 0
-        # there so exp(-inf - 0) = 0 instead of exp(-inf - -inf) = NaN.
-        shift = jnp.where(m_new == NEG_INF, 0.0, m_new)
-        p = jnp.exp(logits - shift[:, :1])                   # [bq, bk]
-        corr = jnp.where(m_prev == NEG_INF, 0.0,
-                         jnp.exp(m_prev - shift))            # [bq, 128]
-        l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[0, 0].astype(jnp.float32)                  # [bk, D]
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [bq, D]
-        acc_ref[...] = acc_ref[...] * corr[:, :1] + pv
-        m_ref[...] = m_new
-
-    # Skip blocks entirely outside the causal band (future keys, or —
-    # with a window — keys entirely in the past) and clamped
-    # duplicates past the banded grid's end. Non-causal keeps a traced
-    # trivially-true guard ("block intersects real keys"): an
-    # UNGUARDED body trips a varying-manual-axes mismatch inside the
-    # pallas interpreter under shard_map(check_vma=True).
-    rel = _relevant_block(q_start, k_start, causal=causal,
-                          window=window, block_q=block_q,
-                          block_k=block_k)
-    if rel is None:
-        rel = jnp.asarray(jc) * block_k < kv_len
-    if banded:
-        rel = jnp.logical_and(rel, in_range)
-    pl.when(rel)(_block)
-
-    @pl.when(ki == nk - 1)
     def _finalize():
-        l = l_ref[...][:, :1]
+        l = l_ref[...]
         denom = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0, :, :] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        o_ref[0, 0, :, :] = (acc_ref[...] / denom).T.astype(o_ref.dtype)
         # Row logsumexp for the fused backward: L = m + log(l), -inf on
         # fully-masked rows (the bwd kernels turn those into p = 0).
-        m = m_ref[...][:, :1]
-        lse = jnp.where(l == 0.0, NEG_INF, m + jnp.log(denom))
-        lse_ref[0, 0, :, :] = jnp.broadcast_to(
-            lse, (lse.shape[0], LSE_LANES))
+        lse_ref[0, 0, 0] = jnp.where(l == 0.0, NEG_INF,
+                                     m_ref[...] + jnp.log(denom))
+    _when(last, _finalize)
 
 
+def _head_major(x, rows):
+    """[B, S, H, D] -> [B, H, rows, D], zero-padded along the
+    sequence; XLA fuses the transposes."""
+    xt = jnp.transpose(x, (0, 2, 1, 3))
+    if rows != xt.shape[2]:
+        xt = jnp.pad(xt, ((0, 0), (0, 0), (0, rows - xt.shape[2]),
+                          (0, 0)))
+    return xt
+
+
+def _row_spec(bq: int):
+    """BlockSpec of a q-block's [1, bq] float32 row (lse, dvec) on the
+    fwd and dQ grids. The arrays are [B, H, nq, 1, bq], so the block's
+    last two dims ARE the array's: legal on real Mosaic at any tile (a
+    rank-3 [B, H, S] array with (1, 1, bq) blocks is not — it only
+    ever worked in interpret mode — and a lane-replicated [S, 128]
+    column costs 128x the HBM traffic)."""
+    return pl.BlockSpec((1, 1, 1, 1, bq),
+                        lambda b, h, i, s: (b, h, i, 0, 0))
+
+
+def _column(row):
+    """[1, n] row -> [n, 1] column, through the one relayout Mosaic
+    always has: a 2D transpose of a full (128-sublane) tile."""
+    return jnp.broadcast_to(row, (128, row.shape[1])).T[:, :1]
+
+
+def _kv_spec(t: _Tiles, group: int, D: int, sweep):
+    """BlockSpec of K (or V) on the fwd and dQ grids (b, h, i, step):
+    the head's whole K when resident, else the ``step``-th block of
+    q-block ``i``'s band."""
+    if t.kv_resident:
+        return pl.BlockSpec((1, 1, t.Skp, D),
+                            lambda b, h, i, s: (b, _fdiv(h, group), 0, 0))
+    return pl.BlockSpec(
+        (1, 1, t.bk, D),
+        lambda b, h, i, s: (b, _fdiv(h, group), _streamed_block(
+            i, s, sweep=sweep, n=t.nk), 0))
+
+
+# The wrappers are jitted INLINE: a model calls them once a layer with
+# the same shapes, and an inlined jit traces the wrapper and its kernel
+# once per shape and hands every layer the same pallas_call equation,
+# which then lowers to Mosaic once — 24 layers of gpt2-medium trace and
+# lower in 2.1 s instead of 4.9 (sandbox, PR 25), seconds of every
+# process's set-up on the chip's host. Inlined, the jit adds no scope
+# to the name stack, so a kernel keeps its caller's name in the trace.
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "causal", "window", "q_offset", "k_offset", "block_q", "block_k",
+    "interpret"))
 def _flash_forward(q, k, v, *, causal, window, q_offset, k_offset,
                    block_q, block_k, interpret):
     """[B, S, H, D] flash attention forward via pallas_call.
 
-    Returns `(out [B, Sq, H, D], lse [B, H, nq*bq, LSE_LANES] f32)` —
-    the row logsumexp rides along for the fused Pallas backward
-    (head-major, lane-replicated, padded to the block grid; -inf on
-    fully-masked rows)."""
+    Returns `(out [B, Sq, H, D], lse [B, H, Sqp/bq, 1, bq] f32)` —
+    the row logsumexp rides along for the fused Pallas backward as
+    the kernels keep it: one [1, bq] row a q-block (head-major, padded
+    to the block grid, -inf on fully-masked rows)."""
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
     group = _gqa_group(q, k, v)
-    # Snapped tiles: multi-block tiles must be 8-aligned for real
-    # Mosaic (last two block dims multiples of (8, 128) or equal to
-    # the array dims) — see `_snap_tile` / `flash_tile_check`.
-    bq = _snap_tile(block_q, Sq)
-    bk = _snap_tile(block_k, Sk)
-    nq = -(-Sq // bq)
-    nk = -(-Sk // bk)
+    t = _pick_tiles(Sq, Sk, D, q.dtype.itemsize, group, block_q,
+                    block_k)
+    qt = _head_major(q, t.Sqp)
+    kt = _head_major(k, t.Skp)
+    vt = _head_major(v, t.Skp)
 
-    # Head-major layout for the kernel; XLA fuses the transposes.
-    qt = jnp.transpose(q, (0, 2, 1, 3))
-    kt = jnp.transpose(k, (0, 2, 1, 3))
-    vt = jnp.transpose(v, (0, 2, 1, 3))
-    if nq * bq != Sq:
-        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, nq * bq - Sq), (0, 0)))
-    if nk * bk != Sk:
-        kt = jnp.pad(kt, ((0, 0), (0, 0), (0, nk * bk - Sk), (0, 0)))
-        vt = jnp.pad(vt, ((0, 0), (0, 0), (0, nk * bk - Sk), (0, 0)))
-
-    # Sliding window: shrink the innermost grid to the k-blocks that
-    # can intersect each q-block's band — out-of-band K/V blocks are
-    # never DMA'd at all, so a long-context SWA step moves
-    # O(S·(window+block)) bytes instead of O(S²).
-    banded = causal and window is not None
-    if banded:
-        span = bq + window - 1                 # key span of one q-block
-        nkb = min(nk, -(-span // bk) + 1)
-
-        def k_map(b, h, i, j):
-            j0 = _band_j0(i, window=window, q_offset=q_offset,
-                          k_offset=k_offset, block_q=bq, block_k=bk)
-            return (b, h // group, jnp.minimum(j0 + j, nk - 1), 0)
-    else:
-        nkb = nk
-
-        def k_map(b, h, i, j):
-            return (b, h // group, j, 0)
-
-    kernel = functools.partial(
-        _flash_kernel, scale=D ** -0.5, causal=causal, window=window,
-        banded=banded, nk_total=nk,
-        q_offset=q_offset, k_offset=k_offset, kv_len=Sk,
-        block_q=bq, block_k=bk)
-
-    grid = (B, H, nq, nkb)
+    # Sliding window: the sweep — the in-kernel loop, or the streamed
+    # grid axis — covers only the k-blocks that can intersect each
+    # q-block's band; out-of-band K/V is never read.
+    common = dict(causal=causal, window=window, q_offset=q_offset,
+                  k_offset=k_offset, kv_len=Sk, block_q=t.bq,
+                  block_k=t.bk, nk=t.nk)
+    k_spec = _kv_spec(t, group, D,
+                      functools.partial(_k_sweep, **common))
+    q_spec = pl.BlockSpec((1, 1, t.bq, D), lambda b, h, i, s: (b, h, i, 0))
     out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bk, D), k_map),
-            pl.BlockSpec((1, 1, bk, D), k_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq, LSE_LANES),
-                         lambda b, h, i, j: (b, h, i, 0)),
-        ],
+        _kernel(_flash_kernel, interpret, scale=D ** -0.5,
+                fold=_fold_scale(D, q.dtype),
+                streamed=not t.kv_resident, **common),
+        grid=(B, H, t.nq, t.sweep_steps(window)[0]),
+        in_specs=[q_spec, k_spec, k_spec],
+        out_specs=[q_spec, _row_spec(t.bq)],
         out_shape=[
-            _sds((B, H, nq * bq, D), q.dtype, qt, kt, vt),
-            _sds((B, H, nq * bq, LSE_LANES), jnp.float32, qt, kt, vt),
+            _sds((B, H, t.Sqp, D), q.dtype, qt, kt, vt),
+            _sds((B, H, t.nq, 1, t.bq), jnp.float32, qt, kt, vt),
         ],
         scratch_shapes=[
-            _scratch((bq, D), jnp.float32),
-            _scratch((bq, 128), jnp.float32),
-            _scratch((bq, 128), jnp.float32),
+            _scratch((D, t.bq), jnp.float32),
+            _scratch((1, t.bq), jnp.float32),
+            _scratch((1, t.bq), jnp.float32),
         ],
-        compiler_params=None if interpret else _compiler_params(),
+        compiler_params=(None if interpret
+                         else _compiler_params(t.vmem_fwd)),
         interpret=interpret,
     )(qt, kt, vt)
     out = out[:, :, :Sq, :]
-    # lse stays rank-4 (lane-replicated) so a fused backward can DMA it
-    # straight back in without a 128x re-broadcast; public surfaces
-    # slice `[..., 0]`.
+    # lse stays in the kernels' layout so the fused backward can DMA
+    # it straight back in; public surfaces flatten the rows.
     return jnp.transpose(out, (0, 2, 1, 3)), lse
 
 
@@ -376,174 +725,183 @@ def _sds(shape, dtype, *like):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _recompute_p(q_ref, k_ref, lse_ref, *, scale, causal, window,
-                 kv_len, q_start, k_start, k_local0, block_q, block_k):
-    """Shared bwd-kernel tile: rebuild the probability block
-    `p = exp(scale·q·kᵀ − lse)` exactly as the forward computed it
-    (same f32 dot, same mask, -inf lse rows → 0)."""
-    qs = q_ref[0, 0].astype(jnp.float32) * scale           # [bq, D]
-    kb = k_ref[0, 0].astype(jnp.float32)                   # [bk, D]
-    s = jax.lax.dot_general(qs, kb, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    mask = _mask_block(q_start, k_start, causal=causal, window=window,
-                       kv_len=kv_len, k_local0=k_local0,
-                       block_q=block_q, block_k=block_k)
-    if mask is not None:
-        s = jnp.where(mask, s, NEG_INF)
-    lse = lse_ref[0, 0, :, :1]                             # [bq, 1]
-    p = jnp.where(jnp.isfinite(lse),
-                  jnp.exp(s - lse), 0.0)                   # [bq, bk]
-    return qs, kb, p
+def _group_q_map(t: _Tiles, sweep):
+    """Index map of the head group's Q (dO, lse, dvec) on dK/dV's grid
+    (b, hkv, j, step), in blocks of the group's heads: the whole
+    sequence when resident, else the ``step``-th q-block of k-block
+    ``j``'s band."""
+    if t.q_resident:
+        return lambda b, hkv, j, s: (b, hkv, 0, 0)
+    return lambda b, hkv, j, s: (b, hkv, _streamed_block(
+        j, s, sweep=sweep, n=t.nq), 0)
 
 
-def _relevant_block(q_start, k_start, *, causal, window, block_q,
-                    block_k):
-    """Causal/window block-skip predicate shared by the forward and
-    both backward kernels (~2x for long causal sequences); None when
-    nothing can be skipped."""
-    if not causal:
-        return None
-    rel = k_start <= q_start + block_q - 1
-    if window is not None:
-        rel = jnp.logical_and(
-            rel, k_start + block_k - 1 >= q_start - window + 1)
-    return rel
-
-
-def _flash_bwd_dkv_kernel(q_ref, do_ref, lse_ref, dvec_ref, k_ref,
-                          v_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                          scale, causal, window, banded, nq_total,
-                          nq_band, q_offset, k_offset,
-                          kv_len, block_q, block_k):
-    """dK/dV: grid (B, Hkv, k-block, group·q-block) — the innermost
-    sequential sweep runs every (gqa-group, q-block) pair, so the
-    accumulators fold the whole query-head group in VMEM scratch and
-    each dK/dV block is written to HBM exactly once AT KV WIDTH (with
-    GQA there is no full-H gradient materialization + reduce pass).
-
-    ``banded``: the q sweep covers only the blocks whose rows can see
-    this k-block under the sliding-window band (index_map adds
-    `_band_i0`; clamped duplicates skipped by the validity guard)."""
-    j = pl.program_id(2)
-    inner = pl.program_id(3)
-    nin = pl.num_programs(3)
-    qi = inner % nq_band       # q-block within this query head
-    # (inner // nq_band = the group member; only index maps need it)
-
-    @pl.when(inner == 0)
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    if banded:
-        il = _band_i0(j, q_offset=q_offset, k_offset=k_offset,
-                      block_q=block_q, block_k=block_k) + qi
-        ic = jnp.minimum(il, nq_total - 1)   # what the index_map DMA'd
-        in_range = il <= nq_total - 1
-    else:
-        ic = qi
-        in_range = True
-    q_start = q_offset + ic * block_q
-    k_start = k_offset + j * block_k
-
-    def _block():
-        qs, kb, p = _recompute_p(
-            q_ref, k_ref, lse_ref, scale=scale, causal=causal,
-            window=window, kv_len=kv_len, q_start=q_start,
-            k_start=k_start, k_local0=j * block_k,
-            block_q=block_q, block_k=block_k)
-        dob = do_ref[0, 0].astype(jnp.float32)             # [bq, D]
-        dv_acc[...] += jax.lax.dot_general(
-            p, dob, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bk, D]
-        vb = v_ref[0, 0].astype(jnp.float32)
-        dp = jax.lax.dot_general(
-            dob, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bq, bk]
-        ds = p * (dp - dvec_ref[0, 0, :, :1])
-        # s = (scale·q)·kᵀ, so dk = dsᵀ·(scale·q) — qs carries scale.
-        dk_acc[...] += jax.lax.dot_general(
-            ds, qs, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bk, D]
-
-    rel = _relevant_block(q_start, k_start, causal=causal, window=window,
-                        block_q=block_q, block_k=block_k)
-    if rel is None:  # traced guard; see _flash_kernel
-        rel = jnp.asarray(j) * block_k < kv_len
-    if banded:
-        rel = jnp.logical_and(rel, in_range)
-    pl.when(rel)(_block)
-
-    @pl.when(inner == nin - 1)
-    def _fin():
-        dk_ref[0, 0, :, :] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, 0, :, :] = dv_acc[...].astype(dv_ref.dtype)
-
-
-def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, dvec_ref, k_ref,
+def _flash_bwd_dq_kernel(pos, q_ref, do_ref, lse_ref, dvec_ref, k_ref,
                          v_ref, dq_ref, dq_acc, *,
-                         scale, causal, window, banded, nk_total,
-                         q_offset, k_offset,
-                         kv_len, block_q, block_k):
-    """dQ: grid (B, H, q-block, k-block) with the k sweep innermost.
+                         scale, fold, causal, window, q_offset,
+                         k_offset, kv_len, block_q, block_k, nk,
+                         streamed):
+    """dQ: the forward's grid and sweep (`_flash_kernel`), rebuilding
+    each probability block `p = exp(scale·q·kᵀ − lse)` exactly as the
+    forward computed it (same operands, same mask, -inf lse rows → 0):
+    dq = scale · Σ_j (p ∘ (dO·Vᵀ − dvec)) · K."""
+    qi = pos[0]
+    bounds, base, first, last = _swept(
+        pos, functools.partial(
+            _k_sweep, causal=causal, window=window, q_offset=q_offset,
+            k_offset=k_offset, kv_len=kv_len, block_q=block_q,
+            block_k=block_k, nk=nk), streamed)
+    q_start = q_offset + qi * block_q
 
-    ``banded``: same banded k sweep as the forward (`_band_j0`)."""
-    qi = pl.program_id(2)
-    j = pl.program_id(3)
-    nk = pl.num_programs(3)
-
-    @pl.when(j == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
+    _when(first, _init)
 
-    if banded:
-        jl = _band_j0(qi, window=window, q_offset=q_offset,
-                      k_offset=k_offset, block_q=block_q,
-                      block_k=block_k) + j
-        jc = jnp.minimum(jl, nk_total - 1)
-        in_range = jl <= nk_total - 1
-    else:
-        jc = j
-        in_range = True
-    q_start = q_offset + qi * block_q
-    k_start = k_offset + jc * block_k
+    q = q_ref[0, 0]                                          # [bq, D]
+    if fold:
+        q = _scaled(q, scale)
+    dob = do_ref[0, 0]
+    lse = _column(lse_ref[0, 0, 0])                          # [bq, 1]
+    dvec = _column(dvec_ref[0, 0, 0])
 
-    def _block():
-        qs, kb, p = _recompute_p(
-            q_ref, k_ref, lse_ref, scale=scale, causal=causal,
-            window=window, kv_len=kv_len, q_start=q_start,
-            k_start=k_start, k_local0=jc * block_k,
-            block_q=block_q, block_k=block_k)
-        dob = do_ref[0, 0].astype(jnp.float32)
-        vb = v_ref[0, 0].astype(jnp.float32)
-        dp = jax.lax.dot_general(
-            dob, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bq, bk]
-        ds = p * (dp - dvec_ref[0, 0, :, :1])
-        dq_acc[...] += jax.lax.dot_general(
-            ds, kb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bq, D]
+    def body(masked):
+        def _block(j, carry):
+            off = pl.multiple_of((j - base) * block_k, block_k)
+            k = k_ref[0, 0, pl.ds(off, block_k), :]          # [bk, D]
+            v = v_ref[0, 0, pl.ds(off, block_k), :]
+            s = jax.lax.dot_general(
+                q, k, _NT, preferred_element_type=jnp.float32)
+            if not fold:
+                s = s * scale
+            if masked:
+                mask = _mask_block(
+                    q_start, k_offset + j * block_k, causal=causal,
+                    window=window, kv_len=kv_len, k_local0=j * block_k,
+                    block_q=block_q, block_k=block_k)
+                p = jnp.where(
+                    jnp.logical_and(mask, jnp.isfinite(lse)),
+                    jnp.exp(s - lse), 0.0)
+            else:
+                # a row that sees a whole block has a finite lse
+                p = jnp.exp(s - lse)                         # [bq, bk]
+            dp = jax.lax.dot_general(
+                dob, v, _NT, preferred_element_type=jnp.float32)
+            ds = p * (dp - dvec)
+            dq_acc[...] += jax.lax.dot_general(
+                ds.astype(k.dtype), k, _NN,
+                preferred_element_type=jnp.float32)          # [bq, D]
+            return carry
+        return _block
 
-    rel = _relevant_block(q_start, k_start, causal=causal, window=window,
-                        block_q=block_q, block_k=block_k)
-    if rel is None:  # traced guard; see _flash_kernel
-        rel = jnp.asarray(jc) * block_k < kv_len
-    if banded:
-        rel = jnp.logical_and(rel, in_range)
-    pl.when(rel)(_block)
+    _sweep_loops(bounds, body,
+                 masked_head=causal and window is not None,
+                 masked_tail=causal or kv_len % block_k != 0)
 
-    @pl.when(j == nk - 1)
     def _fin():
         # dq = scale · Σ_j ds·k (ds was taken w.r.t. scale·q·kᵀ).
         dq_ref[0, 0, :, :] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+    _when(last, _fin)
 
 
+def _flash_bwd_dkv_kernel(pos, q_ref, do_ref, lse_ref, dvec_ref, k_ref,
+                          v_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                          scale, fold, causal, window, q_offset,
+                          k_offset, block_q, block_k, group, nq,
+                          streamed):
+    """dK/dV: grid (B, Hkv, k-block, sweep step). The k-block sweeps,
+    for every query head of its GQA group, the q sub-blocks FROM the
+    diagonal (`_q_sweep`) of the Q/dO/lse/dvec block in VMEM — the
+    whole group's sequence when resident, one q-block of it a step
+    when streamed — so the accumulators fold the group in VMEM
+    scratch and each dK/dV block is written to HBM exactly once AT KV
+    WIDTH (no full-H gradient materialization + reduce pass).
+
+    Everything is computed transposed — Sᵀ = K·Qᵀ, Pᵀ, dPᵀ = V·dOᵀ —
+    with lse and dvec as [1, block_q] rows, so dV += Pᵀ·dO and
+    dK += dSᵀ·Q are plain matmuls and nothing crosses the XLU."""
+    kj = pos[0]
+    bounds, base, first, last = _swept(
+        pos, functools.partial(
+            _q_sweep, causal=causal, window=window, q_offset=q_offset,
+            k_offset=k_offset, block_q=block_q, block_k=block_k,
+            nq=nq), streamed)
+    k_start = k_offset + kj * block_k
+
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+    _when(first, _init)
+
+    k = k_ref[0, 0]                                          # [bk, D]
+    if fold:
+        k = _scaled(k, scale)
+    v = v_ref[0, 0]
+
+    def body(g, masked):
+        def _block(i, carry):
+            sub = i - base
+            off = pl.multiple_of(sub * block_q, block_q)
+            qb = q_ref[0, g, pl.ds(off, block_q), :]         # [bq, D]
+            dob = do_ref[0, g, pl.ds(off, block_q), :]
+            lse = lse_ref[0, g, sub]                         # [1, bq]
+            dvec = dvec_ref[0, g, sub]
+            st = jax.lax.dot_general(
+                k, qb, _NT, preferred_element_type=jnp.float32)
+            if not fold:
+                st = st * scale                              # [bk, bq]
+            if masked:
+                # (zero-padded keys need no mask here: they only reach
+                # their own dK/dV rows, which the wrapper drops)
+                mask = _mask_block(
+                    q_offset + i * block_q, k_start, causal=causal,
+                    window=window, kv_len=0, k_local0=None,
+                    block_q=block_q, block_k=block_k, transposed=True)
+                pt = jnp.where(
+                    jnp.logical_and(mask, jnp.isfinite(lse)),
+                    jnp.exp(st - lse), 0.0)
+            else:
+                pt = jnp.exp(st - lse)
+            dv_acc[...] += jax.lax.dot_general(
+                pt.astype(dob.dtype), dob, _NN,
+                preferred_element_type=jnp.float32)          # [bk, D]
+            dpt = jax.lax.dot_general(
+                v, dob, _NT, preferred_element_type=jnp.float32)
+            dst = pt * (dpt - dvec)
+            dk_acc[...] += jax.lax.dot_general(
+                dst.astype(qb.dtype), qb, _NN,
+                preferred_element_type=jnp.float32)          # [bk, D]
+            return carry
+        return _block
+
+    def _group_member(g, carry):
+        _sweep_loops(bounds, functools.partial(body, g),
+                     masked_head=causal,
+                     masked_tail=causal and window is not None)
+        return carry
+    if group == 1:
+        _group_member(0, 0)
+    else:
+        jax.lax.fori_loop(0, group, _group_member, 0)
+
+    def _fin():
+        # s = scale·q·kᵀ, so dk = scale · dsᵀ·q: a folded scale sits on
+        # k, not on the q that dsᵀ multiplies.
+        dk_ref[0, 0, :, :] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0, 0, :, :] = dv_acc[...].astype(dv_ref.dtype)
+    _when(last, _fin)
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "causal", "window", "q_offset", "k_offset", "block_q", "block_k",
+    "interpret"))
 def _flash_backward(q, k, v, o, lse, g, *, causal, window, q_offset,
                     k_offset, block_q, block_k, interpret, dlse=None):
     """Fused Pallas backward (FlashAttention-2 style): recompute each
     probability tile from Q/K and the saved row logsumexp, never
-    materializing [Sq, Sk] — two kernels (dK/dV with q innermost, dQ
-    with k innermost), each output written once.
+    materializing [Sq, Sk] — two kernels on the forward's tiles
+    (`_pick_tiles`): dQ with the forward's grid and k sweep, dK/dV
+    owning k-blocks and sweeping q from the diagonal, each output
+    written once.
 
     vs the XLA recompute VJP it replaces on this path: no per-block
     scan residuals in HBM and no [B,Sq,H,D]-carry rewrite per k-block
@@ -552,23 +910,16 @@ def _flash_backward(q, k, v, o, lse, g, *, causal, window, q_offset,
     """
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
-    # Same snapped tiles as the forward (v5-lite divisibility).
-    bq = _snap_tile(block_q, Sq)
-    bk = _snap_tile(block_k, Sk)
-    nq = -(-Sq // bq)
-    nk = -(-Sk // bk)
-
-    qt = jnp.transpose(q, (0, 2, 1, 3))
-    kt = jnp.transpose(k, (0, 2, 1, 3))
-    vt = jnp.transpose(v, (0, 2, 1, 3))
-    ot = jnp.transpose(o, (0, 2, 1, 3))
-    gt = jnp.transpose(g, (0, 2, 1, 3))
-    if nq * bq != Sq:
-        pad = ((0, 0), (0, 0), (0, nq * bq - Sq), (0, 0))
-        qt, ot, gt = jnp.pad(qt, pad), jnp.pad(ot, pad), jnp.pad(gt, pad)
-    if nk * bk != Sk:
-        pad = ((0, 0), (0, 0), (0, nk * bk - Sk), (0, 0))
-        kt, vt = jnp.pad(kt, pad), jnp.pad(vt, pad)
+    group = _gqa_group(q, k, v)
+    Hkv = H // group
+    # Same tiles as the forward (v5-lite divisibility).
+    t = _pick_tiles(Sq, Sk, D, q.dtype.itemsize, group, block_q,
+                    block_k)
+    qt = _head_major(q, t.Sqp)
+    ot = _head_major(o, t.Sqp)
+    gt = _head_major(g, t.Sqp)
+    kt = _head_major(k, t.Skp)
+    vt = _head_major(v, t.Skp)
     # D_i = Σ_d dO_id · O_id (rowwise) — the softmax-jacobian term;
     # cheap elementwise+reduce, XLA fuses it into the transposes.
     # When the row logsumexp is itself an output with a cotangent
@@ -578,82 +929,61 @@ def _flash_backward(q, k, v, o, lse, g, *, causal, window, q_offset,
     dvec = (gt.astype(jnp.float32) * ot.astype(jnp.float32)).sum(-1)
     if dlse is not None:
         dvec = dvec - dlse.astype(jnp.float32)
-    # dvec is born rank-3 here; lane-replicate it for Mosaic (see
-    # LSE_LANES). lse arrives already rank-4 from the forward.
-    dvec = jnp.broadcast_to(dvec[..., None], (*dvec.shape, LSE_LANES))
+    # Like lse from the forward, dvec is one [1, block_q] row a
+    # q-block: what dK/dV, which works transposed, reads as it is, and
+    # dQ turns into a column once a grid step.
+    dvec = dvec.reshape(B, H, t.nq, 1, t.bq)
 
     # Sliding window: both sweeps shrink to the band, mirroring the
-    # forward grid — out-of-band blocks are never DMA'd.
-    banded = causal and window is not None
-    group = _gqa_group(q, k, v)
-    if banded:
-        nkb = min(nk, -(-(bq + window - 1) // bk) + 1)
-        nqb = min(nq, -(-(bk + window - 1) // bq) + 1)
-
-        def dq_k_map(b, h, i, j):
-            j0 = _band_j0(i, window=window, q_offset=q_offset,
-                          k_offset=k_offset, block_q=bq, block_k=bk)
-            return (b, h // group, jnp.minimum(j0 + j, nk - 1), 0)
-
-        def dkv_q_map(b, hkv, j, inner):
-            i0 = _band_i0(j, q_offset=q_offset, k_offset=k_offset,
-                          block_q=bq, block_k=bk)
-            i = jnp.minimum(i0 + inner % nqb, nq - 1)
-            return (b, hkv * group + inner // nqb, i, 0)
-    else:
-        nkb, nqb = nk, nq
-
-        def dq_k_map(b, h, i, j):
-            return (b, h // group, j, 0)
-
-        def dkv_q_map(b, hkv, j, inner):
-            return (b, hkv * group + inner // nqb, inner % nqb, 0)
-
-    common = dict(scale=D ** -0.5, causal=causal, window=window,
-                  banded=banded, q_offset=q_offset, k_offset=k_offset,
-                  kv_len=Sk, block_q=bq, block_k=bk)
-    q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0))
-    r_spec = pl.BlockSpec((1, 1, bq, LSE_LANES),
-                          lambda b, h, i, j: (b, h, i, 0))
+    # forward — out-of-band blocks are never read.
+    dq_steps, dkv_steps = t.sweep_steps(window)
+    common = dict(causal=causal, window=window, q_offset=q_offset,
+                  k_offset=k_offset, block_q=t.bq, block_k=t.bk)
+    scale_kw = dict(scale=D ** -0.5, fold=_fold_scale(D, q.dtype))
+    k_sweep = dict(kv_len=Sk, nk=t.nk, **common)
+    q_spec = pl.BlockSpec((1, 1, t.bq, D), lambda b, h, i, s: (b, h, i, 0))
+    r_spec = _row_spec(t.bq)
+    k_spec = _kv_spec(t, group, D,
+                      functools.partial(_k_sweep, **k_sweep))
 
     dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, nk_total=nk, **common),
-        grid=(B, H, nq, nkb),
-        in_specs=[
-            q_spec, q_spec, r_spec, r_spec,
-            pl.BlockSpec((1, 1, bk, D), dq_k_map),
-            pl.BlockSpec((1, 1, bk, D), dq_k_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, D),
-                               lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=_sds((B, H, nq * bq, D), q.dtype, qt, gt, kt, vt),
-        scratch_shapes=[_scratch((bq, D), jnp.float32)],
-        compiler_params=None if interpret else _compiler_params(),
+        _kernel(_flash_bwd_dq_kernel, interpret,
+                streamed=not t.kv_resident, **scale_kw, **k_sweep),
+        grid=(B, H, t.nq, dq_steps),
+        in_specs=[q_spec, q_spec, r_spec, r_spec, k_spec, k_spec],
+        out_specs=q_spec,
+        out_shape=_sds((B, H, t.Sqp, D), q.dtype, qt, gt, kt, vt),
+        scratch_shapes=[_scratch((t.bq, D), jnp.float32)],
+        compiler_params=(None if interpret
+                         else _compiler_params(t.vmem_dq)),
         interpret=interpret,
     )(qt, gt, lse, dvec, kt, vt)
 
-    kq_spec = pl.BlockSpec((1, 1, bq, D), dkv_q_map)
-    kr_spec = pl.BlockSpec((1, 1, bq, LSE_LANES), dkv_q_map)
-    kk_spec = pl.BlockSpec((1, 1, bk, D),
-                           lambda b, hkv, j, inner: (b, hkv, j, 0))
-    Hkv = H // group
-    # Grid over KV heads; the inner sequential sweep folds the whole
+    # Grid over KV heads; the in-kernel sweep folds the whole
     # query-head group into the VMEM accumulators, so dK/dV are
     # written once, at kv width — no full-H gradient + reduce pass.
+    q_sweep = dict(nq=t.nq, **common)
+    swept_map = _group_q_map(t, functools.partial(_q_sweep, **q_sweep))
+    kq_spec = pl.BlockSpec((1, group, t.q_rows, D), swept_map)
+    kr_spec = pl.BlockSpec(
+        (1, group, t.q_rows // t.bq, 1, t.bq),
+        lambda b, hkv, j, s: (*swept_map(b, hkv, j, s), 0))
+    kk_spec = pl.BlockSpec((1, 1, t.bk, D),
+                           lambda b, hkv, j, s: (b, hkv, j, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, nq_total=nq,
-                          nq_band=nqb, **common),
-        grid=(B, Hkv, nk, group * nqb),
-        in_specs=[kq_spec, kq_spec, kr_spec, kr_spec,
-                  kk_spec, kk_spec],
+        _kernel(_flash_bwd_dkv_kernel, interpret, group=group,
+                streamed=not t.q_resident, **scale_kw, **q_sweep),
+        grid=(B, Hkv, t.nk, dkv_steps),
+        in_specs=[kq_spec, kq_spec, kr_spec, kr_spec, kk_spec, kk_spec],
         out_specs=[kk_spec, kk_spec],
         out_shape=[
-            _sds((B, Hkv, nk * bk, D), k.dtype, qt, gt, kt, vt),
-            _sds((B, Hkv, nk * bk, D), v.dtype, qt, gt, kt, vt),
+            _sds((B, Hkv, t.Skp, D), k.dtype, qt, gt, kt, vt),
+            _sds((B, Hkv, t.Skp, D), v.dtype, qt, gt, kt, vt),
         ],
-        scratch_shapes=[_scratch((bk, D), jnp.float32),
-                        _scratch((bk, D), jnp.float32)],
-        compiler_params=None if interpret else _compiler_params(),
+        scratch_shapes=[_scratch((t.bk, D), jnp.float32),
+                        _scratch((t.bk, D), jnp.float32)],
+        compiler_params=(None if interpret
+                         else _compiler_params(t.vmem_dkv)),
         interpret=interpret,
     )(qt, gt, lse, dvec, kt, vt)
 
@@ -661,6 +991,10 @@ def _flash_backward(q, k, v, o, lse, g, *, causal, window, q_offset,
     dk = jnp.transpose(dk[:, :, :Sk], (0, 2, 1, 3))
     dv = jnp.transpose(dv[:, :, :Sk], (0, 2, 1, 3))
     return dq, dk, dv
+
+
+def _opt_int(x):
+    return None if x is None else int(x)
 
 
 def _auto_interpret() -> bool:
@@ -689,6 +1023,11 @@ def _make_flash(causal, window, q_offset, k_offset, block_q, block_k,
     """
     from horovod_tpu.parallel.sequence import blockwise_attention
 
+    # The XLA recompute fallback scans in its own blocks: the caller's
+    # where given, else the 128 it always used (the shape-chosen tiles
+    # are the Pallas kernels').
+    scan_q, scan_k = block_q or 128, block_k or 128
+
     def ref(q, k, v):
         # GQA: repeat kv INSIDE the vjp'd fn — jnp.repeat's transpose
         # is the per-group sum, so dk/dv come back at kv-head width.
@@ -697,13 +1036,13 @@ def _make_flash(causal, window, q_offset, k_offset, block_q, block_k,
             k = jnp.repeat(k, g_, axis=2)
             v = jnp.repeat(v, g_, axis=2)
         return blockwise_attention(
-            q, k, v, block_size=block_k, causal=causal, window=window,
+            q, k, v, block_size=scan_k, causal=causal, window=window,
             q_offset=q_offset, k_offset=k_offset)
 
     def _banded_bwd(q, k, v, g):
         B, Sq, H, D = q.shape
         Sk = k.shape[1]
-        C = min(block_q, Sq)
+        C = min(scan_q, Sq)
         span = C + window - 1          # keys one q-chunk's band touches
         nc = -(-Sq // C)
         pad_q = nc * C - Sq
@@ -733,7 +1072,7 @@ def _make_flash(causal, window, q_offset, k_offset, block_q, block_k,
                     kc = jnp.repeat(kc, _g, axis=2)
                     vc = jnp.repeat(vc, _g, axis=2)
                 return blockwise_attention(
-                    qc, kc, vc, block_size=block_k, causal=True,
+                    qc, kc, vc, block_size=scan_k, causal=True,
                     window=window, q_offset=q_offset + ci * C,
                     k_offset=k_offset + _start)
             _, vjp = jax.vjp(fn, qc, kc, vc)
@@ -785,7 +1124,7 @@ def _make_flash(causal, window, q_offset, k_offset, block_q, block_k,
             q, k, v = res
             # Band the backward only when it shrinks the key span.
             if (causal and window is not None
-                    and min(block_q, q.shape[1]) + window - 1
+                    and min(scan_q, q.shape[1]) + window - 1
                     < k.shape[1]):
                 return _banded_bwd(q, k, v, g)
             _, vjp = jax.vjp(ref, q, k, v)
@@ -793,6 +1132,11 @@ def _make_flash(causal, window, q_offset, k_offset, block_q, block_k,
 
     flash.defvjp(fwd, bwd)
     return flash
+
+
+def _public_lse(lse, Sq):
+    """[B, H, Sq] from the forward's kernel-layout logsumexp."""
+    return lse.reshape(*lse.shape[:2], -1)[:, :, :Sq]
 
 
 @functools.lru_cache(maxsize=None)
@@ -808,19 +1152,19 @@ def _make_flash_lse(causal, window, q_offset, k_offset, block_q,
             q, k, v, causal=causal, window=window,
             q_offset=q_offset, k_offset=k_offset,
             block_q=block_q, block_k=block_k, interpret=interpret)
-        return o, lse[:, :, :q.shape[1], 0]
+        return o, _public_lse(lse, q.shape[1])
 
     def fwd(q, k, v):
         o, lse = _flash_forward(
             q, k, v, causal=causal, window=window,
             q_offset=q_offset, k_offset=k_offset,
             block_q=block_q, block_k=block_k, interpret=interpret)
-        return (o, lse[:, :, :q.shape[1], 0]), (q, k, v, o, lse)
+        return (o, _public_lse(lse, q.shape[1])), (q, k, v, o, lse)
 
     def bwd(res, cot):
         q, k, v, o, lse = res
         g, dlse = cot
-        pad = lse.shape[2] - q.shape[1]
+        pad = lse.shape[2] * lse.shape[4] - q.shape[1]
         if pad:
             dlse = jnp.pad(dlse, ((0, 0), (0, 0), (0, pad)))
         return _flash_backward(
@@ -837,7 +1181,8 @@ def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array,
                         *, causal: bool = False,
                         window: Optional[int] = None,
                         q_offset: int = 0, k_offset: int = 0,
-                        block_q: int = 128, block_k: int = 128,
+                        block_q: Optional[int] = None,
+                        block_k: Optional[int] = None,
                         interpret: Optional[bool] = None):
     """Flash attention that ALSO returns the row logsumexp.
 
@@ -864,7 +1209,8 @@ def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array,
     fn = _make_flash_lse(bool(causal),
                          None if window is None else int(window),
                          int(q_offset), int(k_offset),
-                         int(block_q), int(block_k), bool(interpret))
+                         _opt_int(block_q), _opt_int(block_k),
+                         bool(interpret))
     return fn(q, k, v)
 
 
@@ -875,15 +1221,20 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     mask=None, *, causal: bool = False,
                     window: Optional[int] = None,
                     q_offset: int = 0, k_offset: int = 0,
-                    block_q: int = 128, block_k: int = 128,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     bwd_impl: str = "auto") -> jax.Array:
     """Fused flash attention, [B, S, H, D] → [B, S, H, D].
 
     Args:
-      q, k, v: [batch, seq, heads, head_dim] (any float dtype; compute is
-        float32, output matches `q.dtype`). `head_dim` a multiple of 128
-        keeps the MXU fully tiled; smaller values work but underfill lanes.
+      q, k, v: [batch, seq, heads, head_dim], any float dtype. The
+        matmuls take their operands in that dtype (bf16 inputs ride the
+        MXU as bf16; `p` and `ds` are cast to it before theirs) and
+        accumulate in float32; max, exp, sum, lse and the accumulators
+        are float32; the output matches `q.dtype`. `head_dim` a multiple
+        of 128 keeps the MXU fully tiled; smaller values work but fill
+        half its contraction depth (D 64: a fifth of the roofline).
       mask: unsupported here (only `causal=`); pass explicit masks to
         `parallel.tensor.dot_product_attention`. Accepted positionally as
         None so the fn is drop-in for `ParallelSelfAttention.attn_fn`.
@@ -891,15 +1242,22 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         `q_offset + i >= k_offset + j` (offsets support ring-attention
         style rotated blocks).
       window: sliding-window attention (last `window` positions only;
-        requires causal; >= 1). Banded end to end: the FORWARD's
-        innermost grid axis covers only the k-blocks intersecting each
-        q-block's band (out-of-band K/V never read from HBM), and the
-        recompute BACKWARD scans q in `block_q` chunks whose VJPs see
-        only each band's `block_q + window - 1` keys — so an SWA
-        training step moves O(S·(window+block)) bytes and FLOPs, not
-        O(S²).
-      block_q, block_k: VMEM tile sizes (128 matches the MXU; raise
-        block_k to 256/512 when head_dim is small).
+        requires causal; >= 1). Banded end to end: every sweep —
+        the in-kernel loop over resident K/V, or the innermost grid
+        axis where K/V stream — covers only the blocks intersecting
+        the band (streamed out-of-band K/V is never read from HBM),
+        and the recompute BACKWARD scans q in `block_q` chunks whose
+        VJPs see only each band's `block_q + window - 1` keys — so an
+        SWA training step does O(S·(window+block)) FLOPs, not O(S²).
+      block_q, block_k: the q and k tiles. None (the default): chosen
+        from the shape by `_pick_tiles` — 512 rows where the sequence
+        is long enough (on one v5e chip 512 x 512 measured fastest at
+        S 1024 and 2048, D 64 and 128; PR 25), the whole padded axis
+        where it is shorter, a smaller tile where that pads a ragged
+        length less. An integer is honoured (snapped to a
+        hardware-legal size). Either way K/V (for dK/dV: the head
+        group's Q and dO) stay resident in VMEM where they fit and
+        stream where not; `flash_tile_check` returns the plan.
       interpret: run the kernel in interpreter mode (None = auto: True
         off-TPU, so the same tests run on the CPU mesh).
       bwd_impl: "auto" (default — the fused Pallas backward
@@ -936,7 +1294,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     fn = _make_flash(bool(causal),
                      None if window is None else int(window),
                      int(q_offset), int(k_offset),
-                     int(block_q), int(block_k), bool(interpret),
+                     _opt_int(block_q), _opt_int(block_k),
+                     bool(interpret),
                      bwd_impl)
     return fn(q, k, v)
 
@@ -989,10 +1348,10 @@ def _decode_kernel(s_ref, q_ref, k_ref, v_ref, o_ref,
         parts = []
         for h in range(hkv):
             qh = q[h * grp:(h + 1) * grp, :]
-            kh = kb[:, h, :].astype(jnp.float32)        # [bk, D]
+            kh = kb[:, h, :].astype(jnp.float32)             # [bk, D]
             parts.append(jax.lax.dot_general(
                 qh, kh, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32))    # [grp, bk]
+                preferred_element_type=jnp.float32))         # [grp, bk]
         logits = parts[0] if hkv == 1 else jnp.concatenate(parts, 0)
         pos = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, logits.shape, 1)
@@ -1013,7 +1372,7 @@ def _decode_kernel(s_ref, q_ref, k_ref, v_ref, o_ref,
             vh = vb[:, h, :].astype(jnp.float32)
             pv_parts.append(jax.lax.dot_general(
                 ph, vh, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))    # [grp, D]
+                preferred_element_type=jnp.float32))         # [grp, D]
         pv = pv_parts[0] if hkv == 1 else jnp.concatenate(pv_parts, 0)
         acc_ref[...] = acc_ref[...] * corr[:, :1] + pv
         m_ref[...] = m_new

@@ -20,6 +20,7 @@ described chip cannot be read back without one).
 """
 
 import os
+import re
 
 import pytest
 
@@ -79,6 +80,42 @@ def custom_calls(fn, *args):
         "tpu_custom_call")
 
 
+# The attention shapes the benchmark's cells and their neighbours use:
+# (batch, seq, heads, kv heads, head_dim).
+FLASH_SHAPES = {
+    "flagship": (B, S, H, H, D),
+    "gpt2-medium": (4, 1024, 16, 16, 64),
+    "qwen2.5-1.5b-prefill": (1, 2048, 12, 2, 128),
+}
+
+
+def vmem_asks(fn, *args):
+    """Bytes of scoped VMEM each Mosaic call of the compiled program
+    was given: the kernel's `vmem_limit_bytes`, or the default where
+    it set none (the record is then the default's size, or empty)."""
+    from horovod_tpu.ops.flash_attention import VMEM_SCOPED_DEFAULT
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    asks = []
+    for line in text.splitlines():
+        if "tpu_custom_call" not in line:
+            continue
+        record = re.search(r'"scoped_memory_configs":\[([^\]]*)\]', line)
+        assert record, "a Mosaic call without a scoped-memory record"
+        size = re.search(r'"size":"(\d+)"', record.group(1))
+        asks.append(int(size.group(1)) if size else VMEM_SCOPED_DEFAULT)
+    return sorted(asks)
+
+
+def planned_asks(shape, kernels):
+    from horovod_tpu.ops.flash_attention import (
+        VMEM_SCOPED_DEFAULT, flash_tile_check)
+    b, s, h, hkv, d = shape
+    plan = flash_tile_check(s, s, h, hkv, d, itemsize=2)
+    assert all(ok for *_, ok in plan)
+    return sorted(plan.vmem_limit_bytes[k] or VMEM_SCOPED_DEFAULT
+                  for k in kernels)
+
+
 def test_described_chip_is_v5_lite(topo):
     """The device_kind the peak table is keyed by."""
     from horovod_tpu.utils.profile_analysis import PEAK_BF16_FLOPS
@@ -87,25 +124,39 @@ def test_described_chip_is_v5_lite(topo):
     assert kind in PEAK_BF16_FLOPS
 
 
-def test_flash_forward(sds):
+@pytest.mark.parametrize("shape", sorted(FLASH_SHAPES))
+def test_flash_forward(sds, shape):
+    """One Mosaic call, on the tiles chosen from the shape, asking
+    for the VMEM its plan said."""
     from horovod_tpu.ops.flash_attention import flash_attention
-    q = sds((B, S, H, D))
-    assert custom_calls(
-        lambda q, k, v: flash_attention(q, k, v, causal=True,
-                                        interpret=False), q, q, q) == 1
+    b, s, h, hkv, d = FLASH_SHAPES[shape]
+    q, kv = sds((b, s, h, d)), sds((b, s, hkv, d))
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    assert vmem_asks(fwd, q, kv, kv) == planned_asks(
+        FLASH_SHAPES[shape], ["fwd"])
 
 
-def test_flash_fused_backward(sds):
-    """Forward + the two FlashAttention-2 style backward kernels."""
+@pytest.mark.parametrize("shape", sorted(FLASH_SHAPES))
+def test_flash_fused_backward(sds, shape):
+    """Forward + the two FlashAttention-2 style backward kernels:
+    EXACTLY three Mosaic calls (the benchmark's `flash_roofline`
+    reader counts 3 a layer), each asking for its plan's VMEM."""
     from horovod_tpu.ops.flash_attention import flash_attention
-    q = sds((B, S, H, D))
+    b, s, h, hkv, d = FLASH_SHAPES[shape]
+    q, kv = sds((b, s, h, d)), sds((b, s, hkv, d))
 
     def loss(q, k, v):
         return flash_attention(q, k, v, causal=True, interpret=False,
                                bwd_impl="pallas").astype(
                                    jnp.float32).mean()
 
-    assert custom_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, q) == 3
+    asks = vmem_asks(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert len(asks) == 3
+    assert asks == planned_asks(FLASH_SHAPES[shape],
+                                ["fwd", "bwd.dq", "bwd.dkv"])
 
 
 def test_flash_forward_window_512(sds):
