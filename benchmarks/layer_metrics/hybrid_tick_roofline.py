@@ -1,0 +1,43 @@
+"""A hybrid model's decode tick against its roofline: as
+`decode_tick_roofline`, with the required bytes and flops of the
+configuration's architecture module (`tick_least_seconds`: experts hit,
+other weights, recurrent state, K/V).
+
+What a tick was asked to do is on two records of the scheduler's loop:
+`sched.tick_dispatch` (lanes_decoding, context_sum) and, one step later,
+`sched.tick_sync` (moe_experts_hit, moe_pairs - counted on the device
+while the tick ran). The ticks of the two inside the trace's range differ
+by one at the edges, so the least time is taken at the MEANS of the
+records (it is linear in each of them but for the choice of the bound)."""
+
+from benchmarks.harness import loopspans, trace
+
+
+def _mean(records, name, key):
+    vals = [x["attrs"][key] for x in records
+            if x["name"] == name and key in x["attrs"]]
+    return sum(vals) / len(vals) if vals else None
+
+
+def read(ctx, module):
+    if ctx.get("trace") is None or not ctx["trace"]["devices"]:
+        return None
+    found = loopspans.traced(ctx)
+    arch_mod = ctx.get("arch_module")
+    if found is None or arch_mod is None:
+        return None
+    rec = found["records"]
+    asked = {
+        "lanes_decoding": _mean(rec, "sched.tick_dispatch",
+                                "lanes_decoding"),
+        "context_sum": _mean(rec, "sched.tick_dispatch", "context_sum"),
+        "experts_hit": _mean(rec, "sched.tick_sync", "moe_experts_hit"),
+        "pairs": _mean(rec, "sched.tick_sync", "moe_pairs")}
+    times = trace.module_times(ctx["trace"], module)
+    if not times or any(v is None for v in asked.values()):
+        return None
+    least, bound = arch_mod.tick_least_seconds(
+        ctx["cell"].config["arch"], ctx["peaks"], **asked)
+    print(f"hybrid tick: mean tick asked {asked}; least "
+          f"{least * 1e3:.3f} ms, bound by {bound}", flush=True)
+    return least / (sum(times) / len(times)) * 100.0

@@ -40,7 +40,8 @@ from jax.sharding import PartitionSpec as P
 import flax.linen as nn
 
 from horovod_tpu.annotations import hot_path
-from horovod_tpu.parallel.expert import MoELayer
+from horovod_tpu.parallel.expert import HeldExpertsMoE, MoELayer
+from horovod_tpu.parallel.linear_attention import KDAAttention
 from horovod_tpu.parallel.mesh import (
     AXIS_DATA, AXIS_MODEL, AXIS_SEQ, constrain, use,
 )
@@ -237,10 +238,23 @@ class TransformerBlock(nn.Module):
     mlp_hidden: Optional[int] = None     # absolute width (else ratio*d)
     lora_rank: int = 0                   # LoRA adapters on the Denses
     lora_alpha: Optional[float] = None
+    # The token mixer: "attn" (softmax attention) | "kda" (delta-rule
+    # linear attention, `parallel.linear_attention.KDAAttention`).
+    mixer: str = "attn"
+    attn_gate: bool = False              # sigmoid output gate (attn)
+    # "gshard" (`MoELayer`: capacity, drops) | "dropless"
+    # (`HeldExpertsMoE`: the experts this chip holds, no drops).
+    moe_impl: str = "gshard"
+    moe_hidden: Optional[int] = None     # expert width (else ratio*d)
+    moe_held: Optional[Tuple[int, int]] = None   # (first, count)
+    moe_shared_hidden: int = 0
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         d = x.shape[-1]
+        if self.mixer not in ("attn", "kda"):
+            raise ValueError(
+                f"mixer must be attn|kda, got {self.mixer!r}")
         if self.window is not None and not self.causal:
             # Every masked impl raises this from inside its scan; the
             # dot baseline would silently drop the window instead —
@@ -266,50 +280,70 @@ class TransformerBlock(nn.Module):
             mask = banded_causal_mask(pos, pos, self.window)[None, None]
         h = _make_norm(self.norm, self.dtype, self.ln_eps,
                        "ln_attn")(x)
-        h = ParallelSelfAttention(
-            num_heads=self.num_heads, head_dim=self.head_dim,
-            num_kv_heads=self.num_kv_heads, pos_emb=self.pos_emb,
-            rope_theta=self.rope_theta, window=self.window,
-            dtype=self.dtype, attn_fn=attn_fn, decode=self.decode,
-            chunked_prefill=self.chunked_prefill,
-            decode_prefix_block=self.decode_prefix_block,
-            decode_prefix_impl=self.decode_prefix_impl,
-            weight_quant=self.weight_quant,
-            kv_quant=self.kv_quant,
-            use_bias=self.attn_bias, out_bias=self.attn_out_bias,
-            lora_rank=self.lora_rank, lora_alpha=self.lora_alpha,
-            name="attn")(h, mask)
+        if self.mixer == "kda":
+            h = KDAAttention(
+                num_heads=self.num_heads, head_dim=self.head_dim,
+                out_features=d, norm_eps=self.ln_eps, dtype=self.dtype,
+                decode=self.decode, name="kda")(h)
+        else:
+            h = ParallelSelfAttention(
+                num_heads=self.num_heads, head_dim=self.head_dim,
+                num_kv_heads=self.num_kv_heads, pos_emb=self.pos_emb,
+                rope_theta=self.rope_theta, window=self.window,
+                dtype=self.dtype, attn_fn=attn_fn, decode=self.decode,
+                chunked_prefill=self.chunked_prefill,
+                decode_prefix_block=self.decode_prefix_block,
+                decode_prefix_impl=self.decode_prefix_impl,
+                weight_quant=self.weight_quant,
+                kv_quant=self.kv_quant,
+                use_bias=self.attn_bias, out_bias=self.attn_out_bias,
+                out_gate=self.attn_gate,
+                out_features=(None if d == self.num_heads * self.head_dim
+                              else d),
+                lora_rank=self.lora_rank, lora_alpha=self.lora_alpha,
+                name="attn")(h, mask)
         x = x + h
         h = _make_norm(self.norm, self.dtype, self.ln_eps,
                        "ln_mlp")(x)
-        if self.moe:
+        hidden = self.mlp_hidden or self.mlp_ratio * d
+        if self.moe and self.moe_impl == "dropless":
+            h = HeldExpertsMoE(
+                num_experts=self.num_experts,
+                hidden=self.moe_hidden or self.mlp_ratio * d,
+                k=self.moe_k, held=self.moe_held,
+                shared_hidden=self.moe_shared_hidden,
+                dtype=self.dtype, name="moe")(h)
+        elif self.moe and self.moe_impl == "gshard":
             h = MoELayer(num_experts=self.num_experts,
-                         hidden=self.mlp_ratio * d, k=self.moe_k,
+                         hidden=self.moe_hidden or self.mlp_ratio * d,
+                         k=self.moe_k,
                          capacity_factor=self.moe_capacity_factor,
                          dtype=self.dtype, name="moe")(h)
+        elif self.moe:
+            raise ValueError(
+                f"moe_impl must be gshard|dropless, got "
+                f"{self.moe_impl!r}")
+        elif self.mlp_impl in ("swiglu", "geglu"):
+            # Same gated two-projection block; geglu (Gemma) gates
+            # with tanh-gelu instead of silu.
+            h = ParallelSwiGLU(hidden=hidden, out=d,
+                               activation=("gelu_tanh"
+                                           if self.mlp_impl
+                                           == "geglu" else "silu"),
+                               weight_quant=self.weight_quant,
+                               lora_rank=self.lora_rank,
+                               lora_alpha=self.lora_alpha,
+                               dtype=self.dtype, name="mlp")(h)
+        elif self.mlp_impl == "gelu":
+            h = ParallelMLP(hidden=hidden, out=d,
+                            weight_quant=self.weight_quant,
+                            lora_rank=self.lora_rank,
+                            lora_alpha=self.lora_alpha,
+                            dtype=self.dtype, name="mlp")(h)
         else:
-            hidden = self.mlp_hidden or self.mlp_ratio * d
-            if self.mlp_impl in ("swiglu", "geglu"):
-                # Same gated two-projection block; geglu (Gemma) gates
-                # with tanh-gelu instead of silu.
-                h = ParallelSwiGLU(hidden=hidden, out=d,
-                                   activation=("gelu_tanh"
-                                               if self.mlp_impl
-                                               == "geglu" else "silu"),
-                                   weight_quant=self.weight_quant,
-                                   lora_rank=self.lora_rank,
-                                   lora_alpha=self.lora_alpha,
-                                   dtype=self.dtype, name="mlp")(h)
-            elif self.mlp_impl == "gelu":
-                h = ParallelMLP(hidden=hidden, out=d,
-                                weight_quant=self.weight_quant,
-                                lora_rank=self.lora_rank,
-                                lora_alpha=self.lora_alpha,
-                                dtype=self.dtype, name="mlp")(h)
-            else:
-                raise ValueError(
-                    f"mlp_impl must be gelu|swiglu|geglu, got "
-                    f"{self.mlp_impl!r}")
+            raise ValueError(
+                f"mlp_impl must be gelu|swiglu|geglu, got "
+                f"{self.mlp_impl!r}")
         return x + h
 
 
@@ -378,16 +412,43 @@ class TransformerLM(nn.Module):
     # merge for serving with `models.lora.merge_lora`.
     lora_rank: int = 0
     lora_alpha: Optional[float] = None
+    # Width of the residual stream; None = num_heads x head_dim.
+    hidden_size: Optional[int] = None
+    # Hybrid models: the token mixer of each layer, "attn" | "kda"
+    # (len == num_layers); None = softmax attention everywhere. A "kda"
+    # layer keeps a recurrent state in the decode cache, not K/V
+    # (`parallel.linear_attention`).
+    layer_kinds: Optional[Tuple[str, ...]] = None
+    attn_gate: bool = False              # sigmoid output gate (attn)
+    # The expert layer of the `moe_every`-th blocks: "gshard"
+    # (`MoELayer`) | "dropless" (`HeldExpertsMoE`: routes over all
+    # `num_experts`, computes the `moe_held` = (first, count) it holds,
+    # plus a shared expert of width `moe_shared_hidden`).
+    moe_impl: str = "gshard"
+    moe_hidden: Optional[int] = None
+    moe_held: Optional[Tuple[int, int]] = None
+    moe_shared_hidden: int = 0
+
+    @property
+    def has_recurrent_state(self) -> bool:
+        """True when some layer's decode cache is a state that each
+        step overwrites (no K/V rows to graft, page or rewind)."""
+        return "kda" in (self.layer_kinds or ())
 
     @nn.compact
     def __call__(self, tokens: jax.Array,
                  return_hidden: bool = False) -> Any:
-        if self.pos_emb not in ("learned", "rope"):
+        if self.pos_emb not in ("learned", "rope", "none"):
             raise ValueError(
-                f"pos_emb must be 'learned' or 'rope', "
+                f"pos_emb must be 'learned', 'rope' or 'none', "
                 f"got {self.pos_emb!r}")
+        kinds = self.layer_kinds or ("attn",) * self.num_layers
+        if len(kinds) != self.num_layers:
+            raise ValueError(
+                f"layer_kinds has {len(kinds)} entries for "
+                f"{self.num_layers} layers")
         B, S = tokens.shape
-        d = self.num_heads * self.head_dim
+        d = self.hidden_size or self.num_heads * self.head_dim
         embed = self.param(
             "embed",
             nn.with_partitioning(nn.initializers.normal(0.02),
@@ -396,11 +457,12 @@ class TransformerLM(nn.Module):
         x = jnp.take(embed, tokens, axis=0)
         if self.embed_scale is not None:
             x = x * jnp.asarray(self.embed_scale, x.dtype)
-        if self.pos_emb != "rope":
+        if self.pos_emb == "learned":
             # Rotary positions live inside the attention (applied to
             # q/k at absolute positions — no learned table, no
             # position state outside the per-block KV cache index);
-            # learned positions add a table slice here.
+            # learned positions add a table slice here; "none" (NoPE)
+            # adds nothing anywhere.
             pos = self.param("pos", nn.initializers.normal(0.02),
                              (self.max_len, d), jnp.float32)
             if self.decode:
@@ -446,6 +508,10 @@ class TransformerLM(nn.Module):
                 mlp_hidden=self.mlp_hidden,
                 lora_rank=self.lora_rank,
                 lora_alpha=self.lora_alpha,
+                mixer=kinds[i], attn_gate=self.attn_gate,
+                moe_impl=self.moe_impl,
+                moe_hidden=self.moe_hidden, moe_held=self.moe_held,
+                moe_shared_hidden=self.moe_shared_hidden,
                 name=f"block_{i}")(x)
             x = constrain(x, AXIS_DATA, AXIS_SEQ, None)
 
@@ -1119,12 +1185,24 @@ def slot_reset(dec_model, cache, slot):
         cache)
 
 
+def _moe_pairs(dec_model, mut):
+    """What the dropless expert layers sowed in one apply, in layer
+    order: int32 [expert layers, experts held], the (token, expert)
+    pairs on each held expert; [0, 0] for a model without such a
+    layer."""
+    sown = mut.get("moe_stats", {})
+    rows = [sown[f"block_{i}"]["moe"]["pairs"]
+            for i in range(dec_model.num_layers) if f"block_{i}" in sown]
+    return jnp.stack(rows) if rows else jnp.zeros((0, 0), jnp.int32)
+
+
 @hot_path
 @functools.partial(jax.jit, static_argnames=("dec_model",),
                    donate_argnums=(2,))
 def slot_prefill_chunk(dec_model, params, cache, slot, chunk):
     """Append one [C]-token prompt chunk into slot ``slot``'s cache and
-    return ``(cache, last-position logits [V])``.
+    return ``(cache, last-position logits [V], expert pairs)`` - the
+    last as `_moe_pairs` has them.
 
     Runs the `chunked_prefill` path (cache-wide mask — correct for ANY
     current fill), so a prompt of arbitrary length P streams in as its
@@ -1135,12 +1213,12 @@ def slot_prefill_chunk(dec_model, params, cache, slot, chunk):
     sub = jax.tree.map(lambda l: l[slot], cache)
     (hidden, embed), mut = dec_model.apply(
         {"params": params, "cache": sub}, chunk[None, :],
-        return_hidden=True, mutable=["cache"])
+        return_hidden=True, mutable=["cache", "moe_stats"])
     logits = jnp.einsum("d,vd->v", hidden[0, -1],
                         embed.astype(hidden.dtype))
     cache = jax.tree.map(lambda l, s: l.at[slot].set(s), cache,
                          mut["cache"])
-    return cache, logits.astype(jnp.float32)
+    return cache, logits.astype(jnp.float32), _moe_pairs(dec_model, mut)
 
 
 def prefill_chunks(length: int, max_chunk: Optional[int] = None) -> list:
@@ -1197,21 +1275,37 @@ def sample_token(logits, temperature, top_p, key):
     return jnp.where(temperature <= 0.0, greedy, sampled)
 
 
+def recurrent_leaf(path) -> bool:
+    """A cache leaf that is a recurrent layer's state: the variables
+    the layer itself lists as overwritten each step
+    (`KDAAttention.OVERWRITTEN`; a further recurrent layer adds its
+    own list here)."""
+    return getattr(path[-1], "key", None) in KDAAttention.OVERWRITTEN
+
+
+def overwritten_leaf(path) -> bool:
+    """A cache leaf that a step OVERWRITES: the fill indices and a
+    recurrent layer's state. K/V rows are appended to instead."""
+    return "index" in str(path) or recurrent_leaf(path)
+
+
 def _freeze_cache_indices(new_cache, old_cache, advance):
-    """Select per-leaf between the advanced and the input fill indices
-    (scalar ``advance`` under the tick's vmap): a lane whose index must
-    not move (FREE or mid-prefill slots riding the shared vmapped tick,
-    finished-but-unretired slots) keeps its old index. The K/V bytes
-    the masked lane wrote at that frozen position are harmless — the
-    causal masks attend positions < index, and the next real writer
-    (prefill chunk or live tick) lands on the same position — so only
-    the cheap scalar index leaves need the select, never the [max_len]
-    cache rows."""
+    """Select per-leaf between the advanced and the input cache (scalar
+    ``advance`` under the tick's vmap): a lane that must not move
+    (FREE or mid-prefill slots riding the shared vmapped tick,
+    finished-but-unretired slots) keeps its old fill indices - and its
+    old recurrent state and convolution tail, which a step overwrites:
+    a tick interleaved with a chunked prefill would otherwise corrupt
+    the half-built state of that slot. The K/V bytes the masked lane
+    wrote at its frozen position are harmless - the causal masks attend
+    positions < index, and the next real writer (prefill chunk or live
+    tick) lands on the same position - so the [max_len] cache rows
+    never need the select."""
     from jax.tree_util import tree_flatten_with_path, tree_unflatten
     flat, treedef = tree_flatten_with_path(new_cache)
     old_leaves = jax.tree.leaves(old_cache)
     out = [jnp.where(advance, leaf, old)
-           if "index" in str(path) else leaf
+           if overwritten_leaf(path) else leaf
            for (path, leaf), old in zip(flat, old_leaves)]
     return tree_unflatten(treedef, out)
 
@@ -1223,16 +1317,20 @@ def slot_decode_tick(dec_model, params, cache, toks, temps, top_ps,
                      rngs, live, done, eos):
     """One continuous-batching decode tick over EVERY slot: vmap of the
     B=1 decode step over the slot axis. Returns ``(cache, next_toks
-    [num_slots], new_rngs, done)``. One compiled program serves every
-    occupancy pattern; per-slot occupancy state is traced:
+    [num_slots], new_rngs, done, expert pairs)`` - the last as
+    `_moe_pairs` has them, summed over the lanes that decode. (A
+    dropless expert layer inside the vmap still sees every lane's
+    token at once: `parallel.expert.grouped_experts` batches itself.)
+    One compiled program serves every occupancy pattern; per-slot
+    occupancy state is traced:
 
     * ``live`` [S] bool — host-known active lanes. Non-live lanes
       (FREE or mid-prefill slots) still ride the vmapped step but
-      their cache fill indices are FROZEN (`_freeze_cache_indices`),
-      so an idle lane never creeps its index — and with it the shared
-      prefix-attention trip count every live slot pays for — and a
-      partially prefilled slot's next chunk lands exactly where the
-      previous one stopped.
+      their cache fill indices, and a recurrent layer's state, are
+      FROZEN (`_freeze_cache_indices`), so an idle lane never creeps
+      its index — and with it the shared prefix-attention trip count
+      every live slot pays for — and a partially prefilled slot's
+      next chunk lands exactly where the previous one stopped.
     * ``done`` [S] bool + ``eos`` scalar (pass -1 to disable) — ON-
       DEVICE stop detection: a lane that has emitted eos keeps
       emitting eos (never a post-eos garbage token) and stops
@@ -1245,7 +1343,7 @@ def slot_decode_tick(dec_model, params, cache, toks, temps, top_ps,
     def one(sub, tok, temp, top_p, rng, lv, dn):
         (hidden, embed), mut = dec_model.apply(
             {"params": params, "cache": sub}, tok[None, None],
-            return_hidden=True, mutable=["cache"])
+            return_hidden=True, mutable=["cache", "moe_stats"])
         new = _freeze_cache_indices(mut["cache"], sub, lv & ~dn)
         logits = jnp.einsum("d,vd->v", hidden[0, -1],
                             embed.astype(hidden.dtype))
@@ -1253,9 +1351,14 @@ def slot_decode_tick(dec_model, params, cache, toks, temps, top_ps,
         nxt = sample_token(logits.astype(jnp.float32), temp, top_p, r)
         nxt = nxt.astype(tok.dtype)
         emit = jnp.where(dn, eos.astype(tok.dtype), nxt)
-        return new, emit, rng, dn | (emit == eos)
+        return (new, emit, rng, dn | (emit == eos),
+                _moe_pairs(dec_model, mut))
 
-    return jax.vmap(one)(cache, toks, temps, top_ps, rngs, live, done)
+    cache, emit, rngs, dn, pairs = jax.vmap(one)(
+        cache, toks, temps, top_ps, rngs, live, done)
+    decoding = (live & ~done)[:, None, None]
+    return cache, emit, rngs, dn, jnp.sum(
+        jnp.where(decoding, pairs, 0), axis=0)
 
 
 # ---------------------------------------------------------------------------

@@ -146,3 +146,131 @@ def expert_alltoall_combine(expert_outputs: jax.Array,
     """Inverse shuffle: [E/ep, ep·C_local, d] → [E, C_local, d]."""
     return lax.all_to_all(expert_outputs, axis_name, split_axis=1,
                           concat_axis=0, tiled=True)
+
+
+# ---------------------------------------------------------------------------
+# Dropless expert layer over the experts HELD here (one chip's share of an
+# expert-parallel deployment).
+# ---------------------------------------------------------------------------
+
+def _grouped_experts(x, key, weight, w_gate, w_up, w_down):
+    """sum_k weight[t, k] * expert_{key[t, k]}(x[t]) over the E experts
+    held (key == E: the chosen expert lives on another chip and adds
+    nothing here). x [T, d]; key, weight [T, k]; w_* [E, ., .]. The
+    (token, expert) pairs are sorted by expert and go through one
+    grouped matrix product per projection (`lax.ragged_dot`): however
+    uneven the routing, no pair is dropped."""
+    T, k = key.shape
+    E = w_gate.shape[0]
+    flat = key.reshape(-1)
+    order = jnp.argsort(flat, stable=True)              # held pairs first
+    sizes = jnp.sum(flat[:, None] == jnp.arange(E), axis=0,
+                    dtype=jnp.int32)
+    xs = jnp.take(x, order // k, axis=0)                # [T k, d]
+    h = (jax.nn.silu(lax.ragged_dot(xs, w_gate, sizes))
+         * lax.ragged_dot(xs, w_up, sizes))
+    y = lax.ragged_dot(h, w_down, sizes)
+    held = (flat < E)[order]
+    y = jnp.where(held[:, None],
+                  y * weight.reshape(-1)[order][:, None].astype(y.dtype),
+                  0)
+    back = jnp.argsort(order)                           # the pairs' own order
+    return jnp.take(y, back, axis=0).reshape(T, k, -1).sum(1)
+
+
+@jax.custom_batching.custom_vmap
+def grouped_experts(x, key, weight, w_gate, w_up, w_down):
+    return _grouped_experts(x, key, weight, w_gate, w_up, w_down)
+
+
+@grouped_experts.def_vmap
+def _grouped_experts_vmap(axis_size, in_batched, x, key, weight, *ws):
+    """A `vmap` over lanes (the serving tick is a vmap of B = 1 applies)
+    hands the layer every lane's tokens at once: one grouped product
+    over all of them, not `axis_size` products of one token each."""
+    if any(in_batched[3:]):         # per-lane weights: nothing to merge
+        axes = tuple(0 if b else None for b in in_batched)
+        return jax.vmap(_grouped_experts, axes)(x, key, weight, *ws), True
+    x, key, weight = (
+        a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+        for a, b in zip((x, key, weight), in_batched[:3]))
+    y = _grouped_experts(x.reshape(-1, x.shape[-1]),
+                         key.reshape(-1, key.shape[-1]),
+                         weight.reshape(-1, weight.shape[-1]), *ws)
+    return y.reshape(axis_size, -1, y.shape[-1]), True
+
+
+class HeldExpertsMoE(nn.Module):
+    """Mixture of experts as one chip of an expert-parallel deployment
+    computes it: the router scores all ``num_experts``, each token's
+    ``k`` experts and their weights are chosen over all of them, and
+    this chip adds the part of the result that ITS experts give -
+    ``held = (first, count)``, expert ids first .. first+count-1 - plus
+    the shared expert that every chip computes alike. What the absent
+    experts would add is left out (on a real mesh the exchange brings
+    it; nothing here stands in for it). ``held=None`` holds them all.
+
+    Routing (the DeepSeek-V3 family's): scores = sigmoid(x W_r) in
+    float32; the k largest of scores + bias are chosen (the bias takes
+    part in the choice only); weights = the chosen scores, normalised
+    to sum to one. Experts are SwiGLU MLPs of width ``hidden``.
+    Dropless: see `grouped_experts`.
+
+    Sows into the "moe_stats" collection (when the caller makes it
+    mutable) `pairs`: the (token, expert) pairs per held expert, int32
+    [count]."""
+
+    num_experts: int
+    hidden: int
+    k: int = 8
+    held: Optional[Tuple[int, int]] = None
+    shared_hidden: int = 0           # 0 = no shared expert
+    dtype: Any = None
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        from horovod_tpu.parallel.tensor import ParallelSwiGLU
+        *lead, d = x.shape
+        first, E = self.held or (0, self.num_experts)
+        if not 0 <= first <= first + E <= self.num_experts or E < 1:
+            raise ValueError(
+                f"held={self.held} is no range of the {self.num_experts} "
+                f"experts")
+        dtype = self.dtype or x.dtype
+        init = nn.initializers.lecun_normal()
+        router = self.param("router", init, (d, self.num_experts),
+                            jnp.float32)
+        bias = self.param("router_bias", nn.initializers.zeros,
+                          (self.num_experts,), jnp.float32)
+
+        def experts(name, shape):
+            return self.param(name, nn.with_partitioning(
+                init, (AXIS_EXPERT, None, None)), shape,
+                jnp.float32).astype(dtype)
+
+        w_gate = experts("w_gate", (E, d, self.hidden))
+        w_up = experts("w_up", (E, d, self.hidden))
+        w_down = experts("w_down", (E, self.hidden, d))
+
+        xt = x.reshape(-1, d)
+        scores = jax.nn.sigmoid(jnp.matmul(
+            xt.astype(jnp.float32), router.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST))
+        _, chosen = lax.top_k(scores + bias, self.k)
+        weight = jnp.take_along_axis(scores, chosen, axis=-1)
+        weight = weight / weight.sum(-1, keepdims=True)
+        local = chosen - first
+        key = jnp.where((local >= 0) & (local < E), local, E)
+        self.sow("moe_stats", "pairs",
+                 jnp.sum(key.reshape(-1, 1) == jnp.arange(E), axis=0,
+                         dtype=jnp.int32),
+                 reduce_fn=lambda _, new: new, init_fn=lambda: None)
+        self.sow("intermediates", "chosen", chosen,
+                 reduce_fn=lambda _, new: new, init_fn=lambda: None)
+        y = grouped_experts(xt.astype(dtype), key, weight, w_gate, w_up,
+                            w_down)
+        if self.shared_hidden:
+            y = y + ParallelSwiGLU(hidden=self.shared_hidden, out=d,
+                                   dtype=dtype, name="shared")(
+                xt.astype(dtype))
+        return y.reshape(*lead, d).astype(x.dtype)

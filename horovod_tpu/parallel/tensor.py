@@ -442,6 +442,13 @@ class ParallelSelfAttention(nn.Module):
     # Qwen2-style split: bias on the qkv projection but not on the
     # output projection. None = follow use_bias (GPT-2: both).
     out_bias: Optional[bool] = None
+    # Elementwise output gate: the attention's output is multiplied by
+    # sigmoid(x W_g) (W_g: d -> H*D, no bias) before the output
+    # projection.
+    out_gate: bool = False
+    # Width of the output projection (the residual stream's); None =
+    # H*D, the models whose hidden size is heads x head_dim.
+    out_features: Optional[int] = None
     lora_rank: int = 0
     lora_alpha: Optional[float] = None
 
@@ -489,13 +496,20 @@ class ParallelSelfAttention(nn.Module):
             q, k = self._maybe_rope(q, k)
             o = self._dispatch_attn(q, k, v, mask)
         o = o.reshape(*o.shape[:-2], features)
+        if self.out_gate:
+            gate = ColumnParallelDense(features, use_bias=False,
+                                       weight_quant=self.weight_quant,
+                                       dtype=self.dtype, name="gate")(x)
+            o = (o * jax.nn.sigmoid(gate.astype(jnp.float32))
+                 ).astype(o.dtype)
         if o.ndim == 2:
             o = constrain(o, AXIS_SEQ, AXIS_MODEL)
         else:
             o = constrain(o, AXIS_DATA, *([None] * (o.ndim - 3)),
                           AXIS_SEQ, AXIS_MODEL)
         ob = self.use_bias if self.out_bias is None else self.out_bias
-        return RowParallelDense(features, use_bias=ob,
+        return RowParallelDense(self.out_features or features,
+                                use_bias=ob,
                                 weight_quant=self.weight_quant,
                                 lora_rank=self.lora_rank,
                                 lora_alpha=self.lora_alpha,

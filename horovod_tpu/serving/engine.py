@@ -81,6 +81,37 @@ _IDLE_WAIT_S = 0.05
 _ENGINE_IDS = itertools.count()
 
 
+def _refuse_recurrent(model, **options):
+    """A model with a recurrent layer (`TransformerLM.layer_kinds`
+    holds "kda") keeps, beside K/V, a state that every step
+    OVERWRITES. The fixed slot pool serves it; every path that grafts,
+    exports, pages, shelves or rewinds K/V blocks would need a
+    snapshot form of that state, and none exists: refuse loudly
+    rather than run it wrongly (docs/serving.md "Hybrid models")."""
+    if not model.has_recurrent_state:
+        return
+    needs = {
+        "paged": "the paged pool keeps K/V in blocks; a recurrent "
+                 "state has no block form",
+        "spec_draft": "speculative decoding rewinds rejected "
+                      "positions; a recurrent state cannot be rewound "
+                      "without a snapshot per position",
+        "swap_bytes": "swap-preemption shelves K/V blocks on the "
+                      "host; a recurrent state has no shelved form",
+        "transfer": "disaggregated serving ships K/V blocks between "
+                    "pools; a recurrent state has no transfer form",
+        "mesh": "no serving mesh is defined for the recurrent state "
+                "and the held experts",
+    }
+    for name, on in options.items():
+        if on:
+            raise ValueError(
+                f"{name}: this model has recurrent (linear-attention) "
+                f"layers, and {needs[name]} - missing snapshot form "
+                f"of the recurrent state; serve it from the fixed "
+                f"slot pool (ServingEngine defaults)")
+
+
 def _resolve_serving_mesh(mesh):
     """Normalize `ServingEngine`'s ``mesh`` argument to a built
     `jax.sharding.Mesh` (or None = unsharded).
@@ -331,6 +362,9 @@ class ServingEngine:
         # pools and params all see the ONE resolved layout.
         mesh = _resolve_serving_mesh(mesh)
         self.mesh = mesh
+        _refuse_recurrent(model, paged=paged, spec_draft=spec_draft,
+                          swap_bytes=preempt and swap_bytes,
+                          mesh=mesh is not None)
         # Weight-only quantization at the engine door (docs/serving.md
         # "Decode fast path"): the block-matmul kernels land int8 +
         # per-channel f32 scales, halving decode's weight HBM reads.
@@ -431,6 +465,7 @@ class ServingEngine:
         else:
             self.pool = SlotPool(model, params, num_slots, mesh=mesh,
                                  eos_id=eos_id, **spec_kw)
+            self.metrics.observe_pool_bytes(self.pool.cache_bytes())
         # Warmup runs on the constructor thread BEFORE the dispatch
         # thread exists, so the single-jax-thread contract holds.
         self.warmup_info = None
@@ -809,6 +844,7 @@ class ServingEngine:
         is matched. False when this engine cannot ingest (non-paged
         pool, or closing) — the caller's submit still works, it just
         re-prefills (the fallback ladder)."""
+        _refuse_recurrent(self.model, transfer=transfer is not None)
         if transfer is None or not self.paged or self._closing:
             return False
         self._grafts.append(transfer)

@@ -161,6 +161,20 @@ class EngineMetrics:
         self.lane_ticks_prefilling = 0
         self.lane_ticks_free = 0
         self.tick_context_positions = 0
+        # Dropless expert layers (`parallel.expert.HeldExpertsMoE`):
+        # over the decode ticks synced, the (token, expert) pairs on
+        # the experts held here (decoding lanes only), the busiest
+        # held expert's pairs, the held experts that got any pair -
+        # each summed over layers - and the (tick, layer) records
+        # they were summed over; the pairs of the prefill chunks.
+        self.moe_pairs = 0
+        self.moe_expert_load_max = 0
+        self.moe_experts_hit = 0
+        self.moe_layers_ticks = 0
+        self.moe_prefill_pairs = 0
+        # Bytes of the fixed pool's cache by kind (None = a pool that
+        # does not report them).
+        self.pool_bytes = None
         # Self-healing counters (engine watchdog, docs/resilience.md).
         self.restarts = 0          # in-place engine restarts
         self.requeued = 0          # in-flight requests replayed
@@ -269,6 +283,28 @@ class EngineMetrics:
             self.lane_ticks_prefilling += tick["lanes_prefilling"]
             self.lane_ticks_free += tick["lanes_free"]
             self.tick_context_positions += tick["context_sum"]
+
+    def observe_moe(self, stats: Dict[str, int]):
+        """One synced tick's expert-layer record
+        (`SlotPool.tick_stats`) into the counters."""
+        with self._lock:
+            self.moe_pairs += stats["moe_pairs"]
+            self.moe_expert_load_max += stats["moe_expert_load_max"]
+            self.moe_experts_hit += stats["moe_experts_hit"]
+            self.moe_layers_ticks += stats["moe_layers"]
+            self.moe_prefill_pairs += stats["moe_prefill_pairs"]
+
+    def observe_pool_bytes(self, by_kind: Dict[str, int]):
+        """The fixed pool's cache bytes by kind (constructor-time,
+        once): the `hvd_serving_pool_bytes` gauge rows and the
+        snapshot's `pool_bytes`."""
+        with self._lock:
+            self.pool_bytes = dict(by_kind)
+            if self._closed:
+                return
+            for kind, n in by_kind.items():
+                self._obs["pool_bytes"].set(
+                    n, engine=self._engine_label, kind=kind)
 
     def observe_admission(self, admitted: bool, *, tenant: str = ""):
         """One admission decision into the SLO shed-rate objective
@@ -418,6 +454,8 @@ class EngineMetrics:
                          "kv_blocks_free", "kv_blocks_used",
                          "kv_blocks_cached", "mesh_devices"):
                 self._obs[name].remove(engine=eng)
+            for kind in self.pool_bytes or ():
+                self._obs["pool_bytes"].remove(engine=eng, kind=kind)
             for name in ("swap_store_bytes", "swap_store_entries"):
                 self._obs_pre[name].remove(engine=eng)
             for i in range(self.mesh_devices):
@@ -453,6 +491,12 @@ class EngineMetrics:
                 "lane_ticks_prefilling": self.lane_ticks_prefilling,
                 "lane_ticks_free": self.lane_ticks_free,
                 "tick_context_positions": self.tick_context_positions,
+                "moe_pairs": self.moe_pairs,
+                "moe_expert_load_max": self.moe_expert_load_max,
+                "moe_experts_hit": self.moe_experts_hit,
+                "moe_layers_ticks": self.moe_layers_ticks,
+                "moe_prefill_pairs": self.moe_prefill_pairs,
+                "pool_bytes": self.pool_bytes,
                 "host_syncs_per_token": (
                     round(self.host_syncs / self.tokens_out, 4)
                     if self.tokens_out else None),

@@ -429,11 +429,12 @@ class ContinuousBatchingScheduler:
         moves: exposed host syncs per token)."""
         with _spans.loop_span("sched.tick_sync",
                               overlapped=overlapped) as sync_span:
-            tokens, retired = self._sync_tick(overlapped)
-            sync_span.set(tokens=tokens, retired=retired)
+            tokens, retired, moe = self._sync_tick(overlapped)
+            sync_span.set(tokens=tokens, retired=retired, **(moe or {}))
 
     def _sync_tick(self, overlapped: bool):
-        """`_sync_pending`'s work; (tokens appended, lanes retired)."""
+        """`_sync_pending`'s work; (tokens appended, lanes retired,
+        the expert layers' record of the tick or None)."""
         # hvd: disable=HVD004(dispatch-thread-owned ring slot; a racing abandon() clears it too, and the snapshot re-check below tolerates that)
         pending, self._pending = self._pending, None
         sync_name = f"serving_sync_{self._gen}.{self.metrics.ticks}"
@@ -446,11 +447,15 @@ class ContinuousBatchingScheduler:
                 self.stall.end(sync_name)
         self.metrics.count("ticks_overlapped" if overlapped
                            else "host_syncs")
+        stats = getattr(self.pool, "tick_stats", None)
+        moe = stats(pending.handle) if stats is not None else None
+        if moe is not None:
+            self.metrics.observe_moe(moe)
         if self.abandoned:
             # Superseded mid-pipeline: the successor owns these
             # requests now — appending this tick's tokens would
             # corrupt their replay-from-prompt.
-            return 0, 0
+            return 0, 0, moe
         t_tick = time.time()
         tokens = retired = 0
         for slot, req in pending.snapshot.items():
@@ -462,7 +467,7 @@ class ContinuousBatchingScheduler:
             self.metrics.count("tokens_out")
             self._maybe_retire(slot, req, tok, t_tick)
             retired += self.active.get(slot) is not req
-        return tokens, retired
+        return tokens, retired, moe
 
     # -- admission / chunked prefill ----------------------------------
 

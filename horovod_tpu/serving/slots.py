@@ -50,7 +50,8 @@ import jax.numpy as jnp
 
 from horovod_tpu.annotations import hot_path
 from horovod_tpu.models.transformer import (
-    TransformerLM, init_slot_cache, prefill_chunks, sample_token,
+    TransformerLM, init_slot_cache, prefill_chunks, recurrent_leaf,
+    sample_token,
     shard_slot_cache, slot_decode_model, slot_decode_tick,
     slot_prefill_advance, slot_prefill_chunk, slot_reset,
     slot_spec_round,
@@ -142,10 +143,16 @@ class TickHandle:
     copy already started via `copy_to_host_async`). `tick_sync` turns
     it into the [num_slots] numpy vector."""
 
-    __slots__ = ("toks",)
+    __slots__ = ("toks", "moe_pairs", "moe_prefill_pairs")
 
-    def __init__(self, toks):
+    def __init__(self, toks, moe_pairs=None, moe_prefill_pairs=()):
         self.toks = toks
+        # int32 [expert layers, experts held] (token, expert) pairs of
+        # this tick's decoding lanes (None for a model without a
+        # dropless expert layer), and one such array for each prefill
+        # chunk since the last tick
+        self.moe_pairs = moe_pairs
+        self.moe_prefill_pairs = moe_prefill_pairs
 
 
 class SlotPool:
@@ -190,6 +197,7 @@ class SlotPool:
             self.drf_model = slot_decode_model(draft_model)
             self.drf_params = draft_params
             self._drf_cache = init_slot_cache(draft_model, num_slots)
+        self._prefill_pairs = []     # see prefill_chunk / tick_dispatch
         self._toks = jnp.zeros((num_slots,), jnp.int32)
         self._temps = jnp.zeros((num_slots,), jnp.float32)
         self._top_ps = jnp.ones((num_slots,), jnp.float32)
@@ -287,6 +295,19 @@ class SlotPool:
         assert idx, "slot cache has no index leaves"
         return np.max(np.stack(idx), axis=0)
 
+    def cache_bytes(self) -> dict:
+        """Device bytes of the pool's cache by kind: `kv` (keys and
+        values, appended to) and `state` (a recurrent layer's state
+        and convolution tail, overwritten each step; 0 for a model
+        without one). The fill indices are not counted."""
+        from jax.tree_util import tree_flatten_with_path
+        out = {"kv": 0, "state": 0}
+        for path, leaf in tree_flatten_with_path(self._cache)[0]:
+            if "index" not in str(path):
+                kind = "state" if recurrent_leaf(path) else "kv"
+                out[kind] += int(leaf.nbytes)
+        return out
+
     # -- occupancy ----------------------------------------------------
 
     @property
@@ -361,9 +382,12 @@ class SlotPool:
         self.maybe_compiling = ("prefill", c) not in self._seen_shapes
         try:
             with self._ctx():
-                self._cache, logits = slot_prefill_chunk(
+                self._cache, logits, pairs = slot_prefill_chunk(
                     self.dec_model, self.params, self._cache,
                     jnp.int32(slot), jnp.asarray(chunk, jnp.int32))
+                if pairs.size:
+                    # on the device until the next tick's copy takes it
+                    self._prefill_pairs.append(pairs)
                 if self.spec_on:
                     # The draft's cache must hold the SAME prompt as
                     # the target's before any round — same chunk
@@ -445,7 +469,7 @@ class SlotPool:
         try:
             with self._ctx():
                 (self._cache, self._toks, self._rngs,
-                 self._done) = slot_decode_tick(
+                 self._done, pairs) = slot_decode_tick(
                     self.dec_model, self.params, self._cache,
                     self._toks, self._temps, self._top_ps, self._rngs,
                     self._live, self._done, self._eos)
@@ -454,7 +478,15 @@ class SlotPool:
             self.maybe_compiling = False
         toks = self._toks
         toks.copy_to_host_async()
-        return TickHandle(toks)
+        if not pairs.size:
+            return TickHandle(toks)
+        # The expert layers' pair counts ride the same asynchronous
+        # copy as the tokens: this tick's, and those of the prefill
+        # chunks since the last tick.
+        prefill, self._prefill_pairs = self._prefill_pairs, []
+        for a in (pairs, *prefill):
+            a.copy_to_host_async()
+        return TickHandle(toks, pairs, prefill)
 
     @staticmethod
     @hot_path
@@ -464,6 +496,25 @@ class SlotPool:
         # this only after dispatching the next tick, so the read hides
         # behind device compute (metrics: ticks_overlapped).
         return np.asarray(handle.toks)  # hvd: disable=HVD001(the one designed sync of the tick ring)
+
+    @staticmethod
+    def tick_stats(handle: TickHandle) -> Optional[dict]:
+        """The expert layers' record of one SYNCED tick (its copies
+        have landed with the tokens): pairs on the held experts summed
+        over layers, the busiest expert's pairs summed over layers,
+        the experts that got any, the layers - and the pairs of the
+        prefill chunks since the tick before. None for a model
+        without a dropless expert layer."""
+        if handle.moe_pairs is None:
+            return None
+        pairs = np.asarray(handle.moe_pairs)  # hvd: disable=HVD001(rides the tick's designed sync - the copy started with the tokens')
+        return {"moe_pairs": int(pairs.sum()),
+                "moe_expert_load_max": int(pairs.max(axis=1).sum()),
+                "moe_experts_hit": int((pairs > 0).sum()),
+                "moe_layers": int(pairs.shape[0]),
+                "moe_prefill_pairs": sum(
+                    int(np.asarray(a).sum())  # hvd: disable=HVD001(copies started with the tick's, as above)
+                    for a in handle.moe_prefill_pairs)}
 
     def tick(self) -> np.ndarray:
         """Synchronous tick (dispatch + immediate sync) — the

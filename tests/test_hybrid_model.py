@@ -1,0 +1,332 @@
+"""A hybrid model on the serving path: delta-rule linear attention (KDA)
+with recurrent state beside K/V, gated NoPE GQA, and the chip's share of a
+dropless mixture of experts - at a tiny size on the CPU, seeded random
+weights, against the plain reference the benchmark keeps
+(`benchmarks/arch/solar_open2.py`, which imports nothing of the program).
+"""
+
+import json
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmarks.harness.cells import load_module
+from horovod_tpu.models.transformer import (
+    generate, init_slot_cache, slot_decode_model, slot_decode_tick,
+    slot_prefill_chunk,
+)
+from horovod_tpu.parallel.linear_attention import (
+    kda_chunked, kda_recurrent,
+)
+from horovod_tpu.serving import ServingEngine
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+A = load_module(os.path.join(REPO, "benchmarks", "arch", "solar_open2.py"),
+                "arch_solar_open2_for_tests")
+with open(os.path.join(REPO, "tests", "benchmark", "tiny",
+                       "tiny-solar.json")) as f:
+    ARCH = json.load(f)["arch"]     # hidden 64, 2 KDA heads x 16, 16 experts
+MAX_LEN = 64
+
+
+def f32_model(arch=ARCH, **kw):
+    return A.program_model(arch, max_len=MAX_LEN, attn_impl="dot",
+                           dtype="float32", **kw)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return A.make_params(ARCH, MAX_LEN, 11, "float32")
+
+
+@pytest.fixture(autouse=True)
+def highest():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def tokens(n, seed=0):
+    return np.random.default_rng(seed).integers(
+        0, ARCH["vocab_size"], n).astype(np.int32)
+
+
+# ---- (a) the chunkwise form = the recurrence = the reference -------------
+def kda_inputs(T, seed, state, decay=1.0, B=2, H=3, D=16):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q, k = (jax.random.normal(ks[i], (B, T, H, D)) for i in (0, 1))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * D ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (B, T, H, D))
+    g = -decay * jax.nn.softplus(jax.random.normal(ks[3], (B, T, H, D)))
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (B, T, H)))
+    s0 = (jax.random.normal(ks[5], (B, H, D, D)) if state
+          else jnp.zeros((B, H, D, D)))
+    return s0, q, k, v, g, beta
+
+
+@pytest.mark.parametrize("state", [False, True],
+                         ids=["empty-state", "non-empty-state"])
+@pytest.mark.parametrize("T,decay", [(2, 1.0), (16, 1.0), (37, 0.05),
+                                     (64, 1.0), (128, 8.0), (200, 1.0)])
+def test_kda_chunkwise_equals_recurrence(T, decay, state):
+    """Sub-chunks of 64 in blocks of 16, any length, any state to start
+    from; a decay of exp(-8) a step overflows nothing."""
+    args = kda_inputs(T, T, state, decay)
+    o1, s1 = kda_recurrent(*args)
+    o2, s2 = jax.jit(kda_chunked)(*args)
+    assert bool(jnp.isfinite(o2).all())
+    np.testing.assert_allclose(o2, o1, atol=2e-5)
+    np.testing.assert_allclose(s2, s1, atol=2e-5)
+
+
+@pytest.mark.parametrize("split", [None, 24], ids=["whole", "two-chunks"])
+def test_kda_layer_equals_reference(params, split):
+    """The layer (convolution, gates, chunkwise core, norm) against the
+    reference's token-by-token layer; in two chunks through the cache,
+    the second starts from the first's state and convolution tail."""
+    from horovod_tpu.parallel.linear_attention import KDAAttention
+    p = params["block_1"]["kda"]
+    x = jax.random.normal(jax.random.PRNGKey(3), (40, ARCH["hidden_size"]))
+    want = A.kda_mixer(ARCH, p, x)
+    layer = KDAAttention(
+        num_heads=ARCH["num_heads"], head_dim=ARCH["head_dim"],
+        out_features=ARCH["hidden_size"], dtype=jnp.float32,
+        decode=split is not None)
+    if split is None:
+        got = layer.apply({"params": p}, x[None])[0]
+    else:
+        cache = jax.tree.map(
+            jnp.zeros_like, layer.init(jax.random.PRNGKey(0),
+                                       x[None])["cache"])
+        parts = []
+        for part in (x[:split], x[split:-1], x[-1:]):   # S > 1, then S = 1
+            y, mut = layer.apply({"params": p, "cache": cache},
+                                 part[None], mutable=["cache"])
+            cache = mut["cache"]
+            parts.append(y[0])
+        got = jnp.concatenate(parts)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+# ---- (b) the expert shares add up ----------------------------------------
+def moe_layer(held):
+    from horovod_tpu.parallel.expert import HeldExpertsMoE
+    return HeldExpertsMoE(
+        num_experts=ARCH["num_experts"], hidden=ARCH["expert_hidden"],
+        k=ARCH["experts_per_token"], held=held,
+        shared_hidden=ARCH["shared_hidden"], dtype=jnp.float32)
+
+
+def share_params(p, first, n):
+    return dict(p, **{k: p[k][first:first + n]
+                      for k in ("w_gate", "w_up", "w_down")})
+
+
+def test_expert_shares_add_up_to_the_uncut_layer():
+    """Four chips hold 4 of 16 experts each: the four partial results,
+    the shared expert counted once, equal the uncut reference's layer."""
+    whole = dict(ARCH, experts_held=[0, 16])
+    p = A.make_params(whole, MAX_LEN, 5, "float32")["block_0"]["moe"]
+    x = jax.random.normal(jax.random.PRNGKey(1), (24, ARCH["hidden_size"]))
+    want = A.moe(whole, p, x)
+    sh = p["shared"]
+    shared = A._swiglu(x, sh["gate"]["kernel"], sh["up"]["kernel"],
+                       sh["down"]["kernel"], None)
+    total = shared
+    for first in (0, 4, 8, 12):
+        part = moe_layer((first, 4)).apply(
+            {"params": share_params(p, first, 4)}, x)
+        # the reference given the same share gives the same part
+        np.testing.assert_allclose(
+            part, A.moe(whole, share_params(p, first, 4), x,
+                        held=(first, 4)), atol=2e-5)
+        total = total + (part - shared)
+    np.testing.assert_allclose(total, want, atol=2e-5)
+
+
+def test_no_pair_is_dropped_when_every_token_takes_one_expert():
+    """A routing that sends every token to expert 5 first: 24 pairs on
+    one held expert, 0 on its neighbours, and the result is still the
+    reference's."""
+    p = dict(A.make_params(ARCH, MAX_LEN, 6, "float32")["block_0"]["moe"])
+    p["router_bias"] = p["router_bias"].at[5].set(10.0)
+    x = jax.random.normal(jax.random.PRNGKey(2), (24, ARCH["hidden_size"]))
+    got, mut = moe_layer((4, 4)).apply({"params": p}, x,
+                                       mutable=["moe_stats"])
+    pairs = np.asarray(mut["moe_stats"]["pairs"])
+    assert pairs[1] == 24 and pairs.sum() >= 24
+    np.testing.assert_allclose(got, A.moe(ARCH, p, x), atol=2e-5)
+
+
+def test_a_vmap_over_lanes_is_one_grouped_product(params):
+    """Under the tick's vmap the layer sees every lane's token at once:
+    the result equals lane-by-lane applies, and the traced program holds
+    one sort over all lanes, not one per lane."""
+    p = params["block_2"]["moe"]
+    x = jax.random.normal(jax.random.PRNGKey(4),
+                          (6, 1, 1, ARCH["hidden_size"]))
+    layer = moe_layer(tuple(ARCH["experts_held"]))
+    one = lambda xi: layer.apply({"params": p}, xi)
+    want = jnp.stack([one(x[i]) for i in range(6)])
+    np.testing.assert_allclose(jax.vmap(one)(x), want, atol=2e-5)
+    text = str(jax.make_jaxpr(jax.vmap(one))(x))
+    k = ARCH["experts_per_token"]
+    assert f"i32[{6 * k}]" in text      # the pairs of all six lanes, flat
+
+
+# ---- the whole model ------------------------------------------------------
+def test_program_equals_reference_full_forward(params):
+    toks = tokens(40)
+    want = A.logits(ARCH, params, jnp.asarray(toks))
+    got = f32_model().apply({"params": params}, toks[None])[0]
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    A.check_layout(ARCH, MAX_LEN, f32_model())
+
+
+# ---- (c) slots: chunks, ticks, and a tick between another slot's chunks ---
+def test_slot_chunks_and_ticks_equal_reference_with_an_interleaved_tick(
+        params):
+    """Slot 0 decodes while slot 1's prompt streams in in two chunks
+    with a tick between them: the tick must leave slot 1's half-built
+    state, convolution tail and fill alone. Every logit - slot 0's
+    ticks, slot 1's chunks and its later ticks - is the reference's
+    full forward pass."""
+    model = f32_model()
+    dec = slot_decode_model(model)
+    cache = init_slot_cache(model, 3)
+    a, b = tokens(21, 1), tokens(30, 2)
+    ref_a = A.logits(ARCH, params, jnp.asarray(a))
+    ref_b = A.logits(ARCH, params, jnp.asarray(b))
+
+    def chunk(cache, slot, toks):
+        cache, lg, _ = slot_prefill_chunk(dec, params, cache,
+                                          jnp.int32(slot),
+                                          jnp.asarray(toks))
+        return cache, lg
+
+    def tick(cache, feed, live):
+        """Greedy tick; returns each slot's logits too (recomputed by
+        a B = 1 apply on the same cache rows)."""
+        def lg(slot):
+            sub = jax.tree.map(lambda l: l[slot], cache)
+            (h, emb), _ = dec.apply(
+                {"params": params, "cache": sub},
+                jnp.asarray(feed[slot])[None, None], return_hidden=True,
+                mutable=["cache"])
+            return jnp.einsum("d,vd->v", h[0, -1], emb)
+        logits = [lg(s) for s in range(3)]
+        cache, *_ = slot_decode_tick(
+            dec, params, cache, jnp.asarray(feed, jnp.int32),
+            jnp.zeros(3), jnp.ones(3),
+            jnp.stack([jax.random.PRNGKey(i) for i in range(3)]),
+            jnp.asarray(live), jnp.zeros(3, bool), jnp.int32(-1))
+        return cache, logits
+
+    cache, lg = chunk(cache, 0, a[:16])
+    np.testing.assert_allclose(lg, ref_a[15], atol=3e-5)
+    cache, lg = chunk(cache, 1, b[:16])             # slot 1: first chunk
+    np.testing.assert_allclose(lg, ref_b[15], atol=3e-5)
+    # ticks of slot 0 alone; slot 1 (mid-prefill) and 2 (free) ride them
+    for t in range(16, 19):
+        cache, logits = tick(cache, [a[t], 7, 9], [True, False, False])
+        np.testing.assert_allclose(logits[0], ref_a[t], atol=3e-5)
+    cache, lg = chunk(cache, 1, b[16:24])           # slot 1: second chunk
+    np.testing.assert_allclose(lg, ref_b[23], atol=3e-5)
+    for t in range(24, 30):                         # both decode
+        feed = [a[min(t - 5, 20)], b[t], 3]
+        cache, logits = tick(cache, feed, [t - 5 <= 20, True, False])
+        np.testing.assert_allclose(logits[1], ref_b[t], atol=3e-5)
+        if t - 5 <= 20:
+            np.testing.assert_allclose(logits[0], ref_a[t - 5], atol=3e-5)
+    # the free lane never moved
+    free = jax.tree.map(lambda l: np.abs(np.asarray(l[2])).max(), cache)
+    from jax.tree_util import tree_flatten_with_path
+    for path, v in tree_flatten_with_path(free)[0]:
+        if "cached_" not in str(path):
+            assert v == 0, path
+
+
+def test_tick_counts_pairs_of_decoding_lanes_only(params):
+    model = f32_model()
+    dec = slot_decode_model(model)
+    cache = init_slot_cache(model, 4)
+    args = (jnp.zeros(4), jnp.ones(4),
+            jnp.stack([jax.random.PRNGKey(i) for i in range(4)]))
+    out = slot_decode_tick(dec, params, cache, jnp.asarray([1, 2, 3, 4]),
+                           *args, jnp.asarray([True, True, False, True]),
+                           jnp.asarray([False, True, False, False]),
+                           jnp.int32(-1))
+    pairs = np.asarray(out[4])
+    assert pairs.shape == (ARCH["num_layers"], ARCH["experts_held"][1])
+    # two lanes decode; each brings at most k pairs a layer
+    assert 0 < pairs.sum() <= 2 * ARCH["experts_per_token"] * 4
+
+
+# ---- (d) the engine -------------------------------------------------------
+def test_engine_greedy_equals_generate(params):
+    model = f32_model()
+    prompts = [tokens(n, n) for n in (5, 19, 33, 12)]
+    refs = [np.asarray(generate(model, params, p[None], 7))[0, len(p):]
+            for p in prompts]
+    with ServingEngine(model, params, num_slots=2, warmup=True,
+                       prefill_chunk_budget=8) as eng:
+        outs = [np.asarray(h.result(timeout=300).tokens) for h in
+                [eng.submit(p, 7) for p in prompts]]
+        snap = eng.metrics_snapshot()
+    for got, want in zip(outs, refs):
+        np.testing.assert_array_equal(got, want)
+    assert snap["compiles"] == 0
+    assert snap["moe_layers_ticks"] >= ARCH["num_layers"]
+    assert 0 < snap["moe_pairs"] <= (
+        snap["moe_layers_ticks"] * 2 * ARCH["experts_per_token"])
+    assert snap["moe_expert_load_max"] <= snap["moe_pairs"]
+    assert snap["moe_prefill_pairs"] > 0
+    assert snap["pool_bytes"]["state"] > 0 and snap["pool_bytes"]["kv"] > 0
+    from horovod_tpu.obs import spans
+    syncs = [r for r in spans.loop_tail(name="sched.tick_sync")
+             if "moe_pairs" in r["attrs"]]
+    assert syncs and all(r["attrs"]["moe_layers"] == ARCH["num_layers"]
+                         for r in syncs)
+
+
+def test_a_dense_model_reports_no_expert_counters():
+    from horovod_tpu.models.transformer import TransformerLM
+    from horovod_tpu.parallel.tensor import unbox
+    model = TransformerLM(vocab_size=64, num_layers=1, num_heads=2,
+                          head_dim=8, max_len=32, dtype=jnp.float32,
+                          attn_impl="dot")
+    p = unbox(model.init(jax.random.PRNGKey(0),
+                         jnp.zeros((1, 8), jnp.int32))["params"])
+    assert not model.has_recurrent_state
+    with ServingEngine(model, p, num_slots=2) as eng:
+        eng.submit(np.arange(5), 4).result(timeout=120)
+        snap = eng.metrics_snapshot()
+    assert snap["moe_layers_ticks"] == 0 and snap["moe_pairs"] == 0
+    assert snap["pool_bytes"] == {"kv": 2 * 2 * 32 * 2 * 8 * 4, "state": 0}
+
+
+# ---- (e) what cannot serve a recurrent state says so ------------------------
+@pytest.mark.parametrize("kw,names", [
+    (dict(paged=True), "paged"),
+    (dict(spec_draft="self", spec_k=2), "spec_draft"),
+    (dict(preempt=True, swap_bytes=1 << 20), "swap_bytes"),
+    (dict(mesh=2), "mesh"),
+])
+def test_engine_refuses_what_has_no_snapshot_form(params, kw, names):
+    model = f32_model()
+    assert model.has_recurrent_state
+    if kw.get("spec_draft") == "self":
+        kw = dict(kw, spec_draft=(model, params))
+    with pytest.raises(ValueError, match=f"{names}.*snapshot form"):
+        ServingEngine(model, params, num_slots=2, **kw)
+
+
+def test_engine_refuses_a_block_transfer(params):
+    with ServingEngine(f32_model(), params, num_slots=2) as eng:
+        with pytest.raises(ValueError, match="transfer.*snapshot form"):
+            eng.offer_transfer(object())
+        assert eng.offer_transfer(None) is False
