@@ -295,10 +295,15 @@ def run_decode(args, devices, n_chips, log):
         eff_impl = "rolling_window"
     elif not (blk and args.seq % min(blk, args.seq) == 0):
         eff_impl = "cache_wide"
-    elif args.decode_prefix_impl == "pallas" and args.kv_quant:
-        eff_impl = "lax"       # kernel is bf16/f32-only
     else:
-        eff_impl = args.decode_prefix_impl
+        # the rule the model itself follows (kernel on a TPU at S = 1,
+        # un-quantized; the lax walk otherwise)
+        from horovod_tpu.ops.flash_attention import decode_attention_plan
+        eff_impl = {"kernel": "pallas", "lax": "lax"}[
+            decode_attention_plan(
+                B, args.seq, args.heads, args.kv_heads or args.heads,
+                args.head_dim, impl=args.decode_prefix_impl,
+                quantized=bool(args.kv_quant)).path]
     prompt = np.random.RandomState(0).randint(0, 32768, (B, P))
     log(f"decode: {n_params / 1e6:.1f}M params, B={B}, prompt={P}, "
         f"steps={steps}, quant={args.weight_quant or 'none'}, "
@@ -1942,11 +1947,12 @@ def main():
                          "slices this big instead of masking against "
                          "all max_len slots (0 = cache-wide path; the "
                          "r4 10ms/tick suspect A/B)")
-    ap.add_argument("--decode-prefix-impl", default="lax",
+    ap.add_argument("--decode-prefix-impl", default=None,
                     choices=["lax", "pallas"],
-                    help="prefix-attention engine: lax fori_loop "
-                         "(oracle) or the fused Pallas flash-decode "
-                         "kernel (no per-block loop overhead)")
+                    help="force the prefix-attention engine: the lax "
+                         "fori_loop (oracle) or the ragged Pallas "
+                         "flash-decode kernel; default: the code "
+                         "chooses (the kernel on a TPU)")
     ap.add_argument("--no-serve-cast", dest="serve_cast",
                     action="store_false", default=True,
                     help="keep decode params stored-f32 (double the "

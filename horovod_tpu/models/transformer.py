@@ -224,7 +224,8 @@ class TransformerBlock(nn.Module):
     # (see ParallelSelfAttention.decode_prefix_block); 0/None = the
     # cache-wide-mask path.
     decode_prefix_block: Optional[int] = 256
-    decode_prefix_impl: str = "lax"   # "lax" | "pallas" (flash-decode)
+    # None = the code chooses (ops.flash_attention.decode_attention_plan)
+    decode_prefix_impl: Optional[str] = None   # | "lax" | "pallas"
     causal: bool = True     # False = bidirectional (encoder / ViT)
     weight_quant: Optional[str] = None   # None | "int8" (block matmuls)
     kv_quant: Optional[str] = None       # None | "int8" (decode cache)
@@ -381,7 +382,8 @@ class TransformerLM(nn.Module):
     # slices this big (ParallelSelfAttention.decode_prefix_block);
     # 0/None = cache-wide-mask path.
     decode_prefix_block: Optional[int] = 256
-    decode_prefix_impl: str = "lax"   # "lax" | "pallas" (flash-decode)
+    # None = the code chooses (ops.flash_attention.decode_attention_plan)
+    decode_prefix_impl: Optional[str] = None   # | "lax" | "pallas"
     # "int8": block matmul kernels stored int8 + per-channel scales
     # (weight-only, inference; `ops.quantization.quantize_lm_params`).
     # Embedding/head and LayerNorms stay full precision.
@@ -1097,6 +1099,31 @@ def slot_decode_model(model: TransformerLM) -> TransformerLM:
     appends at arbitrary fill) and S=1 ticks, so the flax-module hash —
     and therefore the jit cache — is shared across all of them."""
     return model.clone(decode=True, chunked_prefill=True)
+
+
+def decode_attention_plan(model: TransformerLM, lanes: int = 1):
+    """The way an S = 1 step of ``model`` against the linear cache
+    attends, as `ParallelSelfAttention` decides it when a tick is
+    traced under the ambient mesh: the `ops.flash_attention.DecodePlan`
+    (ragged kernel or lax walk, and why) for ``lanes`` slots. What the
+    engine logs at warm-up and `metrics_snapshot()` carries."""
+    from horovod_tpu.ops import flash_attention
+    from horovod_tpu.parallel.tensor import _mesh_is_trivial
+    W, blk = model.max_len, model.decode_prefix_block
+    if "attn" not in (model.layer_kinds or ("attn",)):
+        return flash_attention.DecodePlan("lax", "no softmax layer")
+    if model.window is not None:
+        return flash_attention.DecodePlan("lax", "rolling-window cache")
+    if not blk or W % min(blk, W):
+        return flash_attention.DecodePlan(
+            "lax", "decode_prefix_block off: the cache-wide mask")
+    return flash_attention.decode_attention_plan(
+        lanes, W, model.num_heads,
+        model.num_kv_heads or model.num_heads, model.head_dim,
+        itemsize=jnp.dtype(model.dtype or jnp.float32).itemsize,
+        impl=model.decode_prefix_impl,
+        quantized=model.kv_quant is not None,
+        trivial_mesh=_mesh_is_trivial())
 
 
 def init_slot_cache(model: TransformerLM, num_slots: int):

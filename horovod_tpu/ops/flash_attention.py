@@ -1307,74 +1307,185 @@ flash_attention.native_gqa = True
 
 
 # ---------------------------------------------------------------------------
-# Flash-decode: single-tick attention against the KV cache.
+# Flash-decode: one S = 1 step of attention against the linear KV
+# cache, every lane to its own length.
 # ---------------------------------------------------------------------------
+
+# Bytes of one K (or V) block a grid step streams, where the cache
+# length allows: large enough that a step's ~0.35 us of grid overhead
+# is small beside its DMA (PR 25's lesson), small enough that a lane's
+# last, partly filled block does not cost more than a short context
+# reads in all.
+DECODE_BLOCK_BYTES = 512 * 2 ** 10
+_DECODE_BLOCKS = (2048, 1024, 512, 256, 128)
+_DECODE_M0 = -1e30      # the running maximum before any key
+
+
+@dataclasses.dataclass(frozen=True)
+class DecodePlan:
+    """Which way an S = 1 decode step's attention goes at one shape,
+    and why: the trace-time record of `decode_attention_plan` (the
+    decode twin of `FlashPlan`) — the rule the model obeys, what the
+    engine logs at warm-up and carries in ``metrics_snapshot()``."""
+    path: str           # "kernel" | "lax" ("paged": the paged pool)
+    why: str
+    block_k: Optional[int] = None   # kernel path only, as below
+    grid: Optional[tuple] = None    # (lanes, k-blocks a lane)
+    vmem_bytes: Optional[int] = None
+    vmem_limit_bytes: Optional[int] = None
+
+    def describe(self) -> str:
+        if self.path != "kernel":
+            return f"{self.path} ({self.why})"
+        return (f"kernel ({self.why}): block_k {self.block_k}, grid "
+                f"{self.grid}, VMEM {self.vmem_bytes / 2 ** 20:.1f} MiB")
+
+
+def _decode_block_k(W: int, Hkv: int, D: int, itemsize: int,
+                    block_k: Optional[int] = None) -> Optional[int]:
+    """Keys a grid step streams: the largest block of `_DECODE_BLOCKS`
+    that divides the cache length and keeps a K block inside
+    `DECODE_BLOCK_BYTES` (at least 128 keys), the whole cache where it
+    is shorter than that, None where nothing divides it. ``block_k``
+    given: that block, capped at W."""
+    if block_k:
+        bk = min(int(block_k), W)
+        return bk if W % bk == 0 else None
+    fits = [b for b in _DECODE_BLOCKS if W % b == 0]
+    if not fits:
+        return W if W < _DECODE_BLOCKS[-1] else None
+    row = Hkv * D * itemsize
+    return next((b for b in fits if b * row <= DECODE_BLOCK_BYTES),
+                fits[-1])
+
+
+def _decode_vmem(bk: int, H: int, Hkv: int, D: int, itemsize: int):
+    """VMEM the decode kernel's plan sums to: K and V blocks double
+    buffered, q and the output, the float32 accumulator and softmax
+    state, and a step's score tile (scores, mask, probabilities and
+    their cast)."""
+    Dp = -(-D // 128) * 128
+    rows = -(-H // 8) * 8
+    return (2 * 2 * bk * Hkv * Dp * itemsize
+            + 2 * 2 * rows * Dp * itemsize
+            + rows * (Dp + 2 * 128) * 4
+            + 4 * rows * bk * Hkv * 4)
+
+
+def decode_attention_plan(lanes: int, W: int, H: int, Hkv: int, D: int,
+                          *, itemsize: int = 2, S: int = 1,
+                          impl: Optional[str] = None,
+                          quantized: bool = False,
+                          trivial_mesh: bool = True,
+                          on_tpu: Optional[bool] = None,
+                          block_k: Optional[int] = None) -> DecodePlan:
+    """THE rule for decode attention against the linear cache: the
+    ragged kernel (`flash_decode_attention`) for an S = 1 step on an
+    un-quantized cache with no serving mesh, at a shape Mosaic takes,
+    on a TPU; the lax walk (`ParallelSelfAttention._prefix_attention`)
+    for everything else. ``impl`` "lax" / "pallas" force a path (the
+    oracle, and the kernel in interpret mode off the chip); a forced
+    kernel still needs what the kernel cannot do without."""
+    if impl not in (None, "lax", "pallas"):
+        raise ValueError(
+            f"decode_prefix_impl must be None|lax|pallas, got {impl!r}")
+    if impl == "lax":
+        return DecodePlan("lax", "forced")
+    if S != 1:
+        return DecodePlan("lax", f"S = {S}: a prefill chunk or a "
+                          "verify block keeps the walk")
+    if quantized:
+        return DecodePlan("lax", "int8 KV is dequantized a block at a "
+                          "time by the walk")
+    if not trivial_mesh:
+        return DecodePlan("lax", "a serving mesh: the walk's ops "
+                          "partition over heads, a bare kernel does not")
+    if H % Hkv:
+        return DecodePlan("lax", f"{H} heads over {Hkv} KV heads")
+    bk = _decode_block_k(W, Hkv, D, itemsize, block_k)
+    if bk is None:
+        return DecodePlan("lax", f"no key block divides a cache of {W}")
+    if impl is None:
+        if on_tpu is None:
+            on_tpu = not _auto_interpret()
+        if not on_tpu:
+            return DecodePlan("lax", "not on a TPU")
+        if D % 128:
+            return DecodePlan("lax", f"head_dim {D} is not a multiple "
+                              "of 128 lanes")
+    vmem = _decode_vmem(bk, H, Hkv, D, itemsize)
+    return DecodePlan(
+        "kernel", "forced" if impl else "S = 1, linear cache, TPU",
+        block_k=bk, grid=(lanes, W // bk), vmem_bytes=vmem,
+        vmem_limit_bytes=vmem if vmem > VMEM_SCOPED_DEFAULT else None)
+
 
 def _decode_kernel(s_ref, q_ref, k_ref, v_ref, o_ref,
                    acc_ref, m_ref, l_ref, *,
                    scale: float, block_k: int, hkv: int, grp: int):
-    """One (batch, k-block) grid cell of the decode tick.
+    """One (lane, k-block) grid cell of the decode step.
 
-    The cache is consumed IN ITS STORED LAYOUT [B, W, Hkv, D] — a
-    head-major transpose would itself read the whole cache, the exact
-    traffic this kernel exists to avoid. Per kv-head 2D dots (grp q
-    rows each) + one concatenated online-softmax update over the full
-    [H, block] score matrix keep every op a plain Mosaic-lowerable
-    2D primitive (the r4 lesson: interpret mode accepts shapes real
-    Mosaic rejects — stick to [8k, 128m]-safe blocks).
+    The cache is consumed IN ITS STORED LAYOUT: the leaf [B, W, Hkv, D]
+    seen as [B, W*Hkv, D] — position-major rows with the KV heads
+    interleaved, which is how the bytes lie (the reshape is free, a
+    head-major transpose would itself read the whole cache). A block
+    is therefore ONE dense [bk*Hkv, D] tile, and the step is two plain
+    products on the MXU with the cache's own dtype as operands and
+    float32 accumulation (what the lax walk's einsums ask for):
+    every query head against every row, [H, D] x [D, bk*Hkv], the
+    columns of the OTHER KV heads masked like the unfilled tail, then
+    [H, bk*Hkv] x [bk*Hkv, D], where the masked columns weigh nothing.
+    No head is sliced out of the tile, the group's K/V is never
+    repeated and never widened; what it costs is Hkv x the softmax's
+    vector work on a score tile that is small at S = 1.
 
-    Scratch persists across the k-block sweep (innermost axis):
+    Scratch persists across a lane's k-block sweep (innermost axis):
       acc_ref [H, D] f32, m_ref/l_ref [H, 128] f32 (lane-replicated).
-    Scalar prefetch `s_ref`: [0] = number of VALID k-blocks for this
-    tick, [1] = filled prefix length. Blocks past s_ref[0] are skipped
-    (and the index_map clamps them onto the last valid block, whose
-    re-fetch the pipeline elides) — per-tick HBM traffic follows the
-    generated length, not the cache allocation.
+    Scalar prefetch `s_ref` [B, 2]: per lane the number of VALID
+    k-blocks and the filled prefix length. Blocks past a lane's count
+    are skipped (and the index_map clamps them onto its last valid
+    block, whose re-fetch the pipeline elides) — a lane's HBM traffic
+    follows ITS context, not the cache allocation and not the longest
+    context in flight.
     """
+    b = pl.program_id(0)
     j = pl.program_id(1)
-    nblk = s_ref[0]
-    length = s_ref[1]
+    nblk = s_ref[b, 0]
+    length = s_ref[b, 1]
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        # finite, so a block with no key kept (length 0) rescales by
+        # exp(0) and adds exp(-inf) = 0: never a NaN
+        m_ref[...] = jnp.full_like(m_ref, _DECODE_M0)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def _block():
-        q = q_ref[0].astype(jnp.float32) * scale        # [H, D]
-        kb = k_ref[0]                                   # [bk, Hkv, D]
+        # scaled in q's dtype, as the walk scales it
+        q = q_ref[0] * jnp.asarray(scale, q_ref.dtype)      # [H, D]
+        kb = k_ref[0]                                  # [bk*Hkv, D]
         vb = v_ref[0]
-        parts = []
-        for h in range(hkv):
-            qh = q[h * grp:(h + 1) * grp, :]
-            kh = kb[:, h, :].astype(jnp.float32)             # [bk, D]
-            parts.append(jax.lax.dot_general(
-                qh, kh, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32))         # [grp, bk]
-        logits = parts[0] if hkv == 1 else jnp.concatenate(parts, 0)
-        pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, logits.shape, 1)
-        logits = jnp.where(pos < length, logits, NEG_INF)
-
-        m_prev = m_ref[...]                             # [H, 128]
-        l_prev = l_ref[...]
-        m_cur = jnp.max(logits, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        shift = jnp.where(m_new == NEG_INF, 0.0, m_new)
-        p = jnp.exp(logits - shift[:, :1])              # [H, bk]
-        corr = jnp.where(m_prev == NEG_INF, 0.0,
-                         jnp.exp(m_prev - shift))
-        l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        pv_parts = []
-        for h in range(hkv):
-            ph = p[h * grp:(h + 1) * grp, :]
-            vh = vb[:, h, :].astype(jnp.float32)
-            pv_parts.append(jax.lax.dot_general(
-                ph, vh, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))         # [grp, D]
-        pv = pv_parts[0] if hkv == 1 else jnp.concatenate(pv_parts, 0)
-        acc_ref[...] = acc_ref[...] * corr[:, :1] + pv
+        s = jax.lax.dot_general(q.astype(kb.dtype), kb, _NT,
+                                preferred_element_type=jnp.float32)
+        # column c is key j*bk + c // Hkv of KV head c % Hkv; row r
+        # is a query head of KV head r // grp
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        keep = col < (length - j * block_k) * hkv
+        if hkv > 1:
+            first = jax.lax.rem(col, jnp.int32(hkv)) * grp
+            keep &= (row >= first) & (row < first + grp)
+        s = jnp.where(keep, s, NEG_INF)
+        m_prev = m_ref[...]                                 # [H, 128]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new[:, :1])
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1,
+                                                  keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha[:, :1] + jax.lax.dot_general(
+            p.astype(vb.dtype), vb, _NN,
+            preferred_element_type=jnp.float32)             # [H, D]
         m_ref[...] = m_new
 
     pl.when(j < nblk)(_block)
@@ -1382,66 +1493,39 @@ def _decode_kernel(s_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
         l = l_ref[...][:, :1]
-        denom = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+                    ).astype(o_ref.dtype)
 
 
-def flash_decode_attention(q: jax.Array, k_cache: jax.Array,
-                           v_cache: jax.Array, length: jax.Array, *,
-                           block_k: int = 512,
-                           interpret: Optional[bool] = None
-                           ) -> jax.Array:
-    """One decode tick of attention against the filled cache prefix.
-
-    q [B, 1, H, D]; k_cache/v_cache [B, W, Hkv, D] (the linear decode
-    cache, already containing the current token at position
-    ``length - 1``); ``length`` traced int32 — the filled prefix
-    length. Returns [B, 1, H, D] at q.dtype.
-
-    One fused kernel per (batch, k-block): only the
-    ceil(length/block_k) leading cache blocks are DMA'd (scalar-
-    prefetched block count; clamped index_map + pipeline elision make
-    the tail free), GQA consumed natively at Hkv width, online softmax
-    in f32 VMEM scratch. The lax.fori_loop equivalent lives in
-    `ParallelSelfAttention._prefix_attention` (`decode_prefix_impl=
-    "lax"`, the default + oracle); this kernel removes that loop's
-    per-iteration overhead. bf16/f32 caches only (int8 KV uses the lax
-    path's per-block dequant).
-    """
-    if interpret is None:
-        interpret = _auto_interpret()
+@functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
+def _flash_decode(q, k_cache, v_cache, lengths, block_k, interpret):
+    """The batched pallas_call: q [B, H, D], caches [B, W, Hkv, D],
+    lengths [B] -> [B, H, D]."""
     B, W, Hkv, D = k_cache.shape
-    if q.ndim != 4 or q.shape[1] != 1:
-        raise ValueError(f"flash_decode_attention wants q [B,1,H,D], "
-                         f"got {q.shape}")
-    H = q.shape[2]
-    if H % Hkv:
-        raise ValueError(f"H={H} not divisible by Hkv={Hkv}")
-    grp = H // Hkv
-    bk = min(block_k, W)
-    if W % bk:
-        raise ValueError(
-            f"block_k={bk} must divide cache length {W}")
-    nk = W // bk
-    length = jnp.asarray(length, jnp.int32)
-    scalars = jnp.stack([(length + bk - 1) // bk, length])
+    H = q.shape[1]
+    plan = decode_attention_plan(
+        B, W, H, Hkv, D, itemsize=k_cache.dtype.itemsize,
+        impl="pallas", block_k=block_k)
+    if plan.path != "kernel":
+        raise ValueError(f"flash_decode_attention: {plan.why}")
+    bk = plan.block_k
+    lengths = jnp.asarray(lengths, jnp.int32)
+    # a lane at length 0 (never the engine's: the step's own token is
+    # in the cache) still sweeps one block, fully masked
+    nblk = jnp.maximum(_fdiv(lengths + (bk - 1), bk), 1)
+    scalars = jnp.stack([nblk, lengths], axis=1)        # [B, 2]
 
-    q3 = q[:, 0]                                        # [B, H, D]
-    kernel = functools.partial(_decode_kernel, scale=D ** -0.5,
-                               block_k=bk, hkv=Hkv, grp=grp)
+    def kv_map(b, j, s):
+        return (b, jnp.minimum(j, s[b, 0] - 1), 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, nk),
+        grid=plan.grid,
         in_specs=[
-            # index_map args: (*grid_indices, *scalar_prefetch_refs) —
-            # the scalar ref comes LAST (jax pallas TPU convention).
+            # index_map args: (*grid_indices, *scalar_prefetch_refs)
             pl.BlockSpec((1, H, D), lambda b, j, s: (b, 0, 0)),
-            pl.BlockSpec((1, bk, Hkv, D),
-                         lambda b, j, s: (b, jnp.minimum(j, s[0] - 1),
-                                          0, 0)),
-            pl.BlockSpec((1, bk, Hkv, D),
-                         lambda b, j, s: (b, jnp.minimum(j, s[0] - 1),
-                                          0, 0)),
+            pl.BlockSpec((1, bk * Hkv, D), kv_map),
+            pl.BlockSpec((1, bk * Hkv, D), kv_map),
         ],
         out_specs=pl.BlockSpec((1, H, D), lambda b, j, s: (b, 0, 0)),
         scratch_shapes=[
@@ -1450,12 +1534,83 @@ def flash_decode_attention(q: jax.Array, k_cache: jax.Array,
             _scratch((H, 128), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
-        kernel,
+    return pl.pallas_call(
+        functools.partial(_decode_kernel, scale=D ** -0.5, block_k=bk,
+                          hkv=Hkv, grp=H // Hkv),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=plan.vmem_limit_bytes),
         interpret=interpret,
-    )(scalars, q3, k_cache, v_cache)
-    return out[:, None]
+    )(scalars, q, k_cache.reshape(B, W * Hkv, D),
+      v_cache.reshape(B, W * Hkv, D))
+
+
+@functools.lru_cache(maxsize=None)
+def _make_decode(block_k: Optional[int], interpret: bool):
+    """custom_vmap-wrapped entry (the `_make_paged_decode` pattern):
+    under the serving tick's `jax.vmap` over slots the batch rule
+    fires and the slot axis JOINS the kernel's lane axis — the cache
+    leaf [num_slots, 1, W, Hkv, D] is read where it lies (merging two
+    leading axes is free), each lane to its own length. The default
+    batching of a pallas_call would instead run the lanes one after
+    another inside a `while` (its scalar-prefetch operand is
+    batched)."""
+
+    @jax.custom_batching.custom_vmap
+    def decode(q, k_cache, v_cache, lengths):
+        return _flash_decode(q, k_cache, v_cache, lengths, block_k,
+                            interpret)
+
+    @decode.def_vmap
+    def _rule(axis_size, in_batched, q, k_cache, v_cache, lengths):
+        def lanes(x, batched):
+            if not batched:     # e.g. a query against a shared cache
+                x = jnp.broadcast_to(x, (axis_size,) + jnp.shape(x))
+            return x.reshape((axis_size * x.shape[1],) + x.shape[2:])
+
+        args = [lanes(x, b) for x, b in zip(
+            (q, k_cache, v_cache, lengths), in_batched)]
+        out = decode(*args)
+        return out.reshape((axis_size, -1) + out.shape[1:]), True
+
+    return decode
+
+
+def flash_decode_attention(q: jax.Array, k_cache: jax.Array,
+                           v_cache: jax.Array, length: jax.Array, *,
+                           block_k: Optional[int] = None,
+                           interpret: Optional[bool] = None
+                           ) -> jax.Array:
+    """One decode step of attention against the filled cache prefix,
+    ragged over the batch.
+
+    q [B, 1, H, D]; k_cache/v_cache [B, W, Hkv, D] (the linear decode
+    cache, already containing the current token at position
+    ``length - 1``); ``length`` traced int32, a scalar (`generate`:
+    every row at the same index) or [B] (each lane its own filled
+    prefix). Returns [B, 1, H, D] at q.dtype.
+
+    One fused kernel, a grid cell per (lane, k-block): only a lane's
+    own ceil(length/block_k) leading blocks are DMA'd (scalar-
+    prefetched per-lane block counts; clamped index_map + pipeline
+    elision make the tail free), GQA consumed natively at Hkv width
+    with the cache dtype on the MXU, online softmax in f32 VMEM
+    scratch. ``block_k`` None: from the shape (`_decode_block_k`).
+    `jax.vmap` over a leading slot axis — the serving tick — folds
+    that axis into the lanes of the same one call. The lax.fori_loop
+    equivalent lives in `ParallelSelfAttention._prefix_attention` (the
+    oracle, and what `decode_attention_plan` keeps for everything this
+    kernel does not take). bf16/f32 caches only (int8 KV uses the
+    walk's per-block dequant).
+    """
+    if interpret is None:
+        interpret = _auto_interpret()
+    if q.ndim != 4 or q.shape[1] != 1:
+        raise ValueError(f"flash_decode_attention wants q [B,1,H,D], "
+                         f"got {q.shape}")
+    lengths = jnp.broadcast_to(jnp.asarray(length, jnp.int32),
+                               (q.shape[0],))
+    fn = _make_decode(_opt_int(block_k), bool(interpret))
+    return fn(q[:, 0], k_cache, v_cache, lengths)[:, None]

@@ -430,12 +430,14 @@ class ParallelSelfAttention(nn.Module):
     # = the cache-wide-mask path (also the fallback when the block
     # doesn't divide the cache length).
     decode_prefix_block: Optional[int] = 256
-    # "lax" (default): the fori_loop prefix attention — composes with
-    # everything (int8 KV, S>1 chunks, any batch rank) and is the
-    # oracle. "pallas": ops.flash_attention.flash_decode_attention —
-    # one fused kernel per tick (no per-block loop overhead); S=1,
-    # un-quantized cache, [B,S,H,D] only, falls back to lax otherwise.
-    decode_prefix_impl: str = "lax"
+    # None (default): the code chooses (`ops.flash_attention.
+    # decode_attention_plan`) — the ragged flash-decode kernel for an
+    # S=1 step on a TPU (un-quantized cache, no serving mesh, head_dim
+    # a multiple of 128), the fori_loop walk for everything else (CPU,
+    # int8 KV, S>1 chunks, a mesh). "lax" forces the walk (the
+    # oracle); "pallas" forces the kernel wherever it can run at all
+    # (interpret mode off the chip) — what tests need, not users.
+    decode_prefix_impl: Optional[str] = None
     # Projections carry no bias by default (LLaMA-style); GPT-2-family
     # checkpoints (compat.hf) need them.
     use_bias: bool = False
@@ -662,21 +664,24 @@ class ParallelSelfAttention(nn.Module):
         (per-block `_repeat_kv`), int8 KV (per-block dequant), and TP
         (all ops are shard-local over the head axis).
         """
-        if self.decode_prefix_impl not in ("lax", "pallas"):
-            raise ValueError(
-                f"decode_prefix_impl must be lax|pallas, got "
-                f"{self.decode_prefix_impl!r}")
+        from horovod_tpu.ops.flash_attention import (
+            decode_attention_plan, flash_decode_attention)
         W = cached_k.value.shape[-3]
         blk = min(self.decode_prefix_block, W)
-        if (self.decode_prefix_impl == "pallas" and scale_k is None
-                and q.ndim == 4 and S == 1 and _mesh_is_trivial()):
-            # Trivial-mesh only: a bare pallas_call is opaque to the
-            # GSPMD partitioner, so sharded (TP) decode keeps the lax
-            # path, whose ops partition over the head axis naturally.
-            from horovod_tpu.ops.flash_attention import (
-                flash_decode_attention)
+        # Trivial-mesh only: a bare pallas_call is opaque to the GSPMD
+        # partitioner, so sharded (TP) decode keeps the lax path,
+        # whose ops partition over the head axis naturally.
+        plan = decode_attention_plan(
+            q.shape[0], W, self.num_heads,
+            self.num_kv_heads or self.num_heads, self.head_dim,
+            itemsize=cached_k.value.dtype.itemsize,
+            S=S, impl=self.decode_prefix_impl,
+            quantized=scale_k is not None,
+            trivial_mesh=_mesh_is_trivial())
+        if plan.path == "kernel" and q.ndim == 4:
             return flash_decode_attention(
-                q, cached_k.value, cached_v.value, i + S, block_k=blk)
+                q, cached_k.value, cached_v.value, i + S,
+                block_k=plan.block_k)
         H = self.num_heads
         D = self.head_dim
         lead = q.shape[:-3]

@@ -38,6 +38,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import itertools
+import logging
 import sys
 import threading
 import time
@@ -469,11 +470,17 @@ class ServingEngine:
         # Warmup runs on the constructor thread BEFORE the dispatch
         # thread exists, so the single-jax-thread contract holds.
         self.warmup_info = None
+        plan = self.pool.decode_attention_plan()
+        self.metrics.observe_decode_attn(plan.path, plan.describe())
         if warmup:
             self.warmup_info = self.pool.warmup(
                 max_chunk=(self.prefill_chunk_budget
                            if self.prefill_chunk_budget > 0 else None))
             self.metrics.observe_warmup(self.warmup_info["seconds"])
+            logging.getLogger("horovod_tpu").info(
+                "serving warm-up: %d programs in %.1f s; decode "
+                "attention: %s", self.warmup_info["compiles"],
+                self.warmup_info["seconds"], plan.describe())
         # Hot-path compiles = pool compiles past this baseline.
         self._compile_baseline = self.pool.compiles
         self.metrics.observe_pipeline(self.pipeline_depth)

@@ -225,6 +225,10 @@ class EngineMetrics:
         self.mesh_devices = 1
         self.mesh_shape = None
         self.warmup_s = None       # startup precompile cost, if run
+        # How the pool's S = 1 ticks attend ("kernel" | "lax" |
+        # "paged") and the plan in words; set once by the engine.
+        self.decode_attn_path = None
+        self.decode_attn_plan = None
         # Latency series (seconds).
         self.queue_wait_s = Series()
         self.ttft_s = Series()
@@ -246,6 +250,11 @@ class EngineMetrics:
     def observe_warmup(self, seconds: float):
         with self._lock:
             self.warmup_s = seconds
+
+    def observe_decode_attn(self, path: str, plan: str):
+        with self._lock:
+            self.decode_attn_path = path
+            self.decode_attn_plan = plan
 
     def count(self, name: str, n: int = 1):
         with self._lock:
@@ -505,6 +514,8 @@ class EngineMetrics:
                 "mesh": self.mesh_shape,
                 "warmup_s": (round(self.warmup_s, 3)
                              if self.warmup_s is not None else None),
+                "decode_attn_path": self.decode_attn_path,
+                "decode_attn_plan": self.decode_attn_plan,
                 "restarts": self.restarts,
                 "requeued": self.requeued,
                 "faults_injected": self.faults_injected,

@@ -664,6 +664,13 @@ class PagedSlotPool:
     def spec_on(self) -> bool:
         return self.spec_draft is not None and self.spec_k > 0
 
+    def decode_attention_plan(self):
+        """`SlotPool.decode_attention_plan`'s twin: this pool's ticks
+        attend through the block tables, never the linear cache."""
+        from horovod_tpu.ops.flash_attention import DecodePlan
+        return DecodePlan(
+            "paged", f"block tables, HVD_PAGED_KERNEL={self.kernel_mode}")
+
     def _ctx(self):
         return use(self.mesh) if self.mesh is not None \
             else contextlib.nullcontext()

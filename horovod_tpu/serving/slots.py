@@ -50,8 +50,8 @@ import jax.numpy as jnp
 
 from horovod_tpu.annotations import hot_path
 from horovod_tpu.models.transformer import (
-    TransformerLM, init_slot_cache, prefill_chunks, recurrent_leaf,
-    sample_token,
+    TransformerLM, decode_attention_plan, init_slot_cache,
+    prefill_chunks, recurrent_leaf, sample_token,
     shard_slot_cache, slot_decode_model, slot_decode_tick,
     slot_prefill_advance, slot_prefill_chunk, slot_reset,
     slot_spec_round,
@@ -250,6 +250,13 @@ class SlotPool:
     def _ctx(self):
         return use(self.mesh) if self.mesh is not None \
             else contextlib.nullcontext()
+
+    def decode_attention_plan(self):
+        """The plan this pool's ticks compile with (kernel or lax walk,
+        and why): `models.transformer.decode_attention_plan` under the
+        pool's mesh."""
+        with self._ctx():
+            return decode_attention_plan(self.model, self.num_slots)
 
     def _note_shape(self, key):
         if key not in self._seen_shapes:

@@ -250,6 +250,85 @@ def test_paged_decode_tick_whole_program(sds, monkeypatch):
     assert need < 16 * 2 ** 30, f"tick needs {need / 2**30:.1f} GiB"
 
 
+# The serving cells' attention shapes: (lanes, cache positions, model
+# fields). Two layers, a narrow MLP and a small vocabulary: the tick's
+# attention, cache write and aliasing do not depend on the rest.
+TICK_SHAPES = {
+    "qwen2.5-1.5b": (32, 4096, dict(
+        num_heads=12, num_kv_heads=2, head_dim=128, pos_emb="rope",
+        rope_theta=1e6, attn_bias=True, attn_out_bias=False)),
+    "solar-open2-250b": (128, 2048, dict(
+        num_heads=64, num_kv_heads=8, head_dim=128, hidden_size=4096,
+        pos_emb="none", attn_gate=True)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(TICK_SHAPES))
+def test_slot_decode_tick_attends_through_the_ragged_kernel(
+        sds, monkeypatch, cell):
+    """The fixed pool's whole tick on the DEFAULT rule (nothing
+    forced): per layer ONE Mosaic call under the attention's scope in
+    place of the lax walk's `while`, the donated cache still aliased
+    input to output, and no copy of a K or V leaf (the walk under vmap
+    cost two layout copies of each a tick)."""
+    from horovod_tpu.models.transformer import (
+        TransformerLM, decode_attention_plan, init_slot_cache,
+        serving_params, slot_decode_model, slot_decode_tick)
+    from horovod_tpu.ops import flash_attention
+    from horovod_tpu.parallel.tensor import unbox
+
+    # the rule asks for the default backend - the CPU here (see
+    # test_paged_decode_tick_whole_program)
+    monkeypatch.setattr(flash_attention, "_auto_interpret",
+                        lambda: False)
+    lanes, W, fields = TICK_SHAPES[cell]
+    layers = 2
+    model = TransformerLM(
+        vocab_size=4096, num_layers=layers, max_len=W, norm="rmsnorm",
+        mlp_impl="swiglu", mlp_hidden=1024, dtype=jnp.bfloat16,
+        attn_impl="flash", **fields)
+    plan = decode_attention_plan(model, lanes)
+    assert plan.path == "kernel" and plan.grid[0] == lanes, plan
+    dec = slot_decode_model(model)
+
+    def place(tree):
+        return jax.tree.map(lambda s: sds(s.shape, s.dtype), tree)
+
+    params = place(jax.eval_shape(
+        lambda r: serving_params(unbox(model.init(
+            r, jnp.zeros((1, 64), jnp.int32))["params"])),
+        jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: init_slot_cache(model, lanes)))
+    vec = lambda dt: sds((lanes,), dt)  # noqa: E731
+    compiled = slot_decode_tick.lower(
+        dec, params, cache, vec(jnp.int32), vec(jnp.float32),
+        vec(jnp.float32), sds((lanes, 2), jnp.uint32), vec(bool),
+        vec(bool), sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == layers
+    assert all("attn._prefix_attention" in ln for ln in calls)
+    # the only loops left under the attention are the cache write's
+    # (XLA runs a scatter of one row a lane as a loop of updates)
+    loops = re.findall(r' while\(.*op_name="([^"]*)"', text)
+    assert [n for n in loops if "/attn/" in n
+            and "_cache_write" not in n] == []
+    kv = [leaf for path, leaf in
+          jax.tree_util.tree_flatten_with_path(cache)[0]
+          if "cached_" in str(path)]
+    kv_bytes = sum(leaf.dtype.itemsize * leaf.size for leaf in kv)
+    assert compiled.memory_analysis().alias_size_in_bytes >= kv_bytes
+    # a copy of a leaf would carry its element count in some shape
+    elems = {str(kv[0].size)}
+    for ln in text.splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\S* copy\(", ln)
+        if m:
+            n = 1
+            for d in m.group(1).split(","):
+                n *= int(d)
+            assert str(n) not in elems, ln[:200]
+
+
 def test_flash_under_a_four_chip_data_mesh(topo, chip_config,
                                            monkeypatch):
     """The LM's `attn_impl="flash"` inside a GSPMD program over four
