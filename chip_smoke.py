@@ -2,8 +2,8 @@
 the chip.
 
 Drives the system's main paths ONCE, through the entry points a user
-calls, at the full width of the flagship LM (`bench.py`'s
-`TransformerLM(vocab 32768, 12 layers, 8 heads x 128, max_len 2048,
+calls, at the full width of the flagship LM
+(`TransformerLM(vocab 32768, 12 layers, 8 heads x 128, max_len 2048,
 bf16)`, 186.8 M parameters, `attn_impl="flash"`), with random weights
 and tokens made from `--seed`:
 
@@ -43,7 +43,7 @@ import sys
 import time
 import traceback
 
-# ---- sizes (the flagship LM as bench.py builds it) -------------------
+# ---- sizes (the flagship LM) ------------------------------------------
 LM_KW = dict(vocab_size=32768, num_layers=12, num_heads=8, head_dim=128,
              max_len=2048)
 LM_BATCH, LM_STEPS = 8, 5            # per-chip batch x max_len tokens
@@ -298,7 +298,7 @@ def phase_trainer(devs, seed):
 
     # The LM's TP/SP annotations name every canonical axis, so its
     # mesh is the 5-axis one over hvd's devices (size-1 axes are
-    # free), as bench.py builds it — hvd.mesh() itself is 1-D `data`.
+    # free) — hvd.mesh() itself is 1-D `data`.
     mesh = make_mesh(devices=devs, data=hvd.size())
     toks = np.random.RandomState(seed).randint(
         0, LM_KW["vocab_size"],

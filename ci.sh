@@ -17,12 +17,6 @@ JAX_PLATFORMS=cpu python -m pytest tests/ -q
 # temp file, so CI itself stays green-on-clean.
 JAX_PLATFORMS=cpu python -m horovod_tpu.analysis \
     --baseline .hvdlint-baseline.json
-# Env-knob discipline beyond the package: bench.py reads
-# HVD_* knobs too — HVD005 (only; bench's exception style is its own)
-# keeps them inside the runtime/config.py registry so the generated
-# troubleshooting table stays complete.
-JAX_PLATFORMS=cpu python -m horovod_tpu.analysis --rules HVD005 \
-    bench.py
 
 # Runtime lock witness (docs/analysis.md "The runtime witness"): the
 # dynamic half of HVD007. Re-run the lock-heaviest suites (serving
@@ -131,8 +125,7 @@ JAX_PLATFORMS=cpu python -m horovod_tpu.obs.flightrec \
 # Then 8 client arrivals are recorded to an obs.reqlog JSONL,
 # prompt-synthesized back from their digests, and re-served on a
 # fresh engine: request count and every per-request token count must
-# round-trip exactly — the record->replay guarantee bench.py's
-# --record-reqlog/--replay flags build on. Knobs: HVD_TRACE_LOG,
+# round-trip exactly — the record->replay guarantee. Knobs: HVD_TRACE_LOG,
 # HVD_TRACE_SAMPLE, HVD_REQLOG (runtime/config.py registry).
 JAX_PLATFORMS=cpu python examples/transformer_serving.py --requests 2 \
     --trace-check

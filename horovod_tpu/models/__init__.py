@@ -4,7 +4,7 @@ The reference itself ships no model library — its examples and README
 benchmarks use MNIST convnets, word2vec, and tf_cnn_benchmarks'
 ResNet-101 / Inception V3 / VGG-16 (`README.md:27-32`, SURVEY §6). These
 TPU-first implementations (flax.linen, NHWC, bfloat16-friendly) back
-`examples/`, `bench.py`, and the scaling-efficiency targets in
+`examples/`, `chip_smoke.py` and the scaling-efficiency targets in
 BASELINE.md.
 """
 

@@ -2,7 +2,7 @@
 
 The reference's headline number is 90 % scaling efficiency for ResNet-101
 data-parallel training on 128 GPUs (`README.md:27-32`, BASELINE.md); this
-is the TPU-first implementation used by `bench.py` and
+is the TPU-first implementation used by `chip_smoke.py` and
 `__graft_entry__.py`.
 
 TPU design notes:
@@ -207,8 +207,8 @@ class ResNet(nn.Module):
     sync_bn: bool = False
     axis_name: str = "data"
     # MXU-friendly stem (SpaceToDepthStem): same parameters, same
-    # outputs, 16x larger stem contraction dim. Off by default so the
-    # benchmark measures plain vs s2d explicitly (bench.py --stem).
+    # outputs, 16x larger stem contraction dim. Off by default: the
+    # one chip reading of it was throughput-neutral (docs/mfu.md).
     s2d_stem: bool = False
     # >1: train-time BN statistics from batch[: B/bn_sample]
     # (SampledBatchNorm) — attacks the measured 37.8 %-of-step BN stat

@@ -48,7 +48,7 @@ def make_cnn_train_step(model, tx: optax.GradientTransformation,
     and `hvd_training_step_seconds` cadence histogram always record;
     declaring the step's work turns on the throughput gauges —
     ``examples_per_step`` drives `hvd_training_tokens_per_s` and
-    ``flops_per_step`` (analytic, e.g. bench.py's per-image tables)
+    ``flops_per_step`` (analytic, from the model's shapes)
     the `hvd_training_mfu` gauge against the device's known peak
     (`utils/profile_analysis.py` math).
     """

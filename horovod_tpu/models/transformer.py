@@ -60,37 +60,24 @@ Dtype = Any
 ATTN_IMPLS = ("dot", "blockwise", "flash", "ring", "ring_flash",
               "ulysses", "ulysses_flash")
 
-# The LLaMA-family knob set — single source for `compat.hf.from_hf_llama`,
-# `bench.py --arch llama`, and the driver dryrun's llama leg, so the
-# three can never silently diverge.
+# The LLaMA-family knob set — single source for `compat.hf.from_hf_llama`
+# and the driver dryrun's llama leg, so the two can never silently
+# diverge.
 LLAMA_ARCH_KW = dict(norm="rmsnorm", mlp_impl="swiglu",
                      tied_head=False)
 
 
 def make_attn_fn(impl: str, *, causal: bool = True,
                  block_size: int = 512,
-                 window: Optional[int] = None,
-                 flash_block_q: Optional[int] = None,
-                 flash_block_k: Optional[int] = None
-                 ) -> Optional[Callable]:
+                 window: Optional[int] = None) -> Optional[Callable]:
     """attn_fn for `ParallelSelfAttention` (None = dot baseline, which
     consumes the explicit mask argument instead). ``window`` = sliding
     -window attention (last `window` positions only; requires causal).
-    ``flash_block_q``/``flash_block_k``: Pallas kernel tile sizes
-    (``impl="flash"``). None (the default) lets the kernel choose them
-    from the shape (`ops.flash_attention._pick_tiles`); an integer is
-    honoured, so `bench.py --flash-block-q/-k` can still sweep them on
-    hardware.
+    ``impl="flash"`` runs the Pallas kernel at the tiles it chooses
+    from the shape (`ops.flash_attention._pick_tiles`).
     """
     from horovod_tpu.parallel.sequence import check_window
     check_window(window)
-    if (flash_block_q, flash_block_k) != (None, None) and impl != "flash":
-        # ring_flash/ulysses_flash run the kernel at its shape-chosen
-        # tiles; silently ignoring the knob would make a hardware
-        # sweep measure identical kernels.
-        raise ValueError(
-            f"flash_block_q/flash_block_k apply to attn_impl='flash' "
-            f"only (got impl={impl!r})")
     if impl == "dot":
         return None
 
@@ -111,8 +98,7 @@ def make_attn_fn(impl: str, *, causal: bool = True,
         from horovod_tpu.ops.flash_attention import flash_attention
 
         kernel = functools.partial(
-            flash_attention, causal=causal, window=window,
-            block_q=flash_block_q, block_k=flash_block_k)
+            flash_attention, causal=causal, window=window)
 
         def attn(q, k, v, m):
             _no_mask(m)
@@ -229,8 +215,6 @@ class TransformerBlock(nn.Module):
     causal: bool = True     # False = bidirectional (encoder / ViT)
     weight_quant: Optional[str] = None   # None | "int8" (block matmuls)
     kv_quant: Optional[str] = None       # None | "int8" (decode cache)
-    flash_block_q: Optional[int] = None  # Pallas flash tile sizes;
-    flash_block_k: Optional[int] = None  # None = chosen from the shape
     attn_bias: bool = False              # GPT-2-family checkpoints
     attn_out_bias: Optional[bool] = None  # None = follow attn_bias
     ln_eps: float = 1e-6
@@ -269,9 +253,7 @@ class TransformerBlock(nn.Module):
         # ONE-PASS PREFILL (S>1 from an empty cache), which is plain
         # causal attention over the prompt block — flash-able.
         attn_fn = make_attn_fn(self.attn_impl, causal=self.causal,
-                               window=self.window,
-                               flash_block_q=self.flash_block_q,
-                               flash_block_k=self.flash_block_k)
+                               window=self.window)
         mask = None
         if attn_fn is None and not self.decode and self.causal:
             # dot baseline materializes the banded causal mask
@@ -391,11 +373,6 @@ class TransformerLM(nn.Module):
     # "int8": decode KV cache stored int8 with per-(position, head)
     # scales — 2x context length per byte of cache HBM.
     kv_quant: Optional[str] = None
-    # Pallas flash tile sizes: None = chosen from the shape by the
-    # kernel's plan (`flash_tile_check` shows it); an integer is
-    # honoured (bench.py --flash-block-q/-k sweeps them).
-    flash_block_q: Optional[int] = None
-    flash_block_k: Optional[int] = None
     attn_bias: bool = False    # attention projection biases (GPT-2)
     attn_out_bias: Optional[bool] = None  # Qwen2: qkv bias, no out bias
     ln_eps: float = 1e-6       # LayerNorm epsilon (GPT-2: 1e-5)
@@ -501,8 +478,6 @@ class TransformerLM(nn.Module):
                 decode_prefix_impl=self.decode_prefix_impl,
                 weight_quant=self.weight_quant,
                 kv_quant=self.kv_quant,
-                flash_block_q=self.flash_block_q,
-                flash_block_k=self.flash_block_k,
                 attn_bias=self.attn_bias,
                 attn_out_bias=self.attn_out_bias,
                 ln_eps=self.ln_eps,

@@ -20,7 +20,7 @@ primitives as the flagship LM:
   exactly (H/p)(W/p), which divides SP degrees and kernel block sizes.
 
 Works with `make_cnn_train_step` (no BatchNorm state; the empty
-batch_stats collection is handled) and `bench.py --model vit`.
+batch_stats collection is handled); `examples/jax_vit.py` trains it.
 """
 
 from __future__ import annotations

@@ -306,7 +306,7 @@ def install(log: Optional[EventLog]) -> Optional[EventLog]:
     """Swap the global log, returning the PREVIOUS one (which may be
     None if nothing ever emitted). The scoped-use twin of `configure`:
     save the return value and re-install it when done, so a temporary
-    redirect (bench's trace check, a test) never silently disables a
+    redirect (an example's check, a test) never silently disables a
     log the user configured via ``HVD_EVENTS_LOG``."""
     global _LOG
     with _LOG_LOCK:

@@ -10,7 +10,7 @@ rule as the rest of the repo) serving three endpoints:
 * ``/metrics.json`` — the registry's full JSON snapshot (histogram
   quantile estimates + exemplars included) plus the newest structured
   events, this process's rank and its collective timing window; what
-  `bench.py`, the fleet aggregator and humans read.
+  the fleet aggregator and humans read.
 * ``/healthz`` — liveness + the registered health providers (the
   serving engine reports its dispatch generation here, so a prober
   can tell an in-place watchdog restart from a process restart; an

@@ -4,10 +4,9 @@ ROADMAP 1(c): autoscaler and overload policies should be tuned
 against replayed production-shaped traffic, not Poisson toys. This
 module records the WORKLOAD SHAPE of a live engine/router — arrival
 times (relative to the log's start), prompt/output budgets,
-tenant/priority lanes, and the prefix-sharing structure — and
-`bench.py --serving --replay <log>` re-serves it open-loop at a
-``--replay-speed`` factor, emitting the same artifact schema as a
-synthetic run.
+tenant/priority lanes, and the prefix-sharing structure — so that
+`load` + `synthesize_prompt` can re-serve it open-loop at the recorded
+gaps (`examples/transformer_serving.py --trace-check` does).
 
 Privacy/size by construction: prompts are NOT stored. Each record
 carries the prompt's block-aligned blake2b CHAIN digests (the exact

@@ -24,9 +24,7 @@ allowed rejection fraction (its own budget); ``target`` is the good
 fraction for the latency objectives (budget = 1 - target); ``fast``/
 ``slow`` are the window lengths in seconds; ``burn`` overrides the
 fast-burn threshold (default 14.4). `ServingEngine` wires its request
-stream in automatically when the knob (or ``slo=``) is set, and
-``bench.py --serving`` records the objectives / burn rates / breach
-count in its artifact.
+stream in automatically when the knob (or ``slo=``) is set.
 """
 
 from __future__ import annotations
@@ -340,29 +338,6 @@ class SLOMonitor:
             "objectives": {n: {"burn_rate_fast": st["burn_rate_fast"],
                                "burn_rate_slow": st["burn_rate_slow"]}
                            for n, st in state.items()},
-        }
-
-    def summary(self) -> Dict:
-        """The bench-artifact block: objectives, burn rates, breach
-        count."""
-        state = self.evaluate()
-        return {
-            "objectives": {
-                n: {"kind": st["kind"],
-                    "threshold_s": st["threshold_s"],
-                    "budget": st["budget"]}
-                for n, st in state.items()},
-            "burn_rates": {
-                n: {"fast": st["burn_rate_fast"],
-                    "slow": st["burn_rate_slow"]}
-                for n, st in state.items()},
-            "windows_s": {"fast": self.fast_window_s,
-                          "slow": self.slow_window_s},
-            "fast_burn_threshold": self.fast_burn,
-            "breaching": [n for n, st in state.items()
-                          if st["breaching"]],
-            "breach_count": self.breach_count,
-            "tenants_breaching": self.tenant_breaching(),
         }
 
     # -- construction from the knob -----------------------------------

@@ -5,9 +5,8 @@ bitwise-equal to the fixed slot pool by GATHERING each lane's whole
 block table back into a linear [max_len] view every tick
 (`models.transformer._paged_view`) — correct, but the gather touches
 every allocated block whether or not the sequence ever filled it, so
-at serving shapes the capacity winner was the latency loser
-(BENCH_serving_pr7: paged TPOT p50 211 ms vs 76 ms fixed at equal KV
-bytes). This module deletes that tax: attention reads the pool
+per-tick HBM traffic followed the table's span where the fixed pool's
+follows the fill. This module deletes that tax: attention reads the pool
 THROUGH the block table, touching only the blocks the lane actually
 filled, in two interchangeable forms:
 

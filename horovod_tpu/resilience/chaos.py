@@ -252,14 +252,6 @@ def fired(site: str) -> int:
     return 0 if m is None else m.fired(site)
 
 
-def arm(site: str, count: int = 1, *, prob: float = 1.0,
-        delay: float = 0.0) -> ChaosMonkey:
-    """Arm one site on the installed monkey (installing a fresh one if
-    chaos was disabled) — the programmatic entry bench.py uses."""
-    m = _active or install(ChaosMonkey(seed=_env_seed()))
-    return m.arm(site, count, prob=prob, delay=delay)
-
-
 @contextlib.contextmanager
 def armed(spec: str, *, seed: int = 0):
     """Test scoping: install a monkey for the with-block, restore the

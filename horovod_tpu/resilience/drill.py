@@ -36,9 +36,8 @@ CI entry (ci.sh ``elastic-mc`` smoke; docs/resilience.md)::
     python -m horovod_tpu.resilience.drill --workdir /tmp/mc \\
         --world 3 --kill-rank 2
 
-`bench.py --elastic-check --real-procs` records the same report —
-detect_s and time_to_resume_s for the real multi-process path — as a
-benchmark artifact.
+The report carries detect_s and time_to_resume_s for the real
+multi-process path.
 """
 
 from __future__ import annotations
@@ -428,7 +427,7 @@ class _Worker:
 @dataclasses.dataclass
 class DrillReport:
     """What one hvdrun-launched drill proved (the ci.sh assertion
-    surface and the bench artifact)."""
+    surface)."""
 
     ok: bool
     union_match: bool

@@ -33,8 +33,7 @@ CI entry (docs/resilience.md "Exact resume")::
     HVD_CHAOS=train_crash:2,ckpt_kill:1 \\
         python -m horovod_tpu.resilience.equivalence --workdir /tmp/eq
 
-`bench.py --resume-check` records the same report (recovery_ms,
-resume_gap_batches, kills) as a benchmark artifact entry.
+The report it prints carries recovery_ms, resume_gap_batches and kills.
 
 **Resize equivalence** (``--resize``, docs/resilience.md "Elastic
 membership"): the elastic twin. A 4-member in-process simulated world
@@ -54,8 +53,6 @@ across the chained shrink→grow migration. CI entry::
     HVD_CHAOS=rank_death:1 \\
         python -m horovod_tpu.resilience.equivalence --resize \\
         --workdir /tmp/eqr
-
-`bench.py --elastic-check` records the same report as an artifact.
 """
 
 from __future__ import annotations
@@ -97,7 +94,7 @@ class EquivalenceReport:
         return self.batches_match and self.params_match
 
     def summary(self) -> Dict:
-        """JSON-able digest (the bench artifact / CI log line)."""
+        """JSON-able digest (the CI log line)."""
         ms = sorted(self.recovery_ms)
         return {
             "ok": self.ok,

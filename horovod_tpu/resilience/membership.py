@@ -1012,7 +1012,7 @@ def record_keys(batch: Dict[str, np.ndarray]) -> List[str]:
 @dataclasses.dataclass
 class WorldRunReport:
     """What one simulated elastic run did (the union proof's chaos
-    leg, the CI smoke's assertion surface, bench's artifact)."""
+    leg, the CI smoke's assertion surface)."""
 
     completed: bool
     final_world: int

@@ -313,8 +313,8 @@ register_knob(
     "HVD_REQLOG", "str", "(unset)", "obs/reqlog.py",
     "Record every client-entry submit (arrival time, prompt/output "
     "budgets, tenant/priority, prefix-group chain digests) to this "
-    "JSONL request log; re-serve it with bench.py --serving "
-    "--replay, docs/observability.md 'Record/replay'")
+    "JSONL request log; reqlog.load + reqlog.synthesize_prompt "
+    "re-serve it, docs/observability.md 'Record/replay'")
 register_knob(
     "HVD_PROFILE_DIR", "str", "(unset)", "obs/profiling.py",
     "Opt-in jax.profiler trace session directory "
@@ -364,7 +364,7 @@ register_knob(
     "HVD_ROUTER_REPLICAS", "int", str(DEFAULT_ROUTER_REPLICAS),
     "runtime/config.py",
     "Serving fleet: ServingRouter replica count when the caller "
-    "doesn't pass num_replicas (bench --router / examples), "
+    "doesn't pass num_replicas (examples), "
     "docs/serving.md 'Fleet failover'")
 register_knob(
     "HVD_ROUTER_POLL", "float", str(DEFAULT_ROUTER_POLL_S),

@@ -121,8 +121,8 @@ class Request:
     # Prompt tokens the paged pool's prefix cache already held at
     # admission (prefill skipped them); 0 on the fixed pool and on
     # every cache miss. Set by the dispatcher, surfaced on
-    # CompletedRequest — the per-request cache-hit evidence the bench
-    # and the ci.sh --prefix-check read.
+    # CompletedRequest — the per-request cache-hit evidence the
+    # ci.sh --prefix-check reads.
     prefix_cached: int = 0
     # Token-exact continuation (docs/serving.md "Fleet failover"): a
     # request migrated off a dead replica is resubmitted with the

@@ -277,8 +277,8 @@ class ServingEngine:
     pipeline_depth : decode-tick pipelining depth — 1 (default) keeps
         a one-deep in-flight ring (tick N+1 dispatched before tick N's
         tokens are read, hiding the host sync behind device compute);
-        0 syncs every tick immediately (the A/B control
-        `bench.py --serving` measures against).
+        0 syncs every tick immediately (what speculative decoding
+        forces: a draft-verify round has no tick to overlap).
     paged : use the paged KV cache (docs/serving.md "Paged KV cache"):
         device KV is a shared block pool (`serving.paging`) instead of
         a private max_len region per slot, admission gates on BLOCK

@@ -235,7 +235,7 @@ class EngineMetrics:
         self.tpot_s = Series()
         self.e2e_s = Series()
         # Fault → requeued-and-running latency per watchdog restart
-        # (time-to-requeue): the robustness cost bench --chaos tracks.
+        # (time-to-requeue): what a fault costs the requests it hit.
         self.recovery_s = Series()
 
     def observe_recovery(self, dt_s: float):
@@ -550,9 +550,8 @@ class EngineMetrics:
                 # excluding the prefill-sampled first tokens (which
                 # cost no tick): ~busy-lane count without spec
                 # decode, x (1 + acceptance_rate x k) per lane with
-                # it — the accepted-tokens-per-tick number the bench
-                # matrix records per config (compare legs at the
-                # same occupancy).
+                # it — the accepted-tokens-per-tick number (compare
+                # configurations at the same occupancy).
                 "tokens_per_tick": (
                     round((self.tokens_out
                            - self.prefill_first_tokens)

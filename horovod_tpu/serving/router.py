@@ -58,7 +58,7 @@ it through the factory; a dead replica is replaced the same way (both
 draw on the ``HVD_ROUTER_REPLACEMENTS`` budget — once spent the fleet
 just shrinks). The ``router.replica_kill`` chaos site (HVD_CHAOS)
 hard-kills a busy replica from the monitor loop — the seeded fault the
-equivalence tests and ``bench.py --serving --router`` drive.
+equivalence tests and the ``--failover-check`` example drive.
 
 All routing state lives behind one lock; engine calls (submit,
 shutdown, health probes) happen OUTSIDE it because engine future
@@ -923,7 +923,7 @@ class ServingRouter:
         now = time.time()
         # 1. Chaos: the router.replica_kill site hard-kills a busy
         # replica (docs/resilience.md chaos-site table) — the seeded
-        # fault behind the failover acceptance tests and bench A/B.
+        # fault behind the failover acceptance tests.
         if chaos.fires("router.replica_kill"):
             self._chaos_kill()
         # 2. Liveness: drain the shared FailureDetector's DEAD

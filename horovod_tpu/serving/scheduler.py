@@ -132,7 +132,7 @@ class ContinuousBatchingScheduler:
     (<= 0 = unbounded, the PR-1 whole-prompt behavior); also caps the
     chunk sizes themselves, so a single chunk never exceeds the
     budget. ``pipeline_depth``: 0 = sync every tick immediately (the
-    PR-1 behavior, the bench A/B control), 1 = the one-deep in-flight
+    PR-1 behavior), 1 = the one-deep in-flight
     ring (default)."""
 
     def __init__(self, pool: SlotPool, queue: AdmissionQueue,

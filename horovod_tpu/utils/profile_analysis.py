@@ -3,9 +3,9 @@
 `docs/scaling.md`'s efficiency model rests on the exposed-collective
 fraction α (the share of collective time NOT hidden under compute).
 The reference measured its 90/79 % efficiencies on hardware
-(`README.md:27-32` there); this module turns a `bench.py --profile DIR`
-capture into a *measured* α so the modeled numbers can be replaced the
-moment a chip window opens.
+(`README.md:27-32` there); this module turns an `HVD_PROFILE_DIR`
+capture (`obs.profiling.profiler_session`) into a *measured* α to set
+against the modeled numbers.
 
 Works on the Chrome-trace JSON (`*.trace.json.gz`) the profiler writes
 next to the xplane protobuf — dependency-free parsing. Device timelines
@@ -34,8 +34,8 @@ import re
 from typing import Any, Dict, List, Optional, Tuple
 
 # Peak bf16 FLOP/s by device kind (public TPU specs) — the MFU
-# denominator, shared by bench.py's analytic estimates and the
-# obs-plane `hvd_training_mfu` gauge (obs/profiling.StepProfiler).
+# denominator of the obs-plane `hvd_training_mfu` gauge
+# (obs/profiling.StepProfiler).
 PEAK_BF16_FLOPS = {
     "TPU v4": 275e12, "TPU v5 lite": 197e12, "TPU v5e": 197e12,
     "TPU v5p": 459e12, "TPU v6 lite": 918e12, "TPU v6e": 918e12,
@@ -241,9 +241,8 @@ def analyze_op_breakdown(trace: Dict[str, Any],
 
     The r4 ResNet diagnosis (BN statistics = 37.8 % of the step,
     docs/mfu.md) was assembled by hand from a trace; this automates it
-    so every `bench.py --profile` capture carries its own cost ranking
-    in the artifact (the profiled configs must
-    yield named top costs, not just a number).
+    so every capture carries its own cost ranking (named top costs,
+    not just a number).
 
     Category = the event's `hlo_category` arg when the profiler
     provides it, else the op-name prefix with trailing `.N` indices

@@ -1,9 +1,8 @@
 """What keeps the program honest about the machine it runs on: the
 compile cache's placement, the native build's source-hash key, the
 single compile of a train step, and the entry points that must REFUSE
-to run without the chip (docs: README "Tests & the chip")."""
+to run without the chip (docs: README "Tests & the benchmark")."""
 
-import json
 import math
 import os
 import subprocess
@@ -193,24 +192,6 @@ def test_chip_smoke_alone_fails(tmp_path):
                        capture_output=True, text=True, timeout=300)
     assert r.returncode != 0
     assert '"ok"' not in r.stdout, r.stdout
-
-
-def test_bench_refuses_the_cpu_without_explicit_platform():
-    """No fall-back: without a chip and without `--platform cpu`,
-    bench.py exits non-zero with one message and emits no number."""
-    r = _run(["bench.py", "--model", "mnist"], JAX_PLATFORMS="cpu")
-    assert r.returncode != 0
-    assert r.stdout.strip() == "", r.stdout
-    assert "no accelerator" in r.stderr
-
-
-def test_bench_explicit_cpu_names_its_platform():
-    r = _run(["bench.py", "--platform", "cpu", "--model", "mnist",
-              "--batch", "8", "--steps", "1", "--warmup", "1",
-              "--no-flash"])
-    assert r.returncode == 0, r.stderr[-2000:]
-    line = json.loads(r.stdout.strip().splitlines()[-1])
-    assert line["platform"] == "cpu" and "backend_fallback" not in line
 
 
 def test_launcher_refuses_several_tpu_workers_on_one_host():

@@ -59,8 +59,8 @@ def lm(hvd):
 @pytest.fixture
 def event_log(tmp_path):
     """Point the global event log at a temp JSONL for one test;
-    restore the previous log after (the scoped-swap pattern bench's
-    trace check uses — a user-configured log must survive)."""
+    restore the previous log after (the scoped-swap pattern: a
+    user-configured log must survive)."""
     path = str(tmp_path / "events.jsonl")
     log = events.EventLog(path)
     prev = events.install(log)

@@ -232,7 +232,7 @@ class TestSpecMigration:
                             auto_restart=True, max_restarts=4)
         try:
             hs = [eng.submit(p, 8) for p in prompts]
-            chaos.arm("serving_dispatch_crash", 1)
+            chaos.install(chaos.ChaosMonkey("serving_dispatch_crash:1"))
             out = [list(h.result(timeout=300).tokens) for h in hs]
             snap = eng.metrics_snapshot()
         finally:

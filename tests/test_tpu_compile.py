@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-# The flagship LM's attention shape (bench.py / chip_smoke.py).
+# The flagship LM's attention shape (chip_smoke.py).
 B, S, H, D = 8, 2048, 8, 128
 LANES = 8
 

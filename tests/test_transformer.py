@@ -277,42 +277,6 @@ class TestTransformerLM:
                 np.asarray(x), np.asarray(y), atol=2e-4, rtol=2e-3),
             g1, g2)
 
-    def test_flash_block_sizes_thread_through_model(self, monkeypatch):
-        """TransformerLM(flash_block_q/k=...) REACHES the kernel (the
-        bench sweep knob is wired end to end — observed via a
-        recording wrapper, so a dropped pass-through fails loudly) and
-        a non-default tiling matches the default-block model."""
-        from horovod_tpu.ops import flash_attention as fa_mod
-        seen = []
-        orig = fa_mod.flash_attention
-
-        def recording(*a, **kw):
-            seen.append((kw.get("block_q"), kw.get("block_k")))
-            return orig(*a, **kw)
-
-        monkeypatch.setattr(fa_mod, "flash_attention", recording)
-        toks = _tokens(B=2, S=16, seed=13)
-        kw = dict(vocab_size=64, num_layers=1, num_heads=2, head_dim=8,
-                  max_len=32, dtype=jnp.float32, attn_impl="flash")
-        default = TransformerLM(**kw)
-        tiled = TransformerLM(flash_block_q=4, flash_block_k=8, **kw)
-        variables = default.init(jax.random.PRNGKey(14), toks)
-        a = default.apply(variables, toks)
-        b = tiled.apply(variables, toks)
-        assert (4, 8) in seen, seen   # the knob reached the kernel
-        np.testing.assert_allclose(np.asarray(a, np.float32),
-                                   np.asarray(b, np.float32),
-                                   atol=2e-4)
-
-    def test_flash_blocks_rejected_for_non_flash_impls(self):
-        toks = _tokens(B=1, S=8, seed=15)
-        model = TransformerLM(vocab_size=64, num_layers=1, num_heads=2,
-                              head_dim=8, max_len=16,
-                              dtype=jnp.float32, attn_impl="blockwise",
-                              flash_block_q=64)
-        with pytest.raises(ValueError, match="flash_block"):
-            model.init(jax.random.PRNGKey(0), toks)
-
     @pytest.mark.parametrize("chunk", [5, 8, 32])
     def test_chunked_lm_loss_matches_plain(self, chunk):
         """The fused head+loss (no [B,S,V] logits materialization) is
