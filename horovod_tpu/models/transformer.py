@@ -1265,16 +1265,66 @@ def nucleus_mask(logits, top_p):
 
 def sample_token(logits, temperature, top_p, key):
     """One sampled (or greedy) token from [V] logits, with TRACED
-    temperature/top_p so one compiled program serves every request mix:
-    temperature <= 0 selects argmax, top_p >= 1 disables the nucleus
-    truncation (`nucleus_mask`, shared with `generate`'s pick). The
-    serving tick vmaps this over slots."""
+    temperature/top_p: temperature <= 0 selects argmax, top_p >= 1
+    disables the nucleus truncation (`nucleus_mask`, shared with
+    `generate`'s pick). THE per-lane rule - and the whole of its work
+    whatever the lane asks for: both `where`s select between values
+    already computed, so the sort and the draw run for a greedy lane
+    too. The decode programs therefore do not vmap this themselves;
+    `sample_lanes` decides on the batch which part of it is needed
+    and calls it only where some lane wants a nucleus."""
     greedy = jnp.argmax(logits, axis=-1)
     scaled = logits / jnp.maximum(temperature, 1e-6)
     sampled = jax.random.categorical(
         key, jnp.where(top_p < 1.0, nucleus_mask(scaled, top_p),
                        scaled))
     return jnp.where(temperature <= 0.0, greedy, sampled)
+
+
+def _sample_lane_plain(logits, temperature, key):
+    """`sample_token` for a lane that asks for no nucleus: the same
+    argmax, the same divide, the same draw from the same key on the
+    same operand (``scaled``) - without the sort that `sample_token`
+    computes and then does not select."""
+    greedy = jnp.argmax(logits, axis=-1)
+    scaled = logits / jnp.maximum(temperature, 1e-6)
+    sampled = jax.random.categorical(key, scaled)
+    return jnp.where(temperature <= 0.0, greedy, sampled)
+
+
+def sample_lanes(logits, temperature, top_p, keys):
+    """One token a lane from [S, V] float32 logits, [S] temperatures,
+    [S] top_ps and [S] keys: `sample_token` over the lanes, with the
+    work decided ONCE, on the batch, by a scalar the device computes
+    from the lanes' own parameters - so one compiled program still
+    serves every request mix, and only the branch that some lane
+    needs is executed (`lax.switch` on a scalar; under a vmap it
+    would lower to a select and run all three):
+
+    0. no lane has ``temperature > 0``: `argmax` alone - no divide,
+       no noise, no sort;
+    1. some lane samples, none of those with ``top_p < 1``: the
+       categorical draw on the scaled logits, no sort;
+    2. some sampling lane has ``top_p < 1``: `sample_token` for the
+       whole batch, per-lane `where`s and all.
+
+    A batch pays for its most demanding lane. Every lane's token is
+    bitwise `sample_token`'s in every mix: a greedy lane is the argmax
+    of the same logits on every path, and a sampling lane draws from
+    the same key on the same operand. The predicates read every lane,
+    live or not: the pools write a lane's parameters when its prefill
+    closes and reset them to 0.0 / 1.0 when it is freed
+    (`SlotPool.finish_prefill`, `free`), so a lane no request holds
+    never asks for more than argmax."""
+    sampling = temperature > 0.0
+    path = (jnp.any(sampling).astype(jnp.int32)
+            + jnp.any(sampling & (top_p < 1.0)).astype(jnp.int32))
+    return jax.lax.switch(
+        path,
+        (lambda lg, t, p, k: jnp.argmax(lg, axis=-1),
+         lambda lg, t, p, k: jax.vmap(_sample_lane_plain)(lg, t, k),
+         jax.vmap(sample_token)),
+        logits, temperature, top_p, keys)
 
 
 def recurrent_leaf(path) -> bool:
@@ -1340,9 +1390,18 @@ def slot_decode_tick(dec_model, params, cache, toks, temps, top_ps,
       therefore retire from the (asynchronously transferred) token
       buffer alone, pipeline-depth ticks late, without a second
       device->host sync per tick to check stops.
+    * ``temps`` / ``top_ps`` [S] — each lane's sampling parameters,
+      traced too. The vmap ends at the lane's float32 logits and its
+      split key (every lane splits every tick: a request's stream is
+      keyed by token ordinal); `sample_lanes` then picks the tokens
+      on the batch - argmax alone when no lane samples, a draw with
+      no sort when none of the sampling lanes has ``top_p < 1``, the
+      per-lane rule `sample_token` for every lane otherwise. A batch
+      pays for its most demanding lane; a lane's token is the same
+      on every path.
     """
 
-    def one(sub, tok, temp, top_p, rng, lv, dn):
+    def one(sub, tok, rng, lv, dn):
         (hidden, embed), mut = dec_model.apply(
             {"params": params, "cache": sub}, tok[None, None],
             return_hidden=True, mutable=["cache", "moe_stats"])
@@ -1350,16 +1409,15 @@ def slot_decode_tick(dec_model, params, cache, toks, temps, top_ps,
         logits = jnp.einsum("d,vd->v", hidden[0, -1],
                             embed.astype(hidden.dtype))
         rng, r = jax.random.split(rng)
-        nxt = sample_token(logits.astype(jnp.float32), temp, top_p, r)
-        nxt = nxt.astype(tok.dtype)
-        emit = jnp.where(dn, eos.astype(tok.dtype), nxt)
-        return (new, emit, rng, dn | (emit == eos),
+        return (new, logits.astype(jnp.float32), rng, r,
                 _moe_pairs(dec_model, mut))
 
-    cache, emit, rngs, dn, pairs = jax.vmap(one)(
-        cache, toks, temps, top_ps, rngs, live, done)
+    cache, logits, rngs, keys, pairs = jax.vmap(one)(
+        cache, toks, rngs, live, done)
+    nxt = sample_lanes(logits, temps, top_ps, keys).astype(toks.dtype)
+    emit = jnp.where(done, eos.astype(toks.dtype), nxt)
     decoding = (live & ~done)[:, None, None]
-    return cache, emit, rngs, dn, jnp.sum(
+    return cache, emit, rngs, done | (emit == eos), jnp.sum(
         jnp.where(decoding, pairs, 0), axis=0)
 
 
@@ -1654,9 +1712,12 @@ def paged_decode_tick(dec_model, spec: PagedCacheSpec, pools, params,
     Same occupancy semantics as `slot_decode_tick` — ``live`` gates
     fill advance, ``done`` is the on-device stop — expressed in paged
     form: a non-advancing lane keeps its fill (the freeze) and routes
-    its dead row to the null block (the masked write)."""
+    its dead row to the null block (the masked write). The sampling
+    epilogue is `slot_decode_tick`'s: the vmap returns the lanes'
+    logits and keys, and `sample_lanes` does on the batch only the
+    work some lane needs (argmax | draw | nucleus sort)."""
 
-    def one(table, fill, tok, temp, top_p, rng, lv, dn):
+    def one(table, fill, tok, rng):
         variables = _paged_cache_vars(spec, pools, params, table,
                                       fill, 1, fused)
         (hidden, embed), mut = dec_model.apply(
@@ -1667,13 +1728,15 @@ def paged_decode_tick(dec_model, spec: PagedCacheSpec, pools, params,
         logits = jnp.einsum("d,vd->v", hidden[0, -1],
                             embed.astype(hidden.dtype))
         rng, r = jax.random.split(rng)
-        nxt = sample_token(logits.astype(jnp.float32), temp, top_p, r)
-        nxt = nxt.astype(tok.dtype)
-        emit = jnp.where(dn, eos.astype(tok.dtype), nxt)
-        return rows, emit, rng, dn | (emit == eos), lv & ~dn
+        return rows, logits.astype(jnp.float32), rng, r
 
-    rows, emit, rngs, done, adv = jax.vmap(one)(
-        tables, fills, toks, temps, top_ps, rngs, live, done)
+    rows, logits, rngs, keys = jax.vmap(one)(tables, fills, toks, rngs)
+    nxt = sample_lanes(logits, temps, top_ps, keys).astype(toks.dtype)
+    # the INPUT done: a lane that emits eos this tick still writes
+    # this tick's row and advances past it
+    adv = live & ~done
+    emit = jnp.where(done, eos.astype(toks.dtype), nxt)
+    done = done | (emit == eos)
     bs = spec.block_size
     # A lane at the P + max_new - 1 == max_len boundary gets one
     # pipelined extra tick with fill == max_len: the table lookup

@@ -128,7 +128,8 @@ SPAN_CATALOG: Dict[str, str] = {
     "sched.tick_dispatch":
         "Loop span: the decode tick's dispatch; attrs are the tick "
         "record (lanes_decoding, lanes_prefilling, lanes_free, "
-        "queue_depth, context_sum, context_max)",
+        "queue_depth, context_sum, context_max, lanes_sampling, "
+        "lanes_nucleus)",
     "sched.tick_sync":
         "Loop span: reading the previous tick's tokens, appending "
         "them and retiring the finished (attrs overlapped, tokens, "

@@ -161,6 +161,12 @@ class EngineMetrics:
         self.lane_ticks_prefilling = 0
         self.lane_ticks_free = 0
         self.tick_context_positions = 0
+        # Which sampling work the ticks did (`sample_lanes`' three
+        # paths, from the record's `lanes_sampling` / `lanes_nucleus`):
+        # argmax alone, a draw with no sort, the sort for the batch.
+        self.ticks_greedy = 0
+        self.ticks_sampled = 0
+        self.ticks_nucleus = 0
         # Dropless expert layers (`parallel.expert.HeldExpertsMoE`):
         # over the decode ticks synced, the (token, expert) pairs on
         # the experts held here (decoding lanes only), the busiest
@@ -286,12 +292,19 @@ class EngineMetrics:
 
     def observe_tick(self, tick: Dict[str, int]):
         """One decode tick's record (the scheduler's `_tick_record`)
-        into the four lane/context counters: one lock, once a tick."""
+        into the lane/context counters and the count of its sampling
+        path: one lock, once a tick."""
         with self._lock:
             self.lane_ticks_decoding += tick["lanes_decoding"]
             self.lane_ticks_prefilling += tick["lanes_prefilling"]
             self.lane_ticks_free += tick["lanes_free"]
             self.tick_context_positions += tick["context_sum"]
+            if tick["lanes_nucleus"]:
+                self.ticks_nucleus += 1
+            elif tick["lanes_sampling"]:
+                self.ticks_sampled += 1
+            else:
+                self.ticks_greedy += 1
 
     def observe_moe(self, stats: Dict[str, int]):
         """One synced tick's expert-layer record
@@ -500,6 +513,9 @@ class EngineMetrics:
                 "lane_ticks_prefilling": self.lane_ticks_prefilling,
                 "lane_ticks_free": self.lane_ticks_free,
                 "tick_context_positions": self.tick_context_positions,
+                "ticks_greedy": self.ticks_greedy,
+                "ticks_sampled": self.ticks_sampled,
+                "ticks_nucleus": self.ticks_nucleus,
                 "moe_pairs": self.moe_pairs,
                 "moe_expert_load_max": self.moe_expert_load_max,
                 "moe_experts_hit": self.moe_experts_hit,

@@ -339,11 +339,17 @@ class ContinuousBatchingScheduler:
         """What this tick is asked to do, from the scheduler's own
         books (O(lanes), no device read): the lanes that decode, the
         lanes a request holds without a first token yet, the free
-        ones, the queue behind them, and the cached positions (prompt
-        + emitted) the decoding lanes bring. The `sched.tick_dispatch`
-        span carries it; the engine's counters accumulate it."""
+        ones, the queue behind them, the cached positions (prompt
+        + emitted) the decoding lanes bring - and, of the decoding
+        lanes, those whose request samples (``temperature > 0``) and
+        of those the ones that ask for a nucleus (``top_p < 1``):
+        which of `sample_lanes`' three paths the tick takes. The
+        `sched.tick_dispatch` span carries it; the engine's counters
+        accumulate it."""
         contexts = [len(r.prompt) + len(r.tokens)
                     for r in self.active.values()]
+        sampling = [r.sampling for r in self.active.values()
+                    if r.sampling.temperature > 0]
         decoding, prefilling = len(contexts), len(self.prefilling)
         return {"lanes_decoding": decoding,
                 "lanes_prefilling": prefilling,
@@ -351,7 +357,11 @@ class ContinuousBatchingScheduler:
                     0, self.pool.num_slots - decoding - prefilling),
                 "queue_depth": len(self.queue),
                 "context_sum": sum(contexts),
-                "context_max": max(contexts, default=0)}
+                "context_max": max(contexts, default=0),
+                "lanes_sampling": len(sampling),
+                "lanes_nucleus": sum(
+                    1 for sp in sampling
+                    if sp.top_p is not None and sp.top_p < 1)}
 
     @hot_path
     def _spec_round(self):

@@ -51,7 +51,7 @@ import jax.numpy as jnp
 from horovod_tpu.annotations import hot_path
 from horovod_tpu.models.transformer import (
     TransformerLM, decode_attention_plan, init_slot_cache,
-    prefill_chunks, recurrent_leaf, sample_token,
+    prefill_chunks, recurrent_leaf, sample_lanes,
     shard_slot_cache, slot_decode_model, slot_decode_tick,
     slot_prefill_advance, slot_prefill_chunk, slot_reset,
     slot_spec_round,
@@ -99,11 +99,16 @@ def _first_token(logits, temp, top_p, key, skips):
     k+1 from the SAME r_k the original stream would have used, since
     the per-request stream is keyed by token ordinal (each token
     consumes one ``rng, r = split(rng)``), not by position. A traced
-    bound keeps this one compiled program for every k."""
+    bound keeps this one compiled program for every k.
+
+    The draw is `sample_lanes` over a batch of this one row: a greedy
+    request pays an argmax, and only a request with ``top_p < 1``
+    pays the sort of its vocabulary."""
     key = jax.lax.fori_loop(
         0, skips, lambda i, k: jax.random.split(k)[0], key)
     rng, r0 = jax.random.split(key)
-    tok = sample_token(logits, temp, top_p, r0)
+    tok = sample_lanes(logits[None], temp[None], top_p[None],
+                       r0[None])[0]
     return tok.astype(jnp.int32), rng
 
 

@@ -295,13 +295,19 @@ class TestTickRecord:
             pipeline_depth=0)
         now = time.time()
 
-        def req(i, n_prompt, steps):
+        def req(i, n_prompt, steps, **sampling):
             return Request(id=i, prompt=np.arange(1, n_prompt + 1),
                            max_new_tokens=steps,
-                           sampling=SamplingParams(), deadline=None,
-                           future=Future(), t_submit=now)
+                           sampling=SamplingParams(**sampling),
+                           deadline=None, future=Future(),
+                           t_submit=now)
 
-        a, b, c = req(0, 2, 6), req(1, 6, 2), req(2, 1, 2)
+        # a is greedy, b asks for a nucleus, c samples without one:
+        # lengths are budgets, so the schedule does not depend on
+        # what they draw
+        a = req(0, 2, 6)
+        b = req(1, 6, 2, temperature=0.8, top_p=0.9, seed=1)
+        c = req(2, 1, 2, temperature=0.7, seed=2)
         mark = _last_seq()
         queue.offer(a)
         queue.offer(b)
@@ -317,16 +323,18 @@ class TestTickRecord:
         recs = _since(mark)
         ticks = [r for r in recs if r["name"] == "sched.tick_dispatch"]
         want = [
-            # decoding, prefilling, free, queue, context sum, max
-            (1, 0, 2, 1, 3, 3),
-            (1, 1, 1, 0, 4, 4),
-            (1, 1, 1, 1, 5, 5),
-            (2, 0, 1, 1, 13, 7),
-            (2, 0, 1, 0, 9, 7),
+            # decoding, prefilling, free, queue, context sum, max,
+            # sampling, nucleus
+            (1, 0, 2, 1, 3, 3, 0, 0),
+            (1, 1, 1, 0, 4, 4, 0, 0),
+            (1, 1, 1, 1, 5, 5, 0, 0),
+            (2, 0, 1, 1, 13, 7, 1, 1),
+            (2, 0, 1, 0, 9, 7, 1, 0),
         ]
         got = [tuple(t["attrs"][k] for k in (
             "lanes_decoding", "lanes_prefilling", "lanes_free",
-            "queue_depth", "context_sum", "context_max"))
+            "queue_depth", "context_sum", "context_max",
+            "lanes_sampling", "lanes_nucleus"))
             for t in ticks]
         assert got == want
         for t in ticks:
@@ -341,6 +349,10 @@ class TestTickRecord:
         assert snap["lane_ticks_prefilling"] == 2
         assert snap["lane_ticks_free"] == 6
         assert snap["tick_context_positions"] == 34
+        # which of `sample_lanes`' paths each tick took
+        assert snap["ticks_greedy"] == 3
+        assert snap["ticks_nucleus"] == 1
+        assert snap["ticks_sampled"] == 1
         assert (snap["lane_ticks_decoding"] + snap["lane_ticks_prefilling"]
                 + snap["lane_ticks_free"]) == 5 * pool.num_slots
 

@@ -313,6 +313,13 @@ def test_slot_decode_tick_attends_through_the_ragged_kernel(
     loops = re.findall(r' while\(.*op_name="([^"]*)"', text)
     assert [n for n in loops if "/attn/" in n
             and "_cache_write" not in n] == []
+    # the sampling epilogue is one conditional on a scalar, and the
+    # vocabulary-wide sort is in a branch of it: the entry computation,
+    # which every tick runs, holds none (`sample_lanes`)
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    assert " conditional(" in entry
+    assert " sort(" not in entry and " sort(" in text
     kv = [leaf for path, leaf in
           jax.tree_util.tree_flatten_with_path(cache)[0]
           if "cached_" in str(path)]
