@@ -105,7 +105,7 @@ def generate_speculative(draft_model, draft_params, target_model,
         raise ValueError(
             f"speculative decoding is batch-1 (got {prompt.shape}); "
             "the per-layer cache index cannot diverge per row")
-    if target_model.window is not None or draft_model.window is not None:
+    if target_model.has_rolling_cache or draft_model.has_rolling_cache:
         raise ValueError(
             "sliding-window (rolling-cache) models cannot rewind "
             "rejected proposals; use models.generate")

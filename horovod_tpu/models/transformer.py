@@ -28,8 +28,9 @@ Attention kernels: ``dot`` (materialized softmax baseline), ``blockwise``
 
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -50,7 +51,7 @@ from horovod_tpu.parallel.sequence import (
     ulysses_attention_gspmd,
 )
 from horovod_tpu.parallel.tensor import (
-    ParallelMLP, ParallelSelfAttention, ParallelSwiGLU,
+    ParallelMLP, ParallelSelfAttention, ParallelSwiGLU, RopeSpec,
     dot_product_attention,
     param_specs, shard_params, unbox,
 )
@@ -59,6 +60,27 @@ Dtype = Any
 
 ATTN_IMPLS = ("dot", "blockwise", "flash", "ring", "ring_flash",
               "ulysses", "ulysses_flash")
+
+# A layer's token mixer (`TransformerLM.layer_kinds`): softmax attention
+# under the flax scope "attn" or - the kind that a model with two kinds
+# of softmax layer gives its sliding-window layers - "swa", and the
+# delta-rule linear attention "kda".
+SOFTMAX_KINDS = ("attn", "swa")
+LAYER_KINDS = SOFTMAX_KINDS + ("kda",)
+
+
+@dataclasses.dataclass(frozen=True)
+class AttnSpec:
+    """What one KIND of softmax layer has of its own where a model's
+    kinds differ (`TransformerLM.attn_specs`): the query heads
+    (None = the model's ``num_heads``), the sliding window (as given:
+    None = full attention, a kind that brings a spec states its
+    window) and the rotary rule (None = the model's ``rope_theta``
+    over the whole head). K/V heads and the head size are the
+    model's."""
+    num_heads: Optional[int] = None
+    window: Optional[int] = None
+    rope: Optional[RopeSpec] = None
 
 # The LLaMA-family knob set — single source for `compat.hf.from_hf_llama`
 # and the driver dryrun's llama leg, so the two can never silently
@@ -196,6 +218,7 @@ class TransformerBlock(nn.Module):
     num_kv_heads: Optional[int] = None
     pos_emb: str = "none"        # "none" | "rope"
     rope_theta: float = 10000.0
+    rope: Optional[RopeSpec] = None   # see ParallelSelfAttention.rope
     window: Optional[int] = None  # sliding-window attention
     mlp_ratio: int = 4
     dtype: Optional[Dtype] = jnp.bfloat16
@@ -223,23 +246,30 @@ class TransformerBlock(nn.Module):
     mlp_hidden: Optional[int] = None     # absolute width (else ratio*d)
     lora_rank: int = 0                   # LoRA adapters on the Denses
     lora_alpha: Optional[float] = None
-    # The token mixer: "attn" (softmax attention) | "kda" (delta-rule
-    # linear attention, `parallel.linear_attention.KDAAttention`).
+    # The token mixer (`LAYER_KINDS`): "attn" | "swa" (softmax
+    # attention; the name is the flax scope, so that a model with two
+    # kinds of softmax layer tells them apart in its parameters and
+    # its trace) | "kda" (delta-rule linear attention,
+    # `parallel.linear_attention.KDAAttention`).
     mixer: str = "attn"
-    attn_gate: bool = False              # sigmoid output gate (attn)
+    # sigmoid output gate (attn): True a channel, "head" a head
+    attn_gate: Union[bool, str] = False
     # "gshard" (`MoELayer`: capacity, drops) | "dropless"
     # (`HeldExpertsMoE`: the experts this chip holds, no drops).
     moe_impl: str = "gshard"
     moe_hidden: Optional[int] = None     # expert width (else ratio*d)
     moe_held: Optional[Tuple[int, int]] = None   # (first, count)
     moe_shared_hidden: int = 0
+    moe_router: str = "sigmoid"          # see HeldExpertsMoE.router
+    moe_scale: float = 1.0
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         d = x.shape[-1]
-        if self.mixer not in ("attn", "kda"):
+        if self.mixer not in LAYER_KINDS:
             raise ValueError(
-                f"mixer must be attn|kda, got {self.mixer!r}")
+                f"mixer must be one of {LAYER_KINDS}, got "
+                f"{self.mixer!r}")
         if self.window is not None and not self.causal:
             # Every masked impl raises this from inside its scan; the
             # dot baseline would silently drop the window instead —
@@ -272,7 +302,8 @@ class TransformerBlock(nn.Module):
             h = ParallelSelfAttention(
                 num_heads=self.num_heads, head_dim=self.head_dim,
                 num_kv_heads=self.num_kv_heads, pos_emb=self.pos_emb,
-                rope_theta=self.rope_theta, window=self.window,
+                rope_theta=self.rope_theta, rope=self.rope,
+                window=self.window,
                 dtype=self.dtype, attn_fn=attn_fn, decode=self.decode,
                 chunked_prefill=self.chunked_prefill,
                 decode_prefix_block=self.decode_prefix_block,
@@ -284,7 +315,7 @@ class TransformerBlock(nn.Module):
                 out_features=(None if d == self.num_heads * self.head_dim
                               else d),
                 lora_rank=self.lora_rank, lora_alpha=self.lora_alpha,
-                name="attn")(h, mask)
+                name=self.mixer)(h, mask)
         x = x + h
         h = _make_norm(self.norm, self.dtype, self.ln_eps,
                        "ln_mlp")(x)
@@ -295,6 +326,7 @@ class TransformerBlock(nn.Module):
                 hidden=self.moe_hidden or self.mlp_ratio * d,
                 k=self.moe_k, held=self.moe_held,
                 shared_hidden=self.moe_shared_hidden,
+                router=self.moe_router, scale=self.moe_scale,
                 dtype=self.dtype, name="moe")(h)
         elif self.moe and self.moe_impl == "gshard":
             h = MoELayer(num_experts=self.num_experts,
@@ -393,12 +425,18 @@ class TransformerLM(nn.Module):
     lora_alpha: Optional[float] = None
     # Width of the residual stream; None = num_heads x head_dim.
     hidden_size: Optional[int] = None
-    # Hybrid models: the token mixer of each layer, "attn" | "kda"
-    # (len == num_layers); None = softmax attention everywhere. A "kda"
-    # layer keeps a recurrent state in the decode cache, not K/V
-    # (`parallel.linear_attention`).
+    # Hybrid models: the token mixer of each layer, "attn" | "swa" |
+    # "kda" (`LAYER_KINDS`; len == num_layers); None = "attn"
+    # everywhere. A "kda" layer keeps a recurrent state in the decode
+    # cache, not K/V (`parallel.linear_attention`); "swa" is a second
+    # kind of softmax layer, under its own scope.
     layer_kinds: Optional[Tuple[str, ...]] = None
-    attn_gate: bool = False              # sigmoid output gate (attn)
+    # What a kind of softmax layer has of its own: ((kind, AttnSpec),
+    # ...). A kind without an entry takes the model-wide ``num_heads``
+    # / ``window`` / ``rope_theta``.
+    attn_specs: Optional[Tuple[Tuple[str, AttnSpec], ...]] = None
+    # sigmoid output gate (attn): True a channel, "head" a head
+    attn_gate: Union[bool, str] = False
     # The expert layer of the `moe_every`-th blocks: "gshard"
     # (`MoELayer`) | "dropless" (`HeldExpertsMoE`: routes over all
     # `num_experts`, computes the `moe_held` = (first, count) it holds,
@@ -407,12 +445,80 @@ class TransformerLM(nn.Module):
     moe_hidden: Optional[int] = None
     moe_held: Optional[Tuple[int, int]] = None
     moe_shared_hidden: int = 0
+    moe_router: str = "sigmoid"     # `HeldExpertsMoE.router`
+    moe_scale: float = 1.0          # `HeldExpertsMoE.scale`
+    # Layers whose MLP stays dense whatever ``moe_every`` says (the
+    # published `mlp_only_layers`: a leading dense layer is (0,)).
+    mlp_only_layers: Tuple[int, ...] = ()
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        """Each layer's token mixer."""
+        kinds = tuple(self.layer_kinds or ("attn",) * self.num_layers)
+        if len(kinds) != self.num_layers:
+            raise ValueError(
+                f"layer_kinds has {len(kinds)} entries for "
+                f"{self.num_layers} layers")
+        bad = sorted(set(kinds) - set(LAYER_KINDS))
+        if bad:
+            raise ValueError(
+                f"layer_kinds must be of {LAYER_KINDS}, got {bad}")
+        return kinds
+
+    @property
+    def softmax_kinds(self) -> Tuple[str, ...]:
+        """The kinds of softmax layer this model has, in the order
+        they first appear."""
+        return tuple(k for k in dict.fromkeys(self.kinds)
+                     if k in SOFTMAX_KINDS)
+
+    def attn_spec(self, kind: str) -> AttnSpec:
+        """THE expression of "what kind of attention is this layer":
+        the heads, window and rotary rule of the softmax layers of
+        ``kind``, the kind's own entry of ``attn_specs`` over the
+        model-wide values."""
+        if kind not in SOFTMAX_KINDS:
+            raise ValueError(f"{kind!r} is no softmax kind")
+        own = dict(self.attn_specs or ()).get(kind)
+        spec = AttnSpec(
+            num_heads=(own and own.num_heads) or self.num_heads,
+            window=own.window if own else self.window,
+            rope=(own and own.rope) or RopeSpec(theta=self.rope_theta))
+        if kind == "swa" and spec.window is None:
+            raise ValueError(
+                "a 'swa' layer needs a window: give the kind an "
+                "AttnSpec(window=...) or the model a window")
+        return spec
 
     @property
     def has_recurrent_state(self) -> bool:
         """True when some layer's decode cache is a state that each
         step overwrites (no K/V rows to graft, page or rewind)."""
         return "kda" in (self.layer_kinds or ())
+
+    @property
+    def rolling_window(self) -> Optional[int]:
+        """The slots of the rolling caches (the widest, should the
+        kinds differ); None when no layer has a sliding window."""
+        windows = [self.attn_spec(k).window for k in self.softmax_kinds]
+        return max((w for w in windows if w is not None), default=None)
+
+    @property
+    def has_rolling_cache(self) -> bool:
+        """True when some layer's decode cache is a ring of `window`
+        slots (slot = position mod window): K/V rows that later
+        positions overwrite in place - no block-aligned prefix to
+        page, share or ship, no rewind."""
+        return self.rolling_window is not None
+
+    @property
+    def context_unbounded(self) -> bool:
+        """True when no cache bounds a sequence: rotary positions,
+        and every softmax layer a ring. One full-attention layer (or a
+        position table) bounds a request by ``max_len``."""
+        kinds = self.softmax_kinds
+        return (self.pos_emb == "rope" and bool(kinds) and all(
+            self.attn_spec(k).window is not None for k in kinds))
 
     @nn.compact
     def __call__(self, tokens: jax.Array,
@@ -421,11 +527,7 @@ class TransformerLM(nn.Module):
             raise ValueError(
                 f"pos_emb must be 'learned', 'rope' or 'none', "
                 f"got {self.pos_emb!r}")
-        kinds = self.layer_kinds or ("attn",) * self.num_layers
-        if len(kinds) != self.num_layers:
-            raise ValueError(
-                f"layer_kinds has {len(kinds)} entries for "
-                f"{self.num_layers} layers")
+        kinds = self.kinds
         B, S = tokens.shape
         d = self.hidden_size or self.num_heads * self.head_dim
         embed = self.param(
@@ -462,12 +564,17 @@ class TransformerLM(nn.Module):
         if self.remat:
             block_cls = nn.remat(TransformerBlock)
         for i in range(self.num_layers):
-            moe = self.moe_every > 0 and (i + 1) % self.moe_every == 0
+            moe = (self.moe_every > 0 and (i + 1) % self.moe_every == 0
+                   and i not in self.mlp_only_layers)
+            # a recurrent layer has the model's heads and no positions
+            spec = (self.attn_spec(kinds[i]) if kinds[i] in SOFTMAX_KINDS
+                    else AttnSpec(num_heads=self.num_heads))
             x = block_cls(
-                num_heads=self.num_heads, head_dim=self.head_dim,
+                num_heads=spec.num_heads, head_dim=self.head_dim,
                 num_kv_heads=self.num_kv_heads,
                 pos_emb=("rope" if self.pos_emb == "rope" else "none"),
-                rope_theta=self.rope_theta, window=self.window,
+                rope_theta=self.rope_theta, rope=spec.rope,
+                window=spec.window,
                 mlp_ratio=self.mlp_ratio, dtype=self.dtype,
                 attn_impl=self.attn_impl, moe=moe,
                 num_experts=self.num_experts, moe_k=self.moe_k,
@@ -489,6 +596,7 @@ class TransformerLM(nn.Module):
                 moe_impl=self.moe_impl,
                 moe_hidden=self.moe_hidden, moe_held=self.moe_held,
                 moe_shared_hidden=self.moe_shared_hidden,
+                moe_router=self.moe_router, moe_scale=self.moe_scale,
                 name=f"block_{i}")(x)
             x = constrain(x, AXIS_DATA, AXIS_SEQ, None)
 
@@ -880,7 +988,7 @@ def generate(model: TransformerLM, params, prompt, steps: int, *,
     if early_stop and eos_id is None:
         raise ValueError("early_stop requires eos_id (without a stop "
                          "token there is nothing to stop early on)")
-    unbounded = model.pos_emb == "rope" and model.window is not None
+    unbounded = model.context_unbounded
     if not unbounded and P + steps - 1 > model.max_len:
         # dynamic_update_slice would clamp writes past the cache end —
         # plausible-looking garbage, so refuse loudly instead. With
@@ -1076,29 +1184,41 @@ def slot_decode_model(model: TransformerLM) -> TransformerLM:
     return model.clone(decode=True, chunked_prefill=True)
 
 
-def decode_attention_plan(model: TransformerLM, lanes: int = 1):
-    """The way an S = 1 step of ``model`` against the linear cache
-    attends, as `ParallelSelfAttention` decides it when a tick is
-    traced under the ambient mesh: the `ops.flash_attention.DecodePlan`
-    (ragged kernel or lax walk, and why) for ``lanes`` slots. What the
-    engine logs at warm-up and `metrics_snapshot()` carries."""
+def decode_attention_plan(model: TransformerLM, lanes: int = 1,
+                          kind: Optional[str] = None):
+    """The way an S = 1 step of ``model``'s softmax layers of ``kind``
+    (None: the first kind the model has) attends against their cache,
+    as `ParallelSelfAttention` decides it when a tick is traced under
+    the ambient mesh: the `ops.flash_attention.DecodePlan` (ragged
+    kernel or lax, and why) for ``lanes`` slots - over the linear
+    cache of ``max_len``, or over the ring of a kind with a window.
+    `decode_attention_plans` answers every kind; the engine logs them
+    at warm-up and `metrics_snapshot()` carries them."""
     from horovod_tpu.ops import flash_attention
     from horovod_tpu.parallel.tensor import _mesh_is_trivial
-    W, blk = model.max_len, model.decode_prefix_block
-    if "attn" not in (model.layer_kinds or ("attn",)):
+    if not model.softmax_kinds:
         return flash_attention.DecodePlan("lax", "no softmax layer")
-    if model.window is not None:
-        return flash_attention.DecodePlan("lax", "rolling-window cache")
-    if not blk or W % min(blk, W):
+    spec = model.attn_spec(kind or model.softmax_kinds[0])
+    ring = spec.window is not None
+    W, blk = (spec.window if ring else model.max_len,
+              model.decode_prefix_block)
+    if not ring and (not blk or W % min(blk, W)):
         return flash_attention.DecodePlan(
             "lax", "decode_prefix_block off: the cache-wide mask")
     return flash_attention.decode_attention_plan(
-        lanes, W, model.num_heads,
-        model.num_kv_heads or model.num_heads, model.head_dim,
+        lanes, W, spec.num_heads,
+        model.num_kv_heads or spec.num_heads, model.head_dim,
         itemsize=jnp.dtype(model.dtype or jnp.float32).itemsize,
         impl=model.decode_prefix_impl,
         quantized=model.kv_quant is not None,
-        trivial_mesh=_mesh_is_trivial())
+        trivial_mesh=_mesh_is_trivial(), ring=ring)
+
+
+def decode_attention_plans(model: TransformerLM, lanes: int = 1) -> dict:
+    """{kind: `decode_attention_plan`} over the model's softmax kinds
+    (one entry for a model without one: the plan that says so)."""
+    return {kind: decode_attention_plan(model, lanes, kind)
+            for kind in model.softmax_kinds or ("attn",)}
 
 
 def init_slot_cache(model: TransformerLM, num_slots: int):
@@ -1352,7 +1472,11 @@ def _freeze_cache_indices(new_cache, old_cache, advance):
     wrote at its frozen position are harmless - the causal masks attend
     positions < index, and the next real writer (prefill chunk or live
     tick) lands on the same position - so the [max_len] cache rows
-    never need the select."""
+    never need the select. Nor do a sliding-window layer's [window]
+    rows: with its index frozen at i the ring stands still - the one
+    slot the masked lane wrote, i mod window, held position
+    i - window, which is outside the band of every position >= i, and
+    the next real writer of position i lands on that slot."""
     from jax.tree_util import tree_flatten_with_path, tree_unflatten
     flat, treedef = tree_flatten_with_path(new_cache)
     old_leaves = jax.tree.leaves(old_cache)
@@ -1495,10 +1619,11 @@ def paged_cache_spec(model: TransformerLM,
     is shape-identical to the linear cache (the bitwise-equality
     contract), and no sliding window (a rolling buffer's slot = pos
     mod window layout has no block-aligned prefix to share)."""
-    if model.window is not None:
+    if model.has_rolling_cache:
         raise ValueError(
-            "paged KV cache requires window=None (a rolling-window "
-            "cache has no block-aligned prefix to page or share)")
+            "paged KV cache requires window=None on every layer (a "
+            "rolling-window cache has no block-aligned prefix to page "
+            "or share)")
     if block_size < 1 or model.max_len % block_size:
         raise ValueError(
             f"block_size must divide max_len={model.max_len} exactly, "

@@ -68,14 +68,17 @@ def serving_metrics(reg: Optional[MetricRegistry] = None) -> Dict:
             "Devices in the engine's serving mesh (1 = unsharded; "
             "KV head shards ride the HVD_SERVE_MESH_AXIS axis)",
             ("engine",)),
-        # Slot-pool bytes by kind (docs/serving.md "Hybrid models"): a
-        # hybrid model's fixed pool holds K/V rows AND recurrent state
-        # (with its convolution tails) in one tree.
+        # Slot-pool bytes by kind (docs/serving.md "Hybrid models",
+        # "Mixed attention"): one tree holds full-attention K/V rows,
+        # sliding-window rings AND recurrent state (with its
+        # convolution tails).
         "pool_bytes": reg.gauge(
             "hvd_serving_pool_bytes",
             "Device bytes of the fixed slot pool's cache by kind "
-            "(kv = keys and values, state = recurrent state and "
-            "convolution tails that each step overwrites)",
+            "(kv = keys and values of full-attention layers, "
+            "kv_window = the rings of sliding-window layers, state = "
+            "recurrent state and convolution tails that each step "
+            "overwrites)",
             ("engine", "kind")),
         "kv_blocks_free_shard": reg.gauge(
             "hvd_kv_blocks_free_per_shard",

@@ -128,8 +128,8 @@ SPAN_CATALOG: Dict[str, str] = {
     "sched.tick_dispatch":
         "Loop span: the decode tick's dispatch; attrs are the tick "
         "record (lanes_decoding, lanes_prefilling, lanes_free, "
-        "queue_depth, context_sum, context_max, lanes_sampling, "
-        "lanes_nucleus)",
+        "queue_depth, context_sum, context_max, context_window_sum, "
+        "lanes_sampling, lanes_nucleus)",
     "sched.tick_sync":
         "Loop span: reading the previous tick's tokens, appending "
         "them and retiring the finished (attrs overlapped, tokens, "
@@ -545,12 +545,14 @@ def tail(n: int = 200) -> List[Dict]:
 # Loop spans: the serving loop and the train step, on the profiler's clock
 # ---------------------------------------------------------------------------
 
-# Records the loop ring keeps. A serving step leaves six or seven
+# Records the loop ring keeps. A serving step leaves about seven
 # (step, housekeeping, prefill chunk, tick dispatch, tick sync,
-# bookkeeping, now and then an admission), so at 15-50 steps a second
-# this is 10 to 5 minutes - long enough for a reader that runs a
-# minute or two after the window it asks about.
-LOOP_RING = 32768
+# bookkeeping, now and then an admission and a first token), so at
+# 15-55 steps a second this is 20 to 5 minutes - long enough for a
+# reader that runs a minute or two after the window it asks about (a
+# benchmark run's readers look back past a warm period and a 50 s
+# window: about 35 k records at 55 steps a second).
+LOOP_RING = 131072
 
 _LOOP: collections.deque = collections.deque(maxlen=LOOP_RING)
 _LOOP_SEQ = itertools.count(1)

@@ -1378,17 +1378,32 @@ def decode_attention_plan(lanes: int, W: int, H: int, Hkv: int, D: int,
                           quantized: bool = False,
                           trivial_mesh: bool = True,
                           on_tpu: Optional[bool] = None,
-                          block_k: Optional[int] = None) -> DecodePlan:
+                          block_k: Optional[int] = None,
+                          ring: bool = False) -> DecodePlan:
     """THE rule for decode attention against the linear cache: the
     ragged kernel (`flash_decode_attention`) for an S = 1 step on an
     un-quantized cache with no serving mesh, at a shape Mosaic takes,
     on a TPU; the lax walk (`ParallelSelfAttention._prefix_attention`)
     for everything else. ``impl`` "lax" / "pallas" force a path (the
     oracle, and the kernel in interpret mode off the chip); a forced
-    kernel still needs what the kernel cannot do without."""
+    kernel still needs what the kernel cannot do without.
+
+    ``ring``: the cache is a sliding-window layer's rolling buffer of
+    W slots (slot = position mod W). The same kernel takes it - the
+    ring's valid slots are its first min(position + 1, W), and rows
+    rotated at their own positions need no order - at the ring's own
+    one or two key blocks; "lax" there is the dense
+    [ring ++ block] branch, not a walk."""
     if impl not in (None, "lax", "pallas"):
         raise ValueError(
             f"decode_prefix_impl must be None|lax|pallas, got {impl!r}")
+    if ring:
+        plan = decode_attention_plan(
+            lanes, W, H, Hkv, D, itemsize=itemsize, S=S, impl=impl,
+            quantized=quantized, trivial_mesh=trivial_mesh,
+            on_tpu=on_tpu, block_k=block_k)
+        return dataclasses.replace(
+            plan, why=f"sliding-window ring of {W} slots: {plan.why}")
     if impl == "lax":
         return DecodePlan("lax", "forced")
     if S != 1:
@@ -1415,7 +1430,7 @@ def decode_attention_plan(lanes: int, W: int, H: int, Hkv: int, D: int,
                               "of 128 lanes")
     vmem = _decode_vmem(bk, H, Hkv, D, itemsize)
     return DecodePlan(
-        "kernel", "forced" if impl else "S = 1, linear cache, TPU",
+        "kernel", "forced" if impl else "S = 1 on a TPU",
         block_k=bk, grid=(lanes, W // bk), vmem_bytes=vmem,
         vmem_limit_bytes=vmem if vmem > VMEM_SCOPED_DEFAULT else None)
 

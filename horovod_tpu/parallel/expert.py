@@ -210,11 +210,14 @@ class HeldExpertsMoE(nn.Module):
     experts would add is left out (on a real mesh the exchange brings
     it; nothing here stands in for it). ``held=None`` holds them all.
 
-    Routing (the DeepSeek-V3 family's): scores = sigmoid(x W_r) in
-    float32; the k largest of scores + bias are chosen (the bias takes
-    part in the choice only); weights = the chosen scores, normalised
-    to sum to one. Experts are SwiGLU MLPs of width ``hidden``.
-    Dropless: see `grouped_experts`.
+    Routing, ``router``: "sigmoid" (the DeepSeek-V3 family's) -
+    scores = sigmoid(x W_r) in float32, the k largest of scores + bias
+    chosen (the bias, a parameter, takes part in the choice only);
+    "softmax" (the Qwen-MoE family's) - scores = softmax(x W_r) over
+    all experts in float32, the k largest chosen, no bias. Either way
+    weights = the chosen scores, normalised to sum to one, times
+    ``scale`` (the published `routed_scaling_factor`). Experts are
+    SwiGLU MLPs of width ``hidden``. Dropless: see `grouped_experts`.
 
     Sows into the "moe_stats" collection (when the caller makes it
     mutable) `pairs`: the (token, expert) pairs per held expert, int32
@@ -225,6 +228,8 @@ class HeldExpertsMoE(nn.Module):
     k: int = 8
     held: Optional[Tuple[int, int]] = None
     shared_hidden: int = 0           # 0 = no shared expert
+    router: str = "sigmoid"          # | "softmax"
+    scale: float = 1.0
     dtype: Any = None
 
     @nn.compact
@@ -238,10 +243,11 @@ class HeldExpertsMoE(nn.Module):
                 f"experts")
         dtype = self.dtype or x.dtype
         init = nn.initializers.lecun_normal()
+        if self.router not in ("sigmoid", "softmax"):
+            raise ValueError(
+                f"router must be sigmoid|softmax, got {self.router!r}")
         router = self.param("router", init, (d, self.num_experts),
                             jnp.float32)
-        bias = self.param("router_bias", nn.initializers.zeros,
-                          (self.num_experts,), jnp.float32)
 
         def experts(name, shape):
             return self.param(name, nn.with_partitioning(
@@ -253,12 +259,21 @@ class HeldExpertsMoE(nn.Module):
         w_down = experts("w_down", (E, self.hidden, d))
 
         xt = x.reshape(-1, d)
-        scores = jax.nn.sigmoid(jnp.matmul(
+        logits = jnp.matmul(
             xt.astype(jnp.float32), router.astype(jnp.float32),
-            precision=lax.Precision.HIGHEST))
-        _, chosen = lax.top_k(scores + bias, self.k)
+            precision=lax.Precision.HIGHEST)
+        if self.router == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            bias = self.param("router_bias", nn.initializers.zeros,
+                              (self.num_experts,), jnp.float32)
+            _, chosen = lax.top_k(scores + bias, self.k)
+        else:
+            scores = jax.nn.softmax(logits, axis=-1)
+            _, chosen = lax.top_k(scores, self.k)
         weight = jnp.take_along_axis(scores, chosen, axis=-1)
         weight = weight / weight.sum(-1, keepdims=True)
+        if self.scale != 1.0:
+            weight = weight * self.scale
         local = chosen - first
         key = jnp.where((local >= 0) & (local < E), local, E)
         self.sow("moe_stats", "pairs",

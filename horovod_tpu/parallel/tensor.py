@@ -19,8 +19,12 @@ wants the collectives visible (`column_parallel_matmul` /
 
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import Any, Callable, Optional, Tuple
+import math
+from typing import Any, Callable, Optional, Tuple, Union
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -408,6 +412,10 @@ class ParallelSelfAttention(nn.Module):
     num_kv_heads: Optional[int] = None
     pos_emb: str = "none"        # "none" | "rope"
     rope_theta: float = 10000.0
+    # The rotary rule where it is more than a theta (a rotated part of
+    # the head, YaRN's frequencies and scale); None = RopeSpec(theta=
+    # rope_theta), the whole head rotated.
+    rope: Optional["RopeSpec"] = None
     window: Optional[int] = None  # sliding-window (decode mask)
     # Decode-mode S>1 calls: False (default) = one-pass prefill from
     # an EMPTY cache through the model's kernel (flash-able; what
@@ -444,10 +452,11 @@ class ParallelSelfAttention(nn.Module):
     # Qwen2-style split: bias on the qkv projection but not on the
     # output projection. None = follow use_bias (GPT-2: both).
     out_bias: Optional[bool] = None
-    # Elementwise output gate: the attention's output is multiplied by
-    # sigmoid(x W_g) (W_g: d -> H*D, no bias) before the output
-    # projection.
-    out_gate: bool = False
+    # Output gate: the attention's output is multiplied by
+    # sigmoid(x W_g) (no bias) before the output projection. True (or
+    # "elementwise"): W_g: d -> H*D, a gate a channel; "head":
+    # W_g: d -> H, one scalar a head.
+    out_gate: Union[bool, str] = False
     # Width of the output projection (the residual stream's); None =
     # H*D, the models whose hidden size is heads x head_dim.
     out_features: Optional[int] = None
@@ -497,13 +506,23 @@ class ParallelSelfAttention(nn.Module):
         else:
             q, k = self._maybe_rope(q, k)
             o = self._dispatch_attn(q, k, v, mask)
-        o = o.reshape(*o.shape[:-2], features)
+        if self.out_gate not in (False, True, "elementwise", "head"):
+            raise ValueError(
+                f"out_gate must be a bool, 'elementwise' or 'head', "
+                f"got {self.out_gate!r}")
         if self.out_gate:
-            gate = ColumnParallelDense(features, use_bias=False,
+            per_head = self.out_gate == "head"
+            gate = ColumnParallelDense(H if per_head else features,
+                                       use_bias=False,
                                        weight_quant=self.weight_quant,
                                        dtype=self.dtype, name="gate")(x)
-            o = (o * jax.nn.sigmoid(gate.astype(jnp.float32))
-                 ).astype(o.dtype)
+            gate = jax.nn.sigmoid(gate.astype(jnp.float32))
+            if per_head:
+                gate = gate[..., None]              # [..., S, H, 1]
+            else:
+                gate = gate.reshape(o.shape)
+            o = (o * gate).astype(o.dtype)
+        o = o.reshape(*o.shape[:-2], features)
         if o.ndim == 2:
             o = constrain(o, AXIS_SEQ, AXIS_MODEL)
         else:
@@ -523,8 +542,10 @@ class ParallelSelfAttention(nn.Module):
         if self.pos_emb != "rope":
             return q, k
         positions = offset + jnp.arange(q.shape[-3])
-        return (apply_rope(q, positions, self.rope_theta),
-                apply_rope(k, positions, self.rope_theta))
+        rule = (self.rope or RopeSpec(theta=self.rope_theta)
+                ).rotation(self.head_dim)
+        return (apply_rope(q, positions, **rule),
+                apply_rope(k, positions, **rule))
 
     def _repeat_kv(self, t: jax.Array) -> jax.Array:
         """Broadcast Hkv KV heads to the full H query heads (no-op for
@@ -873,10 +894,37 @@ class ParallelSelfAttention(nn.Module):
             return dot_product_attention(q, self._repeat_kv(key),
                                          self._repeat_kv(val), mask)
 
-        # Rolling window. Attend BEFORE writing: a same-call write
-        # could evict the oldest key still inside an earlier query
-        # row's band. Slot s currently holds the newest position
-        # <= i-1 congruent to s mod W (negative = never written).
+        # Rolling window, one position (S = 1): position i lands in
+        # slot i mod W - the slot of position i - W, which is outside
+        # i's band - and the ring then holds exactly the band: its
+        # first min(i + 1, W) slots, every one once it has filled.
+        # Keys enter rotated at their own positions and a softmax
+        # needs no order, so the ragged kernel reads the ring as a
+        # linear cache of that length.
+        if S == 1 and q.ndim == 4:
+            from horovod_tpu.ops.flash_attention import (
+                decode_attention_plan, flash_decode_attention)
+            plan = decode_attention_plan(
+                q.shape[0], W, self.num_heads,
+                self.num_kv_heads or self.num_heads, self.head_dim,
+                itemsize=cached_k.value.dtype.itemsize,
+                impl=self.decode_prefix_impl,
+                quantized=scale_k is not None,
+                trivial_mesh=_mesh_is_trivial(), ring=True)
+            if plan.path == "kernel":
+                self._cache_write(cached_k, cached_v, scale_k, scale_v,
+                                  index, k, v, i, S, W)
+                return flash_decode_attention(
+                    q, cached_k.value, cached_v.value,
+                    jnp.minimum(i + 1, W), block_k=plan.block_k)
+        # The dense branch (the oracle, and every S > 1 chunk): attend
+        # [ring ++ block] BEFORE writing - a same-call write could
+        # evict the oldest key still inside an earlier query row's
+        # band. Slot s currently holds the newest position <= i-1
+        # congruent to s mod W (negative = never written). A block
+        # longer than the ring is whole in the second part, so a
+        # chunk that laps the ring is still exact (`_cache_write`
+        # then keeps its last W keys).
         s_idx = jnp.arange(W, dtype=i.dtype)
         last = i - 1
         slot_pos = last - ((last - s_idx) % W)
@@ -917,8 +965,73 @@ def _kv_quantize(t: jax.Array):
     return quantize_int8(t, axis=-1)
 
 
+@dataclasses.dataclass(frozen=True)
+class RopeSpec:
+    """One rotary rule, as a layer's attention applies it: the base,
+    how much of the head turns, and - with ``yarn_factor`` - YaRN's
+    frequencies (Peng et al. 2023, as `transformers` computes them:
+    `truncate` on) and the scale on cos and sin.
+
+    The first ``fraction * head_dim`` dimensions of q and k are rotated
+    (half-split pairs inside that part), the rest pass through. Plain:
+    ``inv_freq_j = theta^(-2j/d_r)``. YaRN: dimensions that turn more
+    than ``beta_fast`` times over the original length keep that
+    frequency, those that turn less than ``beta_slow`` times have it
+    divided by the factor, a linear ramp between."""
+    theta: float = 10000.0
+    fraction: float = 1.0
+    yarn_factor: Optional[float] = None
+    yarn_original_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    scale: float = 1.0      # YaRN's attention_factor, on cos and sin
+
+    def rotary_dim(self, head_dim: int) -> int:
+        d_r = int(head_dim * self.fraction)
+        if d_r < 2 or d_r % 2 or d_r > head_dim:
+            raise ValueError(
+                f"rotary part {self.fraction} of a head of {head_dim} "
+                f"is {d_r} dimensions: need an even count in [2, D]")
+        return d_r
+
+    def yarn_ramp(self, head_dim: int):
+        """(low, high) of the ramp over the frequency index."""
+        d_r = self.rotary_dim(head_dim)
+
+        def turns(n):       # the index that turns n times over L0
+            return (d_r * math.log(self.yarn_original_len
+                                   / (n * 2 * math.pi))
+                    / (2 * math.log(self.theta)))
+
+        return (max(math.floor(turns(self.yarn_beta_fast)), 0),
+                min(math.ceil(turns(self.yarn_beta_slow)), d_r - 1))
+
+    def inv_freq(self, head_dim: int) -> np.ndarray:
+        """float64 [d_r / 2]."""
+        d_r = self.rotary_dim(head_dim)
+        j = np.arange(d_r // 2, dtype=np.float64)
+        plain = float(self.theta) ** (-2.0 * j / d_r)
+        if self.yarn_factor is None:
+            return plain
+        low, high = self.yarn_ramp(head_dim)
+        ramp = np.clip((j - low) / max(high - low, 1e-3), 0.0, 1.0)
+        return plain * ((1.0 - ramp) + ramp / self.yarn_factor)
+
+    def rotation(self, head_dim: int) -> dict:
+        """`apply_rope`'s keywords for a head of ``head_dim``."""
+        if (self.fraction == 1.0 and self.yarn_factor is None
+                and self.scale == 1.0):
+            return {"theta": self.theta}    # the rule as it always was
+        return {"rotary_dim": self.rotary_dim(head_dim),
+                "inv_freq": (None if self.yarn_factor is None else
+                             tuple(self.inv_freq(head_dim))),
+                "theta": self.theta, "scale": self.scale}
+
+
 def apply_rope(x: jax.Array, positions: jax.Array,
-               theta: float = 10000.0) -> jax.Array:
+               theta: float = 10000.0, *,
+               rotary_dim: Optional[int] = None,
+               inv_freq=None, scale: float = 1.0) -> jax.Array:
     """Rotary position embedding (Su et al. 2021), half-split layout.
 
     ``x`` [..., S, H, D] with D even; ``positions`` [S] absolute token
@@ -927,17 +1040,28 @@ def apply_rope(x: jax.Array, positions: jax.Array,
     parallelism (ring/Ulysses shard the rotated tensors) and with the
     KV cache (keys are cached post-rotation at their absolute
     position).
+
+    ``rotary_dim`` d_r < D rotates the first d_r dimensions (pairs j,
+    j + d_r/2) and passes the rest through; ``inv_freq`` [d_r/2] gives
+    the frequencies (None: ``theta^(-2j/d_r)``); ``scale`` multiplies
+    cos and sin (`RopeSpec`).
     """
-    D = x.shape[-1]
-    half = D // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    d_r = x.shape[-1] if rotary_dim is None else int(rotary_dim)
+    half = d_r // 2
+    if inv_freq is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     angles = positions[:, None].astype(jnp.float32) * freqs   # [S, half]
     cos = jnp.cos(angles)[:, None, :]                          # [S, 1, h]
     sin = jnp.sin(angles)[:, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    out = jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return out.astype(x.dtype)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
+    x1, x2 = x[..., :half], x[..., half:d_r]
+    parts = [x1 * cos - x2 * sin, x1 * sin + x2 * cos]
+    if d_r < x.shape[-1]:
+        parts.append(x[..., d_r:].astype(parts[0].dtype))
+    return jnp.concatenate(parts, axis=-1).astype(x.dtype)
 
 
 def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,

@@ -257,8 +257,7 @@ class DisaggRouter(ServingRouter):
         if model is None:
             return
         P = int(np.asarray(prompt).shape[0])
-        unbounded = (model.pos_emb == "rope"
-                     and model.window is not None)
+        unbounded = model.context_unbounded
         if not unbounded and P + max_new_tokens - 1 > model.max_len:
             raise ValueError(
                 f"prompt ({P}) + max_new_tokens ({max_new_tokens}) "

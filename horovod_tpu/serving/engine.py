@@ -82,35 +82,70 @@ _IDLE_WAIT_S = 0.05
 _ENGINE_IDS = itertools.count()
 
 
-def _refuse_recurrent(model, **options):
+# What each option does with K/V blocks, and what it would therefore
+# need of a cache that a step does not only append to: a recurrent
+# layer's state (every step OVERWRITES it) and a sliding-window layer's
+# ring (slot = position mod window: later positions overwrite earlier
+# ones in place, and no block-aligned prefix exists).
+_NEEDS_APPENDED_KV = {
+    "paged": ("the paged pool keeps K/V in blocks",
+              "a recurrent state has no block form",
+              "a ring has no block-aligned prefix to page"),
+    "prefix_cache": ("the prefix cache shares K/V blocks between "
+                     "requests",
+                     "a recurrent state has no block form",
+                     "a ring holds a request's last window, not its "
+                     "prefix"),
+    "spec_draft": ("speculative decoding rewinds rejected positions",
+                   "a recurrent state cannot be rewound without a "
+                   "snapshot per position",
+                   "a ring's overwritten slots cannot be rewound "
+                   "without a snapshot of the rows they held"),
+    "swap_bytes": ("swap-preemption shelves K/V blocks on the host",
+                   "a recurrent state has no shelved form",
+                   "a ring has no shelved form"),
+    "transfer": ("disaggregated serving ships K/V blocks between "
+                 "pools",
+                 "a recurrent state has no transfer form",
+                 "a ring has no transfer form"),
+    "mesh": ("a serving mesh shards the pool's K/V over heads",
+             "no serving mesh is defined for the recurrent state and "
+             "the held experts",
+             "no serving mesh is defined for a model whose kinds of "
+             "layer differ in heads, nor for the ring's kernel"),
+}
+
+
+def _refuse_overwritten_cache(model, **options):
     """A model with a recurrent layer (`TransformerLM.layer_kinds`
     holds "kda") keeps, beside K/V, a state that every step
-    OVERWRITES. The fixed slot pool serves it; every path that grafts,
-    exports, pages, shelves or rewinds K/V blocks would need a
-    snapshot form of that state, and none exists: refuse loudly
-    rather than run it wrongly (docs/serving.md "Hybrid models")."""
-    if not model.has_recurrent_state:
+    OVERWRITES; a model with a sliding-window layer keeps that layer's
+    K/V in a ring whose slots later positions overwrite
+    (`has_rolling_cache`). The fixed slot pool serves both; every path
+    that grafts, exports, pages, shelves or rewinds K/V blocks would
+    need a snapshot form of that state or a block form of that ring,
+    and none exists: refuse loudly and by name rather than run it
+    wrongly (docs/serving.md "Hybrid models", "Mixed attention")."""
+    recurrent, rolling = (model.has_recurrent_state,
+                          model.has_rolling_cache)
+    if not (recurrent or rolling):
         return
-    needs = {
-        "paged": "the paged pool keeps K/V in blocks; a recurrent "
-                 "state has no block form",
-        "spec_draft": "speculative decoding rewinds rejected "
-                      "positions; a recurrent state cannot be rewound "
-                      "without a snapshot per position",
-        "swap_bytes": "swap-preemption shelves K/V blocks on the "
-                      "host; a recurrent state has no shelved form",
-        "transfer": "disaggregated serving ships K/V blocks between "
-                    "pools; a recurrent state has no transfer form",
-        "mesh": "no serving mesh is defined for the recurrent state "
-                "and the held experts",
-    }
     for name, on in options.items():
-        if on:
+        if not on:
+            continue
+        does, no_state, no_ring = _NEEDS_APPENDED_KV[name]
+        if recurrent:
             raise ValueError(
                 f"{name}: this model has recurrent (linear-attention) "
-                f"layers, and {needs[name]} - missing snapshot form "
-                f"of the recurrent state; serve it from the fixed "
-                f"slot pool (ServingEngine defaults)")
+                f"layers, and {does}; {no_state} - missing snapshot "
+                f"form of the recurrent state; serve it from the "
+                f"fixed slot pool (ServingEngine defaults)")
+        raise ValueError(
+            f"{name}: this model has sliding-window layers whose "
+            f"cache is a rolling buffer (ring) of "
+            f"{model.rolling_window} slots, and {does}; {no_ring} - "
+            f"missing block form of the ring; serve it from the "
+            f"fixed slot pool (ServingEngine defaults)")
 
 
 def _resolve_serving_mesh(mesh):
@@ -363,9 +398,10 @@ class ServingEngine:
         # pools and params all see the ONE resolved layout.
         mesh = _resolve_serving_mesh(mesh)
         self.mesh = mesh
-        _refuse_recurrent(model, paged=paged, spec_draft=spec_draft,
-                          swap_bytes=preempt and swap_bytes,
-                          mesh=mesh is not None)
+        _refuse_overwritten_cache(
+            model, paged=paged, prefix_cache=prefix_cache,
+            spec_draft=spec_draft, swap_bytes=preempt and swap_bytes,
+            mesh=mesh is not None)
         # Weight-only quantization at the engine door (docs/serving.md
         # "Decode fast path"): the block-matmul kernels land int8 +
         # per-channel f32 scales, halving decode's weight HBM reads.
@@ -470,8 +506,10 @@ class ServingEngine:
         # Warmup runs on the constructor thread BEFORE the dispatch
         # thread exists, so the single-jax-thread contract holds.
         self.warmup_info = None
-        plan = self.pool.decode_attention_plan()
-        self.metrics.observe_decode_attn(plan.path, plan.describe())
+        plans = self.pool.decode_attention_plans()
+        self.metrics.observe_decode_attn(plans)
+        said = "; ".join(f"{kind}: {plan.describe()}"
+                         for kind, plan in plans.items())
         if warmup:
             self.warmup_info = self.pool.warmup(
                 max_chunk=(self.prefill_chunk_budget
@@ -480,7 +518,7 @@ class ServingEngine:
             logging.getLogger("horovod_tpu").info(
                 "serving warm-up: %d programs in %.1f s; decode "
                 "attention: %s", self.warmup_info["compiles"],
-                self.warmup_info["seconds"], plan.describe())
+                self.warmup_info["seconds"], said)
         # Hot-path compiles = pool compiles past this baseline.
         self._compile_baseline = self.pool.compiles
         self.metrics.observe_pipeline(self.pipeline_depth)
@@ -733,8 +771,9 @@ class ServingEngine:
                     f"the original stream already finished")
             forced = tuple(int(t) for t in fp)
         P = int(prompt.shape[0])
-        unbounded = (self.model.pos_emb == "rope"
-                     and self.model.window is not None)
+        # one full-attention layer bounds a request by its max_len
+        # rows, however many of the layers roll
+        unbounded = self.model.context_unbounded
         if not unbounded and P + max_new_tokens - 1 > self.model.max_len:
             raise ValueError(
                 f"prompt ({P}) + max_new_tokens ({max_new_tokens}) - 1 "
@@ -851,7 +890,8 @@ class ServingEngine:
         is matched. False when this engine cannot ingest (non-paged
         pool, or closing) — the caller's submit still works, it just
         re-prefills (the fallback ladder)."""
-        _refuse_recurrent(self.model, transfer=transfer is not None)
+        _refuse_overwritten_cache(self.model,
+                                  transfer=transfer is not None)
         if transfer is None or not self.paged or self._closing:
             return False
         self._grafts.append(transfer)

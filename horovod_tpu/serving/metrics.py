@@ -161,6 +161,10 @@ class EngineMetrics:
         self.lane_ticks_prefilling = 0
         self.lane_ticks_free = 0
         self.tick_context_positions = 0
+        # ... and of those positions the ones inside a sliding-window
+        # layer's ring: sum of min(context, window), 0 for a model
+        # without such a layer
+        self.tick_window_positions = 0
         # Which sampling work the ticks did (`sample_lanes`' three
         # paths, from the record's `lanes_sampling` / `lanes_nucleus`):
         # argmax alone, a draw with no sort, the sort for the batch.
@@ -232,9 +236,10 @@ class EngineMetrics:
         self.mesh_shape = None
         self.warmup_s = None       # startup precompile cost, if run
         # How the pool's S = 1 ticks attend ("kernel" | "lax" |
-        # "paged") and the plan in words; set once by the engine.
-        self.decode_attn_path = None
-        self.decode_attn_plan = None
+        # "paged") and the plan in words, {kind of softmax layer: ...}
+        # in the model's order; set once by the engine.
+        self.decode_attn_paths = {}
+        self.decode_attn_plans = {}
         # Latency series (seconds).
         self.queue_wait_s = Series()
         self.ttft_s = Series()
@@ -257,10 +262,14 @@ class EngineMetrics:
         with self._lock:
             self.warmup_s = seconds
 
-    def observe_decode_attn(self, path: str, plan: str):
+    def observe_decode_attn(self, plans: dict):
+        """{kind of softmax layer: `DecodePlan`}, in the model's
+        order."""
         with self._lock:
-            self.decode_attn_path = path
-            self.decode_attn_plan = plan
+            self.decode_attn_paths = {k: p.path
+                                      for k, p in plans.items()}
+            self.decode_attn_plans = {k: p.describe()
+                                      for k, p in plans.items()}
 
     def count(self, name: str, n: int = 1):
         with self._lock:
@@ -299,6 +308,7 @@ class EngineMetrics:
             self.lane_ticks_prefilling += tick["lanes_prefilling"]
             self.lane_ticks_free += tick["lanes_free"]
             self.tick_context_positions += tick["context_sum"]
+            self.tick_window_positions += tick["context_window_sum"]
             if tick["lanes_nucleus"]:
                 self.ticks_nucleus += 1
             elif tick["lanes_sampling"]:
@@ -513,6 +523,7 @@ class EngineMetrics:
                 "lane_ticks_prefilling": self.lane_ticks_prefilling,
                 "lane_ticks_free": self.lane_ticks_free,
                 "tick_context_positions": self.tick_context_positions,
+                "tick_window_positions": self.tick_window_positions,
                 "ticks_greedy": self.ticks_greedy,
                 "ticks_sampled": self.ticks_sampled,
                 "ticks_nucleus": self.ticks_nucleus,
@@ -530,8 +541,13 @@ class EngineMetrics:
                 "mesh": self.mesh_shape,
                 "warmup_s": (round(self.warmup_s, 3)
                              if self.warmup_s is not None else None),
-                "decode_attn_path": self.decode_attn_path,
-                "decode_attn_plan": self.decode_attn_plan,
+                # the first kind's, as before there were kinds
+                "decode_attn_path": next(
+                    iter(self.decode_attn_paths.values()), None),
+                "decode_attn_plan": next(
+                    iter(self.decode_attn_plans.values()), None),
+                "decode_attn_paths": dict(self.decode_attn_paths),
+                "decode_attn_plans": dict(self.decode_attn_plans),
                 "restarts": self.restarts,
                 "requeued": self.requeued,
                 "faults_injected": self.faults_injected,

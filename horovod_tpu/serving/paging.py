@@ -664,12 +664,14 @@ class PagedSlotPool:
     def spec_on(self) -> bool:
         return self.spec_draft is not None and self.spec_k > 0
 
-    def decode_attention_plan(self):
-        """`SlotPool.decode_attention_plan`'s twin: this pool's ticks
-        attend through the block tables, never the linear cache."""
+    def decode_attention_plans(self) -> dict:
+        """`SlotPool.decode_attention_plans`' twin: this pool's ticks
+        attend through the block tables, never the linear cache (and
+        it takes no model with a second kind of softmax layer)."""
         from horovod_tpu.ops.flash_attention import DecodePlan
-        return DecodePlan(
-            "paged", f"block tables, HVD_PAGED_KERNEL={self.kernel_mode}")
+        return {"attn": DecodePlan(
+            "paged",
+            f"block tables, HVD_PAGED_KERNEL={self.kernel_mode}")}
 
     def _ctx(self):
         return use(self.mesh) if self.mesh is not None \

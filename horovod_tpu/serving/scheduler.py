@@ -142,6 +142,10 @@ class ContinuousBatchingScheduler:
                  pipeline_depth: int = 1, grafts=None,
                  overload=None):
         self.pool = pool
+        # the slots of the model's sliding-window rings, if it has any
+        # (a scripted pool without a model has none)
+        self._rolling_window = getattr(
+            getattr(pool, "model", None), "rolling_window", None)
         self.queue = queue
         self.metrics = metrics
         # Overload control plane (serving/overload.py): None keeps the
@@ -340,7 +344,11 @@ class ContinuousBatchingScheduler:
         books (O(lanes), no device read): the lanes that decode, the
         lanes a request holds without a first token yet, the free
         ones, the queue behind them, the cached positions (prompt
-        + emitted) the decoding lanes bring - and, of the decoding
+        + emitted) the decoding lanes bring, as the full-attention
+        layers read them (``context_sum``) and as a sliding-window
+        layer's ring holds them (``context_window_sum``: each lane's
+        min(context, window); 0 for a model without such a layer) -
+        and, of the decoding
         lanes, those whose request samples (``temperature > 0``) and
         of those the ones that ask for a nucleus (``top_p < 1``):
         which of `sample_lanes`' three paths the tick takes. The
@@ -351,6 +359,7 @@ class ContinuousBatchingScheduler:
         sampling = [r.sampling for r in self.active.values()
                     if r.sampling.temperature > 0]
         decoding, prefilling = len(contexts), len(self.prefilling)
+        window = self._rolling_window
         return {"lanes_decoding": decoding,
                 "lanes_prefilling": prefilling,
                 "lanes_free": max(
@@ -358,6 +367,9 @@ class ContinuousBatchingScheduler:
                 "queue_depth": len(self.queue),
                 "context_sum": sum(contexts),
                 "context_max": max(contexts, default=0),
+                "context_window_sum": (
+                    sum(min(c, window) for c in contexts)
+                    if window else 0),
                 "lanes_sampling": len(sampling),
                 "lanes_nucleus": sum(
                     1 for sp in sampling

@@ -50,7 +50,7 @@ import jax.numpy as jnp
 
 from horovod_tpu.annotations import hot_path
 from horovod_tpu.models.transformer import (
-    TransformerLM, decode_attention_plan, init_slot_cache,
+    TransformerLM, decode_attention_plans, init_slot_cache,
     prefill_chunks, recurrent_leaf, sample_lanes,
     shard_slot_cache, slot_decode_model, slot_decode_tick,
     slot_prefill_advance, slot_prefill_chunk, slot_reset,
@@ -74,10 +74,11 @@ def validate_spec_draft(model: TransformerLM, spec_draft,
         raise ValueError(
             f"spec draft vocab ({draft_model.vocab_size}) != target "
             f"vocab ({model.vocab_size})")
-    if model.window is not None or draft_model.window is not None:
+    if model.has_rolling_cache or draft_model.has_rolling_cache:
         raise ValueError(
             "speculative decoding cannot rewind a sliding-window "
-            "(rolling) cache; use window=None models")
+            "(rolling) cache; use models without a window on any "
+            "layer")
     if draft_model.max_len < model.max_len:
         raise ValueError(
             f"spec draft max_len ({draft_model.max_len}) must cover "
@@ -256,12 +257,13 @@ class SlotPool:
         return use(self.mesh) if self.mesh is not None \
             else contextlib.nullcontext()
 
-    def decode_attention_plan(self):
-        """The plan this pool's ticks compile with (kernel or lax walk,
-        and why): `models.transformer.decode_attention_plan` under the
-        pool's mesh."""
+    def decode_attention_plans(self) -> dict:
+        """{kind: the plan this pool's ticks compile with (kernel or
+        lax, and why)}, one entry for each kind of softmax layer the
+        model has: `models.transformer.decode_attention_plans` under
+        the pool's mesh."""
         with self._ctx():
-            return decode_attention_plan(self.model, self.num_slots)
+            return decode_attention_plans(self.model, self.num_slots)
 
     def _note_shape(self, key):
         if key not in self._seen_shapes:
@@ -309,15 +311,29 @@ class SlotPool:
 
     def cache_bytes(self) -> dict:
         """Device bytes of the pool's cache by kind: `kv` (keys and
-        values, appended to) and `state` (a recurrent layer's state
-        and convolution tail, overwritten each step; 0 for a model
-        without one). The fill indices are not counted."""
+        values of the full-attention layers, appended to: `max_len`
+        rows a lane), `kv_window` (keys and values of the
+        sliding-window layers, a ring of `window` rows a lane that
+        later positions overwrite in place; 0 for a model without
+        one) and `state` (a recurrent layer's state and convolution
+        tail, overwritten each step; 0 likewise). The fill indices are
+        not counted."""
         from jax.tree_util import tree_flatten_with_path
-        out = {"kv": 0, "state": 0}
+        out = {"kv": 0, "kv_window": 0, "state": 0}
+        kinds = self.model.kinds
+        rolls = {f"block_{i}" for i, kind in enumerate(kinds)
+                 if kind in self.model.softmax_kinds
+                 and self.model.attn_spec(kind).window is not None}
         for path, leaf in tree_flatten_with_path(self._cache)[0]:
-            if "index" not in str(path):
-                kind = "state" if recurrent_leaf(path) else "kv"
-                out[kind] += int(leaf.nbytes)
+            if "index" in str(path):
+                continue
+            if recurrent_leaf(path):
+                kind = "state"
+            elif getattr(path[0], "key", None) in rolls:
+                kind = "kv_window"
+            else:
+                kind = "kv"
+            out[kind] += int(leaf.nbytes)
         return out
 
     # -- occupancy ----------------------------------------------------

@@ -306,7 +306,8 @@ def test_a_dense_model_reports_no_expert_counters():
         eng.submit(np.arange(5), 4).result(timeout=120)
         snap = eng.metrics_snapshot()
     assert snap["moe_layers_ticks"] == 0 and snap["moe_pairs"] == 0
-    assert snap["pool_bytes"] == {"kv": 2 * 2 * 32 * 2 * 8 * 4, "state": 0}
+    assert snap["pool_bytes"] == {"kv": 2 * 2 * 32 * 2 * 8 * 4,
+                                  "kv_window": 0, "state": 0}
 
 
 # ---- (e) what cannot serve a recurrent state says so ------------------------

@@ -336,6 +336,66 @@ def test_slot_decode_tick_attends_through_the_ragged_kernel(
             assert str(n) not in elems, ln[:200]
 
 
+def test_mixed_tick_attends_both_kinds_through_the_ragged_kernel(
+        sds, monkeypatch):
+    """A tick over two kinds of softmax layer at `laguna-s-2.1`'s
+    shapes (64 lanes; 48 heads over a linear cache of 12288, 72 heads
+    over a ring of 512, both on 8 KV heads of 128) on the DEFAULT
+    rule: one Mosaic call a layer, each under its own kind's scope -
+    groups of 6 and of 9 in one program - and both caches still
+    aliased input to output."""
+    from horovod_tpu.models.transformer import (
+        AttnSpec, TransformerLM, decode_attention_plans, init_slot_cache,
+        serving_params, slot_decode_model, slot_decode_tick)
+    from horovod_tpu.ops import flash_attention
+    from horovod_tpu.parallel.tensor import RopeSpec, unbox
+
+    monkeypatch.setattr(flash_attention, "_auto_interpret",
+                        lambda: False)
+    lanes, W = 64, 12288
+    model = TransformerLM(
+        vocab_size=4096, num_layers=2, max_len=W, norm="rmsnorm",
+        mlp_impl="swiglu", mlp_hidden=1024, dtype=jnp.bfloat16,
+        attn_impl="flash", hidden_size=3072, num_heads=48,
+        num_kv_heads=8, head_dim=128, pos_emb="rope", attn_gate="head",
+        layer_kinds=("attn", "swa"),
+        attn_specs=(("attn", AttnSpec(48, None, RopeSpec(
+            theta=5e5, fraction=0.5, yarn_factor=128,
+            yarn_original_len=8192, scale=1.4852030263919618))),
+            ("swa", AttnSpec(72, 512, RopeSpec(theta=1e4)))))
+    plans = decode_attention_plans(model, lanes)
+    assert plans["attn"].path == plans["swa"].path == "kernel", plans
+    assert plans["attn"].grid == (lanes, 48)
+    assert plans["swa"].grid == (lanes, 2)
+    dec = slot_decode_model(model)
+
+    def place(tree):
+        return jax.tree.map(lambda s: sds(s.shape, s.dtype), tree)
+
+    params = place(jax.eval_shape(
+        lambda r: serving_params(unbox(model.init(
+            r, jnp.zeros((1, 64), jnp.int32))["params"])),
+        jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: init_slot_cache(model, lanes)))
+    assert cache["block_1"]["swa"]["cached_key"].shape == (
+        lanes, 1, 512, 8, 128)
+    vec = lambda dt: sds((lanes,), dt)  # noqa: E731
+    compiled = slot_decode_tick.lower(
+        dec, params, cache, vec(jnp.int32), vec(jnp.float32),
+        vec(jnp.float32), sds((lanes, 2), jnp.uint32), vec(bool),
+        vec(bool), sds((), jnp.int32)).compile()
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    assert len(calls) == 2
+    assert "/block_0/attn/attn._decode_attention" in calls[0]
+    assert "/block_1/swa/swa._decode_attention" in calls[1]
+    kv_bytes = sum(
+        leaf.dtype.itemsize * leaf.size for path, leaf in
+        jax.tree_util.tree_flatten_with_path(cache)[0]
+        if "cached_" in str(path))
+    assert compiled.memory_analysis().alias_size_in_bytes >= kv_bytes
+
+
 def test_flash_under_a_four_chip_data_mesh(topo, chip_config,
                                            monkeypatch):
     """The LM's `attn_impl="flash"` inside a GSPMD program over four
