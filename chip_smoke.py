@@ -555,16 +555,18 @@ def phase_server(devs, seed):
             compare_streams(label, prompts, got, want, margins)
             if not kw:
                 # the fixed pool on the default rule: on the chip the
-                # tick attends through the ragged decode kernel
+                # tick attends through the ragged decode kernel and
+                # appends its K/V row in place - two calls a layer
                 snap = eng.metrics_snapshot()
                 n_cc = tick_text(eng.pool).count("tpu_custom_call")
                 say(label, f"decode attention: "
                            f"{snap['decode_attn_plan']}; the compiled "
                            f"tick holds {n_cc} tpu_custom_call(s)")
                 check(snap["decode_attn_path"] == "kernel"
-                      and n_cc == LM_KW["num_layers"],
+                      and "write kernel" in snap["decode_attn_plan"]
+                      and n_cc == 2 * LM_KW["num_layers"],
                       f"{label}: the default tick took "
-                      f"{snap['decode_attn_path']} with {n_cc} "
+                      f"{snap['decode_attn_plan']} with {n_cc} "
                       f"custom calls")
             if kw.get("paged_kernel") == "pallas":
                 n_cc = tick_text(eng.pool).count("tpu_custom_call")

@@ -1333,12 +1333,19 @@ class DecodePlan:
     grid: Optional[tuple] = None    # (lanes, k-blocks a lane)
     vmem_bytes: Optional[int] = None
     vmem_limit_bytes: Optional[int] = None
+    # how the step's new K/V row reaches the cache: "kernel" (the
+    # in-place `flash_cache_append`, kernel path only) | "xla"
+    # (`ParallelSelfAttention._cache_write`), and why
+    write: str = "xla"
+    write_why: str = "only the kernel path appends in place"
 
     def describe(self) -> str:
+        write = f"write {self.write} ({self.write_why})"
         if self.path != "kernel":
-            return f"{self.path} ({self.why})"
+            return f"{self.path} ({self.why}); {write}"
         return (f"kernel ({self.why}): block_k {self.block_k}, grid "
-                f"{self.grid}, VMEM {self.vmem_bytes / 2 ** 20:.1f} MiB")
+                f"{self.grid}, VMEM {self.vmem_bytes / 2 ** 20:.1f} MiB"
+                f"; {write}")
 
 
 def _decode_block_k(W: int, Hkv: int, D: int, itemsize: int,
@@ -1357,6 +1364,20 @@ def _decode_block_k(W: int, Hkv: int, D: int, itemsize: int,
     row = Hkv * D * itemsize
     return next((b for b in fits if b * row <= DECODE_BLOCK_BYTES),
                 fits[-1])
+
+
+def _append_rows(W: int, Hkv: int, itemsize: int) -> Optional[int]:
+    """Rows of the cache seen as [W * Hkv, D] that `flash_cache_append`
+    reads, changes and stores for one lane: the smallest run of whole
+    positions that is whole sublane tiles too (a tile is 8 rows of 32
+    bits: 16 of bf16). None where such runs do not divide the cache."""
+    rows = math.lcm(8 * max(4 // itemsize, 1), Hkv)
+    return None if (W * Hkv) % rows else rows
+
+
+def _no_append_tile(W: int, Hkv: int) -> str:
+    return (f"no sublane tile of whole positions divides a cache of "
+            f"{W} x {Hkv} rows")
 
 
 def _decode_vmem(bk: int, H: int, Hkv: int, D: int, itemsize: int):
@@ -1429,10 +1450,14 @@ def decode_attention_plan(lanes: int, W: int, H: int, Hkv: int, D: int,
             return DecodePlan("lax", f"head_dim {D} is not a multiple "
                               "of 128 lanes")
     vmem = _decode_vmem(bk, H, Hkv, D, itemsize)
+    rows = _append_rows(W, Hkv, itemsize)
     return DecodePlan(
         "kernel", "forced" if impl else "S = 1 on a TPU",
         block_k=bk, grid=(lanes, W // bk), vmem_bytes=vmem,
-        vmem_limit_bytes=vmem if vmem > VMEM_SCOPED_DEFAULT else None)
+        vmem_limit_bytes=vmem if vmem > VMEM_SCOPED_DEFAULT else None,
+        write="kernel" if rows else "xla",
+        write_why=(f"one aliased call for all lanes, a tile of {rows} "
+                   f"rows" if rows else _no_append_tile(W, Hkv)))
 
 
 def _decode_kernel(s_ref, q_ref, k_ref, v_ref, o_ref,
@@ -1562,6 +1587,15 @@ def _flash_decode(q, k_cache, v_cache, lengths, block_k, interpret):
       v_cache.reshape(B, W * Hkv, D))
 
 
+def _slots_into_lanes(x, batched: bool, axis_size: int):
+    """A batch rule's operand [slots, B, ...] (broadcast first where
+    it is shared by the slots) as [slots * B, ...]: merging two
+    leading axes is free."""
+    if not batched:
+        x = jnp.broadcast_to(x, (axis_size,) + jnp.shape(x))
+    return x.reshape((axis_size * x.shape[1],) + x.shape[2:])
+
+
 @functools.lru_cache(maxsize=None)
 def _make_decode(block_k: Optional[int], interpret: bool):
     """custom_vmap-wrapped entry (the `_make_paged_decode` pattern):
@@ -1579,15 +1613,9 @@ def _make_decode(block_k: Optional[int], interpret: bool):
                             interpret)
 
     @decode.def_vmap
-    def _rule(axis_size, in_batched, q, k_cache, v_cache, lengths):
-        def lanes(x, batched):
-            if not batched:     # e.g. a query against a shared cache
-                x = jnp.broadcast_to(x, (axis_size,) + jnp.shape(x))
-            return x.reshape((axis_size * x.shape[1],) + x.shape[2:])
-
-        args = [lanes(x, b) for x, b in zip(
-            (q, k_cache, v_cache, lengths), in_batched)]
-        out = decode(*args)
+    def _rule(axis_size, in_batched, *args):
+        out = decode(*(_slots_into_lanes(x, b, axis_size)
+                       for x, b in zip(args, in_batched)))
         return out.reshape((axis_size, -1) + out.shape[1:]), True
 
     return decode
@@ -1629,3 +1657,135 @@ def flash_decode_attention(q: jax.Array, k_cache: jax.Array,
                                (q.shape[0],))
     fn = _make_decode(_opt_int(block_k), bool(interpret))
     return fn(q[:, 0], k_cache, v_cache, lengths)[:, None]
+
+
+# ---------------------------------------------------------------------------
+# The decode step's cache write: one new K and V row a lane, in place.
+# ---------------------------------------------------------------------------
+
+def _append_kernel(pos_ref, kc_ref, vc_ref, kn_ref, vn_ref,
+                   ko_ref, vo_ref, *, hkv: int):
+    """One lane's grid cell: the tile of the K cache (and of the V
+    cache) that holds position ``pos`` comes in, the position's `hkv`
+    rows are taken from the new rows instead, and the tile goes back
+    where it came from (the outputs alias the caches). The new rows
+    arrive already repeated down the tile, so the choice is one select
+    on a row index - no row is moved inside the kernel."""
+    rows = kc_ref.shape[1]
+    first = jax.lax.rem(pos_ref[pl.program_id(0)],
+                        jnp.int32(rows // hkv)) * hkv
+    row = jax.lax.broadcasted_iota(jnp.int32, kc_ref.shape[1:], 0)
+    new = (row >= first) & (row < first + hkv)
+    ko_ref[0] = jnp.where(new, kn_ref[0], kc_ref[0])
+    vo_ref[0] = jnp.where(new, vn_ref[0], vc_ref[0])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _flash_append(k_cache, v_cache, k_new, v_new, pos, interpret):
+    """The batched pallas_call: caches [B, W, Hkv, D], new rows
+    [B, Hkv, D], pos [B] -> both caches, each aliased to its input."""
+    B, W, Hkv, D = k_cache.shape
+    rows = _append_rows(W, Hkv, k_cache.dtype.itemsize)
+    if rows is None:
+        raise ValueError(
+            f"flash_cache_append: {_no_append_tile(W, Hkv)}")
+    per = rows // Hkv       # positions a tile holds
+    # where `lax.dynamic_update_slice` would put the row
+    pos = jnp.clip(pos, 0, W - 1)
+
+    def tile(b, pos):
+        return (b, jax.lax.div(pos[b], jnp.int32(per)), 0)
+
+    def lane(b, pos):
+        return (b, 0, 0)
+
+    def down_the_tile(new):         # [B, Hkv, D] -> [B, rows, D]
+        return jnp.tile(new, (1, per, 1))
+
+    flat = (B, W * Hkv, D)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B,),
+        in_specs=[pl.BlockSpec((1, rows, D), tile),
+                  pl.BlockSpec((1, rows, D), tile),
+                  pl.BlockSpec((1, rows, D), lane),
+                  pl.BlockSpec((1, rows, D), lane)],
+        out_specs=[pl.BlockSpec((1, rows, D), tile),
+                   pl.BlockSpec((1, rows, D), tile)],
+    )
+    k_out, v_out = pl.pallas_call(
+        functools.partial(_append_kernel, hkv=Hkv),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(flat, k_cache.dtype),
+                   jax.ShapeDtypeStruct(flat, v_cache.dtype)],
+        # operand 0 is the scalar-prefetched `pos`
+        input_output_aliases={1: 0, 2: 1},
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(pos, k_cache.reshape(flat), v_cache.reshape(flat),
+      down_the_tile(k_new), down_the_tile(v_new))
+    return k_out.reshape(k_cache.shape), v_out.reshape(v_cache.shape)
+
+
+@functools.lru_cache(maxsize=None)
+def _make_append(interpret: bool):
+    """custom_vmap-wrapped entry, `_make_decode`'s twin: under the
+    serving tick's `jax.vmap` over slots the slot axis JOINS the lane
+    axis and the cache leaf [num_slots, 1, W, Hkv, D] is written where
+    it lies, by one call. (The default batching of the call, like that
+    of `lax.dynamic_update_slice` at a batched index, runs the lanes
+    one after another inside a `while`.)"""
+
+    @jax.custom_batching.custom_vmap
+    def append(k_cache, v_cache, k_new, v_new, pos):
+        return _flash_append(k_cache, v_cache, k_new, v_new, pos,
+                             interpret)
+
+    @append.def_vmap
+    def _rule(axis_size, in_batched, *args):
+        outs = append(*(_slots_into_lanes(x, b, axis_size)
+                        for x, b in zip(args, in_batched)))
+        return tuple(o.reshape((axis_size, -1) + o.shape[1:])
+                     for o in outs), (True, True)
+
+    return append
+
+
+def flash_cache_append(k_cache: jax.Array, v_cache: jax.Array,
+                       k_new: jax.Array, v_new: jax.Array,
+                       pos: jax.Array, *,
+                       interpret: Optional[bool] = None):
+    """Put one decode step's new K and V rows into the caches, in
+    place: `lax.dynamic_update_slice` at a position a lane, as one
+    call.
+
+    k_cache/v_cache [B, W, Hkv, D] (bf16 or f32; the linear cache or a
+    sliding-window layer's ring); k_new/v_new [B, 1, Hkv, D]; ``pos``
+    traced int32, a scalar (`generate`) or [B] (each lane its own),
+    clamped to [0, W - 1] as `dynamic_update_slice` clamps it, so a
+    lane frozen at a full cache writes where it wrote before and never
+    outside its slot. Returns ``(k_cache, v_cache)``, each aliased to
+    its input: a donated cache is written where it lies, nothing but
+    the touched tiles is read or stored.
+
+    One kernel, a grid cell a lane, K and V in the same call: the cell
+    reads the sublane-aligned tile of the cache seen as [W * Hkv, D]
+    that holds the lane's position (`_append_rows`: 16 rows of bf16, 8
+    of f32, more where the KV heads ask), selects the new rows into
+    it and stores it - 4 KB a lane and leaf. `jax.vmap` over a leading
+    slot axis - the serving tick - folds that axis into the lanes of
+    the same one call. `decode_attention_plan(...).write` says where
+    the model takes this call ("kernel") and where it keeps
+    `ParallelSelfAttention._cache_write` ("xla": every path but the
+    ragged kernel's, and a shape no tile divides).
+    """
+    if interpret is None:
+        interpret = _auto_interpret()
+    if k_new.ndim != 4 or k_new.shape[1] != 1:
+        raise ValueError(f"flash_cache_append wants new rows "
+                         f"[B,1,Hkv,D], got {k_new.shape}")
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32),
+                           (k_cache.shape[0],))
+    return _make_append(bool(interpret))(
+        k_cache, v_cache, k_new[:, 0], v_new[:, 0], pos)

@@ -685,24 +685,8 @@ class ParallelSelfAttention(nn.Module):
         (per-block `_repeat_kv`), int8 KV (per-block dequant), and TP
         (all ops are shard-local over the head axis).
         """
-        from horovod_tpu.ops.flash_attention import (
-            decode_attention_plan, flash_decode_attention)
         W = cached_k.value.shape[-3]
         blk = min(self.decode_prefix_block, W)
-        # Trivial-mesh only: a bare pallas_call is opaque to the GSPMD
-        # partitioner, so sharded (TP) decode keeps the lax path,
-        # whose ops partition over the head axis naturally.
-        plan = decode_attention_plan(
-            q.shape[0], W, self.num_heads,
-            self.num_kv_heads or self.num_heads, self.head_dim,
-            itemsize=cached_k.value.dtype.itemsize,
-            S=S, impl=self.decode_prefix_impl,
-            quantized=scale_k is not None,
-            trivial_mesh=_mesh_is_trivial())
-        if plan.path == "kernel" and q.ndim == 4:
-            return flash_decode_attention(
-                q, cached_k.value, cached_v.value, i + S,
-                block_k=plan.block_k)
         H = self.num_heads
         D = self.head_dim
         lead = q.shape[:-3]
@@ -742,6 +726,46 @@ class ParallelSelfAttention(nn.Module):
         m, l, acc = lax.fori_loop(0, nblk, body, (m0, l0, a0))
         out = acc / l[..., None]                     # [..., H, S, D]
         return jnp.swapaxes(out, -3, -2).astype(dtype)
+
+    def _kernel_plan(self, q, cached_k, scale_k, S, ring=False):
+        """The `DecodePlan` of this step if it is the ragged kernel's
+        (`ops.flash_attention.decode_attention_plan`: S = 1, an
+        un-quantized cache, at a shape Mosaic takes, on a TPU), else
+        None. Trivial-mesh only: a bare pallas_call is opaque to the
+        GSPMD partitioner, so sharded (TP) decode keeps the lax path,
+        whose ops partition over the head axis naturally."""
+        from horovod_tpu.ops.flash_attention import decode_attention_plan
+        if q.ndim != 4:
+            return None
+        plan = decode_attention_plan(
+            q.shape[0], cached_k.value.shape[-3], self.num_heads,
+            self.num_kv_heads or self.num_heads, self.head_dim,
+            itemsize=cached_k.value.dtype.itemsize,
+            S=S, impl=self.decode_prefix_impl,
+            quantized=scale_k is not None,
+            trivial_mesh=_mesh_is_trivial(), ring=ring)
+        return plan if plan.path == "kernel" else None
+
+    def _kernel_step(self, plan, q, k, v, cached_k, cached_v, index,
+                     i, slot, length):
+        """The ragged kernel's S = 1 step: the new row into ``slot``,
+        then the first ``length`` slots attended. Where the plan's
+        write is the kernel's too, the row reaches the cache by
+        `flash_cache_append` - every lane of the tick's vmap in one
+        in-place call; `_cache_write`'s update at a batched index is a
+        scatter, which XLA for the TPU runs a lane at a time."""
+        from horovod_tpu.ops.flash_attention import (
+            flash_cache_append, flash_decode_attention)
+        if plan.write == "kernel":
+            cached_k.value, cached_v.value = flash_cache_append(
+                cached_k.value, cached_v.value, k, v, slot)
+            index.value = i + 1
+        else:
+            self._cache_write(cached_k, cached_v, None, None, index,
+                              k, v, i, 1, cached_k.value.shape[-3])
+        return flash_decode_attention(
+            q, cached_k.value, cached_v.value, length,
+            block_k=plan.block_k)
 
     def _paged_decode_attention(self, q, k, v, cached_k, cached_v,
                                 scale_k, scale_v, index, i, S, W):
@@ -878,10 +902,15 @@ class ParallelSelfAttention(nn.Module):
             # Write first, then attend over the (possibly dequantized)
             # updated cache — the current token reads back through the
             # same codec later ticks will see.
+            blk = self.decode_prefix_block
+            prefix = blk and W % min(blk, W) == 0
+            plan = prefix and self._kernel_plan(q, cached_k, scale_k, S)
+            if plan:
+                return self._kernel_step(plan, q, k, v, cached_k,
+                                         cached_v, index, i, i, i + 1)
             self._cache_write(cached_k, cached_v, scale_k, scale_v,
                               index, k, v, i, S, W)
-            blk = self.decode_prefix_block
-            if blk and W % min(blk, W) == 0:
+            if prefix:
                 return self._prefix_attention(q, cached_k, cached_v,
                                               scale_k, scale_v, i, S)
             key = self._cache_read(cached_k, scale_k)
@@ -901,22 +930,11 @@ class ParallelSelfAttention(nn.Module):
         # Keys enter rotated at their own positions and a softmax
         # needs no order, so the ragged kernel reads the ring as a
         # linear cache of that length.
-        if S == 1 and q.ndim == 4:
-            from horovod_tpu.ops.flash_attention import (
-                decode_attention_plan, flash_decode_attention)
-            plan = decode_attention_plan(
-                q.shape[0], W, self.num_heads,
-                self.num_kv_heads or self.num_heads, self.head_dim,
-                itemsize=cached_k.value.dtype.itemsize,
-                impl=self.decode_prefix_impl,
-                quantized=scale_k is not None,
-                trivial_mesh=_mesh_is_trivial(), ring=True)
-            if plan.path == "kernel":
-                self._cache_write(cached_k, cached_v, scale_k, scale_v,
-                                  index, k, v, i, S, W)
-                return flash_decode_attention(
-                    q, cached_k.value, cached_v.value,
-                    jnp.minimum(i + 1, W), block_k=plan.block_k)
+        plan = self._kernel_plan(q, cached_k, scale_k, S, ring=True)
+        if plan:
+            return self._kernel_step(plan, q, k, v, cached_k, cached_v,
+                                     index, i, i % W,
+                                     jnp.minimum(i + 1, W))
         # The dense branch (the oracle, and every S > 1 chunk): attend
         # [ring ++ block] BEFORE writing - a same-call write could
         # evict the oldest key still inside an earlier query row's

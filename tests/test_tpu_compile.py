@@ -263,14 +263,41 @@ TICK_SHAPES = {
 }
 
 
+def _no_loop_under_attention(text):
+    """No `while` under an attention scope: neither the lax walk's nor
+    the one XLA runs a scatter of one row a lane as."""
+    loops = re.findall(r' while\(.*op_name="([^"]*)"', text)
+    assert [n for n in loops if "/attn/" in n or "/swa/" in n] == []
+
+
+def _cache_stays_in_place(compiled, text, cache):
+    """Every K/V leaf aliased input to output, and none copied."""
+    kv = [leaf for path, leaf in
+          jax.tree_util.tree_flatten_with_path(cache)[0]
+          if "cached_" in str(path)]
+    kv_bytes = sum(leaf.dtype.itemsize * leaf.size for leaf in kv)
+    assert compiled.memory_analysis().alias_size_in_bytes >= kv_bytes
+    # a copy of a leaf would carry its element count in some shape
+    elems = {str(leaf.size) for leaf in kv}
+    for ln in text.splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\S* copy\(", ln)
+        if m:
+            n = 1
+            for d in m.group(1).split(","):
+                n *= int(d)
+            assert str(n) not in elems, ln[:200]
+
+
 @pytest.mark.parametrize("cell", sorted(TICK_SHAPES))
 def test_slot_decode_tick_attends_through_the_ragged_kernel(
         sds, monkeypatch, cell):
     """The fixed pool's whole tick on the DEFAULT rule (nothing
-    forced): per layer ONE Mosaic call under the attention's scope in
-    place of the lax walk's `while`, the donated cache still aliased
-    input to output, and no copy of a K or V leaf (the walk under vmap
-    cost two layout copies of each a tick)."""
+    forced): per layer TWO Mosaic calls under the attention's scope -
+    the row-append and the ragged kernel - in place of the scatter's
+    and the lax walk's `while`s, the donated cache still aliased input
+    to output, and no copy of a K or V leaf (the walk under vmap cost
+    two layout copies of each a tick; a defensive copy before the
+    aliased append would cost more than the loops it replaced)."""
     from horovod_tpu.models.transformer import (
         TransformerLM, decode_attention_plan, init_slot_cache,
         serving_params, slot_decode_model, slot_decode_tick)
@@ -305,14 +332,11 @@ def test_slot_decode_tick_attends_through_the_ragged_kernel(
         vec(jnp.float32), sds((lanes, 2), jnp.uint32), vec(bool),
         vec(bool), sds((), jnp.int32)).compile()
     text = compiled.as_text()
+    assert plan.write == "kernel", plan
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
-    assert len(calls) == layers
-    assert all("attn._prefix_attention" in ln for ln in calls)
-    # the only loops left under the attention are the cache write's
-    # (XLA runs a scatter of one row a lane as a loop of updates)
-    loops = re.findall(r' while\(.*op_name="([^"]*)"', text)
-    assert [n for n in loops if "/attn/" in n
-            and "_cache_write" not in n] == []
+    assert len(calls) == 2 * layers     # attention + append
+    assert all("attn._kernel_step" in ln for ln in calls)
+    _no_loop_under_attention(text)
     # the sampling epilogue is one conditional on a scalar, and the
     # vocabulary-wide sort is in a branch of it: the entry computation,
     # which every tick runs, holds none (`sample_lanes`)
@@ -320,20 +344,7 @@ def test_slot_decode_tick_attends_through_the_ragged_kernel(
     entry = entry[:entry.index("\n}")]
     assert " conditional(" in entry
     assert " sort(" not in entry and " sort(" in text
-    kv = [leaf for path, leaf in
-          jax.tree_util.tree_flatten_with_path(cache)[0]
-          if "cached_" in str(path)]
-    kv_bytes = sum(leaf.dtype.itemsize * leaf.size for leaf in kv)
-    assert compiled.memory_analysis().alias_size_in_bytes >= kv_bytes
-    # a copy of a leaf would carry its element count in some shape
-    elems = {str(kv[0].size)}
-    for ln in text.splitlines():
-        m = re.search(r"= \w+\[([\d,]+)\]\S* copy\(", ln)
-        if m:
-            n = 1
-            for d in m.group(1).split(","):
-                n *= int(d)
-            assert str(n) not in elems, ln[:200]
+    _cache_stays_in_place(compiled, text, cache)
 
 
 def test_mixed_tick_attends_both_kinds_through_the_ragged_kernel(
@@ -341,9 +352,10 @@ def test_mixed_tick_attends_both_kinds_through_the_ragged_kernel(
     """A tick over two kinds of softmax layer at `laguna-s-2.1`'s
     shapes (64 lanes; 48 heads over a linear cache of 12288, 72 heads
     over a ring of 512, both on 8 KV heads of 128) on the DEFAULT
-    rule: one Mosaic call a layer, each under its own kind's scope -
-    groups of 6 and of 9 in one program - and both caches still
-    aliased input to output."""
+    rule: the append and the ragged kernel a layer, each under its
+    own kind's scope - groups of 6 and of 9, a linear cache and a ring
+    in one program - no `while` under either, and both caches still
+    aliased input to output with no leaf copied."""
     from horovod_tpu.models.transformer import (
         AttnSpec, TransformerLM, decode_attention_plans, init_slot_cache,
         serving_params, slot_decode_model, slot_decode_tick)
@@ -384,16 +396,15 @@ def test_mixed_tick_attends_both_kinds_through_the_ragged_kernel(
         dec, params, cache, vec(jnp.int32), vec(jnp.float32),
         vec(jnp.float32), sds((lanes, 2), jnp.uint32), vec(bool),
         vec(bool), sds((), jnp.int32)).compile()
-    calls = [ln for ln in compiled.as_text().splitlines()
-             if "tpu_custom_call" in ln]
-    assert len(calls) == 2
-    assert "/block_0/attn/attn._decode_attention" in calls[0]
-    assert "/block_1/swa/swa._decode_attention" in calls[1]
-    kv_bytes = sum(
-        leaf.dtype.itemsize * leaf.size for path, leaf in
-        jax.tree_util.tree_flatten_with_path(cache)[0]
-        if "cached_" in str(path))
-    assert compiled.memory_analysis().alias_size_in_bytes >= kv_bytes
+    assert plans["attn"].write == plans["swa"].write == "kernel", plans
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 4              # a layer: append + attention
+    for scope in ("/block_0/attn/attn._decode_attention/attn._kernel_step",
+                  "/block_1/swa/swa._decode_attention/swa._kernel_step"):
+        assert sum(scope in ln for ln in calls) == 2, scope
+    _no_loop_under_attention(text)
+    _cache_stays_in_place(compiled, text, cache)
 
 
 def test_flash_under_a_four_chip_data_mesh(topo, chip_config,
