@@ -42,6 +42,9 @@ import flax.linen as nn
 
 from horovod_tpu.annotations import hot_path
 from horovod_tpu.parallel.expert import HeldExpertsMoE, MoELayer
+from horovod_tpu.parallel.latent_attention import (
+    LatentAttention, LatentSpec, latent_decode_plan,
+)
 from horovod_tpu.parallel.linear_attention import KDAAttention
 from horovod_tpu.parallel.mesh import (
     AXIS_DATA, AXIS_MODEL, AXIS_SEQ, constrain, use,
@@ -63,10 +66,13 @@ ATTN_IMPLS = ("dot", "blockwise", "flash", "ring", "ring_flash",
 
 # A layer's token mixer (`TransformerLM.layer_kinds`): softmax attention
 # under the flax scope "attn" or - the kind that a model with two kinds
-# of softmax layer gives its sliding-window layers - "swa", and the
-# delta-rule linear attention "kda".
+# of softmax layer gives its sliding-window layers - "swa", the
+# delta-rule linear attention "kda", and latent attention "mla"
+# (`parallel.latent_attention`: a softmax over heads too, but its cache
+# holds head-less latent rows, so it is no member of SOFTMAX_KINDS -
+# nothing that speaks of K/V heads, windows or `AttnSpec` applies).
 SOFTMAX_KINDS = ("attn", "swa")
-LAYER_KINDS = SOFTMAX_KINDS + ("kda",)
+LAYER_KINDS = SOFTMAX_KINDS + ("kda", "mla")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -211,7 +217,26 @@ def _make_norm(kind: str, dtype, eps: float, name: str):
 
 
 class TransformerBlock(nn.Module):
-    """Pre-LN transformer block: TP attention + TP MLP (or EP MoE)."""
+    """Pre-LN transformer block: a token mixer (``mixer``: softmax
+    attention, delta-rule linear attention or latent attention), then
+    an MLP (dense, or one of the two expert layers) - in line::
+
+        x1 = x  + Mixer(ln_attn(x));   y = x1 + FFN(ln_mlp(x1))
+
+    ``shortcut_moe`` (LongCat-Flash's shortcut-connected expert layer)
+    makes the block that model's LAYER instead - two mixers and two
+    dense MLPs in line, and the expert layer computed from the first
+    MLP's normed input but added only at the end, so that nothing
+    between waits for it::
+
+        x1 = x  + Mixer_0(ln_attn_0(x))
+        h  = ln_mlp_0(x1);  s = MoE(h)
+        x2 = x1 + FFN_0(h)
+        x3 = x2 + Mixer_1(ln_attn_1(x2))
+        x4 = x3 + FFN_1(ln_mlp_1(x3));   y = x4 + s
+
+    under the scopes ``<mixer>_0`` / ``<mixer>_1``, ``mlp_0`` /
+    ``mlp_1`` and ``moe``: two caches a block."""
 
     num_heads: int
     head_dim: int
@@ -262,6 +287,11 @@ class TransformerBlock(nn.Module):
     moe_shared_hidden: int = 0
     moe_router: str = "sigmoid"          # see HeldExpertsMoE.router
     moe_scale: float = 1.0
+    moe_zero_experts: int = 0            # HeldExpertsMoE.zero_experts
+    moe_normalize: bool = True           # HeldExpertsMoE.normalize
+    moe_router_bias: Optional[bool] = None
+    latent: Optional[LatentSpec] = None  # the widths of an "mla" mixer
+    shortcut_moe: bool = False           # see the docstring
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -291,15 +321,30 @@ class TransformerBlock(nn.Module):
             S = x.shape[-2]
             pos = jnp.arange(S)
             mask = banded_causal_mask(pos, pos, self.window)[None, None]
-        h = _make_norm(self.norm, self.dtype, self.ln_eps,
-                       "ln_attn")(x)
-        if self.mixer == "kda":
-            h = KDAAttention(
-                num_heads=self.num_heads, head_dim=self.head_dim,
-                out_features=d, norm_eps=self.ln_eps, dtype=self.dtype,
-                decode=self.decode, name="kda")(h)
-        else:
-            h = ParallelSelfAttention(
+
+        def norm(name):
+            return _make_norm(self.norm, self.dtype, self.ln_eps, name)
+
+        def mix(h, name):
+            if self.mixer == "kda":
+                return KDAAttention(
+                    num_heads=self.num_heads, head_dim=self.head_dim,
+                    out_features=d, norm_eps=self.ln_eps,
+                    dtype=self.dtype, decode=self.decode, name=name)(h)
+            if self.mixer == "mla":
+                if self.latent is None:
+                    raise ValueError("an 'mla' mixer needs `latent`, "
+                                     "its LatentSpec")
+                return LatentAttention(
+                    num_heads=self.num_heads, spec=self.latent,
+                    out_features=d, rope_theta=self.rope_theta,
+                    norm_eps=self.ln_eps, dtype=self.dtype,
+                    decode=self.decode,
+                    chunked_prefill=self.chunked_prefill,
+                    decode_prefix_block=self.decode_prefix_block,
+                    decode_prefix_impl=self.decode_prefix_impl,
+                    name=name)(h)
+            return ParallelSelfAttention(
                 num_heads=self.num_heads, head_dim=self.head_dim,
                 num_kv_heads=self.num_kv_heads, pos_emb=self.pos_emb,
                 rope_theta=self.rope_theta, rope=self.rope,
@@ -315,51 +360,68 @@ class TransformerBlock(nn.Module):
                 out_features=(None if d == self.num_heads * self.head_dim
                               else d),
                 lora_rank=self.lora_rank, lora_alpha=self.lora_alpha,
-                name=self.mixer)(h, mask)
-        x = x + h
-        h = _make_norm(self.norm, self.dtype, self.ln_eps,
-                       "ln_mlp")(x)
-        hidden = self.mlp_hidden or self.mlp_ratio * d
-        if self.moe and self.moe_impl == "dropless":
-            h = HeldExpertsMoE(
-                num_experts=self.num_experts,
-                hidden=self.moe_hidden or self.mlp_ratio * d,
-                k=self.moe_k, held=self.moe_held,
-                shared_hidden=self.moe_shared_hidden,
-                router=self.moe_router, scale=self.moe_scale,
-                dtype=self.dtype, name="moe")(h)
-        elif self.moe and self.moe_impl == "gshard":
-            h = MoELayer(num_experts=self.num_experts,
-                         hidden=self.moe_hidden or self.mlp_ratio * d,
-                         k=self.moe_k,
-                         capacity_factor=self.moe_capacity_factor,
-                         dtype=self.dtype, name="moe")(h)
-        elif self.moe:
+                name=name)(h, mask)
+
+        def experts(h):
+            if self.moe_impl == "dropless":
+                return HeldExpertsMoE(
+                    num_experts=self.num_experts,
+                    hidden=self.moe_hidden or self.mlp_ratio * d,
+                    k=self.moe_k, held=self.moe_held,
+                    shared_hidden=self.moe_shared_hidden,
+                    router=self.moe_router, scale=self.moe_scale,
+                    zero_experts=self.moe_zero_experts,
+                    normalize=self.moe_normalize,
+                    router_bias=self.moe_router_bias,
+                    dtype=self.dtype, name="moe")(h)
+            if self.moe_impl == "gshard":
+                return MoELayer(
+                    num_experts=self.num_experts,
+                    hidden=self.moe_hidden or self.mlp_ratio * d,
+                    k=self.moe_k,
+                    capacity_factor=self.moe_capacity_factor,
+                    dtype=self.dtype, name="moe")(h)
             raise ValueError(
                 f"moe_impl must be gshard|dropless, got "
                 f"{self.moe_impl!r}")
-        elif self.mlp_impl in ("swiglu", "geglu"):
-            # Same gated two-projection block; geglu (Gemma) gates
-            # with tanh-gelu instead of silu.
-            h = ParallelSwiGLU(hidden=hidden, out=d,
-                               activation=("gelu_tanh"
-                                           if self.mlp_impl
-                                           == "geglu" else "silu"),
-                               weight_quant=self.weight_quant,
-                               lora_rank=self.lora_rank,
-                               lora_alpha=self.lora_alpha,
-                               dtype=self.dtype, name="mlp")(h)
-        elif self.mlp_impl == "gelu":
-            h = ParallelMLP(hidden=hidden, out=d,
-                            weight_quant=self.weight_quant,
-                            lora_rank=self.lora_rank,
-                            lora_alpha=self.lora_alpha,
-                            dtype=self.dtype, name="mlp")(h)
-        else:
+
+        def dense(h, name):
+            hidden = self.mlp_hidden or self.mlp_ratio * d
+            if self.mlp_impl in ("swiglu", "geglu"):
+                # Same gated two-projection block; geglu (Gemma) gates
+                # with tanh-gelu instead of silu.
+                return ParallelSwiGLU(
+                    hidden=hidden, out=d,
+                    activation=("gelu_tanh" if self.mlp_impl == "geglu"
+                                else "silu"),
+                    weight_quant=self.weight_quant,
+                    lora_rank=self.lora_rank,
+                    lora_alpha=self.lora_alpha,
+                    dtype=self.dtype, name=name)(h)
+            if self.mlp_impl == "gelu":
+                return ParallelMLP(hidden=hidden, out=d,
+                                   weight_quant=self.weight_quant,
+                                   lora_rank=self.lora_rank,
+                                   lora_alpha=self.lora_alpha,
+                                   dtype=self.dtype, name=name)(h)
             raise ValueError(
                 f"mlp_impl must be gelu|swiglu|geglu, got "
                 f"{self.mlp_impl!r}")
-        return x + h
+
+        if self.shortcut_moe:
+            if not self.moe:
+                raise ValueError("shortcut_moe is a block WITH an "
+                                 "expert layer (moe=True)")
+            x = x + mix(norm("ln_attn_0")(x), self.mixer + "_0")
+            h = norm("ln_mlp_0")(x)
+            shortcut = experts(h)
+            x = x + dense(h, "mlp_0")
+            x = x + mix(norm("ln_attn_1")(x), self.mixer + "_1")
+            x = x + dense(norm("ln_mlp_1")(x), "mlp_1")
+            return x + shortcut
+        x = x + mix(norm("ln_attn")(x), self.mixer)
+        h = norm("ln_mlp")(x)
+        return x + (experts(h) if self.moe else dense(h, "mlp"))
 
 
 class TransformerLM(nn.Module):
@@ -426,11 +488,14 @@ class TransformerLM(nn.Module):
     # Width of the residual stream; None = num_heads x head_dim.
     hidden_size: Optional[int] = None
     # Hybrid models: the token mixer of each layer, "attn" | "swa" |
-    # "kda" (`LAYER_KINDS`; len == num_layers); None = "attn"
+    # "kda" | "mla" (`LAYER_KINDS`; len == num_layers); None = "attn"
     # everywhere. A "kda" layer keeps a recurrent state in the decode
     # cache, not K/V (`parallel.linear_attention`); "swa" is a second
-    # kind of softmax layer, under its own scope.
+    # kind of softmax layer, under its own scope; an "mla" layer keeps
+    # head-less latent rows (`parallel.latent_attention`), at the
+    # widths of ``latent``.
     layer_kinds: Optional[Tuple[str, ...]] = None
+    latent: Optional[LatentSpec] = None
     # What a kind of softmax layer has of its own: ((kind, AttnSpec),
     # ...). A kind without an entry takes the model-wide ``num_heads``
     # / ``window`` / ``rope_theta``.
@@ -447,6 +512,13 @@ class TransformerLM(nn.Module):
     moe_shared_hidden: int = 0
     moe_router: str = "sigmoid"     # `HeldExpertsMoE.router`
     moe_scale: float = 1.0          # `HeldExpertsMoE.scale`
+    moe_zero_experts: int = 0       # `HeldExpertsMoE.zero_experts`
+    moe_normalize: bool = True      # `HeldExpertsMoE.normalize`
+    moe_router_bias: Optional[bool] = None
+    # Every layer is LongCat-Flash's: two mixers, two dense MLPs and a
+    # shortcut-connected expert layer (`TransformerBlock.shortcut_moe`;
+    # with ``moe_every=1``) - so a layer has TWO caches.
+    moe_shortcut: bool = False
     # Layers whose MLP stays dense whatever ``moe_every`` says (the
     # published `mlp_only_layers`: a leading dense layer is (0,)).
     mlp_only_layers: Tuple[int, ...] = ()
@@ -489,6 +561,13 @@ class TransformerLM(nn.Module):
                 "a 'swa' layer needs a window: give the kind an "
                 "AttnSpec(window=...) or the model a window")
         return spec
+
+    @property
+    def has_latent_cache(self) -> bool:
+        """True when some layer's decode cache holds latent rows
+        without a head axis (an "mla" layer): appended to like K/V,
+        but with no heads to shard and no block form yet."""
+        return "mla" in (self.layer_kinds or ())
 
     @property
     def has_recurrent_state(self) -> bool:
@@ -597,6 +676,10 @@ class TransformerLM(nn.Module):
                 moe_hidden=self.moe_hidden, moe_held=self.moe_held,
                 moe_shared_hidden=self.moe_shared_hidden,
                 moe_router=self.moe_router, moe_scale=self.moe_scale,
+                moe_zero_experts=self.moe_zero_experts,
+                moe_normalize=self.moe_normalize,
+                moe_router_bias=self.moe_router_bias,
+                latent=self.latent, shortcut_moe=self.moe_shortcut,
                 name=f"block_{i}")(x)
             x = constrain(x, AXIS_DATA, AXIS_SEQ, None)
 
@@ -1196,6 +1279,13 @@ def decode_attention_plan(model: TransformerLM, lanes: int = 1,
     at warm-up and `metrics_snapshot()` carries them."""
     from horovod_tpu.ops import flash_attention
     from horovod_tpu.parallel.tensor import _mesh_is_trivial
+    if kind == "mla" or (kind is None and not model.softmax_kinds
+                         and model.has_latent_cache):
+        # `LatentAttention._kernel_plan`'s question, asked from outside
+        return latent_decode_plan(
+            lanes, model.max_len, model.num_heads, model.latent,
+            itemsize=jnp.dtype(model.dtype or jnp.float32).itemsize,
+            impl=model.decode_prefix_impl)
     if not model.softmax_kinds:
         return flash_attention.DecodePlan("lax", "no softmax layer")
     spec = model.attn_spec(kind or model.softmax_kinds[0])
@@ -1216,9 +1306,12 @@ def decode_attention_plan(model: TransformerLM, lanes: int = 1,
 
 def decode_attention_plans(model: TransformerLM, lanes: int = 1) -> dict:
     """{kind: `decode_attention_plan`} over the model's softmax kinds
-    (one entry for a model without one: the plan that says so)."""
+    and its latent kind (one entry for a model without any: the plan
+    that says so)."""
+    kinds = model.softmax_kinds + (
+        ("mla",) if model.has_latent_cache else ())
     return {kind: decode_attention_plan(model, lanes, kind)
-            for kind in model.softmax_kinds or ("attn",)}
+            for kind in kinds or ("attn",)}
 
 
 def init_slot_cache(model: TransformerLM, num_slots: int):
@@ -1307,14 +1400,25 @@ def slot_reset(dec_model, cache, slot):
         cache)
 
 
+# What a row of `_moe_pairs` holds after the held experts' pairs where
+# the model has identity experts (`HeldExpertsMoE.zero_experts`).
+MOE_ROUTED_COLUMNS = ("moe_zero_pairs", "moe_chosen_pairs")
+
+
 def _moe_pairs(dec_model, mut):
     """What the dropless expert layers sowed in one apply, in layer
     order: int32 [expert layers, experts held], the (token, expert)
     pairs on each held expert; [0, 0] for a model without such a
-    layer."""
+    layer. A model with identity experts (``moe_zero_experts``) gets
+    `MOE_ROUTED_COLUMNS` as two more columns: the pairs on identity
+    experts and all the pairs chosen."""
     sown = mut.get("moe_stats", {})
-    rows = [sown[f"block_{i}"]["moe"]["pairs"]
-            for i in range(dec_model.num_layers) if f"block_{i}" in sown]
+    layers = [sown[f"block_{i}"]["moe"]
+              for i in range(dec_model.num_layers) if f"block_{i}" in sown]
+    # a layer with identity experts adds its two `routed` counts
+    # (`MOE_ROUTED_COLUMNS`) as the row's last columns
+    rows = [jnp.concatenate([m["pairs"], m["routed"]])
+            if "routed" in m else m["pairs"] for m in layers]
     return jnp.stack(rows) if rows else jnp.zeros((0, 0), jnp.int32)
 
 
@@ -1624,6 +1728,12 @@ def paged_cache_spec(model: TransformerLM,
             "paged KV cache requires window=None on every layer (a "
             "rolling-window cache has no block-aligned prefix to page "
             "or share)")
+    if model.has_latent_cache:
+        raise ValueError(
+            "paged KV cache has no block form of a latent-attention "
+            "layer's rows (one head-less leaf a layer; the block pools "
+            "and the paged kernels want a K and a V leaf with a head "
+            "axis)")
     if block_size < 1 or model.max_len % block_size:
         raise ValueError(
             f"block_size must divide max_len={model.max_len} exactly, "

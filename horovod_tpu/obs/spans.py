@@ -133,7 +133,10 @@ SPAN_CATALOG: Dict[str, str] = {
     "sched.tick_sync":
         "Loop span: reading the previous tick's tokens, appending "
         "them and retiring the finished (attrs overlapped, tokens, "
-        "retired)",
+        "retired; for a model with dropless expert layers the tick's "
+        "moe_pairs, moe_expert_load_max, moe_experts_hit, moe_layers, "
+        "moe_prefill_pairs, and with identity experts moe_zero_pairs, "
+        "moe_chosen_pairs)",
     "serving.admission":
         "Queue-head pop to prefill schedule: slot+block admission, "
         "swap restore credit, prefix-cache match",

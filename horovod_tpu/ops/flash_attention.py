@@ -1380,14 +1380,15 @@ def _no_append_tile(W: int, Hkv: int) -> str:
             f"{W} x {Hkv} rows")
 
 
-def _decode_vmem(bk: int, H: int, Hkv: int, D: int, itemsize: int):
+def _decode_vmem(bk: int, H: int, Hkv: int, D: int, itemsize: int,
+                 latent: Optional[int] = None):
     """VMEM the decode kernel's plan sums to: K and V blocks double
-    buffered, q and the output, the float32 accumulator and softmax
-    state, and a step's score tile (scores, mask, probabilities and
-    their cast)."""
+    buffered (a latent cache has the one), q and the output, the
+    float32 accumulator and softmax state, and a step's score tile
+    (scores, mask, probabilities and their cast)."""
     Dp = -(-D // 128) * 128
     rows = -(-H // 8) * 8
-    return (2 * 2 * bk * Hkv * Dp * itemsize
+    return ((1 if latent else 2) * 2 * bk * Hkv * Dp * itemsize
             + 2 * 2 * rows * Dp * itemsize
             + rows * (Dp + 2 * 128) * 4
             + 4 * rows * bk * Hkv * 4)
@@ -1400,7 +1401,8 @@ def decode_attention_plan(lanes: int, W: int, H: int, Hkv: int, D: int,
                           trivial_mesh: bool = True,
                           on_tpu: Optional[bool] = None,
                           block_k: Optional[int] = None,
-                          ring: bool = False) -> DecodePlan:
+                          ring: bool = False,
+                          latent: Optional[int] = None) -> DecodePlan:
     """THE rule for decode attention against the linear cache: the
     ragged kernel (`flash_decode_attention`) for an S = 1 step on an
     un-quantized cache with no serving mesh, at a shape Mosaic takes,
@@ -1414,7 +1416,14 @@ def decode_attention_plan(lanes: int, W: int, H: int, Hkv: int, D: int,
     ring's valid slots are its first min(position + 1, W), and rows
     rotated at their own positions need no order - at the ring's own
     one or two key blocks; "lax" there is the dense
-    [ring ++ block] branch, not a walk."""
+    [ring ++ block] branch, not a walk.
+
+    ``latent`` = Dv: the cache is a latent-attention layer's
+    (`parallel.latent_attention`) - ONE leaf of rows D wide without a
+    head axis (``Hkv`` is 1), whose first Dv columns are the values
+    too. The same kernel takes it with the one operand: a row is read
+    once, for the scores and for the weighted sum. "lax" there is the
+    absorbed walk (`latent_attention.latent_walk`)."""
     if impl not in (None, "lax", "pallas"):
         raise ValueError(
             f"decode_prefix_impl must be None|lax|pallas, got {impl!r}")
@@ -1422,7 +1431,7 @@ def decode_attention_plan(lanes: int, W: int, H: int, Hkv: int, D: int,
         plan = decode_attention_plan(
             lanes, W, H, Hkv, D, itemsize=itemsize, S=S, impl=impl,
             quantized=quantized, trivial_mesh=trivial_mesh,
-            on_tpu=on_tpu, block_k=block_k)
+            on_tpu=on_tpu, block_k=block_k, latent=latent)
         return dataclasses.replace(
             plan, why=f"sliding-window ring of {W} slots: {plan.why}")
     if impl == "lax":
@@ -1446,13 +1455,19 @@ def decode_attention_plan(lanes: int, W: int, H: int, Hkv: int, D: int,
             on_tpu = not _auto_interpret()
         if not on_tpu:
             return DecodePlan("lax", "not on a TPU")
-        if D % 128:
-            return DecodePlan("lax", f"head_dim {D} is not a multiple "
-                              "of 128 lanes")
-    vmem = _decode_vmem(bk, H, Hkv, D, itemsize)
+        if (latent or D) % 128:
+            return DecodePlan(
+                "lax", (f"a latent's value width {latent}" if latent
+                        else f"head_dim {D}")
+                + " is not a multiple of 128 lanes")
+    vmem = _decode_vmem(bk, H, Hkv, D, itemsize, latent)
     rows = _append_rows(W, Hkv, itemsize)
+    why = "forced" if impl else "S = 1 on a TPU"
+    if latent:
+        why += (f", latent rows of {D} read once as keys and as "
+                f"{latent}-wide values")
     return DecodePlan(
-        "kernel", "forced" if impl else "S = 1 on a TPU",
+        "kernel", why,
         block_k=bk, grid=(lanes, W // bk), vmem_bytes=vmem,
         vmem_limit_bytes=vmem if vmem > VMEM_SCOPED_DEFAULT else None,
         write="kernel" if rows else "xla",
@@ -1460,10 +1475,13 @@ def decode_attention_plan(lanes: int, W: int, H: int, Hkv: int, D: int,
                    f"rows" if rows else _no_append_tile(W, Hkv)))
 
 
-def _decode_kernel(s_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *,
-                   scale: float, block_k: int, hkv: int, grp: int):
-    """One (lane, k-block) grid cell of the decode step.
+def _decode_kernel(s_ref, q_ref, k_ref, *rest,
+                   scale: float, block_k: int, hkv: int, grp: int,
+                   latent: Optional[int] = None):
+    """One (lane, k-block) grid cell of the decode step. ``rest`` is
+    (v_ref, o_ref, acc_ref, m_ref, l_ref), without the v_ref for a
+    ``latent`` cache: its values are the first ``latent`` columns of
+    the rows `k_ref` holds, so the block streams in once.
 
     The cache is consumed IN ITS STORED LAYOUT: the leaf [B, W, Hkv, D]
     seen as [B, W*Hkv, D] — position-major rows with the KV heads
@@ -1488,6 +1506,8 @@ def _decode_kernel(s_ref, q_ref, k_ref, v_ref, o_ref,
     follows ITS context, not the cache allocation and not the longest
     context in flight.
     """
+    v_ref = None if latent else rest[0]
+    o_ref, acc_ref, m_ref, l_ref = rest[-4:]
     b = pl.program_id(0)
     j = pl.program_id(1)
     nblk = s_ref[b, 0]
@@ -1505,7 +1525,7 @@ def _decode_kernel(s_ref, q_ref, k_ref, v_ref, o_ref,
         # scaled in q's dtype, as the walk scales it
         q = q_ref[0] * jnp.asarray(scale, q_ref.dtype)      # [H, D]
         kb = k_ref[0]                                  # [bk*Hkv, D]
-        vb = v_ref[0]
+        vb = kb[:, :latent] if latent else v_ref[0]
         s = jax.lax.dot_general(q.astype(kb.dtype), kb, _NT,
                                 preferred_element_type=jnp.float32)
         # column c is key j*bk + c // Hkv of KV head c % Hkv; row r
@@ -1537,15 +1557,18 @@ def _decode_kernel(s_ref, q_ref, k_ref, v_ref, o_ref,
                     ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
-def _flash_decode(q, k_cache, v_cache, lengths, block_k, interpret):
+@functools.partial(jax.jit, static_argnames=("block_k", "interpret",
+                                             "scale", "latent"))
+def _flash_decode(q, k_cache, v_cache, lengths, block_k, interpret,
+                  scale=None, latent=None):
     """The batched pallas_call: q [B, H, D], caches [B, W, Hkv, D],
-    lengths [B] -> [B, H, D]."""
+    lengths [B] -> [B, H, D]; with ``latent`` = Dv there is no
+    `v_cache` (None) and the result is [B, H, Dv]."""
     B, W, Hkv, D = k_cache.shape
     H = q.shape[1]
     plan = decode_attention_plan(
         B, W, H, Hkv, D, itemsize=k_cache.dtype.itemsize,
-        impl="pallas", block_k=block_k)
+        impl="pallas", block_k=block_k, latent=latent)
     if plan.path != "kernel":
         raise ValueError(f"flash_decode_attention: {plan.why}")
     bk = plan.block_k
@@ -1558,33 +1581,37 @@ def _flash_decode(q, k_cache, v_cache, lengths, block_k, interpret):
     def kv_map(b, j, s):
         return (b, jnp.minimum(j, s[b, 0] - 1), 0)
 
+    caches = [c.reshape(B, W * Hkv, D)
+              for c in ((k_cache,) if latent else (k_cache, v_cache))]
+    Dv = latent or D
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=plan.grid,
         in_specs=[
             # index_map args: (*grid_indices, *scalar_prefetch_refs)
             pl.BlockSpec((1, H, D), lambda b, j, s: (b, 0, 0)),
-            pl.BlockSpec((1, bk * Hkv, D), kv_map),
-            pl.BlockSpec((1, bk * Hkv, D), kv_map),
+            *[pl.BlockSpec((1, bk * Hkv, D), kv_map) for _ in caches],
         ],
-        out_specs=pl.BlockSpec((1, H, D), lambda b, j, s: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, Dv), lambda b, j, s: (b, 0, 0)),
         scratch_shapes=[
-            _scratch((H, D), jnp.float32),
+            _scratch((H, Dv), jnp.float32),
             _scratch((H, 128), jnp.float32),
             _scratch((H, 128), jnp.float32),
         ],
     )
     return pl.pallas_call(
-        functools.partial(_decode_kernel, scale=D ** -0.5, block_k=bk,
-                          hkv=Hkv, grp=H // Hkv),
+        functools.partial(_decode_kernel,
+                          scale=D ** -0.5 if scale is None else scale,
+                          block_k=bk, hkv=Hkv, grp=H // Hkv,
+                          latent=latent),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Dv), q.dtype),
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=plan.vmem_limit_bytes),
         interpret=interpret,
-    )(scalars, q, k_cache.reshape(B, W * Hkv, D),
-      v_cache.reshape(B, W * Hkv, D))
+        name="latent_decode" if latent else None,
+    )(scalars, q, *caches)
 
 
 def _slots_into_lanes(x, batched: bool, axis_size: int):
@@ -1597,7 +1624,9 @@ def _slots_into_lanes(x, batched: bool, axis_size: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _make_decode(block_k: Optional[int], interpret: bool):
+def _make_decode(block_k: Optional[int], interpret: bool,
+                 scale: Optional[float] = None,
+                 latent: Optional[int] = None):
     """custom_vmap-wrapped entry (the `_make_paged_decode` pattern):
     under the serving tick's `jax.vmap` over slots the batch rule
     fires and the slot axis JOINS the kernel's lane axis — the cache
@@ -1608,9 +1637,10 @@ def _make_decode(block_k: Optional[int], interpret: bool):
     batched)."""
 
     @jax.custom_batching.custom_vmap
-    def decode(q, k_cache, v_cache, lengths):
-        return _flash_decode(q, k_cache, v_cache, lengths, block_k,
-                            interpret)
+    def decode(q, *caches_and_lengths):     # K and V, or the latent
+        *caches, lengths = caches_and_lengths
+        return _flash_decode(q, caches[0], None if latent else caches[1],
+                             lengths, block_k, interpret, scale, latent)
 
     @decode.def_vmap
     def _rule(axis_size, in_batched, *args):
@@ -1622,9 +1652,12 @@ def _make_decode(block_k: Optional[int], interpret: bool):
 
 
 def flash_decode_attention(q: jax.Array, k_cache: jax.Array,
-                           v_cache: jax.Array, length: jax.Array, *,
+                           v_cache: Optional[jax.Array],
+                           length: jax.Array, *,
                            block_k: Optional[int] = None,
-                           interpret: Optional[bool] = None
+                           interpret: Optional[bool] = None,
+                           scale: Optional[float] = None,
+                           latent: Optional[int] = None
                            ) -> jax.Array:
     """One decode step of attention against the filled cache prefix,
     ragged over the batch.
@@ -1647,45 +1680,60 @@ def flash_decode_attention(q: jax.Array, k_cache: jax.Array,
     oracle, and what `decode_attention_plan` keeps for everything this
     kernel does not take). bf16/f32 caches only (int8 KV uses the
     walk's per-block dequant).
+
+    ``scale``: the softmax scale where it is not D ** -0.5.
+    ``latent`` = Dv: the absorbed step of a latent-attention layer -
+    ``k_cache`` [B, W, 1, D] holds the latent rows, ``v_cache`` is
+    None, the values are the rows' first Dv columns, and the result
+    is [B, 1, H, Dv]: each row is streamed once for both products.
     """
     if interpret is None:
         interpret = _auto_interpret()
     if q.ndim != 4 or q.shape[1] != 1:
         raise ValueError(f"flash_decode_attention wants q [B,1,H,D], "
                          f"got {q.shape}")
+    if (v_cache is None) != bool(latent):
+        raise ValueError("flash_decode_attention: a latent cache has "
+                         "no v_cache, and every other cache has one")
     lengths = jnp.broadcast_to(jnp.asarray(length, jnp.int32),
                                (q.shape[0],))
-    fn = _make_decode(_opt_int(block_k), bool(interpret))
-    return fn(q[:, 0], k_cache, v_cache, lengths)[:, None]
+    fn = _make_decode(_opt_int(block_k), bool(interpret),
+                      None if scale is None else float(scale),
+                      _opt_int(latent))
+    caches = (k_cache,) if latent else (k_cache, v_cache)
+    return fn(q[:, 0], *caches, lengths)[:, None]
 
 
 # ---------------------------------------------------------------------------
 # The decode step's cache write: one new K and V row a lane, in place.
 # ---------------------------------------------------------------------------
 
-def _append_kernel(pos_ref, kc_ref, vc_ref, kn_ref, vn_ref,
-                   ko_ref, vo_ref, *, hkv: int):
-    """One lane's grid cell: the tile of the K cache (and of the V
-    cache) that holds position ``pos`` comes in, the position's `hkv`
-    rows are taken from the new rows instead, and the tile goes back
-    where it came from (the outputs alias the caches). The new rows
-    arrive already repeated down the tile, so the choice is one select
-    on a row index - no row is moved inside the kernel."""
-    rows = kc_ref.shape[1]
+def _append_kernel(pos_ref, *refs, hkv: int):
+    """One lane's grid cell: the tile of each cache (K and V, or a
+    latent layer's one) that holds position ``pos`` comes in, the
+    position's `hkv` rows are taken from the new rows instead, and the
+    tile goes back where it came from (the outputs alias the caches).
+    The new rows arrive already repeated down the tile, so the choice
+    is one select on a row index - no row is moved inside the kernel.
+    ``refs``: the caches' tiles, the new rows', the outputs'."""
+    n = len(refs) // 3
+    rows = refs[0].shape[1]
     first = jax.lax.rem(pos_ref[pl.program_id(0)],
                         jnp.int32(rows // hkv)) * hkv
-    row = jax.lax.broadcasted_iota(jnp.int32, kc_ref.shape[1:], 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, refs[0].shape[1:], 0)
     new = (row >= first) & (row < first + hkv)
-    ko_ref[0] = jnp.where(new, kn_ref[0], kc_ref[0])
-    vo_ref[0] = jnp.where(new, vn_ref[0], vc_ref[0])
+    for c_ref, n_ref, o_ref in zip(refs[:n], refs[n:2 * n], refs[2 * n:]):
+        o_ref[0] = jnp.where(new, n_ref[0], c_ref[0])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _flash_append(k_cache, v_cache, k_new, v_new, pos, interpret):
-    """The batched pallas_call: caches [B, W, Hkv, D], new rows
-    [B, Hkv, D], pos [B] -> both caches, each aliased to its input."""
-    B, W, Hkv, D = k_cache.shape
-    rows = _append_rows(W, Hkv, k_cache.dtype.itemsize)
+def _flash_append(caches, news, pos, interpret):
+    """The batched pallas_call: ``caches`` a tuple of [B, W, Hkv, D]
+    (K and V, or the one leaf of a latent layer), ``news`` their new
+    rows [B, Hkv, D], pos [B] -> the caches, each aliased to its
+    input."""
+    B, W, Hkv, D = caches[0].shape
+    rows = _append_rows(W, Hkv, caches[0].dtype.itemsize)
     if rows is None:
         raise ValueError(
             f"flash_cache_append: {_no_append_tile(W, Hkv)}")
@@ -1703,57 +1751,56 @@ def _flash_append(k_cache, v_cache, k_new, v_new, pos, interpret):
         return jnp.tile(new, (1, per, 1))
 
     flat = (B, W * Hkv, D)
+    n = len(caches)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B,),
-        in_specs=[pl.BlockSpec((1, rows, D), tile),
-                  pl.BlockSpec((1, rows, D), tile),
-                  pl.BlockSpec((1, rows, D), lane),
-                  pl.BlockSpec((1, rows, D), lane)],
-        out_specs=[pl.BlockSpec((1, rows, D), tile),
-                   pl.BlockSpec((1, rows, D), tile)],
+        in_specs=([pl.BlockSpec((1, rows, D), tile)] * n
+                  + [pl.BlockSpec((1, rows, D), lane)] * n),
+        out_specs=[pl.BlockSpec((1, rows, D), tile)] * n,
     )
-    k_out, v_out = pl.pallas_call(
+    outs = pl.pallas_call(
         functools.partial(_append_kernel, hkv=Hkv),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(flat, k_cache.dtype),
-                   jax.ShapeDtypeStruct(flat, v_cache.dtype)],
+        out_shape=[jax.ShapeDtypeStruct(flat, c.dtype) for c in caches],
         # operand 0 is the scalar-prefetched `pos`
-        input_output_aliases={1: 0, 2: 1},
+        input_output_aliases={1 + i: i for i in range(n)},
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(pos, k_cache.reshape(flat), v_cache.reshape(flat),
-      down_the_tile(k_new), down_the_tile(v_new))
-    return k_out.reshape(k_cache.shape), v_out.reshape(v_cache.shape)
+    )(pos, *(c.reshape(flat) for c in caches),
+      *(down_the_tile(x) for x in news))
+    return tuple(o.reshape(c.shape) for o, c in zip(outs, caches))
 
 
 @functools.lru_cache(maxsize=None)
-def _make_append(interpret: bool):
-    """custom_vmap-wrapped entry, `_make_decode`'s twin: under the
-    serving tick's `jax.vmap` over slots the slot axis JOINS the lane
-    axis and the cache leaf [num_slots, 1, W, Hkv, D] is written where
-    it lies, by one call. (The default batching of the call, like that
-    of `lax.dynamic_update_slice` at a batched index, runs the lanes
-    one after another inside a `while`.)"""
+def _make_append(interpret: bool, n: int = 2):
+    """custom_vmap-wrapped entry, `_make_decode`'s twin, for ``n``
+    caches: under the serving tick's `jax.vmap` over slots the slot
+    axis JOINS the lane axis and the cache leaf [num_slots, 1, W, Hkv,
+    D] is written where it lies, by one call. (The default batching of
+    the call, like that of `lax.dynamic_update_slice` at a batched
+    index, runs the lanes one after another inside a `while`.)"""
 
     @jax.custom_batching.custom_vmap
-    def append(k_cache, v_cache, k_new, v_new, pos):
-        return _flash_append(k_cache, v_cache, k_new, v_new, pos,
-                             interpret)
+    def append(*caches_news_pos):
+        return _flash_append(caches_news_pos[:n],
+                             caches_news_pos[n:2 * n],
+                             caches_news_pos[-1], interpret)
 
     @append.def_vmap
     def _rule(axis_size, in_batched, *args):
         outs = append(*(_slots_into_lanes(x, b, axis_size)
                         for x, b in zip(args, in_batched)))
         return tuple(o.reshape((axis_size, -1) + o.shape[1:])
-                     for o in outs), (True, True)
+                     for o in outs), (True,) * n
 
     return append
 
 
-def flash_cache_append(k_cache: jax.Array, v_cache: jax.Array,
-                       k_new: jax.Array, v_new: jax.Array,
+def flash_cache_append(k_cache: jax.Array,
+                       v_cache: Optional[jax.Array],
+                       k_new: jax.Array, v_new: Optional[jax.Array],
                        pos: jax.Array, *,
                        interpret: Optional[bool] = None):
     """Put one decode step's new K and V rows into the caches, in
@@ -1779,6 +1826,10 @@ def flash_cache_append(k_cache: jax.Array, v_cache: jax.Array,
     the model takes this call ("kernel") and where it keeps
     `ParallelSelfAttention._cache_write` ("xla": every path but the
     ragged kernel's, and a shape no tile divides).
+
+    A latent layer's cache is ONE leaf (`parallel.latent_attention`):
+    pass ``v_cache`` and ``v_new`` as None, and the second result is
+    None - the same call with one cache in it.
     """
     if interpret is None:
         interpret = _auto_interpret()
@@ -1787,5 +1838,8 @@ def flash_cache_append(k_cache: jax.Array, v_cache: jax.Array,
                          f"[B,1,Hkv,D], got {k_new.shape}")
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32),
                            (k_cache.shape[0],))
+    if v_cache is None:
+        return (*_make_append(bool(interpret), 1)(
+            k_cache, k_new[:, 0], pos), None)
     return _make_append(bool(interpret))(
         k_cache, v_cache, k_new[:, 0], v_new[:, 0], pos)

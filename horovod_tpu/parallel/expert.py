@@ -214,14 +214,30 @@ class HeldExpertsMoE(nn.Module):
     scores = sigmoid(x W_r) in float32, the k largest of scores + bias
     chosen (the bias, a parameter, takes part in the choice only);
     "softmax" (the Qwen-MoE family's) - scores = softmax(x W_r) over
-    all experts in float32, the k largest chosen, no bias. Either way
-    weights = the chosen scores, normalised to sum to one, times
-    ``scale`` (the published `routed_scaling_factor`). Experts are
-    SwiGLU MLPs of width ``hidden``. Dropless: see `grouped_experts`.
+    all router outputs in float32, the k largest chosen, no bias.
+    ``router_bias`` overrides whether the choice has the bias (None:
+    as above; LongCat's softmax router has one). Weights = the chosen
+    scores, normalised to sum to one unless ``normalize`` is False
+    (the published `norm_topk_prob`), times ``scale`` (the published
+    `routed_scaling_factor`). Experts are SwiGLU MLPs of width
+    ``hidden``. Dropless: see `grouped_experts`.
+
+    ``zero_experts`` (LongCat-Flash's `zero_expert_num`, type
+    "identity"): the router has ``num_experts + zero_experts`` outputs,
+    and an id past the real experts is an expert that returns the
+    layer's own input. They have no weights and need no exchange, so
+    every chip computes them for its own tokens - here for every
+    token: one add of the input times the sum of its weights on such
+    ids, not ``zero_experts`` experts. On an expert-parallel mesh this
+    part must be added ONCE a token (by the token's own chip), while
+    the routed parts are summed over the chips that hold the experts.
+    ``held`` is a range of the real experts.
 
     Sows into the "moe_stats" collection (when the caller makes it
     mutable) `pairs`: the (token, expert) pairs per held expert, int32
-    [count]."""
+    [count]; with ``zero_experts`` also `routed`: int32 [2], the pairs
+    that fell on identity experts - they cost nothing - and all the
+    pairs the tokens chose (tokens x k)."""
 
     num_experts: int
     hidden: int
@@ -231,6 +247,9 @@ class HeldExpertsMoE(nn.Module):
     router: str = "sigmoid"          # | "softmax"
     scale: float = 1.0
     dtype: Any = None
+    zero_experts: int = 0
+    normalize: bool = True
+    router_bias: Optional[bool] = None   # None: sigmoid yes, softmax no
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -246,8 +265,8 @@ class HeldExpertsMoE(nn.Module):
         if self.router not in ("sigmoid", "softmax"):
             raise ValueError(
                 f"router must be sigmoid|softmax, got {self.router!r}")
-        router = self.param("router", init, (d, self.num_experts),
-                            jnp.float32)
+        outputs = self.num_experts + self.zero_experts
+        router = self.param("router", init, (d, outputs), jnp.float32)
 
         def experts(name, shape):
             return self.param(name, nn.with_partitioning(
@@ -262,16 +281,19 @@ class HeldExpertsMoE(nn.Module):
         logits = jnp.matmul(
             xt.astype(jnp.float32), router.astype(jnp.float32),
             precision=lax.Precision.HIGHEST)
-        if self.router == "sigmoid":
-            scores = jax.nn.sigmoid(logits)
+        scores = (jax.nn.sigmoid(logits) if self.router == "sigmoid"
+                  else jax.nn.softmax(logits, axis=-1))
+        biased = (self.router == "sigmoid" if self.router_bias is None
+                  else self.router_bias)
+        if biased:
             bias = self.param("router_bias", nn.initializers.zeros,
-                              (self.num_experts,), jnp.float32)
+                              (outputs,), jnp.float32)
             _, chosen = lax.top_k(scores + bias, self.k)
         else:
-            scores = jax.nn.softmax(logits, axis=-1)
             _, chosen = lax.top_k(scores, self.k)
         weight = jnp.take_along_axis(scores, chosen, axis=-1)
-        weight = weight / weight.sum(-1, keepdims=True)
+        if self.normalize:
+            weight = weight / weight.sum(-1, keepdims=True)
         if self.scale != 1.0:
             weight = weight * self.scale
         local = chosen - first
@@ -284,6 +306,14 @@ class HeldExpertsMoE(nn.Module):
                  reduce_fn=lambda _, new: new, init_fn=lambda: None)
         y = grouped_experts(xt.astype(dtype), key, weight, w_gate, w_up,
                             w_down)
+        if self.zero_experts:
+            zero = chosen >= self.num_experts
+            self.sow("moe_stats", "routed",
+                     jnp.stack([zero.sum(dtype=jnp.int32),
+                                jnp.int32(zero.size)]),
+                     reduce_fn=lambda _, new: new, init_fn=lambda: None)
+            y = y + (jnp.where(zero, weight, 0.0).sum(-1, keepdims=True)
+                     .astype(dtype) * xt.astype(dtype))
         if self.shared_hidden:
             y = y + ParallelSwiGLU(hidden=self.shared_hidden, out=d,
                                    dtype=dtype, name="shared")(

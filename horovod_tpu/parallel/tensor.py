@@ -1049,8 +1049,12 @@ class RopeSpec:
 def apply_rope(x: jax.Array, positions: jax.Array,
                theta: float = 10000.0, *,
                rotary_dim: Optional[int] = None,
-               inv_freq=None, scale: float = 1.0) -> jax.Array:
-    """Rotary position embedding (Su et al. 2021), half-split layout.
+               inv_freq=None, scale: float = 1.0,
+               interleaved: bool = False) -> jax.Array:
+    """Rotary position embedding (Su et al. 2021), half-split layout
+    (``interleaved``: pairs (2j, 2j + 1) instead, the layout of the
+    DeepSeek family's latent attention; the result keeps its pairs
+    where they were).
 
     ``x`` [..., S, H, D] with D even; ``positions`` [S] absolute token
     positions. Rotation is applied before the attention kernel at the
@@ -1075,8 +1079,13 @@ def apply_rope(x: jax.Array, positions: jax.Array,
     sin = jnp.sin(angles)[:, None, :]
     if scale != 1.0:
         cos, sin = cos * scale, sin * scale
-    x1, x2 = x[..., :half], x[..., half:d_r]
-    parts = [x1 * cos - x2 * sin, x1 * sin + x2 * cos]
+    if interleaved:
+        x1, x2 = x[..., 0:d_r:2], x[..., 1:d_r:2]
+        parts = [jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                           axis=-1).reshape(*x.shape[:-1], d_r)]
+    else:
+        x1, x2 = x[..., :half], x[..., half:d_r]
+        parts = [x1 * cos - x2 * sin, x1 * sin + x2 * cos]
     if d_r < x.shape[-1]:
         parts.append(x[..., d_r:].astype(parts[0].dtype))
     return jnp.concatenate(parts, axis=-1).astype(x.dtype)

@@ -83,36 +83,55 @@ _ENGINE_IDS = itertools.count()
 
 
 # What each option does with K/V blocks, and what it would therefore
-# need of a cache that a step does not only append to: a recurrent
-# layer's state (every step OVERWRITES it) and a sliding-window layer's
-# ring (slot = position mod window: later positions overwrite earlier
-# ones in place, and no block-aligned prefix exists).
+# need of a cache that is not K/V rows a step only appends to: a
+# recurrent layer's state (every step OVERWRITES it), a sliding-window
+# layer's ring (slot = position mod window: later positions overwrite
+# earlier ones in place, and no block-aligned prefix exists) and a
+# latent-attention layer's rows (appended to like K/V, but ONE leaf a
+# layer without a head axis: `parallel.latent_attention`). The last
+# column is None where the option runs on a latent pool as it is:
+# speculative decoding rewinds a latent cache by its index, as it
+# rewinds K/V (tests/test_latent_engine.py).
 _NEEDS_APPENDED_KV = {
     "paged": ("the paged pool keeps K/V in blocks",
               "a recurrent state has no block form",
-              "a ring has no block-aligned prefix to page"),
+              "a ring has no block-aligned prefix to page",
+              "the block pools and the paged kernels are laid out "
+              "[blocks, 1, block, KV heads, head] for a K and a V "
+              "leaf, and a latent row is one leaf with no head axis"),
     "prefix_cache": ("the prefix cache shares K/V blocks between "
                      "requests",
                      "a recurrent state has no block form",
                      "a ring holds a request's last window, not its "
-                     "prefix"),
+                     "prefix",
+                     "it lives in the paged pool, which has no block "
+                     "form of the latent row"),
     "spec_draft": ("speculative decoding rewinds rejected positions",
                    "a recurrent state cannot be rewound without a "
                    "snapshot per position",
                    "a ring's overwritten slots cannot be rewound "
-                   "without a snapshot of the rows they held"),
+                   "without a snapshot of the rows they held",
+                   None),
     "swap_bytes": ("swap-preemption shelves K/V blocks on the host",
                    "a recurrent state has no shelved form",
-                   "a ring has no shelved form"),
+                   "a ring has no shelved form",
+                   "it shelves the paged pool's blocks, and there is "
+                   "no block form of the latent row"),
     "transfer": ("disaggregated serving ships K/V blocks between "
                  "pools",
                  "a recurrent state has no transfer form",
-                 "a ring has no transfer form"),
+                 "a ring has no transfer form",
+                 "it ships the paged pool's blocks, and there is no "
+                 "block form of the latent row"),
     "mesh": ("a serving mesh shards the pool's K/V over heads",
              "no serving mesh is defined for the recurrent state and "
              "the held experts",
              "no serving mesh is defined for a model whose kinds of "
-             "layer differ in heads, nor for the ring's kernel"),
+             "layer differ in heads, nor for the ring's kernel",
+             "a latent row has no head axis to shard "
+             "(`shard_slot_cache` splits dim 3, which is the row "
+             "itself), and no serving mesh is defined for the held "
+             "experts"),
 }
 
 
@@ -121,31 +140,44 @@ def _refuse_overwritten_cache(model, **options):
     holds "kda") keeps, beside K/V, a state that every step
     OVERWRITES; a model with a sliding-window layer keeps that layer's
     K/V in a ring whose slots later positions overwrite
-    (`has_rolling_cache`). The fixed slot pool serves both; every path
-    that grafts, exports, pages, shelves or rewinds K/V blocks would
-    need a snapshot form of that state or a block form of that ring,
-    and none exists: refuse loudly and by name rather than run it
-    wrongly (docs/serving.md "Hybrid models", "Mixed attention")."""
-    recurrent, rolling = (model.has_recurrent_state,
-                          model.has_rolling_cache)
-    if not (recurrent or rolling):
+    (`has_rolling_cache`); a model with latent-attention layers
+    ("mla": `has_latent_cache`) keeps head-less rows in one leaf a
+    layer. The fixed slot pool serves all three; every path that
+    grafts, exports, pages, shelves or shards K/V blocks - and, for
+    the first two, rewinds them - would need a snapshot form of that
+    state, a block form of that ring or of that row, and none exists:
+    refuse loudly and by name rather than run it wrongly
+    (docs/serving.md "Hybrid models", "Mixed attention", "Latent
+    attention")."""
+    recurrent, rolling, latent = (model.has_recurrent_state,
+                                  model.has_rolling_cache,
+                                  model.has_latent_cache)
+    if not (recurrent or rolling or latent):
         return
     for name, on in options.items():
         if not on:
             continue
-        does, no_state, no_ring = _NEEDS_APPENDED_KV[name]
+        does, no_state, no_ring, no_latent = _NEEDS_APPENDED_KV[name]
         if recurrent:
             raise ValueError(
                 f"{name}: this model has recurrent (linear-attention) "
                 f"layers, and {does}; {no_state} - missing snapshot "
                 f"form of the recurrent state; serve it from the "
                 f"fixed slot pool (ServingEngine defaults)")
-        raise ValueError(
-            f"{name}: this model has sliding-window layers whose "
-            f"cache is a rolling buffer (ring) of "
-            f"{model.rolling_window} slots, and {does}; {no_ring} - "
-            f"missing block form of the ring; serve it from the "
-            f"fixed slot pool (ServingEngine defaults)")
+        if rolling:
+            raise ValueError(
+                f"{name}: this model has sliding-window layers whose "
+                f"cache is a rolling buffer (ring) of "
+                f"{model.rolling_window} slots, and {does}; {no_ring} "
+                f"- missing block form of the ring; serve it from the "
+                f"fixed slot pool (ServingEngine defaults)")
+        if no_latent is not None:
+            raise ValueError(
+                f"{name}: this model has latent-attention layers "
+                f"whose cache holds rows of {model.latent.row} numbers "
+                f"without a head axis, and {does}; {no_latent} - "
+                f"missing block form of the latent row; serve it from "
+                f"the fixed slot pool (ServingEngine defaults)")
 
 
 def _resolve_serving_mesh(mesh):

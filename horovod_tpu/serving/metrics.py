@@ -182,6 +182,10 @@ class EngineMetrics:
         self.moe_experts_hit = 0
         self.moe_layers_ticks = 0
         self.moe_prefill_pairs = 0
+        # a model with identity experts (`HeldExpertsMoE.zero_experts`):
+        # the decoding lanes' pairs that fell on them, and all they chose
+        self.moe_zero_pairs = 0
+        self.moe_chosen_pairs = 0
         # Bytes of the fixed pool's cache by kind (None = a pool that
         # does not report them).
         self.pool_bytes = None
@@ -325,6 +329,8 @@ class EngineMetrics:
             self.moe_experts_hit += stats["moe_experts_hit"]
             self.moe_layers_ticks += stats["moe_layers"]
             self.moe_prefill_pairs += stats["moe_prefill_pairs"]
+            self.moe_zero_pairs += stats.get("moe_zero_pairs", 0)
+            self.moe_chosen_pairs += stats.get("moe_chosen_pairs", 0)
 
     def observe_pool_bytes(self, by_kind: Dict[str, int]):
         """The fixed pool's cache bytes by kind (constructor-time,
@@ -532,6 +538,8 @@ class EngineMetrics:
                 "moe_experts_hit": self.moe_experts_hit,
                 "moe_layers_ticks": self.moe_layers_ticks,
                 "moe_prefill_pairs": self.moe_prefill_pairs,
+                "moe_zero_pairs": self.moe_zero_pairs,
+                "moe_chosen_pairs": self.moe_chosen_pairs,
                 "pool_bytes": self.pool_bytes,
                 "host_syncs_per_token": (
                     round(self.host_syncs / self.tokens_out, 4)

@@ -50,8 +50,8 @@ import jax.numpy as jnp
 
 from horovod_tpu.annotations import hot_path
 from horovod_tpu.models.transformer import (
-    TransformerLM, decode_attention_plans, init_slot_cache,
-    prefill_chunks, recurrent_leaf, sample_lanes,
+    MOE_ROUTED_COLUMNS, TransformerLM, decode_attention_plans,
+    init_slot_cache, prefill_chunks, recurrent_leaf, sample_lanes,
     shard_slot_cache, slot_decode_model, slot_decode_tick,
     slot_prefill_advance, slot_prefill_chunk, slot_reset,
     slot_spec_round,
@@ -149,10 +149,16 @@ class TickHandle:
     copy already started via `copy_to_host_async`). `tick_sync` turns
     it into the [num_slots] numpy vector."""
 
-    __slots__ = ("toks", "moe_pairs", "moe_prefill_pairs")
+    __slots__ = ("toks", "moe_pairs", "moe_prefill_pairs",
+                 "routed_columns")
 
-    def __init__(self, toks, moe_pairs=None, moe_prefill_pairs=()):
+    def __init__(self, toks, moe_pairs=None, moe_prefill_pairs=(),
+                 routed_columns=()):
         self.toks = toks
+        # names of the counts that the pair arrays' last columns hold
+        # (`models.transformer.MOE_ROUTED_COLUMNS`; () without
+        # identity experts)
+        self.routed_columns = routed_columns
         # int32 [expert layers, experts held] (token, expert) pairs of
         # this tick's decoding lanes (None for a model without a
         # dropless expert layer), and one such array for each prefill
@@ -260,8 +266,9 @@ class SlotPool:
     def decode_attention_plans(self) -> dict:
         """{kind: the plan this pool's ticks compile with (kernel or
         lax, and why)}, one entry for each kind of softmax layer the
-        model has: `models.transformer.decode_attention_plans` under
-        the pool's mesh."""
+        model has, and one for its latent layers:
+        `models.transformer.decode_attention_plans` under the pool's
+        mesh."""
         with self._ctx():
             return decode_attention_plans(self.model, self.num_slots)
 
@@ -315,11 +322,16 @@ class SlotPool:
         rows a lane), `kv_window` (keys and values of the
         sliding-window layers, a ring of `window` rows a lane that
         later positions overwrite in place; 0 for a model without
-        one) and `state` (a recurrent layer's state and convolution
-        tail, overwritten each step; 0 likewise). The fill indices are
+        one), `state` (a recurrent layer's state and convolution
+        tail, overwritten each step; 0 likewise) and - for a model
+        with latent-attention layers only - `latent` (their rows,
+        appended to like K/V but without a head axis, as stored:
+        `LatentSpec.stored` numbers a position). The fill indices are
         not counted."""
         from jax.tree_util import tree_flatten_with_path
         out = {"kv": 0, "kv_window": 0, "state": 0}
+        if self.model.has_latent_cache:
+            out["latent"] = 0
         kinds = self.model.kinds
         rolls = {f"block_{i}" for i, kind in enumerate(kinds)
                  if kind in self.model.softmax_kinds
@@ -329,6 +341,8 @@ class SlotPool:
                 continue
             if recurrent_leaf(path):
                 kind = "state"
+            elif getattr(path[-1], "key", None) == "cached_latent":
+                kind = "latent"
             elif getattr(path[0], "key", None) in rolls:
                 kind = "kv_window"
             else:
@@ -514,7 +528,9 @@ class SlotPool:
         prefill, self._prefill_pairs = self._prefill_pairs, []
         for a in (pairs, *prefill):
             a.copy_to_host_async()
-        return TickHandle(toks, pairs, prefill)
+        return TickHandle(toks, pairs, prefill,
+                          MOE_ROUTED_COLUMNS
+                          if self.model.moe_zero_experts else ())
 
     @staticmethod
     @hot_path
@@ -536,13 +552,23 @@ class SlotPool:
         if handle.moe_pairs is None:
             return None
         pairs = np.asarray(handle.moe_pairs)  # hvd: disable=HVD001(rides the tick's designed sync - the copy started with the tokens')
+        prefill = [np.asarray(a)  # hvd: disable=HVD001(copies started with the tick's, as above)
+                   for a in handle.moe_prefill_pairs]
+        extra = {}
+        if handle.routed_columns:
+            # a model with identity experts: the rows' last columns
+            # are counts of their own (`_moe_pairs`), not experts
+            n = len(handle.routed_columns)
+            extra = dict(zip(handle.routed_columns,
+                             map(int, pairs[:, -n:].sum(axis=0))))
+            pairs = pairs[:, :-n]
+            prefill = [a[:, :-n] for a in prefill]
         return {"moe_pairs": int(pairs.sum()),
                 "moe_expert_load_max": int(pairs.max(axis=1).sum()),
                 "moe_experts_hit": int((pairs > 0).sum()),
                 "moe_layers": int(pairs.shape[0]),
-                "moe_prefill_pairs": sum(
-                    int(np.asarray(a).sum())  # hvd: disable=HVD001(copies started with the tick's, as above)
-                    for a in handle.moe_prefill_pairs)}
+                "moe_prefill_pairs": sum(int(a.sum()) for a in prefill),
+                **extra}
 
     def tick(self) -> np.ndarray:
         """Synchronous tick (dispatch + immediate sync) — the

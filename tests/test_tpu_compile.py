@@ -407,6 +407,79 @@ def test_mixed_tick_attends_both_kinds_through_the_ragged_kernel(
     _cache_stays_in_place(compiled, text, cache)
 
 
+def test_latent_tick_reads_each_row_once_through_the_ragged_kernel(
+        sds, monkeypatch):
+    """A tick over LongCat-Flash's layer at `longcat-flash-chat`'s
+    shapes (64 lanes of 4096 positions; 64 heads over ONE head-less
+    row of 512 + 64, stored 640 wide) on the DEFAULT rule: a layer is
+    two latent sublayers, each the in-place append and the ragged
+    kernel in its latent form - one cache operand, named
+    `latent_decode` - under its own scope; no `while` under either; the
+    cache aliased input to output and NO leaf copied (a 576-wide leaf
+    is stored position-minor by this compiler, and then costs two
+    relayout copies of the whole leaf a sublayer and tick:
+    `parallel.latent_attention`)."""
+    from horovod_tpu.models.transformer import (
+        TransformerLM, decode_attention_plans, init_slot_cache,
+        serving_params, slot_decode_model, slot_decode_tick)
+    from horovod_tpu.ops import flash_attention
+    from horovod_tpu.parallel.latent_attention import LatentSpec
+    from horovod_tpu.parallel.tensor import unbox
+
+    monkeypatch.setattr(flash_attention, "_auto_interpret",
+                        lambda: False)
+    lanes, W = 64, 4096
+    model = TransformerLM(
+        vocab_size=4096, num_layers=1, max_len=W, norm="rmsnorm",
+        mlp_impl="swiglu", mlp_hidden=1024, dtype=jnp.bfloat16,
+        attn_impl="flash", hidden_size=6144, num_heads=64, head_dim=128,
+        pos_emb="rope", rope_theta=1e7, tied_head=False,
+        layer_kinds=("mla",), latent=LatentSpec(
+            q_rank=1536, kv_rank=512, nope_dim=128, rope_dim=64, v_dim=128,
+            q_scale=2.0, kv_scale=12 ** 0.5),
+        moe_every=1, moe_impl="dropless", moe_shortcut=True,
+        num_experts=512, moe_zero_experts=256, moe_k=12, moe_hidden=256,
+        moe_held=(0, 4), moe_router="softmax", moe_router_bias=True,
+        moe_normalize=False, moe_scale=6.0)
+    plan = decode_attention_plans(model, lanes)["mla"]
+    assert (plan.path, plan.grid, plan.write) == (
+        "kernel", (lanes, 16), "kernel"), plan
+    dec = slot_decode_model(model)
+
+    def place(tree):
+        return jax.tree.map(lambda s: sds(s.shape, s.dtype), tree)
+
+    params = place(jax.eval_shape(
+        lambda r: serving_params(unbox(model.init(
+            r, jnp.zeros((1, 64), jnp.int32))["params"])),
+        jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: init_slot_cache(model, lanes)))
+    assert cache["block_0"]["mla_1"]["cached_latent"].shape == (
+        lanes, 1, W, 640)
+    vec = lambda dt: sds((lanes,), dt)  # noqa: E731
+    compiled = slot_decode_tick.lower(
+        dec, params, cache, vec(jnp.int32), vec(jnp.float32),
+        vec(jnp.float32), sds((lanes, 2), jnp.uint32), vec(bool),
+        vec(bool), sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "/mla_" in ln]
+    assert len(calls) == 4              # a sublayer: append + attention
+    for sub in ("mla_0", "mla_1"):
+        scope = f"/block_0/{sub}/{sub}._decode_attention/"
+        mine = [ln for ln in calls if scope in ln]
+        assert len(mine) == 2, sub
+        kernel = [ln for ln in mine if "jit(_flash_decode)/latent_decode"
+                  in ln]
+        assert len(kernel) == 1 and re.search(
+            r"%latent_decode[\w.]* = bf16\[64,64,512\]", kernel[0])
+        # ONE cache operand: scalars, q, the rows
+        assert kernel[0].count("bf16[64,4096,640]") == 1
+    loops = re.findall(r' while\(.*op_name="([^"]*)"', text)
+    assert [n for n in loops if "/mla_" in n] == []
+    _cache_stays_in_place(compiled, text, cache)
+
+
 def test_flash_under_a_four_chip_data_mesh(topo, chip_config,
                                            monkeypatch):
     """The LM's `attn_impl="flash"` inside a GSPMD program over four
