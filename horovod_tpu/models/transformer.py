@@ -1314,6 +1314,29 @@ def decode_attention_plans(model: TransformerLM, lanes: int = 1) -> dict:
             for kind in kinds or ("attn",)}
 
 
+def moe_product_plans(model: TransformerLM, lanes: int = 1,
+                      chunk: int = 1) -> dict:
+    """The way ``model``'s dropless expert layers multiply their
+    (token, expert) pairs, as `parallel.expert.grouped_experts`
+    decides it under the ambient mesh: {"tick": the
+    `ops.grouped_matmul.GroupedPlan` (kernel or `lax.ragged_dot`, and
+    why) of a tick over ``lanes`` slots, "prefill": that of a prompt
+    chunk of ``chunk`` tokens}; {} for a model without such a layer.
+    The engine logs them at warm-up and `metrics_snapshot()` carries
+    them, so a run that fell back to the lax product says so."""
+    from horovod_tpu.parallel.expert import product_plan
+    if model.moe_every <= 0 or model.moe_impl != "dropless":
+        return {}
+    d = model.hidden_size or model.num_heads * model.head_dim
+    held = (model.moe_held or (0, model.num_experts))[1]
+    w_gate = jax.ShapeDtypeStruct(
+        (held, d, model.moe_hidden or model.mlp_ratio * d),
+        jnp.dtype(model.dtype or jnp.float32))
+    routed = model.num_experts + model.moe_zero_experts
+    return {name: product_plan(tokens, model.moe_k, routed, w_gate)
+            for name, tokens in (("tick", lanes), ("prefill", chunk))}
+
+
 def init_slot_cache(model: TransformerLM, num_slots: int):
     """Zero-filled slot-pool cache: each leaf of the B=1 decode cache
     with a leading [num_slots] axis (K/V [num_slots, 1, max_len, Hkv,

@@ -79,13 +79,13 @@ _NN = (((1,), (0,)), ((), ()))      # a · b
 _TN = (((0,), (0,)), ((), ()))      # aᵀ · b
 
 
-def _compiler_params(vmem_bytes: int):
+def _compiler_params(vmem_bytes: int,
+                     semantics=("parallel", "parallel", "parallel",
+                                "arbitrary")):
     kw = {}
     if vmem_bytes > VMEM_SCOPED_DEFAULT:
         kw["vmem_limit_bytes"] = int(vmem_bytes)
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel",
-                             "arbitrary"), **kw)
+    return pltpu.CompilerParams(dimension_semantics=semantics, **kw)
 
 
 def _snap_tile(block: int, S: int) -> int:

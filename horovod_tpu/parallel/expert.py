@@ -17,6 +17,7 @@ over ICI. Two surfaces:
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Callable, Optional, Tuple
 
@@ -153,24 +154,44 @@ def expert_alltoall_combine(expert_outputs: jax.Array,
 # expert-parallel deployment).
 # ---------------------------------------------------------------------------
 
-def _grouped_experts(x, key, weight, w_gate, w_up, w_down):
+def product_plan(tokens, k, routed, w_gate, impl=None):
+    """`ops.grouped_matmul.grouped_product_plan` for ``tokens`` tokens
+    that each choose ``k`` of ``routed`` router outputs, over held
+    experts' weights ``w_gate`` [E, d, f], under the ambient mesh."""
+    from horovod_tpu.ops.grouped_matmul import grouped_product_plan
+    from horovod_tpu.parallel.tensor import _mesh_is_trivial
+    E, d, f = w_gate.shape
+    return grouped_product_plan(
+        tokens, k, routed, d, f, held=E, dtype=w_gate.dtype,
+        trivial_mesh=_mesh_is_trivial(), impl=impl)
+
+
+def _grouped_experts(x, key, weight, w_gate, w_up, w_down, *,
+                     routed=None, impl=None):
     """sum_k weight[t, k] * expert_{key[t, k]}(x[t]) over the E experts
     held (key == E: the chosen expert lives on another chip and adds
     nothing here). x [T, d]; key, weight [T, k]; w_* [E, ., .]. The
     (token, expert) pairs are sorted by expert and go through one
-    grouped matrix product per projection (`lax.ragged_dot`): however
-    uneven the routing, no pair is dropped."""
+    grouped matrix product per projection: however uneven the
+    routing, no pair is dropped. Which product - the weight-streaming
+    kernel for few rows an expert, or `lax.ragged_dot` - is
+    `product_plan`'s to say, from T, k and the ``routed`` router
+    outputs the tokens chose among (None: the E held)."""
+    from horovod_tpu.ops.grouped_matmul import expert_products
     T, k = key.shape
     E = w_gate.shape[0]
+    plan = product_plan(T, k, routed or E, w_gate, impl)
     flat = key.reshape(-1)
     order = jnp.argsort(flat, stable=True)              # held pairs first
     sizes = jnp.sum(flat[:, None] == jnp.arange(E), axis=0,
                     dtype=jnp.int32)
-    xs = jnp.take(x, order // k, axis=0)                # [T k, d]
-    h = (jax.nn.silu(lax.ragged_dot(xs, w_gate, sizes))
-         * lax.ragged_dot(xs, w_up, sizes))
-    y = lax.ragged_dot(h, w_down, sizes)
+    rows = order // k
+    if plan.path == "kernel":       # whole row tiles: no group owns the pad
+        rows = jnp.pad(rows, (0, -(T * k) % plan.rows))
+    xs = jnp.take(x, rows, axis=0)                      # [T k, d]
+    y = expert_products(xs, sizes, w_gate, w_up, w_down, plan)[:T * k]
     held = (flat < E)[order]
+    # a select: the kernel leaves the rows of no group unwritten
     y = jnp.where(held[:, None],
                   y * weight.reshape(-1)[order][:, None].astype(y.dtype),
                   0)
@@ -178,26 +199,60 @@ def _grouped_experts(x, key, weight, w_gate, w_up, w_down):
     return jnp.take(y, back, axis=0).reshape(T, k, -1).sum(1)
 
 
-@jax.custom_batching.custom_vmap
-def grouped_experts(x, key, weight, w_gate, w_up, w_down):
-    return _grouped_experts(x, key, weight, w_gate, w_up, w_down)
+@functools.lru_cache(maxsize=None)
+def _make_grouped(routed, impl):
+    """`grouped_experts` for one pair of its static arguments."""
+    layer = functools.partial(_grouped_experts, routed=routed, impl=impl)
+    merged = jax.custom_batching.custom_vmap(layer)
+
+    @merged.def_vmap
+    def _rule(axis_size, in_batched, x, key, weight, *ws):
+        if any(in_batched[3:]):     # per-lane weights: nothing to merge
+            axes = tuple(0 if b else None for b in in_batched)
+            return jax.vmap(functools.partial(
+                _grouped_experts, routed=routed, impl="lax"), axes)(
+                x, key, weight, *ws), True
+        x, key, weight = (
+            a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+            for a, b in zip((x, key, weight), in_batched[:3]))
+        y = merged(x.reshape(-1, x.shape[-1]),
+                   key.reshape(-1, key.shape[-1]),
+                   weight.reshape(-1, weight.shape[-1]), *ws)
+        return y.reshape(axis_size, -1, y.shape[-1]), True
+
+    # Reverse mode does not pass a `custom_vmap` (nor a Pallas call):
+    # the backward is the lax formula's own, whichever product ran
+    # forward.
+    grouped = jax.custom_vjp(lambda *args: merged(*args))
+
+    def fwd(*args):
+        return merged(*args), args
+
+    def bwd(args, g):
+        x, key, weight, *ws = args
+        _, vjp = jax.vjp(
+            lambda x, weight, *ws: _grouped_experts(
+                x, key, weight, *ws, routed=routed, impl="lax"),
+            x, weight, *ws)
+        dx, dweight, *dws = vjp(g)
+        return (dx, None, dweight, *dws)
+
+    grouped.defvjp(fwd, bwd)
+    return grouped
 
 
-@grouped_experts.def_vmap
-def _grouped_experts_vmap(axis_size, in_batched, x, key, weight, *ws):
-    """A `vmap` over lanes (the serving tick is a vmap of B = 1 applies)
-    hands the layer every lane's tokens at once: one grouped product
-    over all of them, not `axis_size` products of one token each."""
-    if any(in_batched[3:]):         # per-lane weights: nothing to merge
-        axes = tuple(0 if b else None for b in in_batched)
-        return jax.vmap(_grouped_experts, axes)(x, key, weight, *ws), True
-    x, key, weight = (
-        a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
-        for a, b in zip((x, key, weight), in_batched[:3]))
-    y = _grouped_experts(x.reshape(-1, x.shape[-1]),
-                         key.reshape(-1, key.shape[-1]),
-                         weight.reshape(-1, weight.shape[-1]), *ws)
-    return y.reshape(axis_size, -1, y.shape[-1]), True
+def grouped_experts(x, key, weight, w_gate, w_up, w_down, *,
+                    routed=None, impl=None):
+    """`_grouped_experts`, batching itself: a `vmap` over lanes (the
+    serving tick is a vmap of B = 1 applies) hands the layer every
+    lane's tokens at once - one grouped product over all of them, not
+    `axis_size` products of one token each - and the rule for the
+    product sees all of them too. ``impl`` "lax" | "pallas" forces the
+    product's path (the oracle; the kernel, in interpret mode off the
+    chip). Differentiable: the backward is `lax.ragged_dot`'s, on
+    either path."""
+    return _make_grouped(routed, impl)(x, key, weight, w_gate, w_up,
+                                       w_down)
 
 
 class HeldExpertsMoE(nn.Module):
@@ -305,7 +360,7 @@ class HeldExpertsMoE(nn.Module):
         self.sow("intermediates", "chosen", chosen,
                  reduce_fn=lambda _, new: new, init_fn=lambda: None)
         y = grouped_experts(xt.astype(dtype), key, weight, w_gate, w_up,
-                            w_down)
+                            w_down, routed=outputs)
         if self.zero_experts:
             zero = chosen >= self.num_experts
             self.sow("moe_stats", "routed",

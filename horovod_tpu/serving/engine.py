@@ -542,6 +542,14 @@ class ServingEngine:
         self.metrics.observe_decode_attn(plans)
         said = "; ".join(f"{kind}: {plan.describe()}"
                          for kind, plan in plans.items())
+        products = self.pool.moe_product_plans(
+            self.prefill_chunk_budget if self.prefill_chunk_budget > 0
+            else model.max_len)
+        self.metrics.observe_moe_products(products)
+        if products:
+            said += "; expert products: " + "; ".join(
+                f"{name}: {plan.describe()}"
+                for name, plan in products.items())
         if warmup:
             self.warmup_info = self.pool.warmup(
                 max_chunk=(self.prefill_chunk_budget

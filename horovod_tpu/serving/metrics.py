@@ -244,6 +244,12 @@ class EngineMetrics:
         # in the model's order; set once by the engine.
         self.decode_attn_paths = {}
         self.decode_attn_plans = {}
+        # How the dropless expert layers multiply their (token,
+        # expert) pairs ("kernel" | "lax") and the plan in words,
+        # {"tick" | "prefill": ...}; {} for a model without such a
+        # layer; set once by the engine.
+        self.moe_product_paths = {}
+        self.moe_product_plans = {}
         # Latency series (seconds).
         self.queue_wait_s = Series()
         self.ttft_s = Series()
@@ -273,6 +279,14 @@ class EngineMetrics:
             self.decode_attn_paths = {k: p.path
                                       for k, p in plans.items()}
             self.decode_attn_plans = {k: p.describe()
+                                      for k, p in plans.items()}
+
+    def observe_moe_products(self, plans: dict):
+        """{"tick" | "prefill": `ops.grouped_matmul.GroupedPlan`}."""
+        with self._lock:
+            self.moe_product_paths = {k: p.path
+                                      for k, p in plans.items()}
+            self.moe_product_plans = {k: p.describe()
                                       for k, p in plans.items()}
 
     def count(self, name: str, n: int = 1):
@@ -556,6 +570,8 @@ class EngineMetrics:
                     iter(self.decode_attn_plans.values()), None),
                 "decode_attn_paths": dict(self.decode_attn_paths),
                 "decode_attn_plans": dict(self.decode_attn_plans),
+                "moe_product_paths": dict(self.moe_product_paths),
+                "moe_product_plans": dict(self.moe_product_plans),
                 "restarts": self.restarts,
                 "requeued": self.requeued,
                 "faults_injected": self.faults_injected,

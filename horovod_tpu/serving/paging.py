@@ -677,6 +677,12 @@ class PagedSlotPool:
         return use(self.mesh) if self.mesh is not None \
             else contextlib.nullcontext()
 
+    def moe_product_plans(self, chunk: int = 1) -> dict:
+        """`SlotPool.moe_product_plans`' twin."""
+        from horovod_tpu.models.transformer import moe_product_plans
+        with self._ctx():
+            return moe_product_plans(self.model, self.num_slots, chunk)
+
     def _note_shape(self, key):
         if key not in self._seen_shapes:
             self.compiles += 1

@@ -51,7 +51,8 @@ import jax.numpy as jnp
 from horovod_tpu.annotations import hot_path
 from horovod_tpu.models.transformer import (
     MOE_ROUTED_COLUMNS, TransformerLM, decode_attention_plans,
-    init_slot_cache, prefill_chunks, recurrent_leaf, sample_lanes,
+    init_slot_cache, moe_product_plans, prefill_chunks, recurrent_leaf,
+    sample_lanes,
     shard_slot_cache, slot_decode_model, slot_decode_tick,
     slot_prefill_advance, slot_prefill_chunk, slot_reset,
     slot_spec_round,
@@ -271,6 +272,15 @@ class SlotPool:
         mesh."""
         with self._ctx():
             return decode_attention_plans(self.model, self.num_slots)
+
+    def moe_product_plans(self, chunk: int = 1) -> dict:
+        """{"tick" | "prefill": the plan this pool's ticks and its
+        prompt chunks of ``chunk`` tokens multiply their expert pairs
+        with (kernel or `lax.ragged_dot`, and why)}; {} for a model
+        without a dropless expert layer:
+        `models.transformer.moe_product_plans` under the pool's mesh."""
+        with self._ctx():
+            return moe_product_plans(self.model, self.num_slots, chunk)
 
     def _note_shape(self, key):
         if key not in self._seen_shapes:

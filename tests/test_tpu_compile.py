@@ -480,6 +480,106 @@ def test_latent_tick_reads_each_row_once_through_the_ragged_kernel(
     _cache_stays_in_place(compiled, text, cache)
 
 
+# The serving cells' expert layers at their published widths (one
+# layer, a small vocabulary): lanes, cache positions, the tick's row
+# tile, model fields.
+MOE_CELLS = {
+    "solar-open2-250b": (128, 2048, 64, dict(
+        hidden_size=4096, num_heads=64, num_kv_heads=8, head_dim=128,
+        pos_emb="none", attn_gate=True, num_experts=320, moe_k=8,
+        moe_hidden=1280, moe_held=(0, 40), moe_shared_hidden=1280)),
+    "laguna-s-2.1": (64, 12288, 64, dict(
+        hidden_size=3072, num_heads=48, num_kv_heads=8, head_dim=128,
+        pos_emb="rope", attn_gate="head", num_experts=256, moe_k=10,
+        moe_hidden=1024, moe_held=(0, 32), moe_shared_hidden=1024,
+        moe_router="softmax", moe_scale=2.5)),
+    "longcat-flash-chat": (64, 4096, 64, dict(
+        hidden_size=6144, num_heads=64, head_dim=128, pos_emb="rope",
+        rope_theta=1e7, tied_head=False, layer_kinds=("mla",),
+        moe_shortcut=True, num_experts=512, moe_zero_experts=256,
+        moe_k=12, moe_hidden=2048, moe_held=(0, 16),
+        moe_router="softmax", moe_router_bias=True, moe_normalize=False,
+        moe_scale=6.0)),
+}
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk-128", "chunk-1"])
+@pytest.mark.parametrize("cell", sorted(MOE_CELLS))
+def test_expert_layers_stream_their_weights_through_the_kernel(
+        sds, monkeypatch, cell, program):
+    """The three expert cells' ticks and chunk programs (a whole chunk
+    and the tail of one token) on the DEFAULT rule
+    (`grouped_product_plan`): the expert layer's products are TWO
+    Mosaic calls - gate | up fused, and down - under the layer's own
+    scope `block_<i>/moe/`, which is what the benchmark's
+    `moe_share_of_tick` matches; nothing is left of XLA's `ragged-dot`;
+    and no [E, d, f] weight leaf is copied, transposed or re-laid-out
+    on its way into a call (a relayout of the experts would cost more
+    than the product: count what arrives WITH a part)."""
+    from horovod_tpu.models.transformer import (
+        TransformerLM, init_slot_cache, moe_product_plans,
+        serving_params, slot_decode_model, slot_decode_tick,
+        slot_prefill_chunk)
+    from horovod_tpu.ops import flash_attention
+    from horovod_tpu.parallel.latent_attention import LatentSpec
+    from horovod_tpu.parallel.tensor import unbox
+
+    monkeypatch.setattr(flash_attention, "_auto_interpret",
+                        lambda: False)
+    lanes, W, tile, fields = MOE_CELLS[cell]
+    if "mla" in fields.get("layer_kinds", ()):
+        fields = dict(fields, latent=LatentSpec(
+            q_rank=1536, kv_rank=512, nope_dim=128, rope_dim=64,
+            v_dim=128, q_scale=2.0, kv_scale=12 ** 0.5))
+    model = TransformerLM(
+        vocab_size=4096, num_layers=1, max_len=W, norm="rmsnorm",
+        mlp_impl="swiglu", mlp_hidden=1024, dtype=jnp.bfloat16,
+        attn_impl="flash", moe_every=1, moe_impl="dropless", **fields)
+    plans = moe_product_plans(model, lanes, 128)
+    assert {p.path for p in plans.values()} == {"kernel"}, plans
+    assert plans["tick"].rows == tile
+    dec = slot_decode_model(model)
+
+    def place(tree):
+        return jax.tree.map(lambda s: sds(s.shape, s.dtype), tree)
+
+    params = place(jax.eval_shape(
+        lambda r: serving_params(unbox(model.init(
+            r, jnp.zeros((1, 64), jnp.int32))["params"])),
+        jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: init_slot_cache(model, lanes)))
+    if program == "tick":
+        vec = lambda dt: sds((lanes,), dt)  # noqa: E731
+        compiled = slot_decode_tick.lower(
+            dec, params, cache, vec(jnp.int32), vec(jnp.float32),
+            vec(jnp.float32), sds((lanes, 2), jnp.uint32), vec(bool),
+            vec(bool), sds((), jnp.int32)).compile()
+    else:
+        compiled = slot_prefill_chunk.lower(
+            dec, params, cache, sds((), jnp.int32),
+            sds((int(program.split("-")[1]),), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "/block_0/moe/" in ln]
+    assert len(calls) == 2, [ln[:120] for ln in calls]
+    assert sum("%grouped_swiglu" in ln.split(" = ")[0]
+               for ln in calls) == 1
+    assert sum("%grouped_matmul" in ln.split(" = ")[0]
+               for ln in calls) == 1
+    # the experts' leaves arrive as the parameters they are
+    moe = params["block_0"]["moe"]
+    for name in ("w_gate", "w_up", "w_down"):
+        dims = ",".join(str(n) for n in moe[name].shape)
+        made = [ln for ln in text.splitlines()
+                if re.search(rf"= bf16\[{dims}\]\S* (?!parameter\()", ln)]
+        assert made == [], (name, [ln[:160] for ln in made])
+        assert sum(f"bf16[{dims}]" in ln for ln in calls) == 1, name
+    # nothing of an expert leaf's size among the program's temporaries
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < moe["w_gate"].size * 2
+
+
 def test_flash_under_a_four_chip_data_mesh(topo, chip_config,
                                            monkeypatch):
     """The LM's `attn_impl="flash"` inside a GSPMD program over four
