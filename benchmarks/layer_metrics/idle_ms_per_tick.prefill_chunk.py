@@ -1,0 +1,8 @@
+"""The device's idle time per traced tick under `sched.prefill_chunk`
+(`harness/period.py` `idle_ms_per_tick`)."""
+
+from benchmarks.harness import period
+
+
+def read(ctx, part):
+    return period.idle_ms_per_tick(ctx, part)

@@ -39,7 +39,10 @@ profiles (``HVD_PROFILE_DIR`` / `obs.profiler_session`), the
 plane on the same time axis as the device's ops. The ring's
 ``t0_ns``/``t1_ns`` are ``time.time_ns()`` taken around the
 annotation — the profiler's host clock up to a per-session constant
-(an xplane's times count from the session's start).
+(an xplane's times count from the session's start) — and its
+``cpu_ns`` the thread's own CPU time between them
+(``time.thread_time_ns()``): wall less CPU is what the span waited,
+with no profiler session.
 
 Span NAMES are a contract: every ``begin_span``/``record_span``/
 ``loop_span`` literal must appear in `SPAN_CATALOG` (hvdlint HVD012
@@ -108,11 +111,14 @@ SPAN_CATALOG: Dict[str, str] = {
         "latency through retries, hedges and migrations)",
     "sched.admit":
         "Loop span: one admission, queue-head pop to slot reserved "
-        "and reset (attrs slot, prompt_tokens, prefix_cached)",
+        "and reset (attrs slot, prompt_tokens, prefix_cached, "
+        "queue_wait_ms: submit to this reservation, the wait that "
+        "ended in this step)",
     "sched.first_token":
         "Loop span: a drained prefill's first token sampled and read "
         "(the one exposed host sync per request), the lane moved to "
-        "decoding (attr slot)",
+        "decoding (attrs slot, prompt_tokens, chunks: the chunk "
+        "programs its prompt took)",
     "sched.housekeeping":
         "Loop span: queue sweep, dead prefills, tenant preempts and "
         "KV-block grafts at the top of a scheduler step",
@@ -570,14 +576,18 @@ class loop_span:
     (an atomic load when no profiler session runs; with ``step_num``
     a ``StepTraceAnnotation``, which the profiler's step analysis
     reads) and stamps ``t0_ns``; leaving closes it, stamps ``t1_ns``
-    and appends ``(seq, name, t0_ns, t1_ns, parent, attrs)`` to the
-    loop ring - one deque append, no lock. ``parent`` is the ``seq``
-    of the enclosing loop span of this thread (0 at the top). `set`
-    adds attrs known only at the end. Keep ``name`` a literal from
-    `SPAN_CATALOG` (hvdlint HVD012)."""
+    and appends ``(seq, name, t0_ns, t1_ns, parent, attrs, cpu_ns)``
+    to the loop ring - one deque append, no lock. ``parent`` is the
+    ``seq`` of the enclosing loop span of this thread (0 at the top).
+    ``cpu_ns`` is the CPU time THIS thread burned inside the span
+    (``time.thread_time_ns()``, stamped inside the wall stamps): a
+    thread blocked on the device, a lock or the GIL burns none, so
+    ``cpu_ns`` is the thread's own work and ``t1_ns - t0_ns - cpu_ns``
+    what it waited. `set` adds attrs known only at the end. Keep
+    ``name`` a literal from `SPAN_CATALOG` (hvdlint HVD012)."""
 
     __slots__ = ("name", "attrs", "seq", "parent", "t0_ns", "t1_ns",
-                 "_ann")
+                 "cpu_ns", "_ann")
 
     def __init__(self, name: str, *, step_num: Optional[int] = None,
                  **attrs):
@@ -589,7 +599,7 @@ class loop_span:
                                             **attrs)
             attrs["step"] = step_num
         self.attrs = attrs
-        self.t1_ns = 0
+        self.t1_ns = self.cpu_ns = 0
 
     def __enter__(self):
         try:
@@ -600,6 +610,7 @@ class loop_span:
         self.seq = next(_LOOP_SEQ)
         stack.append(self.seq)
         self.t0_ns = time.time_ns()
+        self.cpu_ns = -time.thread_time_ns()
         self._ann.__enter__()
         return self
 
@@ -610,10 +621,11 @@ class loop_span:
 
     def __exit__(self, exc_type, exc, tb):
         self._ann.__exit__(exc_type, exc, tb)
+        self.cpu_ns += time.thread_time_ns()
         self.t1_ns = time.time_ns()
         _LOOP_TLS.stack.pop()
         _LOOP.append((self.seq, self.name, self.t0_ns, self.t1_ns,
-                      self.parent, self.attrs))
+                      self.parent, self.attrs, self.cpu_ns))
         return False
 
 
@@ -621,8 +633,9 @@ def loop_tail(n: Optional[int] = None, *,
               name: Optional[str] = None) -> List[Dict]:
     """The newest ``n`` completed loop spans (all of the ring by
     default), oldest first, optionally only those called ``name``:
-    ``{"seq", "name", "t0_ns", "t1_ns", "parent", "attrs"}``. A span
-    is appended when it closes, so children precede their parent."""
+    ``{"seq", "name", "t0_ns", "t1_ns", "parent", "attrs",
+    "cpu_ns"}``. A span is appended when it closes, so children
+    precede their parent."""
     while True:
         try:
             recs = list(_LOOP)
@@ -634,7 +647,8 @@ def loop_tail(n: Optional[int] = None, *,
     if n is not None:
         recs = recs[-n:] if n > 0 else []
     return [{"seq": r[0], "name": r[1], "t0_ns": r[2], "t1_ns": r[3],
-             "parent": r[4], "attrs": dict(r[5])} for r in recs]
+             "parent": r[4], "attrs": dict(r[5]), "cpu_ns": r[6]}
+            for r in recs]
 
 
 # ---------------------------------------------------------------------------

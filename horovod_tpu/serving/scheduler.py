@@ -103,6 +103,10 @@ class _PrefillJob:
     chunks: List[int]             # remaining chunk token counts
     off: int = 0                  # prompt tokens already streamed
     logits: Any = None
+    n_chunks: int = field(init=False)   # programs the schedule began with
+
+    def __post_init__(self):
+        self.n_chunks = len(self.chunks)
 
 
 @dataclass
@@ -417,7 +421,7 @@ class ContinuousBatchingScheduler:
         self.metrics.count("host_syncs")
         if self.abandoned:
             return   # successor replays from prompts; drop the round
-        multi = False
+        multi, tokens = False, 0
         for slot, req in list(self.active.items()):
             n = int(counts[slot])
             if int(proposed[slot]) > 0:
@@ -434,8 +438,10 @@ class ContinuousBatchingScheduler:
                     break   # retired mid-round; discard the tail
                 tok = int(emitted[slot, j])
                 req.tokens.append(tok)
-                self.metrics.count("tokens_out")
+                tokens += 1
                 self._maybe_retire(slot, req, tok, t_tick)
+        if tokens:      # one count a round, not one a token
+            self.metrics.count("tokens_out", tokens)
         if prop:
             self.metrics.count("spec_proposed", prop)
         if accepted:
@@ -486,9 +492,10 @@ class ContinuousBatchingScheduler:
             tok = int(toks[slot])
             req.tokens.append(tok)
             tokens += 1
-            self.metrics.count("tokens_out")
             self._maybe_retire(slot, req, tok, t_tick)
             retired += self.active.get(slot) is not req
+        if tokens:      # one count a tick, not one a token
+            self.metrics.count("tokens_out", tokens)
         return tokens, retired, moe
 
     # -- admission / chunked prefill ----------------------------------
@@ -550,9 +557,14 @@ class ContinuousBatchingScheduler:
                                 else self._admit(req))
                     if admitted is not None:
                         slot, job = admitted
-                        adm_span.set(slot=slot,
-                                     prompt_tokens=len(job.prompt),
-                                     prefix_cached=job.off)
+                        # the queue wait ended in THIS step: the
+                        # number `observe_request` adds to
+                        # `queue_wait_s` only when the request finishes
+                        adm_span.set(
+                            slot=slot, prompt_tokens=len(job.prompt),
+                            prefix_cached=job.off,
+                            queue_wait_ms=(req.t_prefill
+                                           - req.t_submit) * 1e3)
                 if admitted is None:
                     break
                 progressed = True
@@ -586,7 +598,9 @@ class ContinuousBatchingScheduler:
                 progressed = True
             if job.chunks:
                 break    # budget spent mid-prompt; resume next step
-            with _spans.loop_span("sched.first_token", slot=slot):
+            with _spans.loop_span("sched.first_token", slot=slot,
+                                  prompt_tokens=len(job.prompt),
+                                  chunks=job.n_chunks):
                 self._finish_prefill(slot, job)
             progressed = True
             if left is not None and left <= 0:

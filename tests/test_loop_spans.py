@@ -143,6 +143,73 @@ class TestRing:
             assert spans.SPAN_CATALOG[name].startswith("Loop span:")
 
 
+# One reading of a clock is good to its grain, and the ring stamps the
+# wall clock outside the CPU clock: cpu_ns can pass the wall time by
+# the two clocks' grain, no more.
+CLOCK_GRAIN_NS = 50_000
+
+
+class TestCpuTime:
+    def test_every_record_carries_cpu_ns_within_its_wall_time(self):
+        mark = _last_seq()
+        with spans.loop_span("sched.step", tick=3):
+            with spans.loop_span("sched.housekeeping"):
+                sum(range(20_000))
+            with spans.loop_span("sched.tick_sync", overlapped=False):
+                time.sleep(0.002)
+        with spans.loop_span("engine.bookkeeping"):
+            pass
+        with spans.loop_span("train.step", step_num=1):
+            pass
+        recs = _since(mark)
+        assert len(recs) == 5
+        for r in recs:
+            assert isinstance(r["cpu_ns"], int)
+            assert 0 <= r["cpu_ns"] <= (r["t1_ns"] - r["t0_ns"]
+                                        + CLOCK_GRAIN_NS), r
+        by = {r["name"]: r for r in recs}
+        # a parent's CPU time holds its children's
+        assert by["sched.step"]["cpu_ns"] >= (
+            by["sched.housekeeping"]["cpu_ns"]
+            + by["sched.tick_sync"]["cpu_ns"] - CLOCK_GRAIN_NS)
+
+    def test_a_sleeping_span_burns_no_cpu_and_a_spinning_one_all(self):
+        with spans.loop_span("engine.idle_wait") as slept:
+            time.sleep(0.05)
+        with spans.loop_span("sched.housekeeping") as spun:
+            t0 = time.thread_time_ns()      # 30 ms of this thread's CPU
+            while time.thread_time_ns() - t0 < 30_000_000:
+                pass
+        wall = slept.t1_ns - slept.t0_ns
+        assert wall >= 50_000_000
+        assert slept.cpu_ns < wall / 20     # far under: a twentieth
+        wall = spun.t1_ns - spun.t0_ns
+        assert spun.cpu_ns >= 30_000_000
+        # near its wall time unless the machine took the thread off the
+        # core meanwhile: the CPU clock, not the wall clock, is the one
+        # that does not care
+        assert spun.cpu_ns <= wall + CLOCK_GRAIN_NS
+        rec = spans.loop_tail(1)[0]
+        assert rec["name"] == "sched.housekeeping"
+        assert rec["cpu_ns"] == spun.cpu_ns
+
+    def test_another_threads_work_is_not_this_spans_cpu(self):
+        import threading
+
+        def burn():
+            t0 = time.thread_time_ns()
+            while time.thread_time_ns() - t0 < 20_000_000:
+                pass
+
+        t = threading.Thread(target=burn)
+        with spans.loop_span("sched.tick_sync", overlapped=True) as sp:
+            t.start()
+            t.join(timeout=30)
+        assert not t.is_alive()
+        assert sp.t1_ns - sp.t0_ns >= 20_000_000
+        assert sp.cpu_ns < 10_000_000   # the join waited, it did not work
+
+
 def _host_events(trace_dir):
     """{line name: [(name, start_ns, dur_ns)]} of the host planes."""
     path = max(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
@@ -388,11 +455,18 @@ class TestTickRecord:
             if x["name"] != "sched.step":
                 assert by_seq[x["parent"]]["name"] == "sched.step"
         (admit,) = [x for x in recs if x["name"] == "sched.admit"]
-        assert admit["attrs"] == {"slot": admit["attrs"]["slot"],
-                                  "prompt_tokens": 3, "prefix_cached": 0}
+        assert admit["attrs"] == {
+            "slot": admit["attrs"]["slot"], "prompt_tokens": 3,
+            "prefix_cached": 0,
+            "queue_wait_ms": admit["attrs"]["queue_wait_ms"]}
+        assert 0 <= admit["attrs"]["queue_wait_ms"] < 60_000
         chunks = [x["attrs"]["tokens"] for x in recs
                   if x["name"] == "sched.prefill_chunk"]
         assert sum(chunks) == 3
+        (first,) = [x for x in recs if x["name"] == "sched.first_token"]
+        assert first["attrs"] == {"slot": admit["attrs"]["slot"],
+                                  "prompt_tokens": 3,
+                                  "chunks": len(chunks)}
         syncs = [x["attrs"] for x in recs if x["name"] == "sched.tick_sync"]
         assert sum(s["tokens"] for s in syncs) == 2   # 3 less the first
         assert sum(s["retired"] for s in syncs) == 1
@@ -421,6 +495,157 @@ class TestTickRecord:
         assert (snap["lane_ticks_decoding"] + snap["lane_ticks_prefilling"]
                 + snap["lane_ticks_free"]) == snap["ticks"] * 2
         assert snap["tick_context_positions"] >= 3 * 4
+
+
+def _three_requests(lm, t_submit=None, clock=None):
+    """The script of `test_three_request_run` again: A (2 prompt
+    tokens, 6 new) and B (6, 2) queued, C (1, 2) offered before the
+    third step; five steps in all. `clock(step)` is called before
+    each step (a fake clock moves there). Returns (records of the
+    run, metrics, the three requests)."""
+    import horovod_tpu.serving as sv
+    from horovod_tpu.serving.admission import Request, SamplingParams
+    model, params = lm
+    pool = sv.SlotPool(model, params, 3)
+    queue = sv.AdmissionQueue(4)
+    metrics = sv.EngineMetrics()
+    sched = sv.ContinuousBatchingScheduler(
+        pool, queue, metrics, prefill_chunk_budget=2,
+        pipeline_depth=0)
+    t_submit = t_submit or [time.time()] * 3
+
+    def req(i, n_prompt, steps):
+        return Request(id=i, prompt=np.arange(1, n_prompt + 1),
+                       max_new_tokens=steps, sampling=SamplingParams(),
+                       deadline=None, future=Future(),
+                       t_submit=t_submit[i])
+
+    a, b, c = req(0, 2, 6), req(1, 6, 2), req(2, 1, 2)
+    mark = _last_seq()
+    queue.offer(a)
+    queue.offer(b)
+    for step in range(1, 6):
+        if step == 3:
+            queue.offer(c)
+        if clock is not None:
+            clock(step)
+        sched.step()
+    assert not sched.has_active()
+    assert all(r.future.done() for r in (a, b, c))
+    return _since(mark), metrics, (a, b, c)
+
+
+class TestPeriod:
+    def test_queue_wait_on_the_admit_record_under_a_fake_clock(
+            self, lm, monkeypatch):
+        """`sched.admit` carries the wait that ended in its step:
+        `t_prefill - t_submit`, by hand. A is submitted at 99.5 s and
+        B at 99.25; step 1 runs at 100 s and admits A (500 ms), step
+        2 at 100.25 admits B (1000 ms: B prefills through steps 2-4);
+        C is submitted at 100.5 and admitted by step 5 at 101.125
+        (625 ms)."""
+        from horovod_tpu.serving import scheduler as sched_mod
+
+        class Clock:            # stands in for the module `time`
+            now = 0.0
+            sleep = time.sleep
+
+            def time(self):
+                return self.now
+
+        clock = Clock()
+        monkeypatch.setattr(sched_mod, "time", clock)
+        at = {1: 100.0, 2: 100.25, 3: 100.5, 4: 100.75, 5: 101.125}
+        recs, metrics, reqs = _three_requests(
+            lm, t_submit=[99.5, 99.25, 100.5],
+            clock=lambda step: setattr(clock, "now", at[step]))
+        admits = [x["attrs"] for x in recs if x["name"] == "sched.admit"]
+        assert [a["prompt_tokens"] for a in admits] == [2, 6, 1]
+        assert [a["queue_wait_ms"] for a in admits] == [500.0, 1000.0,
+                                                        625.0]
+        # ... and it is the number the engine's series gets when the
+        # request finishes
+        assert sorted(metrics.queue_wait_s._buf) == [0.5, 0.625, 1.0]
+        firsts = [x["attrs"] for x in recs
+                  if x["name"] == "sched.first_token"]
+        # chunk programs a prompt took at a budget of 2: 2 -> [2],
+        # 6 -> [2, 2, 2], 1 -> [1]
+        assert [(f["prompt_tokens"], f["chunks"]) for f in firsts] == [
+            (2, 1), (6, 3), (1, 1)]
+        chunks = [x["attrs"]["tokens"] for x in recs
+                  if x["name"] == "sched.prefill_chunk"]
+        assert chunks == [2, 2, 2, 2, 1]
+
+    def test_tokens_out_is_counted_once_a_tick_and_adds_up(self, lm):
+        """One `count("tokens_out", n)` a tick gives the counter the
+        per-token calls gave: every generated token, the first ones
+        (sampled by the prefill) included."""
+        recs, metrics, reqs = _three_requests(lm)
+        snap = metrics.snapshot()
+        assert [len(r.tokens) for r in reqs] == [6, 2, 2]
+        assert snap["tokens_out"] == 10
+        assert snap["prefill_first_tokens"] == 3
+        syncs = [x["attrs"]["tokens"] for x in recs
+                 if x["name"] == "sched.tick_sync"]
+        # what the ticks appended, by hand: A alone, A, A, A and B,
+        # A and C
+        assert syncs == [1, 1, 1, 2, 2]
+        assert snap["tokens_out"] == sum(syncs) + 3
+        calls = []
+        count = metrics.count
+        metrics.count = lambda name, n=1: (calls.append((name, n)),
+                                           count(name, n))
+        try:
+            import horovod_tpu.serving as sv
+            from horovod_tpu.serving.admission import (Request,
+                                                       SamplingParams)
+            model, params = lm
+            sched = sv.ContinuousBatchingScheduler(
+                sv.SlotPool(model, params, 2), sv.AdmissionQueue(4),
+                metrics, pipeline_depth=0)
+            two = [Request(id=i, prompt=np.array([3, 5]),
+                           max_new_tokens=3, sampling=SamplingParams(),
+                           deadline=None, future=Future(),
+                           t_submit=time.time()) for i in (7, 8)]
+            for r in two:
+                sched.queue.offer(r)
+            while not all(r.future.done() for r in two):
+                sched.step()
+        finally:
+            metrics.count = count
+        outs = [n for name, n in calls if name == "tokens_out"]
+        # two first tokens one at a time, then two lanes a tick
+        assert outs == [1, 1, 2, 2]
+        assert metrics.snapshot()["tokens_out"] == 10 + 6
+
+    def test_leaf_spans_tile_the_step(self, lm):
+        """Every stretch of a scheduler step lies in a leaf span: over
+        the scripted run the children's wall time is at least 97 % of
+        `sched.step`'s (one more try if the machine took the thread
+        off the core inside the few microseconds between two spans)."""
+        for attempt in range(3):
+            recs, _, _ = _three_requests(lm)
+            steps = {x["seq"]: x for x in recs
+                     if x["name"] == "sched.step"}
+            assert len(steps) == 5
+            whole = sum(x["t1_ns"] - x["t0_ns"] for x in steps.values())
+            leaves = [x for x in recs if x["parent"] in steps]
+            assert {x["name"] for x in leaves} >= {
+                "sched.housekeeping", "sched.admit",
+                "sched.prefill_chunk", "sched.first_token",
+                "sched.tick_dispatch", "sched.tick_sync"}
+            # leaves do not overlap: each starts where one before ended
+            ordered = sorted(leaves, key=lambda x: x["t0_ns"])
+            for before, after in zip(ordered, ordered[1:]):
+                assert before["t1_ns"] <= after["t0_ns"]
+            covered = sum(x["t1_ns"] - x["t0_ns"] for x in leaves)
+            if covered >= 0.97 * whole:
+                break
+        assert covered >= 0.97 * whole, (covered, whole)
+        # the same in CPU time: the thread's own work is in the leaves
+        cpu_whole = sum(x["cpu_ns"] for x in steps.values())
+        cpu_leaves = sum(x["cpu_ns"] for x in leaves)
+        assert cpu_leaves <= cpu_whole + len(leaves) * CLOCK_GRAIN_NS
 
 
 class TestTrainStepSpans:
