@@ -294,7 +294,8 @@ class TransformerBlock(nn.Module):
     shortcut_moe: bool = False           # see the docstring
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array,
+                 advance: Optional[jax.Array] = None) -> jax.Array:
         d = x.shape[-1]
         if self.mixer not in LAYER_KINDS:
             raise ValueError(
@@ -330,7 +331,8 @@ class TransformerBlock(nn.Module):
                 return KDAAttention(
                     num_heads=self.num_heads, head_dim=self.head_dim,
                     out_features=d, norm_eps=self.ln_eps,
-                    dtype=self.dtype, decode=self.decode, name=name)(h)
+                    dtype=self.dtype, decode=self.decode, name=name)(
+                    h, advance)
             if self.mixer == "mla":
                 if self.latent is None:
                     raise ValueError("an 'mla' mixer needs `latent`, "
@@ -601,7 +603,8 @@ class TransformerLM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens: jax.Array,
-                 return_hidden: bool = False) -> Any:
+                 return_hidden: bool = False,
+                 advance: Optional[jax.Array] = None) -> Any:
         if self.pos_emb not in ("learned", "rope", "none"):
             raise ValueError(
                 f"pos_emb must be 'learned', 'rope' or 'none', "
@@ -680,7 +683,10 @@ class TransformerLM(nn.Module):
                 moe_normalize=self.moe_normalize,
                 moe_router_bias=self.moe_router_bias,
                 latent=self.latent, shortcut_moe=self.moe_shortcut,
-                name=f"block_{i}")(x)
+                name=f"block_{i}")(
+                # which lanes a decode step may move: a recurrent
+                # layer's (`KDAAttention`), nobody else's
+                *((x, advance) if kinds[i] == "kda" else (x,)))
             x = constrain(x, AXIS_DATA, AXIS_SEQ, None)
 
         x = _make_norm(self.norm, self.dtype, self.ln_eps,
@@ -1337,6 +1343,21 @@ def moe_product_plans(model: TransformerLM, lanes: int = 1,
             for name, tokens in (("tick", lanes), ("prefill", chunk))}
 
 
+def state_step_plans(model: TransformerLM, lanes: int = 1) -> dict:
+    """The way ``model``'s recurrent layers step their state in an
+    S = 1 tick over ``lanes`` slots, as `KDAAttention` decides it under
+    the ambient mesh: {"kda": the `ops.kda_step.StateStepPlan` (the
+    in-place kernel or `kda_step` as XLA compiles it, and why)}; {}
+    for a model without such a layer. The slot tick's freeze obeys
+    the same plan, the engine logs it at warm-up and
+    `metrics_snapshot()` carries it."""
+    from horovod_tpu.parallel.linear_attention import state_step_plan
+    if not model.has_recurrent_state:
+        return {}
+    return {"kda": state_step_plan(lanes, model.num_heads,
+                                   model.head_dim)}
+
+
 def init_slot_cache(model: TransformerLM, num_slots: int):
     """Zero-filled slot-pool cache: each leaf of the B=1 decode cache
     with a leading [num_slots] axis (K/V [num_slots, 1, max_len, Hkv,
@@ -1588,7 +1609,7 @@ def overwritten_leaf(path) -> bool:
     return "index" in str(path) or recurrent_leaf(path)
 
 
-def _freeze_cache_indices(new_cache, old_cache, advance):
+def _freeze_cache_indices(new_cache, old_cache, advance, kept=()):
     """Select per-leaf between the advanced and the input cache (scalar
     ``advance`` under the tick's vmap): a lane that must not move
     (FREE or mid-prefill slots riding the shared vmapped tick,
@@ -1603,12 +1624,19 @@ def _freeze_cache_indices(new_cache, old_cache, advance):
     rows: with its index frozen at i the ring stands still - the one
     slot the masked lane wrote, i mod window, held position
     i - window, which is outside the band of every position >= i, and
-    the next real writer of position i lands on that slot."""
+    the next real writer of position i lands on that slot.
+
+    ``kept`` names the leaves that the step, told which lanes advance,
+    kept itself (`KDAAttention.KEPT_BY_KERNEL` where the state's step
+    is the in-place kernel): they pass as they are - one more reader
+    of the old value, and XLA copies the leaf before the call that
+    overwrites it."""
     from jax.tree_util import tree_flatten_with_path, tree_unflatten
     flat, treedef = tree_flatten_with_path(new_cache)
     old_leaves = jax.tree.leaves(old_cache)
     out = [jnp.where(advance, leaf, old)
-           if overwritten_leaf(path) else leaf
+           if overwritten_leaf(path)
+           and getattr(path[-1], "key", None) not in kept else leaf
            for (path, leaf), old in zip(flat, old_leaves)]
     return tree_unflatten(treedef, out)
 
@@ -1630,7 +1658,9 @@ def slot_decode_tick(dec_model, params, cache, toks, temps, top_ps,
     * ``live`` [S] bool — host-known active lanes. Non-live lanes
       (FREE or mid-prefill slots) still ride the vmapped step but
       their cache fill indices, and a recurrent layer's state, are
-      FROZEN (`_freeze_cache_indices`), so an idle lane never creeps
+      FROZEN (`_freeze_cache_indices`; where `state_step_plans` says
+      "kernel" the layer is told which lanes advance and its in-place
+      step keeps the state itself), so an idle lane never creeps
       its index — and with it the shared prefix-attention trip count
       every live slot pays for — and a partially prefilled slot's
       next chunk lands exactly where the previous one stopped.
@@ -1652,11 +1682,18 @@ def slot_decode_tick(dec_model, params, cache, toks, temps, top_ps,
       on every path.
     """
 
+    # what the recurrent layers' step keeps itself for a lane that
+    # does not advance (the in-place kernel): never selected after
+    kept = (KDAAttention.KEPT_BY_KERNEL if any(
+        plan.path == "kernel" for plan in state_step_plans(
+            dec_model, toks.shape[0]).values()) else ())
+
     def one(sub, tok, rng, lv, dn):
+        told = {"advance": lv & ~dn} if kept else {}
         (hidden, embed), mut = dec_model.apply(
             {"params": params, "cache": sub}, tok[None, None],
-            return_hidden=True, mutable=["cache", "moe_stats"])
-        new = _freeze_cache_indices(mut["cache"], sub, lv & ~dn)
+            return_hidden=True, mutable=["cache", "moe_stats"], **told)
+        new = _freeze_cache_indices(mut["cache"], sub, lv & ~dn, kept)
         logits = jnp.einsum("d,vd->v", hidden[0, -1],
                             embed.astype(hidden.dtype))
         rng, r = jax.random.split(rng)

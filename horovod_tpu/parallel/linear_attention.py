@@ -31,6 +31,13 @@ appended to: a decode lane that must not advance has to keep its old
 `state` and `conv_tail`. The layer says so itself, in
 `KDAAttention.OVERWRITTEN`, which `models.transformer.overwritten_leaf`
 reads (`_freeze_cache_indices`, and the pool's bytes by kind).
+
+The S = 1 step over a cached state has two executors, chosen by
+`state_step_plan` (`ops.kda_step.kda_step_plan` under the ambient
+mesh): `kda_step` as XLA compiles it, and `ops.kda_step`'s in-place
+kernel - one call over all lanes that also keeps the state of a lane
+whose ``advance`` flag is off, so that nobody has to select it after
+(`KDAAttention.KEPT_BY_KERNEL`).
 """
 
 from __future__ import annotations
@@ -59,6 +66,18 @@ def kda_step(state, q, k, v, g, beta):
     u = v - jnp.sum(s * k[..., None], axis=-2)
     s = s + (beta[..., None] * k)[..., None] * u[..., None, :]
     return jnp.sum(s * q[..., None], axis=-2), s
+
+
+def state_step_plan(lanes: int, num_heads: int, head_dim: int,
+                    positions: int = 1):
+    """`ops.kda_step.kda_step_plan` for a `KDAAttention` of
+    ``num_heads`` heads of ``head_dim`` stepping ``lanes`` lanes by
+    ``positions`` positions, under the ambient mesh."""
+    from horovod_tpu.ops.kda_step import kda_step_plan
+    from horovod_tpu.parallel.tensor import _mesh_is_trivial
+    return kda_step_plan(lanes, num_heads, head_dim, head_dim,
+                         positions=positions,
+                         trivial_mesh=_mesh_is_trivial())
 
 
 def kda_recurrent(state, q, k, v, g, beta):
@@ -169,11 +188,21 @@ class KDAAttention(nn.Module):
     [B, K-1, 3 H D] at the compute dtype): S = 1 runs the recurrence,
     S > 1 the chunkwise form from whatever state the cache holds.
     Zeros are the right initial state. The convolution has `CONV_TAPS`
-    taps and both low-rank gates the rank ``head_dim``."""
+    taps and both low-rank gates the rank ``head_dim``.
+
+    ``advance`` (bool, a scalar or [B]; None: every lane advances)
+    says which lanes a cached S = 1 step may move. Only the kernel's
+    path (`state_step_plan`) looks at it: there the step keeps the
+    `state` of a lane that does not advance itself. Everything else a
+    step overwrites is still the caller's to put back."""
 
     # The cache variables a step overwrites: whoever steps a lane that
     # must not advance has to put the old values back.
     OVERWRITTEN = ("state", "conv_tail")
+    # ... but for these, where `state_step_plan` says "kernel" and the
+    # step was told which lanes advance: reading the old value after
+    # the in-place step would make XLA copy it first.
+    KEPT_BY_KERNEL = ("state",)
 
     num_heads: int
     head_dim: int
@@ -183,7 +212,8 @@ class KDAAttention(nn.Module):
     decode: bool = False
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array,
+                 advance: Optional[jax.Array] = None) -> jax.Array:
         H, D, K = self.num_heads, self.head_dim, CONV_TAPS
         F = H * D
         B, S, _ = x.shape
@@ -225,7 +255,14 @@ class KDAAttention(nn.Module):
 
         q, k = l2(q) * D ** -0.5, l2(k)
         s0 = state.value if cached else jnp.zeros((B, H, D, D), f32)
-        if S == 1:
+        plan = state_step_plan(B, H, D, S)
+        if cached and plan.path == "kernel":
+            from horovod_tpu.ops.kda_step import kda_state_step
+            o, s1 = kda_state_step(s0, q[:, 0], k[:, 0], v[:, 0],
+                                   g[:, 0], beta[:, 0], advance,
+                                   plan=plan)
+            o = o[:, None]
+        elif S == 1:
             o, s1 = kda_step(s0, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
                              beta[:, 0])
             o = o[:, None]

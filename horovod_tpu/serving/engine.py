@@ -550,6 +550,14 @@ class ServingEngine:
             said += "; expert products: " + "; ".join(
                 f"{name}: {plan.describe()}"
                 for name, plan in products.items())
+        # a recurrent state lives in the fixed pool alone (the paged
+        # pool refuses it)
+        steps = {} if self.paged else self.pool.state_step_plans()
+        self.metrics.observe_state_steps(steps)
+        if steps:
+            said += "; state step: " + "; ".join(
+                f"{kind}: {plan.describe()}"
+                for kind, plan in steps.items())
         if warmup:
             self.warmup_info = self.pool.warmup(
                 max_chunk=(self.prefill_chunk_budget

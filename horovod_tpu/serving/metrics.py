@@ -250,6 +250,12 @@ class EngineMetrics:
         # layer; set once by the engine.
         self.moe_product_paths = {}
         self.moe_product_plans = {}
+        # How the recurrent layers' S = 1 ticks step their state
+        # ("kernel" | "lax") and the plan in words, {kind of layer:
+        # ...}; {} for a model without such a layer; set once by the
+        # engine.
+        self.state_step_paths = {}
+        self.state_step_plans = {}
         # Latency series (seconds).
         self.queue_wait_s = Series()
         self.ttft_s = Series()
@@ -288,6 +294,14 @@ class EngineMetrics:
                                       for k, p in plans.items()}
             self.moe_product_plans = {k: p.describe()
                                       for k, p in plans.items()}
+
+    def observe_state_steps(self, plans: dict):
+        """{kind of recurrent layer: `ops.kda_step.StateStepPlan`}."""
+        with self._lock:
+            self.state_step_paths = {k: p.path
+                                     for k, p in plans.items()}
+            self.state_step_plans = {k: p.describe()
+                                     for k, p in plans.items()}
 
     def count(self, name: str, n: int = 1):
         with self._lock:
@@ -572,6 +586,8 @@ class EngineMetrics:
                 "decode_attn_plans": dict(self.decode_attn_plans),
                 "moe_product_paths": dict(self.moe_product_paths),
                 "moe_product_plans": dict(self.moe_product_plans),
+                "state_step_paths": dict(self.state_step_paths),
+                "state_step_plans": dict(self.state_step_plans),
                 "restarts": self.restarts,
                 "requeued": self.requeued,
                 "faults_injected": self.faults_injected,

@@ -52,6 +52,7 @@ from horovod_tpu.annotations import hot_path
 from horovod_tpu.models.transformer import (
     MOE_ROUTED_COLUMNS, TransformerLM, decode_attention_plans,
     init_slot_cache, moe_product_plans, prefill_chunks, recurrent_leaf,
+    state_step_plans,
     sample_lanes,
     shard_slot_cache, slot_decode_model, slot_decode_tick,
     slot_prefill_advance, slot_prefill_chunk, slot_reset,
@@ -281,6 +282,14 @@ class SlotPool:
         `models.transformer.moe_product_plans` under the pool's mesh."""
         with self._ctx():
             return moe_product_plans(self.model, self.num_slots, chunk)
+
+    def state_step_plans(self) -> dict:
+        """{"kda": the plan this pool's ticks step their recurrent
+        layers' state with (the in-place kernel or `kda_step` as XLA
+        compiles it, and why)}; {} for a model without such a layer:
+        `models.transformer.state_step_plans` under the pool's mesh."""
+        with self._ctx():
+            return state_step_plans(self.model, self.num_slots)
 
     def _note_shape(self, key):
         if key not in self._seen_shapes:

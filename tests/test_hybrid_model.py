@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from benchmarks.harness.cells import load_module
 from horovod_tpu.models.transformer import (
     generate, init_slot_cache, slot_decode_model, slot_decode_tick,
-    slot_prefill_chunk,
+    slot_prefill_chunk, state_step_plans,
 )
 from horovod_tpu.parallel.linear_attention import (
     kda_chunked, kda_recurrent,
@@ -31,6 +31,12 @@ with open(os.path.join(REPO, "tests", "benchmark", "tiny",
                        "tiny-solar.json")) as f:
     ARCH = json.load(f)["arch"]     # hidden 64, 2 KDA heads x 16, 16 experts
 MAX_LEN = 64
+# The same period with heads of 128: what `ops.kda_step`'s kernel takes
+# (a [Dk, Dv] state of whole lanes), at two layers. Used under
+# `kernel_path` ONLY, so no program of it is ever traced on the lax
+# path and the jit caches need no clearing.
+ARCH128 = dict(ARCH, head_dim=128, num_layers=2,
+               layer_kinds=["gqa", "kda"])
 
 
 def f32_model(arch=ARCH, **kw):
@@ -41,6 +47,19 @@ def f32_model(arch=ARCH, **kw):
 @pytest.fixture(scope="module")
 def params():
     return A.make_params(ARCH, MAX_LEN, 11, "float32")
+
+
+@pytest.fixture(scope="module")
+def params128():
+    return A.make_params(ARCH128, MAX_LEN, 11, "float32")
+
+
+@pytest.fixture
+def kernel_path(monkeypatch):
+    """`kda_step_plan` as on a TPU: the state's S = 1 step is the
+    in-place kernel (interpret mode here)."""
+    from horovod_tpu.ops import kda_step
+    monkeypatch.setattr(kda_step, "_on_tpu", lambda: True)
 
 
 @pytest.fixture(autouse=True)
@@ -188,19 +207,42 @@ def test_program_equals_reference_full_forward(params):
 
 
 # ---- (c) slots: chunks, ticks, and a tick between another slot's chunks ---
+@pytest.mark.parametrize("path", ["lax", "kernel"])
 def test_slot_chunks_and_ticks_equal_reference_with_an_interleaved_tick(
-        params):
+        path, request):
     """Slot 0 decodes while slot 1's prompt streams in in two chunks
     with a tick between them: the tick must leave slot 1's half-built
     state, convolution tail and fill alone. Every logit - slot 0's
     ticks, slot 1's chunks and its later ticks - is the reference's
-    full forward pass."""
-    model = f32_model()
+    full forward pass. On both executors of the state's step: XLA's
+    `kda_step`, frozen by the tick's select, and the in-place kernel,
+    which keeps the state itself and is never selected after."""
+    arch = ARCH if path == "lax" else ARCH128
+    if path == "kernel":
+        request.getfixturevalue("kernel_path")
+    params = request.getfixturevalue(
+        "params" if path == "lax" else "params128")
+    model = f32_model(arch)
     dec = slot_decode_model(model)
+    assert state_step_plans(dec, 3)["kda"].path == path
     cache = init_slot_cache(model, 3)
     a, b = tokens(21, 1), tokens(30, 2)
-    ref_a = A.logits(ARCH, params, jnp.asarray(a))
-    ref_b = A.logits(ARCH, params, jnp.asarray(b))
+    ref_a = A.logits(arch, params, jnp.asarray(a))
+    ref_b = A.logits(arch, params, jnp.asarray(b))
+    # the tick as traced: the kernel's call a KDA layer and no select
+    # of a state leaf on its path, the select and no call on the other
+    tick_args = (jnp.zeros(3, jnp.int32), jnp.zeros(3), jnp.ones(3),
+                 jnp.stack([jax.random.PRNGKey(i) for i in range(3)]),
+                 jnp.ones(3, bool), jnp.zeros(3, bool), jnp.int32(-1))
+    text = str(jax.make_jaxpr(
+        lambda c: slot_decode_tick(dec, params, c, *tick_args))(cache))
+    H, D = arch["num_heads"], arch["head_dim"]
+    selects = [ln for ln in text.splitlines() if "select_n" in ln
+               and f"f32[3,1,{H},{D},{D}]" in ln.split("=")[0]]
+    kda_layers = arch["layer_kinds"].count("kda")
+    assert text.count("name=kda_step") == (
+        kda_layers if path == "kernel" else 0)
+    assert bool(selects) == (path == "lax")     # (printed once if shared)
 
     def chunk(cache, slot, toks):
         cache, lg, _ = slot_prefill_chunk(dec, params, cache,
@@ -286,6 +328,8 @@ def test_engine_greedy_equals_generate(params):
     assert snap["moe_expert_load_max"] <= snap["moe_pairs"]
     assert snap["moe_prefill_pairs"] > 0
     assert snap["pool_bytes"]["state"] > 0 and snap["pool_bytes"]["kv"] > 0
+    assert snap["state_step_paths"] == {"kda": "lax"}
+    assert "not whole lanes" in snap["state_step_plans"]["kda"]
     from horovod_tpu.obs import spans
     syncs = [r for r in spans.loop_tail(name="sched.tick_sync")
              if "moe_pairs" in r["attrs"]]
@@ -308,6 +352,118 @@ def test_a_dense_model_reports_no_expert_counters():
     assert snap["moe_layers_ticks"] == 0 and snap["moe_pairs"] == 0
     assert snap["pool_bytes"] == {"kv": 2 * 2 * 32 * 2 * 8 * 4,
                                   "kv_window": 0, "state": 0}
+    assert snap["state_step_paths"] == {} == snap["state_step_plans"]
+
+
+def test_engine_on_the_kernels_path_serves_the_lax_streams(
+        params128, request, caplog):
+    """The engine whose ticks step the state through the in-place
+    kernel: the streams `generate` makes on the lax path (computed
+    before the rule is switched), no compile after warm-up, and the
+    plan in the snapshot and in the warm-up log line."""
+    import logging
+    model = f32_model(ARCH128)
+    prompts = [tokens(n, n) for n in (5, 19, 12)]
+    refs = [np.asarray(generate(model, params128, p[None], 6))[0, len(p):]
+            for p in prompts]
+    request.getfixturevalue("kernel_path")
+    with caplog.at_level(logging.INFO, logger="horovod_tpu"):
+        with ServingEngine(model, params128, num_slots=2, warmup=True,
+                           prefill_chunk_budget=8) as eng:
+            outs = [np.asarray(h.result(timeout=300).tokens) for h in
+                    [eng.submit(p, 6) for p in prompts]]
+            snap = eng.metrics_snapshot()
+    for got, want in zip(outs, refs):
+        np.testing.assert_array_equal(got, want)
+    assert snap["compiles"] == 0
+    assert snap["state_step_paths"] == {"kda": "kernel"}
+    assert "2 heads a step, in place" in snap["state_step_plans"]["kda"]
+    assert any("state step: kda: kernel (on a TPU)" in r.getMessage()
+               for r in caplog.records)
+
+
+# ---- (d2) a model without a recurrent layer: the parent's tick -------------
+def _tiny(name, module):
+    mod = load_module(os.path.join(REPO, "benchmarks", "arch", module),
+                      "arch_" + name.replace("-", "_") + "_for_tick_text")
+    with open(os.path.join(REPO, "tests", "benchmark", "tiny",
+                           name + ".json")) as f:
+        return mod.program_model(json.load(f)["arch"], max_len=MAX_LEN,
+                                 attn_impl="dot", dtype="float32")
+
+
+def _dense():
+    from horovod_tpu.models.transformer import TransformerLM
+    return TransformerLM(vocab_size=64, num_layers=2, num_heads=4,
+                         num_kv_heads=2, head_dim=8, max_len=MAX_LEN,
+                         pos_emb="rope", norm="rmsnorm",
+                         mlp_impl="swiglu", dtype=jnp.float32,
+                         attn_impl="dot")
+
+
+@pytest.mark.parametrize("make", [
+    _dense, lambda: _tiny("tiny-laguna", "laguna.py"),
+    lambda: _tiny("tiny-longcat", "longcat.py")],
+    ids=["dense", "window-and-full", "latent"])
+def test_tick_without_a_recurrent_layer_lowers_to_the_select_on_indices(
+        make):
+    """The other serving cells' kinds of model (dense GQA, full +
+    sliding-window layers over held experts, latent sublayers with a
+    shortcut expert layer): `slot_decode_tick` lowers to the text of
+    the tick as it stood before the state's step had a kernel - the
+    apply is told nothing, and the freeze is one `where` on each fill
+    index and on nothing else - so those programs cannot drift."""
+    import functools
+    from jax.tree_util import tree_flatten_with_path, tree_unflatten
+    from horovod_tpu.models import transformer as T
+
+    def before(dec_model, params, cache, toks, temps, top_ps, rngs, live,
+               done, eos):
+        def one(sub, tok, rng, lv, dn):
+            (hidden, embed), mut = dec_model.apply(
+                {"params": params, "cache": sub}, tok[None, None],
+                return_hidden=True, mutable=["cache", "moe_stats"])
+            advance = lv & ~dn
+            flat, treedef = tree_flatten_with_path(mut["cache"])
+            new = tree_unflatten(treedef, [
+                jnp.where(advance, leaf, old) if "index" in str(path)
+                else leaf for (path, leaf), old
+                in zip(flat, jax.tree.leaves(sub))])
+            logits = jnp.einsum("d,vd->v", hidden[0, -1],
+                                embed.astype(hidden.dtype))
+            rng, r = jax.random.split(rng)
+            return (new, logits.astype(jnp.float32), rng, r,
+                    T._moe_pairs(dec_model, mut))
+
+        cache, logits, rngs, keys, pairs = jax.vmap(one)(
+            cache, toks, rngs, live, done)
+        nxt = T.sample_lanes(logits, temps, top_ps, keys).astype(
+            toks.dtype)
+        emit = jnp.where(done, eos.astype(toks.dtype), nxt)
+        decoding = (live & ~done)[:, None, None]
+        return cache, emit, rngs, done | (emit == eos), jnp.sum(
+            jnp.where(decoding, pairs, 0), axis=0)
+
+    before.__name__ = before.__qualname__ = "slot_decode_tick"
+    before = functools.partial(
+        jax.jit, static_argnames=("dec_model",), donate_argnums=(2,))(
+        before)
+    model = make()
+    assert not model.has_recurrent_state
+    assert state_step_plans(model, 3) == {}
+    dec = slot_decode_model(model)
+    shapes = jax.eval_shape(
+        lambda: (dec.init(jax.random.PRNGKey(0),
+                          jnp.zeros((1, MAX_LEN), jnp.int32))["params"],
+                 init_slot_cache(model, 3)))
+    from horovod_tpu.parallel.tensor import unbox
+    args = (unbox(shapes[0]), shapes[1], jnp.zeros(3, jnp.int32),
+            jnp.zeros(3), jnp.ones(3),
+            jnp.stack([jax.random.PRNGKey(i) for i in range(3)]),
+            jnp.ones(3, bool), jnp.zeros(3, bool), jnp.int32(-1))
+    now = slot_decode_tick.lower(dec, *args).as_text()
+    assert "select" in now
+    assert now == before.lower(dec, *args).as_text()
 
 
 # ---- (e) what cannot serve a recurrent state says so ------------------------
