@@ -11,8 +11,9 @@ thread's own CPU time inside a span, beside its wall time
 none (the parent of that PR, the older recordings under
 `benchmarks/data/`) gives every reader here nothing to read: the
 metrics of the period are one family, reported together or not at all.
-`BENCHMARK.json` does not list the family yet (PERF.md §7 says why);
-`tools/period_report.py` reads it from a traced run of a cell.
+`BENCHMARK.json` lists the family for the serving cells (PR 38), and
+`harness/result.py` names the traced run's idle gaps by `idle_split`;
+`tools/period_report.py` reads the family for a cell it does not list.
 
 The measured window is found as `loopspans.window_ticks` finds it: the
 last `ctx["window_ticks"]` decode ticks of the ring, from the start of

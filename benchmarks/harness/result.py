@@ -3,7 +3,21 @@ the cell by name, the device, and the traced run's breakdown."""
 
 import math
 
+from benchmarks.harness import period
 from benchmarks.harness import trace as _trace
+
+
+def idle_gaps(ctx, k=10):
+    """[[name, seconds of the traced window], ...], the `k` largest.
+    Where the program's loop ring splits the gaps over its leaf spans
+    (a serving cell: `period.idle_split`) the names are those spans,
+    each with its own part of every gap; elsewhere each gap goes whole
+    to the host event that overlaps it most (`trace.idle_gaps`)."""
+    split = period.idle_split(ctx)
+    if split is None:
+        return _trace.idle_gaps(ctx["trace"], k)
+    best = sorted(split["by_phase"].items(), key=lambda kv: -kv[1])[:k]
+    return [[name, ms * split["ticks"] / 1e3] for name, ms in best]
 
 
 def build(cell, args, env, out):
@@ -36,7 +50,7 @@ def build(cell, args, env, out):
         device["window_s"] = env.trace_window_s
         line["breakdown"] = {
             "device_ops": _trace.top_ops(env.trace, 10),
-            "idle_gaps": _trace.idle_gaps(env.trace, 10)}
+            "idle_gaps": idle_gaps(ctx)}
     for name, m in metrics.items():
         env.say(f"metric {name} = {m['value']:.6g} {m['unit']}")
     line["metrics"] = metrics

@@ -11,10 +11,11 @@ are printed too), then each reader on the same context: a `metric`
 line each, the readers' own lines (the split by phase, the chunks by
 size, the five idle parts beside the device's idle share, the period
 check) and, last, the result line with the numbers under `period`.
-PR 36 could not enter the family in `BENCHMARK.json` (PERF.md §7 says
-which tests pin its `per_layer` list); until a `benchmark` PR does,
-this is how the numbers of PERF.md §5's table are read. A benchmark
-run never runs this.
+The family is entered in `BENCHMARK.json` for the four serving cells
+(PR 38), so `run.py --trace 1` prints it and the driver's lines carry
+it. This tool is for a run outside the driver, of a serving cell the
+entries do not list (a later PR's, before it enters its cell in their
+`workloads`). A benchmark run never runs this.
 """
 
 import argparse

@@ -23,7 +23,7 @@ from benchmarks.harness.cells import load_module  # noqa: E402
 
 BENCH = os.path.join(REPO, "benchmarks")
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-READERS = ("sched_host_ms_per_tick", "lanes_prefilling_share",
+READERS = ("sched_cpu_ms_per_tick", "lanes_prefilling_share",
            "lanes_free_share", "decode_tick_roofline",
            "train_host_ms_per_step")
 
@@ -86,35 +86,42 @@ def synthetic(offset=1_000_000_000_000, steps=6, tick_ns=60_000_000):
     """`steps` scheduler steps of `tick_ns`, a trace that holds the
     middle ones, one device whose tick programs leave a 2.1 ms gap at
     the start of every step (most of it under sched.first_token) and
-    a 1 ms gap no span covers after the last."""
+    a 1 ms gap no span covers after the last. Every record carries
+    `cpu_ns`, the thread's own work inside it: a step 1 ms (0.3 ms
+    dispatching the tick, 0.5 reading the first token, 0.1 in the
+    sync, the rest its own), the bookkeeping 80 us."""
     ring, host, modules = [], [], []
     t = 5_000_000
     for i in range(steps):
         dur = tick_ns + (i * 37 % 11) * 90_000    # no two alike
         children = [
-            ("sched.housekeeping", t + 1_000, 8_000, {}),
-            ("sched.first_token", t + 100_000, 2_000_000, {"slot": i}),
-            ("sched.tick_dispatch", t + 2_200_000, 300_000,
+            ("sched.housekeeping", t + 1_000, 8_000, 8_000, {}),
+            ("sched.first_token", t + 100_000, 2_000_000, 500_000,
+             {"slot": i}),
+            ("sched.tick_dispatch", t + 2_200_000, 300_000, 300_000,
              {"lanes_decoding": 3, "lanes_prefilling": 1,
               "lanes_free": 0, "queue_depth": 0,
               "context_sum": 1000 + i, "context_max": 500}),
-            ("sched.tick_sync", t + 2_600_000, dur - 2_700_000,
+            ("sched.tick_sync", t + 2_600_000, dur - 2_700_000, 100_000,
              {"overlapped": True, "tokens": 3, "retired": 0}),
         ]
-        for name, s, d, attrs in children:
+        step_seq = len(ring) + len(children) + 1    # appended last
+        for name, s, d, cpu, attrs in children:
             ring.append({"seq": len(ring) + 1, "name": name,
                          "t0_ns": s + offset, "t1_ns": s + d + offset,
-                         "parent": 0, "attrs": attrs})
+                         "parent": step_seq, "attrs": attrs,
+                         "cpu_ns": cpu})
             if 0 < i < steps - 1 and d >= 20_000:
                 host.append([name, s, d])
-        ring.append({"seq": len(ring) + 1, "name": "sched.step",
+        ring.append({"seq": step_seq, "name": "sched.step",
                      "t0_ns": t + offset - 3_000,
                      "t1_ns": t + dur + offset + 2_000,
-                     "parent": 0, "attrs": {"tick": i}})
+                     "parent": 0, "attrs": {"tick": i},
+                     "cpu_ns": 1_000_000})
         ring.append({"seq": len(ring) + 1, "name": "engine.bookkeeping",
                      "t0_ns": t + dur + offset + 10_000,
                      "t1_ns": t + dur + offset + 110_000,
-                     "parent": 0, "attrs": {}})
+                     "parent": 0, "attrs": {}, "cpu_ns": 80_000})
         if 0 < i < steps - 1:
             host.append(["sched.step", t, dur])
             host.append(["engine.bookkeeping", t + dur + 10_000, 100_000])
@@ -157,9 +164,9 @@ def test_readers_on_a_synthetic_run():
            "lanes_live_share": 0.74,
            "cell": types.SimpleNamespace(
                config={"arch": arch_of("qwen2.5-1.5b")})}
-    # per tick: step (dur + 5 us) + bookkeeping 100 us - sync
-    # (dur - 2.7 ms) = 2.805 ms
-    assert reader("sched_host_ms_per_tick")(ctx) == pytest.approx(2.805)
+    # the measured window's three steps: 1 ms of the thread's own work
+    # in each and 80 us in the bookkeeping after it
+    assert reader("sched_cpu_ms_per_tick")(ctx) == pytest.approx(1.08)
     assert reader("lanes_prefilling_share")(ctx) == pytest.approx(25.0)
     assert reader("lanes_free_share")(ctx) == pytest.approx(0.0)
     roof = reader("decode_tick_roofline")(ctx)
@@ -224,8 +231,11 @@ def test_readers_return_none_on_an_empty_input(name, what):
 # ---- every reader on a recorded run ------------------------------------
 # `loop_tiny_*_cpu`: the rehearsal cells (tests/benchmark/tiny) on the
 # CPU - host plane only, so what needs a device reads nothing there.
-# The others: the benchmark's cells on the chip.
-SERVE_RUNS = ["loop_tiny_serve_cpu.json.gz", "loop_serve_closed32.json.gz"]
+# The others: the benchmark's cells on the chip; `loop_period_closed32`
+# is the one whose ring has `cpu_ns` (PR 36), which the thread's own
+# work a tick is read from.
+SERVE_RUNS = ["loop_tiny_serve_cpu.json.gz", "loop_serve_closed32.json.gz",
+              "loop_period_closed32.json.gz"]
 TRAIN_RUNS = ["loop_tiny_train_cpu.json.gz", "loop_train_1chip.json.gz"]
 
 
@@ -233,13 +243,17 @@ TRAIN_RUNS = ["loop_tiny_train_cpu.json.gz", "loop_train_1chip.json.gz"]
 def test_serve_readers_on_a_recorded_run(name):
     rec, ctx = recorded(name)
     on_chip = bool(rec["trace"]["devices"])
+    has_cpu = all("cpu_ns" in x for x in rec["loop_ring"])
     for metric in READERS[:4]:
         got = reader(metric)(ctx)
         if metric in rec["metrics"]:
             assert got == pytest.approx(
                 rec["metrics"][metric]["value"], rel=1e-9), metric
+        elif metric == "sched_cpu_ms_per_tick":     # an older ring
+            assert got is None and not has_cpu
         else:
             assert got is None and not on_chip, metric
+    assert ("sched_cpu_ms_per_tick" in rec["metrics"]) == has_cpu
     assert reader("train_host_ms_per_step")(ctx) is None
     found = loopspans.traced(ctx)
     assert found["pairs"] >= 10

@@ -273,6 +273,32 @@ def test_spans_under_the_traces_floor_are_kept():
     assert split["parts"]["other"] == pytest.approx(6.8 / 4)   # still
 
 
+def test_the_result_lines_idle_gaps_name_the_phases():
+    """`breakdown.idle_gaps` of a traced serving run: the leaf spans,
+    each with its part of every gap in seconds of the traced window;
+    without a split (a train cell, a CPU's trace, a ring without
+    `cpu_ns`) each gap goes whole to one host event, as before."""
+    from benchmarks.harness import result
+    ctx = context()
+    gaps = result.idle_gaps(ctx)
+    assert [name for name, _ in gaps[:2]] == [period.OWN,
+                                              "sched.first_token"]
+    assert dict(gaps) == pytest.approx({
+        period.OWN: 6.8e-3, "sched.first_token": 4.0e-3,
+        "sched.tick_dispatch": 1.2e-3, "sched.prefill_chunk": 0.8e-3,
+        "sched.admit": 0.2e-3})
+    assert sum(v for _, v in gaps) == pytest.approx(13.0e-3)
+    assert len(result.idle_gaps(ctx, k=2)) == 2
+    whole = _trace.idle_gaps(ctx["trace"], 10)
+    assert whole and {name for name, _ in whole} != set(dict(gaps))
+    for over in ({"traced_ticks": None}, {"loop_ring": []}):
+        assert result.idle_gaps(context(**over)) == whole
+    ctx = context()
+    for x in ctx["loop_ring"]:
+        del x["cpu_ns"]
+    assert result.idle_gaps(ctx) == whole
+
+
 # ---- nothing to read ---------------------------------------------------
 @pytest.mark.parametrize("name", READERS)
 @pytest.mark.parametrize("what", ["no_trace", "no_ring", "no_cpu_ns",
@@ -339,27 +365,31 @@ def test_readers_on_the_recorded_run(capsys):
 
 
 # ---- the family's files, and the tool that reads them -------------------
-def test_the_family_is_twelve_file_pairs_ready_to_be_entered():
-    """`BENCHMARK.json` does not list the family yet (the older
-    rehearsal tests pin its `per_layer` list: PERF.md §7); each pair
-    holds what an entry needs, under a layer the benchmark names, and
-    the harness finds its reader by name as it finds an entered one."""
-    from benchmarks.harness.cells import Cell
+def test_the_family_is_entered_for_the_serving_cells():
+    """`BENCHMARK.json` lists the family (PR 38) for the four serving
+    cells in the order the benchmark lists them; more waiting and more
+    tokens a chunk program are better, less of everything else. (That
+    each entry agrees with its file pair and finds its reader is
+    `test_benchmark_entries.py`'s, as for every entry.)"""
     assert period.METRICS == READERS
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    layers = {m["layer"] for m in bench["per_layer"]}
-    cell = Cell(SERVING[0])
+    assert tuple(w["name"] for w in bench["workloads"]
+                 if w["name"] in SERVING) == SERVING
+    entries = {m["name"]: m for m in bench["per_layer"]}
     for name in READERS:
-        with open(os.path.join(BENCH, "layer_metrics",
-                               name + ".json")) as f:
-            spec = json.load(f)
-        assert spec["moves"] == "serve_tokens_per_s"
-        assert spec["layer"] in layers
-        assert spec["source"] in ("program_span", "program_counter",
-                                  "device_trace")
-        assert spec["unit"] and spec["what"]
-        assert cell.reader(name)({"trace": None}) is None
+        entry = entries[name]
+        # the four, in that order; a later cell may stand behind them
+        assert [c for c in entry["workloads"]
+                if c in SERVING] == list(SERVING)
+        assert entry["moves"] == "serve_tokens_per_s"
+        assert entry["better"] == ("higher" if name in (
+            "sched_wait_ms_per_tick", "prefill_tokens_per_chunk")
+            else "lower")
+    # the metric the family supersedes is gone, file pair and entry
+    assert "sched_host_ms_per_tick" not in entries
+    assert not os.path.exists(os.path.join(
+        BENCH, "layer_metrics", "sched_host_ms_per_tick.json"))
 
 
 def test_the_report_tool_reads_the_family_from_a_traced_run(tmp_path):
@@ -401,5 +431,6 @@ def test_the_report_tool_reads_the_family_from_a_traced_run(tmp_path):
     assert "leaf spans cover" in text
     assert "period idle_ms_per_tick.other: nothing to read" in text
     assert "period chunk_device_ms_per_tick: nothing to read" in text
-    # the family is not entered: the run's own metrics hold none of it
-    assert not set(line["metrics"]) & set(READERS)
+    # the family is entered: the run's own metrics hold the same
+    assert {k: line["metrics"][k] for k in got} == got
+    assert set(line["metrics"]) & set(READERS) == set(got)

@@ -5,7 +5,6 @@ as files. Nothing here is a measurement: a CPU's numbers are never
 written under a device metric's name.
 """
 
-import hashlib
 import json
 import os
 import sys
@@ -102,15 +101,15 @@ def test_without_a_tpu_no_result_line(copy):
     assert "no accelerator" in err
 
 
-def _digest(root):
-    h = {}
+def _files(root):
+    """{relative path: bytes} of every file under `root`."""
+    held = {}
     for d, _, files in os.walk(root):
         for f in files:
             p = os.path.join(d, f)
             with open(p, "rb") as fh:
-                h[os.path.relpath(p, root)] = hashlib.sha256(
-                    fh.read()).hexdigest()
-    return h
+                held[os.path.relpath(p, root)] = fh.read()
+    return held
 
 
 def test_a_later_pr_brings_its_own_cell(tmp_path):
@@ -119,7 +118,7 @@ def test_a_later_pr_brings_its_own_cell(tmp_path):
     the benchmark is edited, and the run reports the new metric."""
     root = tiny.make_copy(tmp_path)
     bench = os.path.join(root, "benchmarks")
-    before = _digest(bench)
+    before = _files(bench)
 
     with open(os.path.join(bench, "configs", "tiny-gpt2.json")) as f:
         config = json.load(f)
@@ -160,7 +159,7 @@ def test_a_later_pr_brings_its_own_cell(tmp_path):
     line = result_line(out)
     assert line["correct"] is True, "\n".join(out[-20:])
     assert line["metrics"]["later_steps"]["value"] == 2 * line["attempted"]
-    after = _digest(bench)
+    after = _files(bench)
     assert {k: after[k] for k in before} == before
     assert set(after) - set(before) == {
         "configs/later.json", "traffic/later-mix.json",

@@ -44,13 +44,19 @@ def add_cell(root, name, config, traffic, like, chips=1):
         json.dump(b, f)
 
 
-def make_copy(dst):
-    """BENCHMARK.json and benchmarks/ copied to `dst`, tiny cells added."""
+def bare_copy(dst):
+    """BENCHMARK.json and benchmarks/ copied to `dst`, as they stand."""
     dst = str(dst)
     shutil.copy(os.path.join(REPO, "BENCHMARK.json"), dst)
     shutil.copytree(os.path.join(REPO, "benchmarks"),
                     os.path.join(dst, "benchmarks"),
                     ignore=shutil.ignore_patterns("__pycache__"))
+    return dst
+
+
+def make_copy(dst):
+    """BENCHMARK.json and benchmarks/ copied to `dst`, tiny cells added."""
+    dst = bare_copy(dst)
     bench = os.path.join(dst, "benchmarks")
     for name, (config, traffic, like) in TINY_CELLS.items():
         src_traffic = os.path.join(HERE, "tiny", traffic + ".json")
