@@ -46,6 +46,7 @@ from horovod_tpu.parallel.latent_attention import (
     LatentAttention, LatentSpec, latent_decode_plan,
 )
 from horovod_tpu.parallel.linear_attention import KDAAttention
+from horovod_tpu.parallel.state_space import Mamba2Mixer, SsmSpec
 from horovod_tpu.parallel.mesh import (
     AXIS_DATA, AXIS_MODEL, AXIS_SEQ, constrain, use,
 )
@@ -67,12 +68,20 @@ ATTN_IMPLS = ("dot", "blockwise", "flash", "ring", "ring_flash",
 # A layer's token mixer (`TransformerLM.layer_kinds`): softmax attention
 # under the flax scope "attn" or - the kind that a model with two kinds
 # of softmax layer gives its sliding-window layers - "swa", the
-# delta-rule linear attention "kda", and latent attention "mla"
+# delta-rule linear attention "kda", latent attention "mla"
 # (`parallel.latent_attention`: a softmax over heads too, but its cache
 # holds head-less latent rows, so it is no member of SOFTMAX_KINDS -
-# nothing that speaks of K/V heads, windows or `AttnSpec` applies).
+# nothing that speaks of K/V heads, windows or `AttnSpec` applies), and
+# the scalar-decay state-space layer "ssm" (`parallel.state_space`).
 SOFTMAX_KINDS = ("attn", "swa")
-LAYER_KINDS = SOFTMAX_KINDS + ("kda", "mla")
+LAYER_KINDS = SOFTMAX_KINDS + ("kda", "mla", "ssm")
+# The kinds whose decode cache is a state that every step OVERWRITES,
+# each with the layer that says which of its cache variables those are
+# (`OVERWRITTEN`) and which of them its in-place step keeps itself for
+# a lane that does not advance (`KEPT_BY_KERNEL`): THE predicate behind
+# `has_recurrent_state`, `recurrent_leaf` and the tick's freeze.
+RECURRENT_LAYERS = {"kda": KDAAttention, "ssm": Mamba2Mixer}
+RECURRENT_KINDS = tuple(RECURRENT_LAYERS)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,12 +90,14 @@ class AttnSpec:
     kinds differ (`TransformerLM.attn_specs`): the query heads
     (None = the model's ``num_heads``), the sliding window (as given:
     None = full attention, a kind that brings a spec states its
-    window) and the rotary rule (None = the model's ``rope_theta``
-    over the whole head). K/V heads and the head size are the
-    model's."""
+    window), the rotary rule (None = the model's ``rope_theta``
+    over the whole head) and the scale of the scores before the
+    softmax (None = head_dim ** -0.5). K/V heads and the head size
+    are the model's."""
     num_heads: Optional[int] = None
     window: Optional[int] = None
     rope: Optional[RopeSpec] = None
+    scale: Optional[float] = None
 
 # The LLaMA-family knob set — single source for `compat.hf.from_hf_llama`
 # and the driver dryrun's llama leg, so the two can never silently
@@ -291,7 +302,12 @@ class TransformerBlock(nn.Module):
     moe_normalize: bool = True           # HeldExpertsMoE.normalize
     moe_router_bias: Optional[bool] = None
     latent: Optional[LatentSpec] = None  # the widths of an "mla" mixer
+    ssm: Optional[SsmSpec] = None        # the widths of an "ssm" mixer
     shortcut_moe: bool = False           # see the docstring
+    softmax_scale: Optional[float] = None    # see `AttnSpec.scale`
+    # Both branches are multiplied by this before they are added to
+    # the residual stream (`TransformerLM.residual_scale`); None = 1.
+    residual_scale: Optional[float] = None
 
     @nn.compact
     def __call__(self, x: jax.Array,
@@ -333,6 +349,14 @@ class TransformerBlock(nn.Module):
                     out_features=d, norm_eps=self.ln_eps,
                     dtype=self.dtype, decode=self.decode, name=name)(
                     h, advance)
+            if self.mixer == "ssm":
+                if self.ssm is None:
+                    raise ValueError("an 'ssm' mixer needs `ssm`, its "
+                                     "SsmSpec")
+                return Mamba2Mixer(
+                    spec=self.ssm, out_features=d, norm_eps=self.ln_eps,
+                    dtype=self.dtype, decode=self.decode, name=name)(
+                    h, advance)
             if self.mixer == "mla":
                 if self.latent is None:
                     raise ValueError("an 'mla' mixer needs `latent`, "
@@ -350,7 +374,7 @@ class TransformerBlock(nn.Module):
                 num_heads=self.num_heads, head_dim=self.head_dim,
                 num_kv_heads=self.num_kv_heads, pos_emb=self.pos_emb,
                 rope_theta=self.rope_theta, rope=self.rope,
-                window=self.window,
+                window=self.window, softmax_scale=self.softmax_scale,
                 dtype=self.dtype, attn_fn=attn_fn, decode=self.decode,
                 chunked_prefill=self.chunked_prefill,
                 decode_prefix_block=self.decode_prefix_block,
@@ -410,10 +434,18 @@ class TransformerBlock(nn.Module):
                 f"mlp_impl must be gelu|swiglu|geglu, got "
                 f"{self.mlp_impl!r}")
 
+        def scaled(branch):
+            if self.residual_scale is None:
+                return branch
+            return branch * jnp.asarray(self.residual_scale, x.dtype)
+
         if self.shortcut_moe:
             if not self.moe:
                 raise ValueError("shortcut_moe is a block WITH an "
                                  "expert layer (moe=True)")
+            if self.residual_scale is not None:
+                raise ValueError("residual_scale is not defined for a "
+                                 "shortcut_moe block")
             x = x + mix(norm("ln_attn_0")(x), self.mixer + "_0")
             h = norm("ln_mlp_0")(x)
             shortcut = experts(h)
@@ -421,9 +453,9 @@ class TransformerBlock(nn.Module):
             x = x + mix(norm("ln_attn_1")(x), self.mixer + "_1")
             x = x + dense(norm("ln_mlp_1")(x), "mlp_1")
             return x + shortcut
-        x = x + mix(norm("ln_attn")(x), self.mixer)
+        x = x + scaled(mix(norm("ln_attn")(x), self.mixer))
         h = norm("ln_mlp")(x)
-        return x + (experts(h) if self.moe else dense(h, "mlp"))
+        return x + scaled(experts(h) if self.moe else dense(h, "mlp"))
 
 
 class TransformerLM(nn.Module):
@@ -482,6 +514,15 @@ class TransformerLM(nn.Module):
     # sqrt(hidden_size)); the tied LM head reads the UNSCALED table,
     # matching that family's convention. None = 1.
     embed_scale: Optional[float] = None
+    # Granite's other multipliers, each None = 1 (and then no
+    # instruction): every block's two branches are multiplied by
+    # ``residual_scale`` before they join the residual stream, and the
+    # logits are divided by ``logits_divisor`` - on the final norm's
+    # output, the hidden state every head reads (`return_hidden` too),
+    # so the divisor reaches the serving programs' heads and the fused
+    # loss alike; a power of two is exact there in any dtype.
+    residual_scale: Optional[float] = None
+    logits_divisor: Optional[float] = None
     # LoRA (Hu et al. 2021): rank-r adapters on every block Dense;
     # train with `models.lora.lora_label_fn` masking the base frozen,
     # merge for serving with `models.lora.merge_lora`.
@@ -490,14 +531,16 @@ class TransformerLM(nn.Module):
     # Width of the residual stream; None = num_heads x head_dim.
     hidden_size: Optional[int] = None
     # Hybrid models: the token mixer of each layer, "attn" | "swa" |
-    # "kda" | "mla" (`LAYER_KINDS`; len == num_layers); None = "attn"
-    # everywhere. A "kda" layer keeps a recurrent state in the decode
-    # cache, not K/V (`parallel.linear_attention`); "swa" is a second
-    # kind of softmax layer, under its own scope; an "mla" layer keeps
-    # head-less latent rows (`parallel.latent_attention`), at the
-    # widths of ``latent``.
+    # "kda" | "mla" | "ssm" (`LAYER_KINDS`; len == num_layers); None =
+    # "attn" everywhere. A "kda" layer keeps a recurrent state in the
+    # decode cache, not K/V (`parallel.linear_attention`), and so does
+    # an "ssm" layer (`parallel.state_space`), at the widths of
+    # ``ssm``; "swa" is a second kind of softmax layer, under its own
+    # scope; an "mla" layer keeps head-less latent rows
+    # (`parallel.latent_attention`), at the widths of ``latent``.
     layer_kinds: Optional[Tuple[str, ...]] = None
     latent: Optional[LatentSpec] = None
+    ssm: Optional[SsmSpec] = None
     # What a kind of softmax layer has of its own: ((kind, AttnSpec),
     # ...). A kind without an entry takes the model-wide ``num_heads``
     # / ``window`` / ``rope_theta``.
@@ -557,7 +600,8 @@ class TransformerLM(nn.Module):
         spec = AttnSpec(
             num_heads=(own and own.num_heads) or self.num_heads,
             window=own.window if own else self.window,
-            rope=(own and own.rope) or RopeSpec(theta=self.rope_theta))
+            rope=(own and own.rope) or RopeSpec(theta=self.rope_theta),
+            scale=own.scale if own else None)
         if kind == "swa" and spec.window is None:
             raise ValueError(
                 "a 'swa' layer needs a window: give the kind an "
@@ -575,7 +619,15 @@ class TransformerLM(nn.Module):
     def has_recurrent_state(self) -> bool:
         """True when some layer's decode cache is a state that each
         step overwrites (no K/V rows to graft, page or rewind)."""
-        return "kda" in (self.layer_kinds or ())
+        return any(k in RECURRENT_KINDS
+                   for k in self.layer_kinds or ())
+
+    @property
+    def recurrent_kinds(self) -> Tuple[str, ...]:
+        """The kinds of recurrent layer this model has, in the order
+        they first appear."""
+        return tuple(k for k in dict.fromkeys(self.layer_kinds or ())
+                     if k in RECURRENT_KINDS)
 
     @property
     def rolling_window(self) -> Optional[int]:
@@ -656,7 +708,8 @@ class TransformerLM(nn.Module):
                 num_kv_heads=self.num_kv_heads,
                 pos_emb=("rope" if self.pos_emb == "rope" else "none"),
                 rope_theta=self.rope_theta, rope=spec.rope,
-                window=spec.window,
+                window=spec.window, softmax_scale=spec.scale,
+                residual_scale=self.residual_scale,
                 mlp_ratio=self.mlp_ratio, dtype=self.dtype,
                 attn_impl=self.attn_impl, moe=moe,
                 num_experts=self.num_experts, moe_k=self.moe_k,
@@ -682,15 +735,19 @@ class TransformerLM(nn.Module):
                 moe_zero_experts=self.moe_zero_experts,
                 moe_normalize=self.moe_normalize,
                 moe_router_bias=self.moe_router_bias,
-                latent=self.latent, shortcut_moe=self.moe_shortcut,
+                latent=self.latent, ssm=self.ssm,
+                shortcut_moe=self.moe_shortcut,
                 name=f"block_{i}")(
                 # which lanes a decode step may move: a recurrent
-                # layer's (`KDAAttention`), nobody else's
-                *((x, advance) if kinds[i] == "kda" else (x,)))
+                # layer's (`RECURRENT_LAYERS`), nobody else's
+                *((x, advance) if kinds[i] in RECURRENT_KINDS
+                  else (x,)))
             x = constrain(x, AXIS_DATA, AXIS_SEQ, None)
 
         x = _make_norm(self.norm, self.dtype, self.ln_eps,
                        "ln_f")(x)
+        if self.logits_divisor is not None:
+            x = x * jnp.asarray(1.0 / self.logits_divisor, x.dtype)
         head = embed
         if not self.tied_head:
             head = self.param(
@@ -1345,17 +1402,18 @@ def moe_product_plans(model: TransformerLM, lanes: int = 1,
 
 def state_step_plans(model: TransformerLM, lanes: int = 1) -> dict:
     """The way ``model``'s recurrent layers step their state in an
-    S = 1 tick over ``lanes`` slots, as `KDAAttention` decides it under
-    the ambient mesh: {"kda": the `ops.kda_step.StateStepPlan` (the
-    in-place kernel or `kda_step` as XLA compiles it, and why)}; {}
-    for a model without such a layer. The slot tick's freeze obeys
-    the same plan, the engine logs it at warm-up and
-    `metrics_snapshot()` carries it."""
-    from horovod_tpu.parallel.linear_attention import state_step_plan
-    if not model.has_recurrent_state:
-        return {}
-    return {"kda": state_step_plan(lanes, model.num_heads,
-                                   model.head_dim)}
+    S = 1 tick over ``lanes`` slots, as the layer decides it under the
+    ambient mesh: {kind ("kda", "ssm"): the
+    `ops.kda_step.StateStepPlan` (the in-place kernel or the step as
+    XLA compiles it, and why)}; {} for a model without such a layer.
+    The slot tick's freeze obeys the same plan, the engine logs it at
+    warm-up and `metrics_snapshot()` carries it."""
+    from horovod_tpu.parallel import linear_attention, state_space
+    rules = {
+        "kda": lambda: linear_attention.state_step_plan(
+            lanes, model.num_heads, model.head_dim),
+        "ssm": lambda: state_space.state_step_plan(lanes, model.ssm)}
+    return {kind: rules[kind]() for kind in model.recurrent_kinds}
 
 
 def init_slot_cache(model: TransformerLM, num_slots: int):
@@ -1596,11 +1654,22 @@ def sample_lanes(logits, temperature, top_p, keys):
 
 
 def recurrent_leaf(path) -> bool:
-    """A cache leaf that is a recurrent layer's state: the variables
-    the layer itself lists as overwritten each step
-    (`KDAAttention.OVERWRITTEN`; a further recurrent layer adds its
-    own list here)."""
-    return getattr(path[-1], "key", None) in KDAAttention.OVERWRITTEN
+    """A cache leaf that is a recurrent layer's state: under the
+    scope of one of `RECURRENT_KINDS`, a variable that kind's layer
+    itself lists as overwritten each step (`OVERWRITTEN`)."""
+    return _leaf_of(path, "OVERWRITTEN")
+
+
+def _leaf_of(path, listed: str, kinds=RECURRENT_KINDS) -> bool:
+    """Is ``path`` a cache variable that the recurrent layer it lies
+    under (a scope `<kind>` or `<kind>_<n>` of ``kinds``) names in its
+    attribute ``listed``?"""
+    keys = [getattr(p, "key", None) for p in path]
+    if len(keys) < 2 or not isinstance(keys[-2], str):
+        return False
+    kind = keys[-2].split("_")[0]
+    return kind in kinds and keys[-1] in getattr(
+        RECURRENT_LAYERS[kind], listed)
 
 
 def overwritten_leaf(path) -> bool:
@@ -1626,17 +1695,17 @@ def _freeze_cache_indices(new_cache, old_cache, advance, kept=()):
     i - window, which is outside the band of every position >= i, and
     the next real writer of position i lands on that slot.
 
-    ``kept`` names the leaves that the step, told which lanes advance,
-    kept itself (`KDAAttention.KEPT_BY_KERNEL` where the state's step
-    is the in-place kernel): they pass as they are - one more reader
-    of the old value, and XLA copies the leaf before the call that
-    overwrites it."""
+    ``kept`` names the recurrent kinds whose step, told which lanes
+    advance, kept `KEPT_BY_KERNEL` itself (where the state's step is
+    the in-place kernel): those leaves pass as they are - one more
+    reader of the old value, and XLA copies the leaf before the call
+    that overwrites it."""
     from jax.tree_util import tree_flatten_with_path, tree_unflatten
     flat, treedef = tree_flatten_with_path(new_cache)
     old_leaves = jax.tree.leaves(old_cache)
     out = [jnp.where(advance, leaf, old)
            if overwritten_leaf(path)
-           and getattr(path[-1], "key", None) not in kept else leaf
+           and not _leaf_of(path, "KEPT_BY_KERNEL", kept) else leaf
            for (path, leaf), old in zip(flat, old_leaves)]
     return tree_unflatten(treedef, out)
 
@@ -1682,11 +1751,10 @@ def slot_decode_tick(dec_model, params, cache, toks, temps, top_ps,
       on every path.
     """
 
-    # what the recurrent layers' step keeps itself for a lane that
-    # does not advance (the in-place kernel): never selected after
-    kept = (KDAAttention.KEPT_BY_KERNEL if any(
-        plan.path == "kernel" for plan in state_step_plans(
-            dec_model, toks.shape[0]).values()) else ())
+    # the recurrent kinds whose step keeps its state itself for a lane
+    # that does not advance (the in-place kernel): never selected after
+    kept = tuple(kind for kind, plan in state_step_plans(
+        dec_model, toks.shape[0]).items() if plan.path == "kernel")
 
     def one(sub, tok, rng, lv, dn):
         told = {"advance": lv & ~dn} if kept else {}

@@ -77,7 +77,8 @@ def serving_metrics(reg: Optional[MetricRegistry] = None) -> Dict:
             "Device bytes of the fixed slot pool's cache by kind "
             "(kv = keys and values of full-attention layers, "
             "kv_window = the rings of sliding-window layers, state = "
-            "recurrent state and convolution tails that each step "
+            "recurrent state - a delta-rule layer's or a state-space "
+            "layer's - and convolution tails that each step "
             "overwrites)",
             ("engine", "kind")),
         "kv_blocks_free_shard": reg.gauge(

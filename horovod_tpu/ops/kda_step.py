@@ -56,14 +56,16 @@ class StateStepPlan:
     engine logs at warm-up and carries in ``metrics_snapshot()``."""
     path: str                       # "kernel" | "lax"
     why: str
-    heads: Optional[int] = None     # kernel path only: heads a grid step
-    grid: Optional[tuple] = None    # (lanes, head blocks a lane)
+    block: Optional[int] = None     # kernel path only: what a grid step
+                                    # holds, in the rule's own unit (heads
+                                    # here, channels in `ops.ssm_step`)
+    grid: Optional[tuple] = None    # (lanes, blocks a lane)
     vmem_bytes: Optional[int] = None
 
     def describe(self) -> str:
         if self.path != "kernel":
             return f"{self.path} ({self.why})"
-        return (f"kernel ({self.why}): {self.heads} heads a step, in "
+        return (f"kernel ({self.why}): a block of {self.block} a step, in "
                 f"place, grid {self.grid}, VMEM "
                 f"{self.vmem_bytes / 2 ** 20:.1f} MiB")
 
@@ -125,7 +127,7 @@ def kda_step_plan(lanes: int, H: int, Dk: int, Dv: int, *,
             "lax", f"no block of whole sublane tiles divides {H} heads "
             f"inside {BLOCK_BYTES >> 20} MiB")
     return StateStepPlan(
-        "kernel", "forced" if impl else "on a TPU", heads=hb,
+        "kernel", "forced" if impl else "on a TPU", block=hb,
         grid=(lanes, H // hb), vmem_bytes=_vmem(hb, Dk, Dv))
 
 
@@ -198,25 +200,32 @@ def _kda_call(state, q, k, v, g, beta, advance, *, hb, interpret):
     )(advance.astype(jnp.int32), state, rows, v)
 
 
-@functools.lru_cache(maxsize=None)
-def _make_step(hb: int, interpret: bool):
-    """custom_vmap-wrapped entry (`flash_attention._make_append`'s
-    twin): under the serving tick's `jax.vmap` over slots the slot
-    axis JOINS the lane axis, and the pool's state leaf [num_slots, 1,
-    H, Dk, Dv] is stepped where it lies, by one call."""
+def over_slots(call):
+    """``call`` (a kernel's entry over [lanes, ...] operands whose
+    outputs lead with the lanes too) wrapped in a custom_vmap
+    (`flash_attention._make_append`'s twin): under the serving tick's
+    `jax.vmap` over slots the slot axis JOINS the lane axis, and the
+    pool's state leaf [num_slots, 1, ...] is stepped where it lies, by
+    one call. `ops.ssm_step` wraps its call the same way."""
 
     @jax.custom_batching.custom_vmap
     def step(*operands):
-        return tuple(_kda_call(*operands, hb=hb, interpret=interpret))
+        return tuple(call(*operands))
 
     @step.def_vmap
     def _rule(axis_size, in_batched, *args):
         outs = step(*(_flash._slots_into_lanes(x, b, axis_size)
                       for x, b in zip(args, in_batched)))
         return tuple(o.reshape((axis_size, -1) + o.shape[1:])
-                     for o in outs), (True, True)
+                     for o in outs), (True,) * len(outs)
 
     return step
+
+
+@functools.lru_cache(maxsize=None)
+def _make_step(hb: int, interpret: bool):
+    return over_slots(functools.partial(_kda_call, hb=hb,
+                                        interpret=interpret))
 
 
 def kda_state_step(state: jax.Array, q: jax.Array, k: jax.Array,
@@ -243,5 +252,5 @@ def kda_state_step(state: jax.Array, q: jax.Array, k: jax.Array,
     advance = jnp.broadcast_to(
         jnp.asarray(True if advance is None else advance, jnp.bool_),
         (state.shape[0],))
-    return _make_step(plan.heads, _flash._auto_interpret())(
+    return _make_step(plan.block, _flash._auto_interpret())(
         state, q, k, v, g, beta, advance)

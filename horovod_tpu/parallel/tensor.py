@@ -460,6 +460,13 @@ class ParallelSelfAttention(nn.Module):
     # Width of the output projection (the residual stream's); None =
     # H*D, the models whose hidden size is heads x head_dim.
     out_features: Optional[int] = None
+    # The scale of the scores before the softmax; None = head_dim **
+    # -0.5, which every inner attention (and every kernel) applies
+    # itself. Another scale multiplies q by its ratio to that one
+    # right after the projection, so no kernel needs an operand for
+    # it; where the ratio is a power of two (Granite: 1/64 at head 64
+    # is 1/8 of 64 ** -0.5) that is exact in any dtype.
+    softmax_scale: Optional[float] = None
     lora_rank: int = 0
     lora_alpha: Optional[float] = None
 
@@ -482,6 +489,9 @@ class ParallelSelfAttention(nn.Module):
                                   lora_alpha=self.lora_alpha,
                                   dtype=self.dtype, name="qkv")(x)
         q = qkv[..., :features]
+        if self.softmax_scale is not None:
+            q = q * jnp.asarray(
+                self.softmax_scale * self.head_dim ** 0.5, q.dtype)
         k = qkv[..., features:features + kv_features]
         v = qkv[..., features + kv_features:]
 

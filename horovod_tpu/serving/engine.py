@@ -137,8 +137,9 @@ _NEEDS_APPENDED_KV = {
 
 def _refuse_overwritten_cache(model, **options):
     """A model with a recurrent layer (`TransformerLM.layer_kinds`
-    holds "kda") keeps, beside K/V, a state that every step
-    OVERWRITES; a model with a sliding-window layer keeps that layer's
+    holds one of `models.transformer.RECURRENT_KINDS`: delta-rule
+    linear attention, a state-space layer) keeps, beside K/V, a state
+    that every step OVERWRITES; a model with a sliding-window layer keeps that layer's
     K/V in a ring whose slots later positions overwrite
     (`has_rolling_cache`); a model with latent-attention layers
     ("mla": `has_latent_cache`) keeps head-less rows in one leaf a
@@ -160,8 +161,8 @@ def _refuse_overwritten_cache(model, **options):
         does, no_state, no_ring, no_latent = _NEEDS_APPENDED_KV[name]
         if recurrent:
             raise ValueError(
-                f"{name}: this model has recurrent (linear-attention) "
-                f"layers, and {does}; {no_state} - missing snapshot "
+                f"{name}: this model has recurrent (linear-attention "
+                f"or state-space) layers, and {does}; {no_state} - missing snapshot "
                 f"form of the recurrent state; serve it from the "
                 f"fixed slot pool (ServingEngine defaults)")
         if rolling:

@@ -296,7 +296,8 @@ class EngineMetrics:
                                       for k, p in plans.items()}
 
     def observe_state_steps(self, plans: dict):
-        """{kind of recurrent layer: `ops.kda_step.StateStepPlan`}."""
+        """{kind of recurrent layer ("kda", "ssm"):
+        `ops.kda_step.StateStepPlan`}."""
         with self._lock:
             self.state_step_paths = {k: p.path
                                      for k, p in plans.items()}

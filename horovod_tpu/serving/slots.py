@@ -284,9 +284,10 @@ class SlotPool:
             return moe_product_plans(self.model, self.num_slots, chunk)
 
     def state_step_plans(self) -> dict:
-        """{"kda": the plan this pool's ticks step their recurrent
-        layers' state with (the in-place kernel or `kda_step` as XLA
-        compiles it, and why)}; {} for a model without such a layer:
+        """{kind ("kda", "ssm"): the plan this pool's ticks step that
+        kind of recurrent layer's state with (the in-place kernel or
+        the step as XLA compiles it, and why)}; {} for a model without
+        such a layer:
         `models.transformer.state_step_plans` under the pool's mesh."""
         with self._ctx():
             return state_step_plans(self.model, self.num_slots)
@@ -342,7 +343,8 @@ class SlotPool:
         sliding-window layers, a ring of `window` rows a lane that
         later positions overwrite in place; 0 for a model without
         one), `state` (a recurrent layer's state and convolution
-        tail, overwritten each step; 0 likewise) and - for a model
+        tail - `models.transformer.RECURRENT_KINDS` - overwritten each
+        step; 0 likewise) and - for a model
         with latent-attention layers only - `latent` (their rows,
         appended to like K/V but without a head axis, as stored:
         `LatentSpec.stored` numbers a position). The fill indices are

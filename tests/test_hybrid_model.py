@@ -7,6 +7,7 @@ weights, against the plain reference the benchmark keeps
 
 import json
 import os
+import time
 
 import numpy as np
 import pytest
@@ -314,6 +315,8 @@ def test_engine_greedy_equals_generate(params):
     prompts = [tokens(n, n) for n in (5, 19, 33, 12)]
     refs = [np.asarray(generate(model, params, p[None], 7))[0, len(p):]
             for p in prompts]
+    born_ns = time.time_ns()    # the ring is the process's: an earlier
+    #                             test file's engine left its records
     with ServingEngine(model, params, num_slots=2, warmup=True,
                        prefill_chunk_budget=8) as eng:
         outs = [np.asarray(h.result(timeout=300).tokens) for h in
@@ -332,7 +335,7 @@ def test_engine_greedy_equals_generate(params):
     assert "not whole lanes" in snap["state_step_plans"]["kda"]
     from horovod_tpu.obs import spans
     syncs = [r for r in spans.loop_tail(name="sched.tick_sync")
-             if "moe_pairs" in r["attrs"]]
+             if "moe_pairs" in r["attrs"] and r["t0_ns"] >= born_ns]
     assert syncs and all(r["attrs"]["moe_layers"] == ARCH["num_layers"]
                          for r in syncs)
 
@@ -377,7 +380,7 @@ def test_engine_on_the_kernels_path_serves_the_lax_streams(
         np.testing.assert_array_equal(got, want)
     assert snap["compiles"] == 0
     assert snap["state_step_paths"] == {"kda": "kernel"}
-    assert "2 heads a step, in place" in snap["state_step_plans"]["kda"]
+    assert "a block of 2 a step, in place" in snap["state_step_plans"]["kda"]
     assert any("state step: kda: kernel (on a TPU)" in r.getMessage()
                for r in caplog.records)
 

@@ -54,7 +54,7 @@ def forced(L, H, heads=None):
     plan = kda_step_plan(L, H, D, D, impl="pallas")
     assert plan.path == "kernel"
     return plan if heads is None else dataclasses.replace(
-        plan, heads=heads, grid=(L, H // heads))
+        plan, block=heads, grid=(L, H // heads))
 
 
 CASES = {
@@ -209,15 +209,15 @@ def test_plan(case):
     assert why in plan.describe()
     assert plan.describe().startswith(path)
     if path == "lax":
-        assert plan.heads is None and plan.grid is None
+        assert plan.block is None and plan.grid is None
         return
     lanes, H, Dk, Dv = args
     # heads a block from the shape: whole sublane tiles inside the
     # budget, the grid and the VMEM asked in the words the log prints
-    assert plan.heads == 32 and plan.grid == (lanes, 2)
-    assert plan.heads * Dk * Dv * 4 <= BLOCK_BYTES
-    assert 4 * plan.heads * Dk * Dv * 4 < plan.vmem_bytes < 16 * 2 ** 20
-    assert "32 heads a step" in plan.describe()
+    assert plan.block == 32 and plan.grid == (lanes, 2)
+    assert plan.block * Dk * Dv * 4 <= BLOCK_BYTES
+    assert 4 * plan.block * Dk * Dv * 4 < plan.vmem_bytes < 16 * 2 ** 20
+    assert "a block of 32 a step" in plan.describe()
     assert "grid (128, 2)" in plan.describe()
 
 
@@ -225,7 +225,7 @@ def test_plan(case):
                                      (64, 32), (96, 32)])
 def test_heads_a_step_come_from_the_shape(H, heads):
     plan = kda_step_plan(4, H, D, D, on_tpu=True)
-    assert (plan.path, plan.heads) == ("kernel", heads)
+    assert (plan.path, plan.block) == ("kernel", heads)
     assert H % heads == 0 and (heads % 8 == 0 or heads == H)
 
 

@@ -608,7 +608,7 @@ def test_kda_state_is_stepped_in_place_by_one_call_a_layer(
         attn_impl="flash", moe_every=1, moe_impl="dropless",
         layer_kinds=("kda",), **fields)
     plan = state_step_plans(model, lanes)["kda"]
-    assert (plan.path, plan.heads, plan.grid) == ("kernel", 32,
+    assert (plan.path, plan.block, plan.grid) == ("kernel", 32,
                                                   (lanes, 2)), plan
     dec = slot_decode_model(model)
 
@@ -644,6 +644,85 @@ def test_kda_state_is_stepped_in_place_by_one_call_a_layer(
     made = [ln for ln in text.splitlines() if re.search(
         r"= f32\[128,(1,)?64,128,128\]\S* "
         r"(?!parameter\(|bitcast\(|get-tuple-element\()", ln)]
+    assert made == [], [ln[:160] for ln in made]
+    assert compiled.memory_analysis().temp_size_in_bytes < state.size * 4
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk-128", "chunk-1"])
+def test_ssm_state_is_stepped_in_place_by_one_call_a_layer(
+        sds, monkeypatch, program):
+    """granite's state-space layer (64 heads x 64, state 128, 64 lanes,
+    float32 state [1, 128, 4096] a lane) with the four multipliers, on
+    the DEFAULT rule (`ssm_step_plan`): the tick steps the
+    state leaf through ONE Mosaic call under the layer's own scope
+    `block_0/ssm/` (what the benchmark's `ssm_share_of_tick` matches;
+    the trace prints it as `ssm_step.<n>`), the leaf goes from the
+    parameter to the call and from the call to the result - nothing
+    else makes, selects, reduces or copies a whole state (64 lanes of
+    36 such layers are 4.8 GB: a second copy would not fit beside the
+    weights), and the program's temporaries stay under one leaf. A
+    chunk of one token is an S = 1 step too; a chunk of 128 keeps the
+    chunkwise form."""
+    from horovod_tpu.models.transformer import (
+        AttnSpec, TransformerLM, init_slot_cache, serving_params,
+        slot_decode_model, slot_decode_tick, slot_prefill_chunk,
+        state_step_plans)
+    from horovod_tpu.ops import flash_attention
+    from horovod_tpu.parallel.state_space import SsmSpec
+    from horovod_tpu.parallel.tensor import unbox
+
+    monkeypatch.setattr(flash_attention, "_auto_interpret",
+                        lambda: False)
+    lanes, W = 64, 2048
+    model = TransformerLM(
+        vocab_size=4096, num_layers=1, max_len=W, norm="rmsnorm",
+        mlp_impl="swiglu", mlp_hidden=8192, dtype=jnp.bfloat16,
+        attn_impl="flash", hidden_size=2048, num_heads=32,
+        num_kv_heads=8, head_dim=64, pos_emb="none", ln_eps=1e-5,
+        layer_kinds=("ssm",),
+        ssm=SsmSpec(num_heads=64, head_dim=64, state_size=128),
+        attn_specs=(("attn", AttnSpec(scale=1 / 64)),),
+        embed_scale=12, residual_scale=0.22, logits_divisor=8)
+    plan = state_step_plans(model, lanes)["ssm"]
+    assert (plan.path, plan.block, plan.grid) == (
+        "kernel", 4096, (lanes, 1, 1)), plan
+    dec = slot_decode_model(model)
+
+    def place(tree):
+        return jax.tree.map(lambda s: sds(s.shape, s.dtype), tree)
+
+    params = place(jax.eval_shape(
+        lambda r: serving_params(unbox(model.init(
+            r, jnp.zeros((1, 64), jnp.int32))["params"])),
+        jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: init_slot_cache(model, lanes)))
+    state = cache["block_0"]["ssm"]["state"]
+    assert state.shape == (lanes, 1, 1, 128, 4096)
+    assert cache["block_0"]["ssm"]["conv_tail"].shape == (
+        lanes, 1, 3, 4352)
+    if program == "tick":
+        vec = lambda dt: sds((lanes,), dt)  # noqa: E731
+        compiled = slot_decode_tick.lower(
+            dec, params, cache, vec(jnp.int32), vec(jnp.float32),
+            vec(jnp.float32), sds((lanes, 2), jnp.uint32), vec(bool),
+            vec(bool), sds((), jnp.int32)).compile()
+    else:
+        compiled = slot_prefill_chunk.lower(
+            dec, params, cache, sds((), jnp.int32),
+            sds((int(program.split("-")[1]),), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "%ssm_step" in ln.split(" = ")[0]]
+    assert len(calls) == (0 if program == "chunk-128" else 1)
+    assert all("/block_0/ssm/" in ln for ln in calls)
+    # the pool's leaf is aliased input to output in every program
+    assert compiled.memory_analysis().alias_size_in_bytes >= state.size * 4
+    if program != "tick":
+        return
+    made = [ln for ln in text.splitlines() if re.search(
+        r"= f32\[64,(1,)?1,128,4096\]\S* "
+        r"(?!parameter\(|bitcast\(|get-tuple-element\()", ln)
+        and "%ssm_step" not in ln.split(" = ")[0]]
     assert made == [], [ln[:160] for ln in made]
     assert compiled.memory_analysis().temp_size_in_bytes < state.size * 4
 
