@@ -1419,7 +1419,9 @@ def state_step_plans(model: TransformerLM, lanes: int = 1) -> dict:
 def init_slot_cache(model: TransformerLM, num_slots: int):
     """Zero-filled slot-pool cache: each leaf of the B=1 decode cache
     with a leading [num_slots] axis (K/V [num_slots, 1, max_len, Hkv,
-    D]; the scalar fill indices become [num_slots] vectors)."""
+    D] - `ops.flash_attention.kv_pack` heads to a row where a head is
+    narrower than 128 lanes: [.., Hkv // pack, D * pack]; the scalar
+    fill indices become [num_slots] vectors)."""
     dec_model = slot_decode_model(model)
     shapes = jax.eval_shape(
         dec_model.init, jax.random.PRNGKey(0),

@@ -1338,6 +1338,10 @@ class DecodePlan:
     # (`ParallelSelfAttention._cache_write`), and why
     write: str = "xla"
     write_why: str = "only the kernel path appends in place"
+    # KV heads stored to one 128-lane cache row (`kv_pack`): block,
+    # grid, VMEM and the append tile are planned at the STORED shape
+    # [W, Hkv // pack, D * pack]
+    pack: int = 1
 
     def describe(self) -> str:
         write = f"write {self.write} ({self.write_why})"
@@ -1346,6 +1350,39 @@ class DecodePlan:
         return (f"kernel ({self.why}): block_k {self.block_k}, grid "
                 f"{self.grid}, VMEM {self.vmem_bytes / 2 ** 20:.1f} MiB"
                 f"; {write}")
+
+
+def kv_pack(Hkv: int, D: int) -> int:
+    """KV heads a softmax layer's cache stores to one row: 128 // D of
+    them side by side (heads ``pack * h .. pack * h + pack - 1`` in
+    row h) where a head is narrower than the chip's 128 lanes and
+    whole rows come out, else 1. A function of the shape alone - it
+    decides the stored leaf (`pack_kv_rows`), not only the kernel's
+    operand: a leaf whose rows are not lane-whole the TPU compiler
+    stores position-minor, and the ragged kernel and the in-place
+    append, which read whole rows where they lie, would each pay a
+    relayout copy of the whole leaf (`parallel.latent_attention`
+    records the same of a 576-wide latent row). The same bytes in the
+    same order: packing and unpacking are reshapes."""
+    pack = 128 // D if D < 128 and 128 % D == 0 else 1
+    return pack if Hkv % pack == 0 else 1
+
+
+def pack_kv_rows(t: jax.Array, pack: int) -> jax.Array:
+    """K or V rows [..., Hkv, D] as the cache stores them,
+    [..., Hkv // pack, D * pack] (`kv_pack`)."""
+    if pack == 1:
+        return t
+    return t.reshape(*t.shape[:-2], t.shape[-2] // pack,
+                     t.shape[-1] * pack)
+
+
+def unpack_kv_rows(t: jax.Array, pack: int) -> jax.Array:
+    """`pack_kv_rows`' inverse: stored rows as [..., Hkv, D]."""
+    if pack == 1:
+        return t
+    return t.reshape(*t.shape[:-2], t.shape[-2] * pack,
+                     t.shape[-1] // pack)
 
 
 def _decode_block_k(W: int, Hkv: int, D: int, itemsize: int,
@@ -1411,6 +1448,15 @@ def decode_attention_plan(lanes: int, W: int, H: int, Hkv: int, D: int,
     oracle, and the kernel in interpret mode off the chip); a forced
     kernel still needs what the kernel cannot do without.
 
+    ``Hkv`` and ``D`` are the layer's TRUE KV heads and head width. A
+    head narrower than 128 lanes whose heads fill whole rows is
+    stored `kv_pack` heads to a row, and block, grid, VMEM and the
+    append's tile are planned at that stored shape [W, Hkv // pack,
+    D * pack] (``DecodePlan.pack``; Granite's 8 heads of 64: 4 rows
+    of 128, block 512). A width that is no multiple of 128 and does
+    not pack (96, 80, three heads of 64) keeps the walk on a TPU:
+    Mosaic pads or refuses its rows.
+
     ``ring``: the cache is a sliding-window layer's rolling buffer of
     W slots (slot = position mod W). The same kernel takes it - the
     ring's valid slots are its first min(position + 1, W), and rows
@@ -1447,6 +1493,9 @@ def decode_attention_plan(lanes: int, W: int, H: int, Hkv: int, D: int,
                           "partition over heads, a bare kernel does not")
     if H % Hkv:
         return DecodePlan("lax", f"{H} heads over {Hkv} KV heads")
+    # the cache as it is stored: `pack` KV heads to a row
+    pack = 1 if latent else kv_pack(Hkv, D)
+    Hkv, D = Hkv // pack, D * pack
     bk = _decode_block_k(W, Hkv, D, itemsize, block_k)
     if bk is None:
         return DecodePlan("lax", f"no key block divides a cache of {W}")
@@ -1455,24 +1504,31 @@ def decode_attention_plan(lanes: int, W: int, H: int, Hkv: int, D: int,
             on_tpu = not _auto_interpret()
         if not on_tpu:
             return DecodePlan("lax", "not on a TPU")
-        if (latent or D) % 128:
+        if latent and latent % 128:
+            return DecodePlan("lax", f"a latent's value width {latent} "
+                              "is not a multiple of 128 lanes")
+        if not latent and D % 128:
+            fit = 128 // D if 128 % D == 0 else 0
             return DecodePlan(
-                "lax", (f"a latent's value width {latent}" if latent
-                        else f"head_dim {D}")
-                + " is not a multiple of 128 lanes")
+                "lax", f"head_dim {D} is not a multiple of 128 lanes, "
+                + (f"and {Hkv} KV heads do not pack {fit} to a row"
+                   if fit else "nor a whole part of them"))
     vmem = _decode_vmem(bk, H, Hkv, D, itemsize, latent)
     rows = _append_rows(W, Hkv, itemsize)
     why = "forced" if impl else "S = 1 on a TPU"
     if latent:
         why += (f", latent rows of {D} read once as keys and as "
                 f"{latent}-wide values")
+    if pack > 1:
+        why += f", {pack} heads a row"
     return DecodePlan(
         "kernel", why,
         block_k=bk, grid=(lanes, W // bk), vmem_bytes=vmem,
         vmem_limit_bytes=vmem if vmem > VMEM_SCOPED_DEFAULT else None,
         write="kernel" if rows else "xla",
         write_why=(f"one aliased call for all lanes, a tile of {rows} "
-                   f"rows" if rows else _no_append_tile(W, Hkv)))
+                   f"rows" if rows else _no_append_tile(W, Hkv)),
+        pack=pack)
 
 
 def _decode_kernel(s_ref, q_ref, k_ref, *rest,
@@ -1663,7 +1719,8 @@ def flash_decode_attention(q: jax.Array, k_cache: jax.Array,
     ragged over the batch.
 
     q [B, 1, H, D]; k_cache/v_cache [B, W, Hkv, D] (the linear decode
-    cache, already containing the current token at position
+    cache as it is stored - packed rows: below -, already containing
+    the current token at position
     ``length - 1``); ``length`` traced int32, a scalar (`generate`:
     every row at the same index) or [B] (each lane its own filled
     prefix). Returns [B, 1, H, D] at q.dtype.
@@ -1686,6 +1743,16 @@ def flash_decode_attention(q: jax.Array, k_cache: jax.Array,
     ``k_cache`` [B, W, 1, D] holds the latent rows, ``v_cache`` is
     None, the values are the rows' first Dv columns, and the result
     is [B, 1, H, Dv]: each row is streamed once for both products.
+
+    A cache of PACKED rows (`kv_pack`: [B, W, Hkv // pack, D * pack],
+    a head narrower than 128 lanes) is the same kernel on a row of
+    ``pack`` heads: each query head is placed in its own KV head's
+    part of a row-wide query and zeros in the rest, so its product
+    with a stored row is its score against its own head exactly; the
+    kernel's head mask keeps the rows of the head's row; and of the
+    row-wide output - the sibling heads' values under this head's
+    weights beside its own - its own part is taken. The scale stays
+    the TRUE head's, q.shape[-1] ** -0.5.
     """
     if interpret is None:
         interpret = _auto_interpret()
@@ -1697,11 +1764,26 @@ def flash_decode_attention(q: jax.Array, k_cache: jax.Array,
                          "no v_cache, and every other cache has one")
     lengths = jnp.broadcast_to(jnp.asarray(length, jnp.int32),
                                (q.shape[0],))
+    q = q[:, 0]
+    B, H, D = q.shape
+    pack = k_cache.shape[-1] // D
+    if pack > 1:
+        if scale is None:
+            scale = D ** -0.5
+        # [H, pack, 1]: the part of a row query head r's KV head
+        # r // grp lies in
+        kv_head = jnp.arange(H) // (H // (k_cache.shape[-2] * pack))
+        own = (kv_head % pack)[:, None, None] == jnp.arange(
+            pack)[None, :, None]
+        q = jnp.where(own, q[:, :, None], 0).reshape(B, H, pack * D)
     fn = _make_decode(_opt_int(block_k), bool(interpret),
                       None if scale is None else float(scale),
                       _opt_int(latent))
     caches = (k_cache,) if latent else (k_cache, v_cache)
-    return fn(q[:, 0], *caches, lengths)[:, None]
+    out = fn(q, *caches, lengths)
+    if pack > 1:
+        out = jnp.where(own, out.reshape(B, H, pack, D), 0).sum(axis=2)
+    return out[:, None]
 
 
 # ---------------------------------------------------------------------------
@@ -1829,7 +1911,9 @@ def flash_cache_append(k_cache: jax.Array,
 
     A latent layer's cache is ONE leaf (`parallel.latent_attention`):
     pass ``v_cache`` and ``v_new`` as None, and the second result is
-    None - the same call with one cache in it.
+    None - the same call with one cache in it. A cache of packed rows
+    (`kv_pack`: [B, W, Hkv // pack, D * pack]) takes the new rows
+    [B, 1, Hkv, D] as they are: packing them is a reshape.
     """
     if interpret is None:
         interpret = _auto_interpret()
@@ -1838,8 +1922,10 @@ def flash_cache_append(k_cache: jax.Array,
                          f"[B,1,Hkv,D], got {k_new.shape}")
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32),
                            (k_cache.shape[0],))
+    pack = k_cache.shape[-1] // k_new.shape[-1]
+    k_new = pack_kv_rows(k_new[:, 0], pack)
     if v_cache is None:
         return (*_make_append(bool(interpret), 1)(
-            k_cache, k_new[:, 0], pos), None)
+            k_cache, k_new, pos), None)
     return _make_append(bool(interpret))(
-        k_cache, v_cache, k_new[:, 0], v_new[:, 0], pos)
+        k_cache, v_cache, k_new, pack_kv_rows(v_new[:, 0], pack), pos)

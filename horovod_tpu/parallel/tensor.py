@@ -441,10 +441,12 @@ class ParallelSelfAttention(nn.Module):
     # None (default): the code chooses (`ops.flash_attention.
     # decode_attention_plan`) — the ragged flash-decode kernel for an
     # S=1 step on a TPU (un-quantized cache, no serving mesh, head_dim
-    # a multiple of 128), the fori_loop walk for everything else (CPU,
-    # int8 KV, S>1 chunks, a mesh). "lax" forces the walk (the
-    # oracle); "pallas" forces the kernel wherever it can run at all
-    # (interpret mode off the chip) — what tests need, not users.
+    # a multiple of 128 or one that packs whole into 128 lanes: the
+    # cache stores `kv_pack` KV heads to a row), the fori_loop walk
+    # for everything else (CPU, int8 KV, S>1 chunks, a mesh). "lax"
+    # forces the walk (the oracle); "pallas" forces the kernel
+    # wherever it can run at all (interpret mode off the chip) — what
+    # tests need, not users.
     decode_prefix_impl: Optional[str] = None
     # Projections carry no bias by default (LLaMA-style); GPT-2-family
     # checkpoints (compat.hf) need them.
@@ -589,15 +591,43 @@ class ParallelSelfAttention(nn.Module):
         m = banded_causal_mask(pos, pos, self.window)[None, None]
         return self._dispatch_attn(q, k, v, m)
 
+    @property
+    def _kv_pack(self) -> int:
+        """KV heads the cache leaves store to a row (`ops.
+        flash_attention.kv_pack`: 128 // head_dim of a head narrower
+        than the chip's lanes, so that a stored row is lane-whole and
+        the ragged kernel and the in-place append step it where it
+        lies). Every other reader and writer of the leaves goes
+        through `_stored` / `_heads` - reshapes. The int8 cache keeps
+        a head a row: its scales are a head's, and it stays on the
+        walk."""
+        from horovod_tpu.ops.flash_attention import kv_pack
+        if self.kv_quant is not None:
+            return 1
+        return kv_pack(self.num_kv_heads or self.num_heads,
+                       self.head_dim)
+
+    def _stored(self, t):
+        """K or V rows [..., Hkv, D] as the cache leaves store them."""
+        from horovod_tpu.ops.flash_attention import pack_kv_rows
+        return pack_kv_rows(t, self._kv_pack)
+
+    def _heads(self, t):
+        """Stored rows (a leaf, a block of one, a paged pool) as
+        [..., Hkv, D]."""
+        from horovod_tpu.ops.flash_attention import unpack_kv_rows
+        return unpack_kv_rows(t, self._kv_pack)
+
     def _kv_cache_vars(self, k, v, L0):
         """Cache storage for K/V (+ per-(position, head) scale vars
-        when ``kv_quant``). Shape args are only read at creation time
+        when ``kv_quant``): [..., L0, Hkv // pack, D * pack]
+        (`_kv_pack`). Shape args are only read at creation time
         (model.init)."""
-        cache_shape = (*k.shape[:-3], L0, *k.shape[-2:])
-        store = jnp.int8 if self.kv_quant == "int8" else k.dtype
         if self.kv_quant not in (None, "int8"):
             raise ValueError(
                 f"unsupported kv_quant {self.kv_quant!r}")
+        cache_shape = (*k.shape[:-3], L0, *self._stored(k).shape[-2:])
+        store = jnp.int8 if self.kv_quant == "int8" else k.dtype
         cached_k = self.variable("cache", "cached_key",
                                  jnp.zeros, cache_shape, store)
         cached_v = self.variable("cache", "cached_value",
@@ -616,7 +646,7 @@ class ParallelSelfAttention(nn.Module):
         """The cache at the compute dtype (dequantized under
         ``kv_quant`` via the single tested codec)."""
         if scale is None:
-            return cached.value
+            return self._heads(cached.value)
         from horovod_tpu.ops.quantization import dequantize_int8
         return dequantize_int8(cached.value, scale.value,
                                self.dtype or jnp.float32, axis=-1)
@@ -631,6 +661,8 @@ class ParallelSelfAttention(nn.Module):
         if self.kv_quant == "int8":
             k, sk = _kv_quantize(k)
             v, sv = _kv_quantize(v)
+        else:
+            k, v = self._stored(k), self._stored(v)
         if self.window is None:
             z = jnp.zeros((), i.dtype)
             cached_k.value = lax.dynamic_update_slice(
@@ -668,7 +700,7 @@ class ParallelSelfAttention(nn.Module):
         blk = lax.dynamic_slice_in_dim(cached.value, start, size,
                                        axis=-3)
         if scale is None:
-            return blk
+            return self._heads(blk)
         from horovod_tpu.ops.quantization import dequantize_int8
         sb = lax.dynamic_slice_in_dim(scale.value, start, size,
                                       axis=-2)
@@ -802,6 +834,9 @@ class ParallelSelfAttention(nn.Module):
                    else None)
         table = self.get_variable("paged", "table")
         fill = self.get_variable("paged", "fill")
+        # the pools take the leaves' stored rows; the paged walk and
+        # kernel read them a head a row
+        k_pool, v_pool = self._heads(k_pool), self._heads(v_pool)
         q, k = self._maybe_rope(q, k, offset=fill)
         # Staging write at position 0 (i is the staging cache_index):
         # the rows pass through the same codec the pool stores, and

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 
 from horovod_tpu.models.transformer import TransformerLM
 from horovod_tpu.ops.flash_attention import (
-    decode_attention_plan, flash_cache_append,
+    decode_attention_plan, flash_cache_append, kv_pack,
 )
 from horovod_tpu.parallel.tensor import ParallelSelfAttention, unbox
 from horovod_tpu.serving import ServingEngine
@@ -38,11 +38,12 @@ class XlaWrite(ParallelSelfAttention):
 
 
 def xla_write(k_cache, v_cache, k_new, v_new, index, ring):
-    """One [B, W, Hkv, D] cache after `_cache_write`: the linear
-    cache's `dynamic_update_slice` at ``index``, or the ring's update
-    of slot ``index mod W``."""
+    """One [B, W, Hkv, D] cache (as stored: a head of 64 has two KV
+    heads to a row) after `_cache_write`: the linear cache's
+    `dynamic_update_slice` at ``index``, or the ring's update of slot
+    ``index mod W``."""
     _, mut = XlaWrite(
-        num_heads=k_cache.shape[-2], head_dim=D, decode=True,
+        num_heads=k_new.shape[-2], head_dim=k_new.shape[-1], decode=True,
         window=W if ring else None).apply(
         {"cache": {"cached_key": k_cache, "cached_value": v_cache,
                    "cache_index": index}}, k_new, v_new,
@@ -65,6 +66,16 @@ CASES.update({
     # `generate`: B rows at one scalar index, no vmap
     "rows-at-one-index": dict(dtype="bfloat16", Hkv=2, index=17, rows=3),
     "rows-past-the-end": dict(dtype="float32", Hkv=1, index=W, rows=2),
+    # heads of 64, two to a stored row of 128: granite's 8 KV heads
+    # are 4 rows a position, 4 positions a tile
+    "packed-bfloat16-hkv8": dict(dtype="bfloat16", Hkv=8, D=64,
+                                 index=LINEAR),
+    "packed-float32-hkv2": dict(dtype="float32", Hkv=2, D=64,
+                                index=LINEAR),
+    "packed-ring": dict(dtype="bfloat16", Hkv=4, D=64, ring=True,
+                        index=[0, W - 1, W, 2 * W + 21]),
+    "packed-rows-at-one-index": dict(dtype="bfloat16", Hkv=2, D=64,
+                                     index=17, rows=3),
 })
 
 
@@ -83,15 +94,18 @@ def test_append_is_cache_writes_update_bit_for_bit(case):
     def slot_of(i):
         return i % W if ring else i
 
+    d = c.get("D", D)
+    stored = (W, Hkv * d // D, D)       # `kv_pack` heads a row
+    assert stored[1:] == (Hkv // kv_pack(Hkv, d), d * kv_pack(Hkv, d))
     if "rows" in c:
-        kc, vc = rand(lanes, W, Hkv, D), rand(lanes, W, Hkv, D)
-        kn, vn = rand(lanes, 1, Hkv, D), rand(lanes, 1, Hkv, D)
+        kc, vc = rand(lanes, *stored), rand(lanes, *stored)
+        kn, vn = rand(lanes, 1, Hkv, d), rand(lanes, 1, Hkv, d)
         want = xla_write(kc, vc, kn, vn, index, ring)
         got = jax.jit(flash_cache_append)(kc, vc, kn, vn, slot_of(index))
     else:
         # the tick's view: a slot axis over B = 1 caches, vmapped
-        kc, vc = rand(lanes, 1, W, Hkv, D), rand(lanes, 1, W, Hkv, D)
-        kn, vn = rand(lanes, 1, 1, Hkv, D), rand(lanes, 1, 1, Hkv, D)
+        kc, vc = rand(lanes, 1, *stored), rand(lanes, 1, *stored)
+        kn, vn = rand(lanes, 1, 1, Hkv, d), rand(lanes, 1, 1, Hkv, d)
         want = jax.vmap(lambda *a: xla_write(*a, ring))(
             kc, vc, kn, vn, index)
         tick = jax.vmap(lambda kc, vc, kn, vn, i: flash_cache_append(
@@ -127,7 +141,13 @@ WRITE = {
     "chunk": (dict(on_tpu=True, S=128), "xla", "only the"),
     "verify-block": (dict(impl="pallas", S=4), "xla", "only the"),
     "forced-lax": (dict(on_tpu=True, impl="lax"), "xla", "only the"),
-    "head-dim-64": (dict(on_tpu=True, D=64), "xla", "only the"),
+    # two heads of 64 to a row: 1 row a position here, 4 at granite
+    "head-dim-64": (dict(on_tpu=True, D=64), "kernel", "16 rows"),
+    "granite": (dict(on_tpu=True, lanes=64, W=2048, H=32, Hkv=8, D=64),
+                "kernel", "16 rows"),
+    "head-dim-64-odd-kv": (dict(on_tpu=True, D=64, H=12, Hkv=3), "xla",
+                           "only the"),
+    "head-dim-96": (dict(on_tpu=True, D=96), "xla", "only the"),
 }
 
 

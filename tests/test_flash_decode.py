@@ -19,37 +19,45 @@ from horovod_tpu.models.transformer import (
     slot_decode_model, slot_decode_tick, slot_prefill_chunk,
 )
 from horovod_tpu.ops.flash_attention import (
-    decode_attention_plan, flash_decode_attention,
+    DecodePlan, decode_attention_plan, flash_decode_attention, kv_pack,
+    unpack_kv_rows,
 )
 from horovod_tpu.parallel.tensor import ParallelSelfAttention, unbox
 
 D, W, BK = 128, 512, 128
 HEADS = {"12q2kv": (12, 2), "64q8kv": (64, 8), "mha4": (4, 4)}
+# heads of 64: the cache stores two KV heads to a 128-lane row
+# (`kv_pack`); "-scale" is granite's 1/64, folded into q against
+# 64 ** -0.5 - a kernel that scales by the row's width misses it
+PACKED = {"32q8kv-d64": (32, 8, 64),
+          "4q2kv-d64-scale": (4, 2, 64, 1 / 64)}
 # one fill a lane; "ragged" is a tick's mix (fill 1: a just-reset lane)
 LENGTHS = {"one": [1], "block": [BK], "block+1": [BK + 1], "full": [W],
            "ragged": [1, BK, BK + 1, W, 37, 300]}
 
 
-def attention(H, Hkv, impl):
+def attention(impl, H, Hkv, D=D, scale=None):
     return ParallelSelfAttention(
         num_heads=H, head_dim=D, num_kv_heads=Hkv, decode=True,
         chunked_prefill=True, decode_prefix_block=BK,
-        decode_prefix_impl=impl, out_features=32, dtype=jnp.float32)
+        decode_prefix_impl=impl, out_features=32, dtype=jnp.float32,
+        softmax_scale=scale)
 
 
-def lanes_state(H, Hkv, lengths, seed=0):
+def lanes_state(lengths, *shape, seed=0):
     """Parameters, one random token a lane and a cache a lane whose
     prefix is filled to ``length - 1`` (the step writes the last)."""
     r = np.random.RandomState(seed)
     L = len(lengths)
-    variables = attention(H, Hkv, "lax").init(
+    variables = attention("lax", *shape).init(
         jax.random.PRNGKey(seed), jnp.zeros((1, W, 32), jnp.float32))
     cache = jax.tree.map(
         lambda leaf: jnp.zeros((L,) + leaf.shape, leaf.dtype),
         variables["cache"])
 
-    def kv():
-        return jnp.asarray(r.randn(L, 1, W, Hkv, D), jnp.float32)
+    def kv():       # the leaf's stored shape: [L, 1, W, Hkv // pack, 128]
+        return jnp.asarray(r.randn(*cache["cached_key"].shape),
+                           jnp.float32)
 
     cache = dict(cache, cached_key=kv(), cached_value=kv(),
                  cache_index=jnp.asarray(lengths, jnp.int32) - 1)
@@ -57,32 +65,37 @@ def lanes_state(H, Hkv, lengths, seed=0):
     return unbox(variables["params"]), cache, x
 
 
-def step(H, Hkv, impl, params, cache, x):
+def step(impl, params, cache, x, *shape):
     """One S = 1 step of every lane, vmapped as the tick vmaps it."""
     def one(sub, x):
-        return attention(H, Hkv, impl).apply(
+        return attention(impl, *shape).apply(
             {"params": params, "cache": sub}, x, mutable=["cache"])
     return jax.jit(jax.vmap(one))(cache, x)
 
 
-@pytest.mark.parametrize("lengths", sorted(LENGTHS))
-@pytest.mark.parametrize("heads", sorted(HEADS))
+@pytest.mark.parametrize("heads,lengths", [
+    (h, n) for h in sorted(HEADS) for n in sorted(LENGTHS)]
+    + [(h, "ragged") for h in sorted(PACKED)])
 def test_kernel_matches_the_walk_lane_by_lane(heads, lengths):
-    H, Hkv = HEADS[heads]
-    params, cache, x = lanes_state(H, Hkv, LENGTHS[lengths])
-    want, cw = step(H, Hkv, "lax", params, cache, x)
-    got, cg = step(H, Hkv, "pallas", params, cache, x)
+    shape = {**HEADS, **PACKED}[heads]
+    params, cache, x = lanes_state(LENGTHS[lengths], *shape)
+    assert cache["cached_key"].shape[-2:] == (
+        (shape[1] // 2, 128) if heads in PACKED else (shape[1], D))
+    want, cw = step("lax", params, cache, x, *shape)
+    got, cg = step("pallas", params, cache, x, *shape)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
     # the write the two paths share: same rows, same advanced index
     for a, b in zip(jax.tree.leaves(cg), jax.tree.leaves(cw)):
         np.testing.assert_array_equal(a, b)
 
 
-def kv_case(H, Hkv, L, seed=3):
+def kv_case(H, Hkv, L, D=D, seed=3):
+    """q and the caches as they are stored (`kv_pack` heads a row)."""
     r = np.random.RandomState(seed)
+    pack = kv_pack(Hkv, D)
     q = jnp.asarray(r.randn(L, 1, H, D), jnp.float32)
-    k = jnp.asarray(r.randn(L, W, Hkv, D), jnp.float32)
-    v = jnp.asarray(r.randn(L, W, Hkv, D), jnp.float32)
+    k = jnp.asarray(r.randn(L, W, Hkv // pack, D * pack), jnp.float32)
+    v = jnp.asarray(r.randn(L, W, Hkv // pack, D * pack), jnp.float32)
     return q, k, v
 
 
@@ -103,6 +116,27 @@ def test_vmapped_entry_matches_a_loop_over_lanes(heads):
     batched = flash_decode_attention(q, k, v, n, block_k=BK)
     np.testing.assert_array_equal(vmapped, looped)
     np.testing.assert_array_equal(batched, looped)
+
+
+@pytest.mark.parametrize("scale", [None, 1 / 64])
+def test_packed_rows_against_the_plain_softmax(scale):
+    """The entry itself at a head of 64 over rows of two KV heads,
+    against softmax(scale q k^T) v on the heads unpacked: every query
+    head reads its own head's half of a row, at the TRUE head's scale
+    (64 ** -0.5 where none is given, not the row's 128 ** -0.5)."""
+    H, Hkv, d = 8, 4, 64
+    lengths = jnp.asarray([1, BK, W, 300], jnp.int32)
+    q, k, v = kv_case(H, Hkv, 4, d)
+    got = jax.vmap(
+        lambda q, k, v, n: flash_decode_attention(
+            q[None], k[None], v[None], n, block_k=BK, scale=scale)[0])(
+        q, k, v, lengths)
+    k, v = (jnp.repeat(unpack_kv_rows(t, 2), H // Hkv, axis=2)
+            for t in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q * (scale or d ** -0.5), k)
+    s = jnp.where(jnp.arange(W) < lengths[:, None, None, None], s, -1e30)
+    want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
 def test_vmapped_entry_is_one_call_not_a_loop():
@@ -202,7 +236,13 @@ RULE = {
     "int8-kv": (dict(on_tpu=True, quantized=True), "lax"),
     "mesh": (dict(on_tpu=True, trivial_mesh=False), "lax"),
     "chunk": (dict(on_tpu=True, S=16), "lax"),
-    "head-dim-64": (dict(on_tpu=True, D=64), "lax"),
+    # two heads of 64 are a 128-lane row; three halves, and a head of
+    # 96 or 80, are not whole rows
+    "head-dim-64": (dict(on_tpu=True, D=64), "kernel", "2 heads a row"),
+    "head-dim-64-odd-kv": (dict(on_tpu=True, D=64, Hkv=3), "lax",
+                           "3 KV heads do not pack 2 to a row"),
+    "head-dim-96": (dict(on_tpu=True, D=96), "lax", "nor a whole part"),
+    "head-dim-80": (dict(on_tpu=True, D=80), "lax", "nor a whole part"),
     "no-block-divides": (dict(on_tpu=True, W=4100), "lax"),
     "forced-lax": (dict(on_tpu=True, impl="lax"), "lax"),
     "forced-kernel-off-chip": (dict(on_tpu=False, impl="pallas"), "kernel"),
@@ -216,13 +256,14 @@ RULE = {
 
 @pytest.mark.parametrize("case", sorted(RULE))
 def test_selection_rule(case):
-    kw, path = RULE[case]
-    shape = dict(QWEN, **{k: kw.pop(k) for k in ("W", "D") if k in kw})
+    kw, path, *why = RULE[case]
+    shape = dict(QWEN, **{k: kw.pop(k) for k in ("W", "Hkv", "D")
+                          if k in kw})
     plan = decode_attention_plan(
         shape["lanes"], shape["W"], shape["H"], shape["Hkv"], shape["D"],
         **kw)
     assert plan.path == path, plan
-    assert plan.why
+    assert plan.why and all(w in plan.why for w in why), plan
     if path == "kernel":
         assert shape["W"] % plan.block_k == 0
         assert plan.grid == (32, shape["W"] // plan.block_k)
@@ -236,16 +277,30 @@ def test_rule_rejects_an_unknown_impl():
         decode_attention_plan(1, 256, 4, 4, 128, impl="cuda")
 
 
-@pytest.mark.parametrize("cell,block_k,grid", [
-    ("qwen", 1024, (32, 4)), ("solar", 256, (128, 8))])
-def test_block_follows_the_shape(cell, block_k, grid):
-    """The two serving cells' attention shapes: a K block of half a
-    MiB, whatever the head count."""
-    shape = {"qwen": (32, 4096, 12, 2, 128),
-             "solar": (128, 2048, 64, 8, 128)}[cell]
-    plan = decode_attention_plan(*shape, on_tpu=True)
-    assert (plan.block_k, plan.grid) == (block_k, grid)
-    assert plan.vmem_limit_bytes is None       # inside Mosaic's default
+WRITE = ("kernel", "one aliased call for all lanes, a tile of 16 rows")
+# the cells' attention shapes -> the plan, field for field (D 128: the
+# plans as they stood before a head of 64 packed)
+CELLS = {
+    "qwen": ((32, 4096, 12, 2, 128), DecodePlan(
+        "kernel", "S = 1 on a TPU", 1024, (32, 4), 2662400, None,
+        *WRITE)),
+    "solar": ((128, 2048, 64, 8, 128), DecodePlan(
+        "kernel", "S = 1 on a TPU", 256, (128, 8), 4358144, None,
+        *WRITE)),
+    "granite": ((64, 2048, 32, 8, 64), DecodePlan(
+        "kernel", "S = 1 on a TPU, 2 heads a row", 512, (64, 4),
+        3227648, None, *WRITE, pack=2)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_block_follows_the_shape(cell):
+    """The serving cells' attention shapes: a K block of half a MiB,
+    whatever the head count, inside Mosaic's default VMEM; granite's
+    head of 64 planned at its stored rows [2048, 4, 128]."""
+    shape, plan = CELLS[cell]
+    assert decode_attention_plan(*shape, on_tpu=True) == plan
+    assert plan.pack == 1 or f"{plan.pack} heads a row" in plan.describe()
 
 
 def test_cpu_model_keeps_the_walk_and_says_why():

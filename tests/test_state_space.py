@@ -18,9 +18,9 @@ import jax.numpy as jnp
 
 from benchmarks.harness.cells import load_module
 from horovod_tpu.models.transformer import (
-    RECURRENT_KINDS, TransformerLM, generate, init_slot_cache,
-    slot_decode_model, slot_decode_tick, slot_prefill_chunk,
-    state_step_plans,
+    RECURRENT_KINDS, TransformerLM, decode_attention_plan, generate,
+    init_slot_cache, slot_decode_model, slot_decode_tick,
+    slot_prefill_chunk, state_step_plans,
 )
 from horovod_tpu.ops.ssm_step import ssm_state_step, ssm_step_plan
 from horovod_tpu.parallel.state_space import (
@@ -381,6 +381,69 @@ def test_engine_greedy_equals_generate_and_reports_the_state(params):
     assert nbytes["state"] == snap["pool_bytes"]["state"] == (
         3 * 2 * (16 * 128 + 3 * 160) * 4)
     assert nbytes["kv"] == 2 * 2 * MAX_LEN * 2 * 16 * 4
+
+
+# ---- granite's head of 64: two KV heads to a stored row ------------------------
+# One state-space and one NoPE GQA layer at the real head, 4 heads of
+# 64 over 2 KV heads at scale 1/64: the leaves store ONE row of 128
+# (`ops.flash_attention.kv_pack`), whatever reads or writes them.
+ARCH_64 = dict(ARCH, num_layers=2, layer_kinds=["mamba", "attention"],
+               head_dim=64, attn_scale=1 / 64)
+
+
+def test_head_of_64_is_served_from_packed_rows_on_every_path():
+    """Chunks prefill over the packed leaf (the walk) and ticks step
+    it - by the walk, and by the ragged kernel and the in-place append
+    forced (interpret mode); `generate` fills it in one pass and steps
+    it by the kernel: one greedy stream, the reference's, and the last
+    logits of kernel and walk within the kernel-against-walk
+    tolerance. A reader or writer of the leaf that missed the packing
+    parts here."""
+    from horovod_tpu.serving.slots import SlotPool
+    params = A.make_params(ARCH_64, MAX_LEN, 5, "float32")
+    model = f32_model(ARCH_64)
+    kernel = model.clone(decode_prefix_impl="pallas")
+    assert decode_attention_plan(kernel, 2).pack == 2
+    prompts = [tokens(20, 3), tokens(4, 4)]     # chunks of 16 + 4, and 4
+    new = 8
+
+    def serve(m):
+        pool = SlotPool(m, params, 2)
+        kv = pool._cache["block_1"]["attn"]["cached_key"]
+        assert kv.shape == (2, 1, MAX_LEN, 1, 128)
+        slots = [pool.alloc() for _ in prompts]
+        first = [pool.prefill(slot, p, 0.0, None, 0)
+                 for slot, p in zip(slots, prompts)]
+        streams = np.stack([first] + [pool.tick()[slots]
+                                      for _ in range(new - 1)])
+
+        @jax.jit
+        def next_logits(cache, slot, tok):
+            sub = jax.tree.map(lambda leaf: leaf[slot], cache)
+            (h, emb), _ = pool.dec_model.apply(
+                {"params": params, "cache": sub}, tok[None, None],
+                return_hidden=True, mutable=["cache"])
+            return jnp.einsum("d,vd->v", h[0, -1], emb)
+
+        last = [next_logits(pool._cache, slot, jnp.asarray(tok))
+                for slot, tok in zip(slots, streams[-1])]
+        return streams.T, np.stack(last), pool.decode_attention_plans()
+
+    want, want_l, plans = serve(model)
+    assert plans["attn"].path == "lax"
+    got, got_l, plans = serve(kernel)
+    assert "2 heads a row" in plans["attn"].describe()
+    assert plans["attn"].write == "kernel"
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_allclose(got_l, want_l, atol=2e-5, rtol=2e-5)
+    # one pass over an empty packed cache, then the kernel's steps
+    out = np.asarray(generate(kernel, params, prompts[1][None], new))[0]
+    np.testing.assert_array_equal(out[len(prompts[1]):], want[1])
+    # and the stream is the reference's: a full forward pass a token
+    seq = np.concatenate([prompts[0], want[0]])
+    ref = A.logits(ARCH_64, params, jnp.asarray(seq[:-1]))
+    np.testing.assert_array_equal(
+        np.argmax(np.asarray(ref[len(prompts[0]) - 1:]), -1), want[0])
 
 
 def test_options_that_need_appended_kv_refuse_by_name(params):

@@ -260,6 +260,12 @@ TICK_SHAPES = {
     "solar-open2-250b": (128, 2048, dict(
         num_heads=64, num_kv_heads=8, head_dim=128, hidden_size=4096,
         pos_emb="none", attn_gate=True)),
+    # heads of 64 at scale 1/64: the leaves store two KV heads to a
+    # 128-lane row, [2048, 4, 128] a lane (`kv_pack`); one layer (the
+    # suite's clock)
+    "granite-4.0-h-micro": (64, 2048, dict(
+        num_heads=32, num_kv_heads=8, head_dim=64, hidden_size=2048,
+        pos_emb="none", attn_scale=1 / 64, num_layers=1)),
 }
 
 
@@ -299,7 +305,7 @@ def test_slot_decode_tick_attends_through_the_ragged_kernel(
     two layout copies of each a tick; a defensive copy before the
     aliased append would cost more than the loops it replaced)."""
     from horovod_tpu.models.transformer import (
-        TransformerLM, decode_attention_plan, init_slot_cache,
+        AttnSpec, TransformerLM, decode_attention_plan, init_slot_cache,
         serving_params, slot_decode_model, slot_decode_tick)
     from horovod_tpu.ops import flash_attention
     from horovod_tpu.parallel.tensor import unbox
@@ -309,13 +315,19 @@ def test_slot_decode_tick_attends_through_the_ragged_kernel(
     monkeypatch.setattr(flash_attention, "_auto_interpret",
                         lambda: False)
     lanes, W, fields = TICK_SHAPES[cell]
-    layers = 2
+    fields = dict(fields)
+    scale = fields.pop("attn_scale", None)
+    if scale:
+        fields["attn_specs"] = (("attn", AttnSpec(scale=scale)),)
+    layers = fields.pop("num_layers", 2)
     model = TransformerLM(
         vocab_size=4096, num_layers=layers, max_len=W, norm="rmsnorm",
         mlp_impl="swiglu", mlp_hidden=1024, dtype=jnp.bfloat16,
         attn_impl="flash", **fields)
     plan = decode_attention_plan(model, lanes)
     assert plan.path == "kernel" and plan.grid[0] == lanes, plan
+    pack = 128 // fields["head_dim"]
+    assert plan.pack == pack
     dec = slot_decode_model(model)
 
     def place(tree):
@@ -326,6 +338,8 @@ def test_slot_decode_tick_attends_through_the_ragged_kernel(
             r, jnp.zeros((1, 64), jnp.int32))["params"])),
         jax.random.PRNGKey(0)))
     cache = place(jax.eval_shape(lambda: init_slot_cache(model, lanes)))
+    assert cache["block_0"]["attn"]["cached_key"].shape == (
+        lanes, 1, W, fields["num_kv_heads"] // pack, 128)
     vec = lambda dt: sds((lanes,), dt)  # noqa: E731
     compiled = slot_decode_tick.lower(
         dec, params, cache, vec(jnp.int32), vec(jnp.float32),
