@@ -301,6 +301,7 @@ class TransformerBlock(nn.Module):
     moe_zero_experts: int = 0            # HeldExpertsMoE.zero_experts
     moe_normalize: bool = True           # HeldExpertsMoE.normalize
     moe_router_bias: Optional[bool] = None
+    moe_groups: Optional[Tuple[int, int]] = None  # HeldExpertsMoE.groups
     latent: Optional[LatentSpec] = None  # the widths of an "mla" mixer
     ssm: Optional[SsmSpec] = None        # the widths of an "ssm" mixer
     shortcut_moe: bool = False           # see the docstring
@@ -399,6 +400,7 @@ class TransformerBlock(nn.Module):
                     zero_experts=self.moe_zero_experts,
                     normalize=self.moe_normalize,
                     router_bias=self.moe_router_bias,
+                    groups=self.moe_groups,
                     dtype=self.dtype, name="moe")(h)
             if self.moe_impl == "gshard":
                 return MoELayer(
@@ -537,7 +539,8 @@ class TransformerLM(nn.Module):
     # an "ssm" layer (`parallel.state_space`), at the widths of
     # ``ssm``; "swa" is a second kind of softmax layer, under its own
     # scope; an "mla" layer keeps head-less latent rows
-    # (`parallel.latent_attention`), at the widths of ``latent``.
+    # (`parallel.latent_attention`), at the widths of ``latent`` - which
+    # also carries that layer's rotary rule and softmax factor.
     layer_kinds: Optional[Tuple[str, ...]] = None
     latent: Optional[LatentSpec] = None
     ssm: Optional[SsmSpec] = None
@@ -560,6 +563,9 @@ class TransformerLM(nn.Module):
     moe_zero_experts: int = 0       # `HeldExpertsMoE.zero_experts`
     moe_normalize: bool = True      # `HeldExpertsMoE.normalize`
     moe_router_bias: Optional[bool] = None
+    # (n_group, topk_group): the choice limited to groups
+    # (`HeldExpertsMoE.groups`, the DeepSeek-V3 family's gate)
+    moe_groups: Optional[Tuple[int, int]] = None
     # Every layer is LongCat-Flash's: two mixers, two dense MLPs and a
     # shortcut-connected expert layer (`TransformerBlock.shortcut_moe`;
     # with ``moe_every=1``) - so a layer has TWO caches.
@@ -735,6 +741,7 @@ class TransformerLM(nn.Module):
                 moe_zero_experts=self.moe_zero_experts,
                 moe_normalize=self.moe_normalize,
                 moe_router_bias=self.moe_router_bias,
+                moe_groups=self.moe_groups,
                 latent=self.latent, ssm=self.ssm,
                 shortcut_moe=self.moe_shortcut,
                 name=f"block_{i}")(
@@ -1507,6 +1514,18 @@ def slot_reset(dec_model, cache, slot):
 # What a row of `_moe_pairs` holds after the held experts' pairs where
 # the model has identity experts (`HeldExpertsMoE.zero_experts`).
 MOE_ROUTED_COLUMNS = ("moe_zero_pairs", "moe_chosen_pairs")
+# ... and where its choice is limited to groups over a share of the
+# experts (`HeldExpertsMoE.groups` with ``held``): the chips of the
+# stated deployment that the tokens' experts lie on.
+MOE_CHIPS_COLUMNS = ("moe_token_chips",)
+
+
+def moe_stat_columns(model: "TransformerLM") -> Tuple[str, ...]:
+    """Names of the counts that a row of `_moe_pairs` holds after the
+    held experts' pairs, in the row's order; () for most models."""
+    return ((MOE_ROUTED_COLUMNS if model.moe_zero_experts else ())
+            + (MOE_CHIPS_COLUMNS if model.moe_groups is not None
+               and model.moe_held is not None else ()))
 
 
 def _moe_pairs(dec_model, mut):
@@ -1515,14 +1534,29 @@ def _moe_pairs(dec_model, mut):
     pairs on each held expert; [0, 0] for a model without such a
     layer. A model with identity experts (``moe_zero_experts``) gets
     `MOE_ROUTED_COLUMNS` as two more columns: the pairs on identity
-    experts and all the pairs chosen."""
+    experts and all the pairs chosen; one whose choice is limited to
+    groups over a share gets `MOE_CHIPS_COLUMNS` (`moe_stat_columns`
+    names what a row holds)."""
     sown = mut.get("moe_stats", {})
     layers = [sown[f"block_{i}"]["moe"]
               for i in range(dec_model.num_layers) if f"block_{i}" in sown]
     # a layer with identity experts adds its two `routed` counts
-    # (`MOE_ROUTED_COLUMNS`) as the row's last columns
-    rows = [jnp.concatenate([m["pairs"], m["routed"]])
-            if "routed" in m else m["pairs"] for m in layers]
+    # (`MOE_ROUTED_COLUMNS`), one with a group rule over a share its
+    # `token_chips`, as the row's last columns. `moe_stat_columns` alone
+    # decides which - the host strips the columns by the same function -
+    # so a layer that did not sow what it names fails here, at trace
+    # time, and not as a count read for an expert's pairs.
+    columns = moe_stat_columns(dec_model)
+
+    def row(m):
+        parts = [m["pairs"]]
+        if MOE_ROUTED_COLUMNS[0] in columns:
+            parts.append(m["routed"])
+        if MOE_CHIPS_COLUMNS[0] in columns:
+            parts.append(m["token_chips"][None])
+        return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
+
+    rows = [row(m) for m in layers]
     return jnp.stack(rows) if rows else jnp.zeros((0, 0), jnp.int32)
 
 

@@ -81,6 +81,15 @@ def serving_metrics(reg: Optional[MetricRegistry] = None) -> Dict:
             "layer's - and convolution tails that each step "
             "overwrites)",
             ("engine", "kind")),
+        "moe_token_chips": reg.counter(
+            "hvd_serving_moe_token_chips_total",
+            "Summed over decoding lanes and expert layers a tick, the "
+            "DISTINCT chips of the stated deployment (chosen expert id "
+            "// experts held a chip) that a token's k experts lie on: "
+            "the fan-out of the exchange a group-limited choice "
+            "(HeldExpertsMoE.groups) exists to bound; it grows only "
+            "for a model with such a gate over a share of the experts "
+            "(metrics_snapshot: moe_token_chips, absent otherwise)"),
         "kv_blocks_free_shard": reg.gauge(
             "hvd_kv_blocks_free_per_shard",
             "Paged-KV block shards on the free list, per mesh shard",

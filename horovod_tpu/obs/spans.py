@@ -141,8 +141,9 @@ SPAN_CATALOG: Dict[str, str] = {
         "them and retiring the finished (attrs overlapped, tokens, "
         "retired; for a model with dropless expert layers the tick's "
         "moe_pairs, moe_expert_load_max, moe_experts_hit, moe_layers, "
-        "moe_prefill_pairs, and with identity experts moe_zero_pairs, "
-        "moe_chosen_pairs)",
+        "moe_prefill_pairs, with identity experts moe_zero_pairs, "
+        "moe_chosen_pairs, and with a group-limited choice over a "
+        "share of the experts moe_token_chips)",
     "serving.admission":
         "Queue-head pop to prefill schedule: slot+block admission, "
         "swap restore credit, prefix-cache match",

@@ -255,6 +255,37 @@ def grouped_experts(x, key, weight, w_gate, w_up, w_down, *,
                                        w_down)
 
 
+def group_limited(pick, n_group, topk_group):
+    """The DeepSeek-V3 family's group-limited choice, as a mask on the
+    scores the choice is made over: ``pick`` [T, outputs] (scores, +
+    bias where the gate has one) in ``n_group`` groups of consecutive
+    outputs; a group's score is the sum of its two largest entries;
+    the ``topk_group`` best groups are kept (ties as `lax.top_k` breaks
+    them: the lower index) and every other group's entries become
+    -inf, so that the `top_k` that follows chooses among the kept
+    groups' outputs alone. float32 in, float32 out."""
+    T, outputs = pick.shape
+    grouped = pick.reshape(T, n_group, outputs // n_group)
+    best2, _ = lax.top_k(grouped, 2)
+    _, kept = lax.top_k(best2.sum(-1), topk_group)      # [T, topk_group]
+    mask = (kept[..., None] == jnp.arange(n_group)).any(-2)
+    return jnp.where(mask[..., None], grouped, -jnp.inf).reshape(
+        T, outputs)
+
+
+def token_chips(chosen, per_chip):
+    """Summed over the tokens of ``chosen`` [T, k], the number of
+    DISTINCT chips (``id // per_chip``) a token's k experts lie on: the
+    fan-out of the exchange an expert-parallel deployment would pay.
+    int32 scalar. Counted as the entries no earlier entry of the token
+    equals (k x k comparisons that fuse; no sort)."""
+    chips = chosen // per_chip
+    same = chips[:, :, None] == chips[:, None, :]           # [T, k, k]
+    k = chosen.shape[-1]
+    earlier = jnp.arange(k)[None, :] < jnp.arange(k)[:, None]
+    return jnp.sum(~(same & earlier).any(-1), dtype=jnp.int32)
+
+
 class HeldExpertsMoE(nn.Module):
     """Mixture of experts as one chip of an expert-parallel deployment
     computes it: the router scores all ``num_experts``, each token's
@@ -271,7 +302,16 @@ class HeldExpertsMoE(nn.Module):
     "softmax" (the Qwen-MoE family's) - scores = softmax(x W_r) over
     all router outputs in float32, the k largest chosen, no bias.
     ``router_bias`` overrides whether the choice has the bias (None:
-    as above; LongCat's softmax router has one). Weights = the chosen
+    as above; LongCat's softmax router has one; A.X-K1's sigmoid
+    gate - `topk_method` "none", balance by an auxiliary loss - has
+    none, where DeepSeek-V3's `noaux_tc` gate is the biased one).
+    ``groups = (n_group, topk_group)`` limits the choice to groups
+    (`group_limited`: the router outputs in n_group groups of
+    consecutive ids, a group scored by the sum of its two largest
+    scores (+ bias where there is one), the topk_group best groups
+    kept, the k largest chosen among their outputs); the choice is
+    still over ALL router outputs, whatever is held here. None and
+    (1, 1) are the unlimited choice, the same program. Weights = the chosen
     scores, normalised to sum to one unless ``normalize`` is False
     (the published `norm_topk_prob`), times ``scale`` (the published
     `routed_scaling_factor`). Experts are SwiGLU MLPs of width
@@ -292,7 +332,10 @@ class HeldExpertsMoE(nn.Module):
     mutable) `pairs`: the (token, expert) pairs per held expert, int32
     [count]; with ``zero_experts`` also `routed`: int32 [2], the pairs
     that fell on identity experts - they cost nothing - and all the
-    pairs the tokens chose (tokens x k)."""
+    pairs the tokens chose (tokens x k); with ``groups`` on a share
+    (``held``) also `token_chips`: int32 scalar, `token_chips` of the
+    choice at ``count`` experts a chip - the fan-out that the group
+    limit exists to bound."""
 
     num_experts: int
     hidden: int
@@ -305,6 +348,7 @@ class HeldExpertsMoE(nn.Module):
     zero_experts: int = 0
     normalize: bool = True
     router_bias: Optional[bool] = None   # None: sigmoid yes, softmax no
+    groups: Optional[Tuple[int, int]] = None     # (n_group, topk_group)
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -321,6 +365,19 @@ class HeldExpertsMoE(nn.Module):
             raise ValueError(
                 f"router must be sigmoid|softmax, got {self.router!r}")
         outputs = self.num_experts + self.zero_experts
+        if self.groups is not None:
+            n_group, topk_group = self.groups
+            if self.zero_experts:
+                raise ValueError(
+                    "groups (a group-limited choice) is not defined "
+                    "beside zero_experts: no published gate has both")
+            if (not 1 <= topk_group <= n_group or outputs % n_group
+                    or topk_group * (outputs // n_group) < self.k
+                    or (n_group > 1 and outputs // n_group < 2)):
+                raise ValueError(
+                    f"groups={self.groups} does not divide {outputs} "
+                    f"router outputs into groups that can give "
+                    f"{self.k} experts a token")
         router = self.param("router", init, (d, outputs), jnp.float32)
 
         def experts(name, shape):
@@ -340,12 +397,14 @@ class HeldExpertsMoE(nn.Module):
                   else jax.nn.softmax(logits, axis=-1))
         biased = (self.router == "sigmoid" if self.router_bias is None
                   else self.router_bias)
+        pick = scores
         if biased:
-            bias = self.param("router_bias", nn.initializers.zeros,
-                              (outputs,), jnp.float32)
-            _, chosen = lax.top_k(scores + bias, self.k)
-        else:
-            _, chosen = lax.top_k(scores, self.k)
+            pick = scores + self.param(
+                "router_bias", nn.initializers.zeros, (outputs,),
+                jnp.float32)
+        if self.groups not in (None, (1, 1)):
+            pick = group_limited(pick, *self.groups)
+        _, chosen = lax.top_k(pick, self.k)
         weight = jnp.take_along_axis(scores, chosen, axis=-1)
         if self.normalize:
             weight = weight / weight.sum(-1, keepdims=True)
@@ -357,6 +416,12 @@ class HeldExpertsMoE(nn.Module):
                  jnp.sum(key.reshape(-1, 1) == jnp.arange(E), axis=0,
                          dtype=jnp.int32),
                  reduce_fn=lambda _, new: new, init_fn=lambda: None)
+        # only where it is asked for: an apply that does not collect the
+        # stats keeps the program it had (`pairs` above predates the rule)
+        if (self.groups is not None and self.held is not None
+                and self.is_mutable_collection("moe_stats")):
+            self.sow("moe_stats", "token_chips", token_chips(chosen, E),
+                     reduce_fn=lambda _, new: new, init_fn=lambda: None)
         self.sow("intermediates", "chosen", chosen,
                  reduce_fn=lambda _, new: new, init_fn=lambda: None)
         y = grouped_experts(xt.astype(dtype), key, weight, w_gate, w_up,

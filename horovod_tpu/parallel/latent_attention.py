@@ -1,6 +1,9 @@
-"""Multi-head latent attention (MLA, DeepSeek-V2 2024; as LongCat-Flash
-publishes it): queries and keys/values go through low-rank bottlenecks,
-and the decode cache holds the bottleneck, not the heads.
+"""Multi-head latent attention (MLA, DeepSeek-V2 2024): queries and
+keys/values go through low-rank bottlenecks, and the decode cache holds
+the bottleneck, not the heads. Two published users: LongCat-Flash (the
+two scale factors, plain rotation, softmax_factor 1) and A.X-K1 (the
+DeepSeek-V3 family's: no scale factors, YaRN's frequencies on the rope
+part, softmax_factor = mscale^2 = 1.81326).
 
 On its normed input u at position t, with H heads::
 
@@ -11,10 +14,14 @@ On its normed input u at position t, with H heads::
     k_rope = RoPE_t(k_r)   (ONE head, shared);   q_rope = RoPE_t(q[:, nope:])
     k_nope_h = W_UK,h c,   v_h = W_UV,h c        in R^nope, R^v
     score_h(t, j) = (q_nope_h . k_nope_h(j) + q_rope_h . k_rope(j))
-                    / sqrt(nope + rope),  causal
+                    * softmax_factor / sqrt(nope + rope),  causal
     out = W_o concat_h(softmax_j(score_h) v_h(j))
 
-(rotation on interleaved pairs (2j, 2j + 1)). The cache row of a
+(rotation on interleaved pairs (2j, 2j + 1), by the layer's `RopeSpec`
+- `LatentSpec.rope`: plain frequencies or YaRN's, cos and sin times its
+``scale`` - on the ONE shared key and on the queries' rope part alike,
+in every form below; the whole softmax scale is multiplied into q once,
+so neither the walk nor the kernel knows the factor). The cache row of a
 position is ``[c ; k_rope]`` - ``kv_rank + rope`` numbers, NO head
 axis - in one leaf, ``cached_latent`` [B, max_len, stored], beside its
 ``cache_index``. ``stored`` is the row padded with zeros to a multiple
@@ -65,7 +72,8 @@ from jax import lax
 import flax.linen as nn
 
 from horovod_tpu.parallel.tensor import (
-    ColumnParallelDense, RowParallelDense, _mesh_is_trivial, apply_rope,
+    ColumnParallelDense, RopeSpec, RowParallelDense, _mesh_is_trivial,
+    apply_rope,
 )
 
 
@@ -76,7 +84,11 @@ class LatentSpec:
     ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``; and
     LongCat's two scale factors, on q and on the normed latent
     (`mla_scale_q_lora`, `mla_scale_kv_lora`: sqrt(hidden / rank)
-    there; 1.0 = none)."""
+    there; 1.0 = none). ``rope`` is the rotary rule of the rope part
+    (None: plain frequencies at the layer's ``rope_theta``; a
+    `RopeSpec` with ``yarn_factor`` is the DeepSeek family's YaRN),
+    ``softmax_factor`` what multiplies 1 / sqrt(nope + rope) (that
+    family's mscale(factor, mscale_all_dim)^2; 1.0 = none)."""
     q_rank: int
     kv_rank: int
     nope_dim: int
@@ -84,6 +96,8 @@ class LatentSpec:
     v_dim: int
     q_scale: float = 1.0
     kv_scale: float = 1.0
+    rope: Optional[RopeSpec] = None
+    softmax_factor: float = 1.0
 
     @property
     def row(self) -> int:
@@ -98,7 +112,8 @@ class LatentSpec:
 
     @property
     def softmax_scale(self) -> float:
-        return (self.nope_dim + self.rope_dim) ** -0.5
+        return (self.softmax_factor
+                * (self.nope_dim + self.rope_dim) ** -0.5)
 
 
 def chunk_form(S: int, num_heads: int, spec: LatentSpec) -> str:
@@ -256,7 +271,8 @@ class LatentAttention(nn.Module):
         0] as stored) at absolute positions offset + arange(S)."""
         n = self.spec.nope_dim
         pos = offset + jnp.arange(q.shape[-3])
-        rule = dict(theta=self.rope_theta, interleaved=True)
+        rope = self.spec.rope or RopeSpec(theta=self.rope_theta)
+        rule = dict(rope.rotation(self.spec.rope_dim), interleaved=True)
         q = jnp.concatenate(
             [q[..., :n], apply_rope(q[..., n:], pos, **rule)], axis=-1)
         kr = apply_rope(kr[..., None, :], pos, **rule)[..., 0, :]

@@ -91,7 +91,7 @@ _ENGINE_IDS = itertools.count()
 # layer without a head axis: `parallel.latent_attention`). The last
 # column is None where the option runs on a latent pool as it is:
 # speculative decoding rewinds a latent cache by its index, as it
-# rewinds K/V (tests/test_latent_engine.py).
+# rewinds K/V (tests/test_latent_model.py).
 _NEEDS_APPENDED_KV = {
     "paged": ("the paged pool keeps K/V in blocks",
               "a recurrent state has no block form",

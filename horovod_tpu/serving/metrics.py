@@ -186,6 +186,12 @@ class EngineMetrics:
         # the decoding lanes' pairs that fell on them, and all they chose
         self.moe_zero_pairs = 0
         self.moe_chosen_pairs = 0
+        # a model whose choice is limited to groups over a share of the
+        # experts (`HeldExpertsMoE.groups`): summed over decoding lanes
+        # and expert layers, the distinct chips of the stated
+        # deployment a token's experts lie on; None (and absent from
+        # the snapshot) for every other model
+        self.moe_token_chips = None
         # Bytes of the fixed pool's cache by kind (None = a pool that
         # does not report them).
         self.pool_bytes = None
@@ -360,6 +366,11 @@ class EngineMetrics:
             self.moe_prefill_pairs += stats["moe_prefill_pairs"]
             self.moe_zero_pairs += stats.get("moe_zero_pairs", 0)
             self.moe_chosen_pairs += stats.get("moe_chosen_pairs", 0)
+            if "moe_token_chips" in stats:
+                self.moe_token_chips = ((self.moe_token_chips or 0)
+                                        + stats["moe_token_chips"])
+                self._obs["moe_token_chips"].inc(
+                    stats["moe_token_chips"])
 
     def observe_pool_bytes(self, by_kind: Dict[str, int]):
         """The fixed pool's cache bytes by kind (constructor-time,
@@ -569,6 +580,8 @@ class EngineMetrics:
                 "moe_prefill_pairs": self.moe_prefill_pairs,
                 "moe_zero_pairs": self.moe_zero_pairs,
                 "moe_chosen_pairs": self.moe_chosen_pairs,
+                **({} if self.moe_token_chips is None else
+                   {"moe_token_chips": self.moe_token_chips}),
                 "pool_bytes": self.pool_bytes,
                 "host_syncs_per_token": (
                     round(self.host_syncs / self.tokens_out, 4)

@@ -50,8 +50,9 @@ import jax.numpy as jnp
 
 from horovod_tpu.annotations import hot_path
 from horovod_tpu.models.transformer import (
-    MOE_ROUTED_COLUMNS, TransformerLM, decode_attention_plans,
-    init_slot_cache, moe_product_plans, prefill_chunks, recurrent_leaf,
+    TransformerLM, decode_attention_plans,
+    init_slot_cache, moe_product_plans, moe_stat_columns, prefill_chunks,
+    recurrent_leaf,
     state_step_plans,
     sample_lanes,
     shard_slot_cache, slot_decode_model, slot_decode_tick,
@@ -158,8 +159,7 @@ class TickHandle:
                  routed_columns=()):
         self.toks = toks
         # names of the counts that the pair arrays' last columns hold
-        # (`models.transformer.MOE_ROUTED_COLUMNS`; () without
-        # identity experts)
+        # (`models.transformer.moe_stat_columns`; () for most models)
         self.routed_columns = routed_columns
         # int32 [expert layers, experts held] (token, expert) pairs of
         # this tick's decoding lanes (None for a model without a
@@ -550,8 +550,7 @@ class SlotPool:
         for a in (pairs, *prefill):
             a.copy_to_host_async()
         return TickHandle(toks, pairs, prefill,
-                          MOE_ROUTED_COLUMNS
-                          if self.model.moe_zero_experts else ())
+                          moe_stat_columns(self.model))
 
     @staticmethod
     @hot_path

@@ -11,7 +11,9 @@ and the shares of a cut router adding up to the uncut layer. What Mosaic
 says of the kernel at the cell's shape is in `tests/test_tpu_compile.py`.
 """
 
+import dataclasses
 import json
+import math
 import os
 
 import numpy as np
@@ -28,7 +30,7 @@ from horovod_tpu.parallel.expert import HeldExpertsMoE, grouped_experts
 from horovod_tpu.parallel.latent_attention import (
     LatentAttention, LatentSpec, chunk_form, latent_walk,
 )
-from horovod_tpu.parallel.tensor import apply_rope, unbox
+from horovod_tpu.parallel.tensor import RopeSpec, apply_rope, unbox
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 A = load_module(os.path.join(REPO, "benchmarks", "arch", "longcat.py"),
@@ -404,3 +406,132 @@ def test_the_older_routers_are_the_expression_they_were(router):
     assert str(jax.make_jaxpr(lambda p, x: explicit.apply(
         {"params": p}, x))(p, x)) == str(jax.make_jaxpr(
             lambda p, x: old.apply({"params": p}, x))(p, x))
+
+
+# ---- the rotary rule and the softmax factor of the DeepSeek family ---------------
+# A.X-K1's rule at a toy width: YaRN over the 4 frequencies of an 8-wide
+# rope part (factor 32 from an original length of 64: the ramp runs over
+# j = 0 .. 2), cos and sin times 1.0, the scale times mscale^2.
+YARN = dict(factor=32.0, original=64, beta_fast=32.0, beta_slow=1.0)
+FACTOR = (0.1 * math.log(YARN["factor"]) + 1.0) ** 2
+SPEC_Y = LatentSpec(
+    q_rank=32, kv_rank=16, nope_dim=16, rope_dim=8, v_dim=16,
+    rope=RopeSpec(theta=1e4, yarn_factor=YARN["factor"],
+                  yarn_original_len=YARN["original"],
+                  yarn_beta_fast=YARN["beta_fast"],
+                  yarn_beta_slow=YARN["beta_slow"], scale=1.0),
+    softmax_factor=FACTOR)
+
+
+def formula(p, x, *, yarn=True, factor=FACTOR, eps=1e-5, theta=1e4):
+    """ISSUE 41's equations in NumPy float64, a position at a time: the
+    whole sublayer on x [S, d] - [S, d]."""
+    p = jax.tree.map(lambda a: np.asarray(a, np.float64), p)
+    x, (H, n, r, kvr) = np.asarray(x, np.float64), (4, 16, 8, 16)
+    rms = lambda v, g: v / np.sqrt((v * v).mean(-1, keepdims=True) + eps) * g
+    j = np.arange(r // 2)
+    inv = theta ** (-2.0 * j / r)
+    if yarn:
+        at = lambda b: r * math.log(YARN["original"] / (2 * math.pi * b)) / (
+            2 * math.log(theta))
+        lo, hi = max(math.floor(at(32.0)), 0), min(math.ceil(at(1.0)), r - 1)
+        ramp = np.clip((j - lo) / (hi - lo), 0, 1)
+        inv = inv * ((1 - ramp) + ramp / YARN["factor"])
+
+    def rot(v, t):          # v [..., r] at position t, pairs (2j, 2j + 1)
+        c, s = np.cos(t * inv), np.sin(t * inv)
+        a, b = v[..., 0::2], v[..., 1::2]
+        return np.stack([a * c - b * s, a * s + b * c], -1).reshape(v.shape)
+
+    q = (rms(x @ p["q_a"]["kernel"], p["q_a_norm"]["scale"])
+         @ p["q_b"]["kernel"]).reshape(-1, H, n + r)
+    kv = x @ p["kv_a"]["kernel"]
+    c = rms(kv[:, :kvr], p["kv_a_norm"]["scale"])
+    out = []
+    for t in range(len(x)):
+        k_rope = np.stack([rot(kv[i, kvr:], i) for i in range(t + 1)])
+        k_nope = np.einsum("jr,rhn->jhn", c[:t + 1], p["k_up"])
+        v = np.einsum("jr,rhv->jhv", c[:t + 1], p["v_up"])
+        score = (np.einsum("hn,jhn->hj", q[t, :, :n], k_nope)
+                 + rot(q[t, :, n:], t) @ k_rope.T) * factor / math.sqrt(n + r)
+        w = np.exp(score - score.max(-1, keepdims=True))
+        o = np.einsum("hj,jhv->hv", w / w.sum(-1, keepdims=True), v)
+        out.append(o.reshape(-1) @ p["out"]["kernel"])
+    return np.stack(out)
+
+
+def layer_y(impl=None, spec=SPEC_Y):
+    return layer(impl).clone(spec=spec)
+
+
+@pytest.mark.parametrize("form", ["expanded", "absorbed", "kernel"])
+def test_every_form_turns_the_shared_key_by_the_layer_s_rule(state, form):
+    """The rule and the factor reach all three forms: the full forward
+    (expanded), chunks and steps through the lax walk (absorbed), and
+    S = 1 steps through the ragged kernel in interpret mode (the
+    pre-scaled query) - each is the formula above."""
+    params, empty = state
+    x = xs(27, seed=7)
+    want = formula(params, x[0])
+    assert SPEC_Y.softmax_scale == pytest.approx(1.81326 / math.sqrt(24),
+                                                 rel=1e-5)
+    if form == "expanded":
+        got = layer_y().clone(decode=False).apply({"params": params}, x)
+    else:
+        def run(cache, rows, impl=None):
+            y, mut = layer_y(impl).apply(
+                {"params": params, "cache": cache}, rows,
+                mutable=["cache"])
+            return y, mut["cache"]
+
+        out, cache = [], empty
+        for lo, hi in ((0, 16), (16, 24)):
+            y, cache = run(cache, x[:, lo:hi])
+            out.append(y)
+        for t in range(24, 27):
+            y, cache = run(cache, x[:, t:t + 1],
+                           "pallas" if form == "kernel" else "lax")
+            out.append(y)
+        got = jnp.concatenate(out, 1)
+    np.testing.assert_allclose(got[0], want, atol=3e-5)
+
+
+@pytest.mark.parametrize("control,kw", [
+    ("scale factor 1.0", dict(factor=1.0)),
+    ("plain frequencies", dict(yarn=False)),
+    ("neither", dict(yarn=False, factor=1.0))])
+def test_the_rule_and_the_factor_move_the_output(state, control, kw):
+    """The comparison sees both mechanisms: the formula without either
+    is NOT what the layer computes - and the layer without them
+    (LongCat's spec: plain rotation, factor 1) is that formula."""
+    params, _ = state
+    x = xs(27, seed=7)
+    got = np.asarray(layer_y().clone(decode=False).apply(
+        {"params": params}, x))[0]
+    assert np.abs(got - formula(params, x[0], **kw)).max() > 1e-3, control
+    plain = dataclasses.replace(SPEC_Y, rope=None, softmax_factor=1.0)
+    np.testing.assert_allclose(
+        np.asarray(layer_y(spec=plain).clone(decode=False).apply(
+            {"params": params}, x))[0],
+        formula(params, x[0], yarn=False, factor=1.0), atol=3e-5)
+
+
+def test_yarn_frequencies_are_the_published_ramp_at_the_real_width():
+    """ISSUE 41's three numbers at A.X-K1's widths: the ramp over
+    j = 10 .. 23 of 32 frequencies, cos / sin x 1.0, scale x 1.81326;
+    LongCat's spec keeps the rule it always had."""
+    rope = RopeSpec(theta=1e4, yarn_factor=32.0, yarn_original_len=4096)
+    assert rope.yarn_ramp(64) == (10, 23)
+    inv = rope.inv_freq(64)
+    plain = 1e4 ** (-2.0 * np.arange(32) / 64)
+    np.testing.assert_allclose(inv[:11], plain[:11], rtol=1e-12)
+    np.testing.assert_allclose(inv[23:], plain[23:] / 32, rtol=1e-12)
+    assert plain[16] / 32 < inv[16] < plain[16]
+    pub = LatentSpec(q_rank=1536, kv_rank=512, nope_dim=128, rope_dim=64,
+                     v_dim=128, rope=rope,
+                     softmax_factor=(0.1 * math.log(32) + 1) ** 2)
+    assert pub.softmax_scale * math.sqrt(192) == pytest.approx(
+        1.81326, abs=1e-5)
+    assert RopeSpec(theta=1e7).rotation(64) == {"theta": 1e7}
+    assert LatentSpec(q_rank=8, kv_rank=8, nope_dim=8, rope_dim=8,
+                      v_dim=8).softmax_scale == 16 ** -0.5

@@ -48,8 +48,13 @@ def params():
 
 @pytest.fixture(autouse=True)
 def highest():
-    with jax.default_matmul_precision("highest"):
-        yield
+    """`highest`, set in the configuration: the context manager is
+    thread-local, and under it an engine's dispatch thread compiled
+    again, at the default, every program the warm-up had compiled."""
+    was = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", "highest")
+    yield
+    jax.config.update("jax_default_matmul_precision", was)
 
 
 def tokens(n, seed=0):

@@ -55,8 +55,13 @@ def params():
 
 @pytest.fixture(autouse=True)
 def highest():
-    with jax.default_matmul_precision("highest"):
-        yield
+    """`highest`, set in the configuration: the context manager is
+    thread-local, and under it an engine's dispatch thread compiled
+    again, at the default, every program the warm-up had compiled."""
+    was = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", "highest")
+    yield
+    jax.config.update("jax_default_matmul_precision", was)
 
 
 def tokens(n, seed=0):
@@ -263,17 +268,21 @@ def test_chunks_then_ticks_through_the_slot_pool_equal_the_reference(
                                           jnp.asarray(toks))
         return cache, lg
 
+    @jax.jit
+    def slot_logits(cache, slot, tok):
+        # jitted: run op by op, this test's hundred-odd applies were
+        # most of its time
+        sub = jax.tree.map(lambda l: l[slot], cache)
+        (h, emb), _ = dec.apply(
+            {"params": params, "cache": sub}, tok[None, None],
+            return_hidden=True, mutable=["cache"])
+        return jnp.einsum("d,vd->v", h[0, -1], emb)
+
     def tick(cache, feed, live):
         """Greedy tick; returns each slot's logits too (recomputed by
         a B = 1 apply on the same cache rows)."""
-        def lg(slot):
-            sub = jax.tree.map(lambda l: l[slot], cache)
-            (h, emb), _ = dec.apply(
-                {"params": params, "cache": sub},
-                jnp.asarray(feed[slot])[None, None], return_hidden=True,
-                mutable=["cache"])
-            return jnp.einsum("d,vd->v", h[0, -1], emb)
-        logits = [lg(s) for s in range(3)]
+        logits = [slot_logits(cache, s, jnp.asarray(feed[s], jnp.int32))
+                  for s in range(3)]
         cache, *_ = slot_decode_tick(
             dec, params, cache, jnp.asarray(feed, jnp.int32),
             jnp.zeros(3), jnp.ones(3),

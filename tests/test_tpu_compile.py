@@ -380,7 +380,7 @@ def test_mixed_tick_attends_both_kinds_through_the_ragged_kernel(
                         lambda: False)
     lanes, W = 64, 12288
     model = TransformerLM(
-        vocab_size=4096, num_layers=2, max_len=W, norm="rmsnorm",
+        vocab_size=128, num_layers=2, max_len=W, norm="rmsnorm",
         mlp_impl="swiglu", mlp_hidden=1024, dtype=jnp.bfloat16,
         attn_impl="flash", hidden_size=3072, num_heads=48,
         num_kv_heads=8, head_dim=128, pos_emb="rope", attn_gate="head",
@@ -444,7 +444,7 @@ def test_latent_tick_reads_each_row_once_through_the_ragged_kernel(
                         lambda: False)
     lanes, W = 64, 4096
     model = TransformerLM(
-        vocab_size=4096, num_layers=1, max_len=W, norm="rmsnorm",
+        vocab_size=128, num_layers=1, max_len=W, norm="rmsnorm",
         mlp_impl="swiglu", mlp_hidden=1024, dtype=jnp.bfloat16,
         attn_impl="flash", hidden_size=6144, num_heads=64, head_dim=128,
         pos_emb="rope", rope_theta=1e7, tied_head=False,
@@ -491,6 +491,87 @@ def test_latent_tick_reads_each_row_once_through_the_ragged_kernel(
         assert kernel[0].count("bf16[64,4096,640]") == 1
     loops = re.findall(r' while\(.*op_name="([^"]*)"', text)
     assert [n for n in loops if "/mla_" in n] == []
+    _cache_stays_in_place(compiled, text, cache)
+
+
+def test_plain_latent_block_beside_held_experts_lowers_for_the_chip(
+        sds, monkeypatch):
+    """A tick over A.X-K1's block at `a.x-k1`'s real widths (hidden
+    7168, 64 lanes of 8192 positions; ONE expert layer, 12 of 192
+    experts held, a vocabulary of 128: a second layer would double the
+    compile, and the sampling epilogue over 4096 rows was two thirds of
+    it) on the DEFAULT rules: the layer's ONE latent mixer is the
+    in-place append and `latent_decode` under `block_0/mla`, its
+    products `grouped_swiglu` + `grouped_matmul` under `block_0/moe` -
+    the scopes
+    `latent_layer_share_of_tick` and `moe_share_of_tick` match - with
+    no `while` under an attention scope, nothing left of XLA's
+    `ragged-dot`, and the group-limited choice and its chips count
+    compiled beside them."""
+    from horovod_tpu.models.transformer import (
+        TransformerLM, decode_attention_plans, init_slot_cache,
+        moe_product_plans, serving_params, slot_decode_model,
+        slot_decode_tick)
+    from horovod_tpu.ops import flash_attention
+    from horovod_tpu.parallel.latent_attention import LatentSpec
+    from horovod_tpu.parallel.tensor import RopeSpec, unbox
+
+    monkeypatch.setattr(flash_attention, "_auto_interpret",
+                        lambda: False)
+    lanes, W = 64, 8192
+    model = TransformerLM(
+        vocab_size=128, num_layers=1, max_len=W, norm="rmsnorm",
+        mlp_impl="swiglu", mlp_hidden=18432, dtype=jnp.bfloat16,
+        attn_impl="flash", hidden_size=7168, num_heads=64, head_dim=128,
+        pos_emb="rope", tied_head=False, layer_kinds=("mla",),
+        latent=LatentSpec(
+            q_rank=1536, kv_rank=512, nope_dim=128, rope_dim=64, v_dim=128,
+            rope=RopeSpec(theta=1e4, yarn_factor=32.0,
+                          yarn_original_len=4096),
+            softmax_factor=1.81326),
+        moe_every=1, moe_impl="dropless",
+        num_experts=192, moe_k=8, moe_hidden=2048, moe_held=(0, 12),
+        moe_shared_hidden=2048, moe_router="sigmoid",
+        moe_router_bias=False, moe_scale=2.5, moe_groups=(8, 4))
+    plan = decode_attention_plans(model, lanes)["mla"]
+    assert (plan.path, plan.grid, plan.write) == (
+        "kernel", (lanes, 32), "kernel"), plan
+    product = moe_product_plans(model, lanes, 128)["tick"]
+    assert (product.path, product.rows) == ("kernel", 64), product
+    dec = slot_decode_model(model)
+
+    def place(tree):
+        return jax.tree.map(lambda s: sds(s.shape, s.dtype), tree)
+
+    params = place(jax.eval_shape(
+        lambda r: serving_params(unbox(model.init(
+            r, jnp.zeros((1, 64), jnp.int32))["params"])),
+        jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: init_slot_cache(model, lanes)))
+    assert cache["block_0"]["mla"]["cached_latent"].shape == (
+        lanes, 1, W, 640)
+    vec = lambda dt: sds((lanes,), dt)  # noqa: E731
+    compiled = slot_decode_tick.lower(
+        dec, params, cache, vec(jnp.int32), vec(jnp.float32),
+        vec(jnp.float32), sds((lanes, 2), jnp.uint32), vec(bool),
+        vec(bool), sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    mine = [ln for ln in calls
+            if "/block_0/mla/mla._decode_attention/" in ln]
+    assert len(mine) == 2, [ln[:120] for ln in mine]
+    assert sum("jit(_flash_decode)/latent_decode" in ln
+               and "%latent_decode" in ln.split(" = ")[0]
+               for ln in mine) == 1
+    moe = [ln for ln in calls if "/block_0/moe/" in ln]
+    assert len(moe) == 2 and len(calls) == 4
+    assert sum("%grouped_swiglu" in ln.split(" = ")[0] for ln in moe) == 1
+    assert sum("%grouped_matmul" in ln.split(" = ")[0] for ln in moe) == 1
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    loops = re.findall(r' while\(.*op_name="([^"]*)"', text)
+    assert [n for n in loops if "/mla/" in n] == []
+    # the tick's last output: a row of 12 held experts' pairs + the chips
+    assert "s32[1,13]" in text
     _cache_stays_in_place(compiled, text, cache)
 
 
@@ -546,7 +627,7 @@ def test_expert_layers_stream_their_weights_through_the_kernel(
             q_rank=1536, kv_rank=512, nope_dim=128, rope_dim=64,
             v_dim=128, q_scale=2.0, kv_scale=12 ** 0.5))
     model = TransformerLM(
-        vocab_size=4096, num_layers=1, max_len=W, norm="rmsnorm",
+        vocab_size=128, num_layers=1, max_len=W, norm="rmsnorm",
         mlp_impl="swiglu", mlp_hidden=1024, dtype=jnp.bfloat16,
         attn_impl="flash", moe_every=1, moe_impl="dropless", **fields)
     plans = moe_product_plans(model, lanes, 128)
@@ -617,7 +698,7 @@ def test_kda_state_is_stepped_in_place_by_one_call_a_layer(
                         lambda: False)
     lanes, W, _, fields = MOE_CELLS["solar-open2-250b"]
     model = TransformerLM(
-        vocab_size=4096, num_layers=1, max_len=W, norm="rmsnorm",
+        vocab_size=128, num_layers=1, max_len=W, norm="rmsnorm",
         mlp_impl="swiglu", mlp_hidden=1024, dtype=jnp.bfloat16,
         attn_impl="flash", moe_every=1, moe_impl="dropless",
         layer_kinds=("kda",), **fields)
@@ -689,7 +770,7 @@ def test_ssm_state_is_stepped_in_place_by_one_call_a_layer(
                         lambda: False)
     lanes, W = 64, 2048
     model = TransformerLM(
-        vocab_size=4096, num_layers=1, max_len=W, norm="rmsnorm",
+        vocab_size=128, num_layers=1, max_len=W, norm="rmsnorm",
         mlp_impl="swiglu", mlp_hidden=8192, dtype=jnp.bfloat16,
         attn_impl="flash", hidden_size=2048, num_heads=32,
         num_kv_heads=8, head_dim=64, pos_emb="none", ln_eps=1e-5,
