@@ -312,7 +312,10 @@ class TransformerBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array,
-                 advance: Optional[jax.Array] = None) -> jax.Array:
+                 advance: Optional[jax.Array] = None,
+                 count: Optional[jax.Array] = None) -> jax.Array:
+        # ``count``: `TransformerLM.__call__`'s, handed to every
+        # sublayer that keeps a cache or routes tokens
         d = x.shape[-1]
         if self.mixer not in LAYER_KINDS:
             raise ValueError(
@@ -349,7 +352,7 @@ class TransformerBlock(nn.Module):
                     num_heads=self.num_heads, head_dim=self.head_dim,
                     out_features=d, norm_eps=self.ln_eps,
                     dtype=self.dtype, decode=self.decode, name=name)(
-                    h, advance)
+                    h, advance, count)
             if self.mixer == "ssm":
                 if self.ssm is None:
                     raise ValueError("an 'ssm' mixer needs `ssm`, its "
@@ -357,7 +360,7 @@ class TransformerBlock(nn.Module):
                 return Mamba2Mixer(
                     spec=self.ssm, out_features=d, norm_eps=self.ln_eps,
                     dtype=self.dtype, decode=self.decode, name=name)(
-                    h, advance)
+                    h, advance, count)
             if self.mixer == "mla":
                 if self.latent is None:
                     raise ValueError("an 'mla' mixer needs `latent`, "
@@ -370,7 +373,7 @@ class TransformerBlock(nn.Module):
                     chunked_prefill=self.chunked_prefill,
                     decode_prefix_block=self.decode_prefix_block,
                     decode_prefix_impl=self.decode_prefix_impl,
-                    name=name)(h)
+                    name=name)(h, count)
             return ParallelSelfAttention(
                 num_heads=self.num_heads, head_dim=self.head_dim,
                 num_kv_heads=self.num_kv_heads, pos_emb=self.pos_emb,
@@ -387,7 +390,7 @@ class TransformerBlock(nn.Module):
                 out_features=(None if d == self.num_heads * self.head_dim
                               else d),
                 lora_rank=self.lora_rank, lora_alpha=self.lora_alpha,
-                name=name)(h, mask)
+                name=name)(h, mask, count)
 
         def experts(h):
             if self.moe_impl == "dropless":
@@ -401,14 +404,14 @@ class TransformerBlock(nn.Module):
                     normalize=self.moe_normalize,
                     router_bias=self.moe_router_bias,
                     groups=self.moe_groups,
-                    dtype=self.dtype, name="moe")(h)
+                    dtype=self.dtype, name="moe")(h, count)
             if self.moe_impl == "gshard":
                 return MoELayer(
                     num_experts=self.num_experts,
                     hidden=self.moe_hidden or self.mlp_ratio * d,
                     k=self.moe_k,
                     capacity_factor=self.moe_capacity_factor,
-                    dtype=self.dtype, name="moe")(h)
+                    dtype=self.dtype, name="moe")(h, count)
             raise ValueError(
                 f"moe_impl must be gshard|dropless, got "
                 f"{self.moe_impl!r}")
@@ -662,7 +665,18 @@ class TransformerLM(nn.Module):
     @nn.compact
     def __call__(self, tokens: jax.Array,
                  return_hidden: bool = False,
-                 advance: Optional[jax.Array] = None) -> Any:
+                 advance: Optional[jax.Array] = None,
+                 count: Optional[jax.Array] = None) -> Any:
+        """``count`` (traced int32 in 1 .. S; decode mode, an S > 1
+        chunk appended to the caches): only the first ``count``
+        positions are the prompt's, the rest are pad. A pad position
+        leaves every cache as it found it - no K/V or latent row, no
+        ring slot, no decay of a recurrent state nor a write to it, no
+        row of its convolution's tail, no index advanced - and is
+        routed to no expert; what the model returns at a pad position
+        is the caller's to discard (`slot_prefill_chunk` reads
+        position ``count - 1``). None: every position is real, and the
+        program is the one it was before the argument existed."""
         if self.pos_emb not in ("learned", "rope", "none"):
             raise ValueError(
                 f"pos_emb must be 'learned', 'rope' or 'none', "
@@ -691,9 +705,17 @@ class TransformerLM(nn.Module):
                 # input offset (tokens arrive one tick at a time).
                 idx = self.variable("cache", "pos_index",
                                     lambda: jnp.zeros((), jnp.int32))
-                p = lax.dynamic_slice_in_dim(pos, idx.value, S, axis=0)
+                if count is None:
+                    p = lax.dynamic_slice_in_dim(pos, idx.value, S,
+                                                 axis=0)
+                else:
+                    # by position: a slice that passes the table's end
+                    # would be shifted down over the real positions
+                    p = jnp.take(pos, idx.value + jnp.arange(S), axis=0,
+                                 mode="clip")
                 if not self.is_initializing():
-                    idx.value = idx.value + S
+                    idx.value = idx.value + (S if count is None
+                                             else count)
             else:
                 p = pos[:S]
             x = x + p
@@ -748,7 +770,8 @@ class TransformerLM(nn.Module):
                 # which lanes a decode step may move: a recurrent
                 # layer's (`RECURRENT_LAYERS`), nobody else's
                 *((x, advance) if kinds[i] in RECURRENT_KINDS
-                  else (x,)))
+                  else (x,)),
+                **({} if count is None else {"count": count}))
             x = constrain(x, AXIS_DATA, AXIS_SEQ, None)
 
         x = _make_norm(self.norm, self.dtype, self.ln_eps,
@@ -1563,47 +1586,84 @@ def _moe_pairs(dec_model, mut):
 @hot_path
 @functools.partial(jax.jit, static_argnames=("dec_model",),
                    donate_argnums=(2,))
-def slot_prefill_chunk(dec_model, params, cache, slot, chunk):
+def slot_prefill_chunk(dec_model, params, cache, slot, chunk,
+                       count=None):
     """Append one [C]-token prompt chunk into slot ``slot``'s cache and
     return ``(cache, last-position logits [V], expert pairs)`` - the
     last as `_moe_pairs` has them.
 
     Runs the `chunked_prefill` path (cache-wide mask — correct for ANY
-    current fill), so a prompt of arbitrary length P streams in as its
-    binary decomposition of power-of-two chunks (`prefill_chunks`):
-    at most log2(max_len) DISTINCT compiled programs ever, instead of
-    one compile per prompt length. ``slot`` is a traced operand, so the
-    same program serves every slot."""
+    current fill), and ``slot`` is a traced operand, so the same
+    program serves every slot and every fill. A prompt of any length P
+    streams in as the chunks of `prefill_chunks`, and under a chunk
+    width C that is TWO compiled programs, whatever P:
+
+    * the whole chunk (``count`` None): all C positions are the
+      prompt's - no count, no mask;
+    * the tail chunk (``count`` a traced int32 in 1 .. C): the first
+      ``count`` positions are the prompt's, the rest are pad, which
+      leaves every cache as it found it and is routed to no expert
+      (`TransformerLM.__call__` has the rule by cache kind). The
+      logits are read at position ``count - 1``; one program serves
+      every tail length.
+
+    Without a width the chunks are a binary decomposition: at most
+    log2(max_len) programs, each compiled on first use."""
     sub = jax.tree.map(lambda l: l[slot], cache)
     (hidden, embed), mut = dec_model.apply(
         {"params": params, "cache": sub}, chunk[None, :],
-        return_hidden=True, mutable=["cache", "moe_stats"])
-    logits = jnp.einsum("d,vd->v", hidden[0, -1],
-                        embed.astype(hidden.dtype))
+        return_hidden=True, count=count,
+        mutable=["cache", "moe_stats"])
+    last = (hidden[0, -1] if count is None else
+            lax.dynamic_index_in_dim(hidden[0], count - 1, 0,
+                                     keepdims=False))
+    logits = jnp.einsum("d,vd->v", last, embed.astype(hidden.dtype))
     cache = jax.tree.map(lambda l, s: l.at[slot].set(s), cache,
                          mut["cache"])
     return cache, logits.astype(jnp.float32), _moe_pairs(dec_model, mut)
 
 
-def prefill_chunks(length: int, max_chunk: Optional[int] = None) -> list:
-    """Binary decomposition of a prompt length into descending
-    power-of-two chunk sizes (13 -> [8, 4, 1]) — the compile-bounded
-    schedule `slot_prefill_chunk` is fed with.
+def chunk_width(max_chunk: Optional[int],
+                max_len: Optional[int] = None) -> Optional[int]:
+    """The positions of a chunk program under a budget of ``max_chunk``
+    prompt tokens a scheduler step (the Sarathi-style knob behind
+    HVD_PREFILL_CHUNK_BUDGET): the largest power of two <= max_chunk
+    (and <= ``max_len``, the rows a cache has to take a chunk into).
+    None without a budget: there is no width to pad a tail to."""
+    if max_chunk is None or max_chunk < 1:
+        return None
+    cap = int(max_chunk if max_len is None else min(max_chunk, max_len))
+    return 1 << (max(1, cap).bit_length() - 1)
 
-    ``max_chunk`` caps every chunk at the largest power of two <=
-    max_chunk (200 at max_chunk=64 -> [64, 64, 64, 8]) — the
-    Sarathi-style knob behind HVD_PREFILL_CHUNK_BUDGET: the scheduler
+
+def prefill_chunks(length: int, max_chunk: Optional[int] = None, *,
+                   pad_tail: bool = True) -> list:
+    """The REAL tokens of each chunk a prompt of ``length`` tokens is
+    streamed in - the schedule `slot_prefill_chunk` is fed with.
+
+    Under a budget, C = `chunk_width` (max_chunk): ``length // C``
+    whole chunks and, where ``length % C`` is not 0, ONE tail chunk of
+    that many tokens, which the pool pads to C positions and runs with
+    its true count a traced operand (200 at 64 -> [64, 64, 64, 8];
+    3 at 8 -> [3]): two programs, whatever the length. The scheduler
     interleaves one bounded chunk with decode ticks instead of
-    streaming a whole long prompt back-to-back. Chunk sizes stay
-    powers of two, so the compiled-program set stays log2-bounded
-    regardless of the cap."""
+    streaming a whole long prompt back-to-back.
+
+    Without one (``max_chunk`` None: the whole prompt back to back),
+    or with ``pad_tail`` False (a pool whose chunk program takes no
+    count: the paged pool), the remainder is its binary decomposition
+    into descending powers of two (13 -> [8, 4, 1]; 200 at 64 ->
+    [64, 64, 64, 8]; 3 at 8 -> [2, 1]): at most log2 programs, and a
+    chunk's width is its length."""
     if length <= 0:
         raise ValueError(f"prompt length must be positive, got {length}")
     out = []
-    if max_chunk is not None and max_chunk >= 1:
-        cap = 1 << (int(max_chunk).bit_length() - 1)   # pow2 floor
+    cap = chunk_width(max_chunk)
+    if cap is not None:
         out = [cap] * (length // cap)
         length -= cap * (length // cap)
+        if pad_tail:
+            return out + [length] * (length > 0)
     return out + [1 << b for b in range(length.bit_length() - 1, -1, -1)
                   if length >> b & 1]
 
@@ -2370,15 +2430,16 @@ def paged_spec_round(dec_model, drf_model, spec: PagedCacheSpec,
 @hot_path
 @functools.partial(jax.jit, static_argnames=("dec_model",),
                    donate_argnums=(2,))
-def slot_prefill_advance(dec_model, params, cache, slot, chunk):
-    """Draft-cache prompt advance: `slot_prefill_chunk` minus the
-    LM-head matmul — spec decode only needs the draft's KV warm, its
-    logits are never read during prefill (the FIRST token is always
-    the target's)."""
+def slot_prefill_advance(dec_model, params, cache, slot, chunk,
+                         count=None):
+    """Draft-cache prompt advance: `slot_prefill_chunk` (``count``
+    too) minus the LM-head matmul — spec decode only needs the draft's
+    KV warm, its logits are never read during prefill (the FIRST token
+    is always the target's)."""
     sub = jax.tree.map(lambda l: l[slot], cache)
     _, mut = dec_model.apply({"params": params, "cache": sub},
                              chunk[None, :], return_hidden=True,
-                             mutable=["cache"])
+                             count=count, mutable=["cache"])
     return jax.tree.map(lambda l, s: l.at[slot].set(s), cache,
                         mut["cache"])
 

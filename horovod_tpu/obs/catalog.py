@@ -132,6 +132,18 @@ def serving_metrics(reg: Optional[MetricRegistry] = None) -> Dict:
             "hvd_serving_prefill_tokens_skipped_total",
             "Prompt tokens never prefilled because the shared-prefix "
             "cache already held them (the TTFT the cache deleted)"),
+        # The padded prompt tail (docs/serving.md "Prefill"): how often
+        # the tail program ran, and the pad positions it carried.
+        "prefill_tail_chunks": reg.counter(
+            "hvd_serving_prefill_tail_chunks_total",
+            "Prefill chunks that ran as the padded tail program (a "
+            "prompt's remainder under the chunk budget, its true "
+            "count a traced operand)"),
+        "prefill_pad_tokens": reg.counter(
+            "hvd_serving_prefill_pad_tokens_total",
+            "Pad positions of the padded tail chunks (chunk width "
+            "less real tokens): device work that carries no prompt "
+            "token and is not in prefill_tokens"),
         # Speculative decoding (docs/serving.md "Decode fast path"):
         # the draft-verify acceptance accounting — acceptance rate =
         # spec_accepted / spec_proposed, and tokens retired per tick

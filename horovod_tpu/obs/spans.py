@@ -124,7 +124,9 @@ SPAN_CATALOG: Dict[str, str] = {
         "KV-block grafts at the top of a scheduler step",
     "sched.prefill_chunk":
         "Loop span: one prefill chunk's dispatch (attrs slot, "
-        "tokens) - the site that records serving.prefill_chunk",
+        "tokens - the real ones - and width, the program's positions: "
+        "more than tokens for a padded tail) - the site that records "
+        "serving.prefill_chunk",
     "sched.spec_round":
         "Loop span: one speculative draft-verify round over the "
         "active lanes (attrs proposed, accepted)",

@@ -73,6 +73,15 @@ def _dispatch_combine(gates, indices, num_experts, capacity):
     return dispatch, combine
 
 
+def real_tokens(lead, count):
+    """bool [tokens]: which of the flattened tokens of an input
+    [*lead, d] (lead = [.., S]) are real, where only the first
+    ``count`` positions of the sequence axis are and the rest are pad
+    (`models.transformer.TransformerLM.__call__`'s ``count``)."""
+    return jnp.broadcast_to(jnp.arange(lead[-1]) < count,
+                            lead).reshape(-1)
+
+
 class MoELayer(nn.Module):
     """Mixture-of-experts MLP, experts sharded over ``expert``.
 
@@ -80,6 +89,9 @@ class MoELayer(nn.Module):
     count per call; dropped tokens ride the residual. The aux
     load-balancing loss is stored in the ``losses`` collection under
     ``moe_aux`` (sow), to be added to the task loss by the train step.
+
+    ``count`` to `__call__` (traced int32 in 1 .. S; x [.., S, d] a
+    chunk whose tail is pad): a pad token claims no expert's capacity.
     """
 
     num_experts: int
@@ -90,7 +102,8 @@ class MoELayer(nn.Module):
     activation: Callable = nn.gelu
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array,
+                 count: Optional[jax.Array] = None) -> jax.Array:
         *lead, d = x.shape
         T = 1
         for s in lead:
@@ -113,6 +126,9 @@ class MoELayer(nn.Module):
         logits = xt.astype(jnp.float32) @ router
         gates, indices, aux = top_k_gating(logits, self.k)
         self.sow("losses", "moe_aux", aux)
+        if count is not None:       # id E: `one_hot` gives it no expert
+            indices = jnp.where(real_tokens(lead, count)[:, None],
+                                indices, E)
 
         dispatch, combine = _dispatch_combine(gates, indices, E, capacity)
         compute_dtype = self.dtype or x.dtype
@@ -273,17 +289,21 @@ def group_limited(pick, n_group, topk_group):
         T, outputs)
 
 
-def token_chips(chosen, per_chip):
-    """Summed over the tokens of ``chosen`` [T, k], the number of
-    DISTINCT chips (``id // per_chip``) a token's k experts lie on: the
-    fan-out of the exchange an expert-parallel deployment would pay.
-    int32 scalar. Counted as the entries no earlier entry of the token
-    equals (k x k comparisons that fuse; no sort)."""
+def token_chips(chosen, per_chip, real=None):
+    """Summed over the tokens of ``chosen`` [T, k] (those of ``real``
+    [T] bool, where given), the number of DISTINCT chips (``id //
+    per_chip``) a token's k experts lie on: the fan-out of the
+    exchange an expert-parallel deployment would pay. int32 scalar.
+    Counted as the entries no earlier entry of the token equals (k x k
+    comparisons that fuse; no sort)."""
     chips = chosen // per_chip
     same = chips[:, :, None] == chips[:, None, :]           # [T, k, k]
     k = chosen.shape[-1]
     earlier = jnp.arange(k)[None, :] < jnp.arange(k)[:, None]
-    return jnp.sum(~(same & earlier).any(-1), dtype=jnp.int32)
+    first = ~(same & earlier).any(-1)
+    if real is not None:
+        first &= real[:, None]
+    return jnp.sum(first, dtype=jnp.int32)
 
 
 class HeldExpertsMoE(nn.Module):
@@ -335,7 +355,13 @@ class HeldExpertsMoE(nn.Module):
     pairs the tokens chose (tokens x k); with ``groups`` on a share
     (``held``) also `token_chips`: int32 scalar, `token_chips` of the
     choice at ``count`` experts a chip - the fan-out that the group
-    limit exists to bound."""
+    limit exists to bound.
+
+    ``count`` to `__call__` (traced int32 in 1 .. S; x [.., S, d] a
+    chunk whose tail is pad): a pad token is routed NOWHERE - its pairs
+    join no expert's group, so they add no row to the grouped product
+    and no expert is read for them - and none of the sown counts
+    counts a pad pair."""
 
     num_experts: int
     hidden: int
@@ -351,7 +377,8 @@ class HeldExpertsMoE(nn.Module):
     groups: Optional[Tuple[int, int]] = None     # (n_group, topk_group)
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array,
+                 count: Optional[jax.Array] = None) -> jax.Array:
         from horovod_tpu.parallel.tensor import ParallelSwiGLU
         *lead, d = x.shape
         first, E = self.held or (0, self.num_experts)
@@ -412,6 +439,10 @@ class HeldExpertsMoE(nn.Module):
             weight = weight * self.scale
         local = chosen - first
         key = jnp.where((local >= 0) & (local < E), local, E)
+        real = None
+        if count is not None:
+            real = real_tokens(lead, count)
+            key = jnp.where(real[:, None], key, E)
         self.sow("moe_stats", "pairs",
                  jnp.sum(key.reshape(-1, 1) == jnp.arange(E), axis=0,
                          dtype=jnp.int32),
@@ -420,7 +451,8 @@ class HeldExpertsMoE(nn.Module):
         # stats keeps the program it had (`pairs` above predates the rule)
         if (self.groups is not None and self.held is not None
                 and self.is_mutable_collection("moe_stats")):
-            self.sow("moe_stats", "token_chips", token_chips(chosen, E),
+            self.sow("moe_stats", "token_chips",
+                     token_chips(chosen, E, real),
                      reduce_fn=lambda _, new: new, init_fn=lambda: None)
         self.sow("intermediates", "chosen", chosen,
                  reduce_fn=lambda _, new: new, init_fn=lambda: None)
@@ -428,9 +460,12 @@ class HeldExpertsMoE(nn.Module):
                             w_down, routed=outputs)
         if self.zero_experts:
             zero = chosen >= self.num_experts
+            pairs = jnp.int32(zero.size)
+            if real is not None:
+                zero &= real[:, None]
+                pairs = real.sum(dtype=jnp.int32) * self.k
             self.sow("moe_stats", "routed",
-                     jnp.stack([zero.sum(dtype=jnp.int32),
-                                jnp.int32(zero.size)]),
+                     jnp.stack([zero.sum(dtype=jnp.int32), pairs]),
                      reduce_fn=lambda _, new: new, init_fn=lambda: None)
             y = y + (jnp.where(zero, weight, 0.0).sum(-1, keepdims=True)
                      .astype(dtype) * xt.astype(dtype))

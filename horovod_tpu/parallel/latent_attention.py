@@ -73,7 +73,7 @@ import flax.linen as nn
 
 from horovod_tpu.parallel.tensor import (
     ColumnParallelDense, RopeSpec, RowParallelDense, _mesh_is_trivial,
-    apply_rope,
+    append_rows, apply_rope,
 )
 
 
@@ -152,7 +152,7 @@ def _softmax_attend(q, k, v, qpos, kpos):
 
 
 def latent_walk(q, rows, i, *, spec: LatentSpec, block: int,
-                expand=None):
+                expand=None, count=None):
     """S query rows at positions i .. i + S - 1 against the cache's
     filled prefix, a block of rows at a time with a trip count that
     follows the fill (`ParallelSelfAttention._prefix_attention`'s walk
@@ -164,13 +164,14 @@ def latent_walk(q, rows, i, *, spec: LatentSpec, block: int,
     kv_rank] float32 - W_UV is the caller's. Expanded: ``expand`` =
     (W_UK [kv_rank, H, nope], W_UV [kv_rank, H, v]), ``q`` [..., S, H,
     nope + rope]; each block's keys and values are made from its rows;
-    returns [..., S, H, v]."""
+    returns [..., S, H, v]. ``count`` (`LatentAttention.__call__`): the
+    filled prefix ends at i + count."""
     S, H = q.shape[-3], q.shape[-2]
     r = spec.kv_rank
     Dv = spec.v_dim if expand else r
     lead = q.shape[:-3]
     qpos = i + jnp.arange(S, dtype=jnp.int32)
-    nblk = (i + S + block - 1) // block
+    nblk = (i + (S if count is None else count) + block - 1) // block
     neg = jnp.finfo(jnp.float32).min
 
     def body(j, carry):
@@ -230,7 +231,11 @@ class LatentAttention(nn.Module):
     decode_prefix_impl: Optional[str] = None
 
     @nn.compact
-    def __call__(self, u: jax.Array) -> jax.Array:
+    def __call__(self, u: jax.Array,
+                 count: Optional[jax.Array] = None) -> jax.Array:
+        """``count`` (traced int32 in 1 .. S; a chunk appended to a
+        cache): as `ParallelSelfAttention.__call__`'s - the pad
+        positions past it write no latent row and advance no index."""
         sp, H = self.spec, self.num_heads
         dense = dict(use_bias=False, dtype=self.dtype)
         init = nn.initializers.lecun_normal()
@@ -258,7 +263,7 @@ class LatentAttention(nn.Module):
                           jnp.float32).astype(c.dtype)
         if self.decode:
             o = self._decode_attention(q, c, kv[..., sp.kv_rank:],
-                                       k_up, v_up)
+                                       k_up, v_up, count)
         else:
             q, rows = self._rotate(q, c, kv[..., sp.kv_rank:], 0)
             o = self._expanded_block(q, rows, k_up, v_up)
@@ -313,7 +318,7 @@ class LatentAttention(nn.Module):
         o = jnp.moveaxis(o, 0, -4)
         return o.reshape(*o.shape[:-4], S, *o.shape[-2:])
 
-    def _decode_attention(self, q, c, kr, k_up, v_up):
+    def _decode_attention(self, q, c, kr, k_up, v_up, count=None):
         sp, H = self.spec, self.num_heads
         is_init = self.has_variable("cache", "cached_latent")
         cached = self.variable(
@@ -357,13 +362,14 @@ class LatentAttention(nn.Module):
                 q, pool, None, i + 1, block_k=plan.block_k,
                 scale=sp.softmax_scale, latent=sp.kv_rank)
         else:
-            self._write(cached, index, rows, i, S)
+            self._write(cached, index, rows, i,
+                        S if count is None else count)
             blk = min(self.decode_prefix_block or W, W)
             o = latent_walk(
                 q * jnp.asarray(sp.softmax_scale, q.dtype),
                 cached.value, i, spec=sp, block=blk if W % blk == 0
                 else W, expand=(None if form == "absorbed"
-                                else (k_up, v_up)))
+                                else (k_up, v_up)), count=count)
         if form == "absorbed":
             o = jnp.einsum("...shr,rhv->...shv", o.astype(v_up.dtype),
                            v_up, preferred_element_type=jnp.float32)
@@ -371,9 +377,12 @@ class LatentAttention(nn.Module):
 
     @staticmethod
     def _write(cached, index, rows, i, S):
-        z = jnp.zeros((), i.dtype)
-        cached.value = lax.dynamic_update_slice(
-            cached.value, rows, (*[z] * (rows.ndim - 2), i, z))
+        """``S``: the rows appended - or, for a chunk whose tail is
+        pad, the TRACED count of its real rows (`__call__`'s
+        ``count``): only those are written."""
+        cached.value = append_rows(
+            cached.value, rows, i, None if isinstance(S, int) else S,
+            axis=-2)
         index.value = i + S
 
     def _kernel_plan(self, q, pool, S):

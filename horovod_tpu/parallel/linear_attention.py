@@ -194,7 +194,13 @@ class KDAAttention(nn.Module):
     says which lanes a cached S = 1 step may move. Only the kernel's
     path (`state_step_plan`) looks at it: there the step keeps the
     `state` of a lane that does not advance itself. Everything else a
-    step overwrites is still the caller's to put back."""
+    step overwrites is still the caller's to put back.
+
+    ``count`` (traced int32 in 1 .. S; an S > 1 chunk whose tail is
+    pad): the positions past the first ``count`` neither decay the
+    state nor write to it (g = 0 and beta = 0 there, as `kda_chunked`
+    pads its own tail), and the convolution's tail kept for the next
+    chunk is the last real positions', not the pads'."""
 
     # The cache variables a step overwrites: whoever steps a lane that
     # must not advance has to put the old values back.
@@ -213,7 +219,8 @@ class KDAAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array,
-                 advance: Optional[jax.Array] = None) -> jax.Array:
+                 advance: Optional[jax.Array] = None,
+                 count: Optional[jax.Array] = None) -> jax.Array:
         H, D, K = self.num_heads, self.head_dim, CONV_TAPS
         F = H * D
         B, S, _ = x.shape
@@ -235,6 +242,10 @@ class KDAAttention(nn.Module):
         g = (-jnp.exp(a_log)[:, None]
              * jax.nn.softplus(decay.astype(f32) + dt_bias)
              .reshape(B, S, H, D))
+        if count is not None:
+            real = jnp.arange(S) < count
+            g = jnp.where(real[:, None, None], g, 0.0)
+            beta = jnp.where(real[:, None], beta, 0.0)
 
         cached = self.decode and self.has_variable("cache", "state")
         if self.decode:
@@ -270,7 +281,8 @@ class KDAAttention(nn.Module):
             o, s1 = kda_chunked(s0, q, k, v, g, beta)
         if cached:
             state.value = s1
-            tail.value = u[:, S:]
+            tail.value = (u[:, S:] if count is None else
+                          lax.dynamic_slice_in_dim(u, count, K - 1, 1))
         scale = self.param("o_norm", nn.initializers.ones, (D,), f32)
         o = (o * lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
                            + self.norm_eps) * scale)

@@ -223,7 +223,13 @@ class Mamba2Mixer(nn.Module):
     says which lanes a cached S = 1 step may move. Only the kernel's
     path (`state_step_plan`) looks at it: there the step keeps the
     `state` of a lane that does not advance itself. Everything else a
-    step overwrites is still the caller's to put back."""
+    step overwrites is still the caller's to put back.
+
+    ``count`` (traced int32 in 1 .. S; an S > 1 chunk whose tail is
+    pad): the positions past the first ``count`` neither decay the
+    state nor write to it (dt = 0 there, as `ssm_chunked` pads its own
+    tail), and the convolution's tail kept for the next chunk is the
+    last real positions', not the pads'."""
 
     # as `KDAAttention`'s: what a step overwrites, and what the
     # in-place kernel keeps itself for a lane that does not advance
@@ -238,7 +244,8 @@ class Mamba2Mixer(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array,
-                 advance: Optional[jax.Array] = None) -> jax.Array:
+                 advance: Optional[jax.Array] = None,
+                 count: Optional[jax.Array] = None) -> jax.Array:
         sp = self.spec
         H, P, N, G, K = (sp.num_heads, sp.head_dim, sp.state_size,
                          sp.groups, sp.conv_taps)
@@ -272,6 +279,8 @@ class Mamba2Mixer(nn.Module):
         xs = xs.reshape(B, S, H, P)
         Bm, Cm = Bm.reshape(B, S, G, N), Cm.reshape(B, S, G, N)
         dt = jax.nn.softplus(dt + dt_bias)              # [B, S, H]
+        if count is not None:
+            dt = jnp.where((jnp.arange(S) < count)[:, None], dt, 0.0)
         A = -jnp.exp(a_log)
 
         h0 = state.value if cached else jnp.zeros(sp.state_shape(B), f32)
@@ -290,7 +299,8 @@ class Mamba2Mixer(nn.Module):
             y, h1 = ssm_chunked(h0, xs, dt, A, Bm, Cm, chunk=sp.chunk)
         if cached:
             state.value = h1
-            tail.value = u[:, S:]
+            tail.value = (u[:, S:] if count is None else
+                          lax.dynamic_slice_in_dim(u, count, K - 1, 1))
         y = (y + skip[:, None] * xs).reshape(B, S, I)
         y = gated_norm(y, z, scale, self.norm_eps)
         return RowParallelDense(self.out_features, use_bias=False,

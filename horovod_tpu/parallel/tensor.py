@@ -474,7 +474,14 @@ class ParallelSelfAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array,
-                 mask: Optional[jax.Array] = None) -> jax.Array:
+                 mask: Optional[jax.Array] = None,
+                 count: Optional[jax.Array] = None) -> jax.Array:
+        """``count`` (traced int32 in 1 .. S; decode mode, a chunk
+        appended to a cache): only the first ``count`` positions are
+        the prompt's, the rest are pad - they write no cache row, lap
+        no ring slot and advance no index; their outputs are the
+        caller's to discard (a real query never sees a pad key: pads
+        come after every real position, and attention is causal)."""
         H = self.num_heads
         Hkv = self.num_kv_heads or H
         if H % Hkv:
@@ -514,7 +521,7 @@ class ParallelSelfAttention(nn.Module):
             # Cache stores the UNREPEATED Hkv heads (the GQA memory
             # win); _decode_attention broadcasts after the cache read
             # and applies RoPE at the absolute cache position.
-            o = self._decode_attention(q, k, v)
+            o = self._decode_attention(q, k, v, count)
         else:
             q, k = self._maybe_rope(q, k)
             o = self._dispatch_attn(q, k, v, mask)
@@ -654,27 +661,30 @@ class ParallelSelfAttention(nn.Module):
     def _cache_write(self, cached_k, cached_v, scale_k, scale_v,
                      index, k, v, i, S, W):
         """Append S new K/V at position i (linear cache) or into their
-        rolling slots (window cache); advances the index. Under
+        rolling slots (window cache); advances the index by S. Under
         ``kv_quant`` the block is quantized here (symmetric int8 over
         head_dim, one scale per (position, head)) and the scales land
-        in the same slots."""
+        in the same slots. ``S`` is the rows of k and v - or, for a
+        chunk whose tail is pad, the TRACED count of its real rows
+        (`__call__`'s ``count``): only those are written, and every
+        other row of the cache keeps what it held."""
+        count, S = (None, S) if isinstance(S, int) else (S, k.shape[-3])
         if self.kv_quant == "int8":
             k, sk = _kv_quantize(k)
             v, sv = _kv_quantize(v)
         else:
             k, v = self._stored(k), self._stored(v)
         if self.window is None:
-            z = jnp.zeros((), i.dtype)
-            cached_k.value = lax.dynamic_update_slice(
-                cached_k.value, k, (z, i, z, z))
-            cached_v.value = lax.dynamic_update_slice(
-                cached_v.value, v, (z, i, z, z))
+            cached_k.value = append_rows(cached_k.value, k, i, count,
+                                         axis=1)
+            cached_v.value = append_rows(cached_v.value, v, i, count,
+                                         axis=1)
             if scale_k is not None:
-                scale_k.value = lax.dynamic_update_slice(
-                    scale_k.value, sk, (z, i, z))
-                scale_v.value = lax.dynamic_update_slice(
-                    scale_v.value, sv, (z, i, z))
-        else:
+                scale_k.value = append_rows(scale_k.value, sk, i,
+                                            count, axis=1)
+                scale_v.value = append_rows(scale_v.value, sv, i,
+                                            count, axis=1)
+        elif count is None:
             # Last min(S, W) keys land in their slots (earlier ones
             # would be overwritten within this block anyway).
             t = min(S, W)
@@ -689,7 +699,25 @@ class ParallelSelfAttention(nn.Module):
                     sk[:, S - t:])
                 scale_v.value = scale_v.value.at[:, slots].set(
                     sv[:, S - t:])
-        index.value = i + S
+        else:
+            # The last min(count, W) REAL keys land in their slots;
+            # every other row of the block (earlier real keys that
+            # these overwrite anyway, and the pads - which would lap a
+            # live slot of the ring) is sent past the ring's end and
+            # dropped.
+            r = jnp.arange(S, dtype=i.dtype)
+            slots = jnp.where((r < count) & (r >= count - W),
+                              (i + r) % W, W)
+
+            def put(ring, rows):
+                return ring.at[:, slots].set(rows, mode="drop")
+
+            cached_k.value = put(cached_k.value, k)
+            cached_v.value = put(cached_v.value, v)
+            if scale_k is not None:
+                scale_k.value = put(scale_k.value, sk)
+                scale_v.value = put(scale_v.value, sv)
+        index.value = i + (S if count is None else count)
 
     def _cache_read_block(self, cached, scale, start, size):
         """One `size`-slot slice of the cache at the compute dtype
@@ -708,7 +736,7 @@ class ParallelSelfAttention(nn.Module):
                                axis=-1)
 
     def _prefix_attention(self, q, cached_k, cached_v, scale_k,
-                          scale_v, i, S):
+                          scale_v, i, S, count=None):
         """Decode attention that touches ONLY the filled cache prefix.
 
         The cache-wide-mask path reads (and masks against) all
@@ -725,7 +753,9 @@ class ParallelSelfAttention(nn.Module):
 
         q: [..., S, H, D]; returns [..., S, H, D]. Composes with GQA
         (per-block `_repeat_kv`), int8 KV (per-block dequant), and TP
-        (all ops are shard-local over the head axis).
+        (all ops are shard-local over the head axis). With ``count``
+        (`__call__`) the prefix ends at i + count: the pad queries
+        past it read what the real ones do, and are discarded.
         """
         W = cached_k.value.shape[-3]
         blk = min(self.decode_prefix_block, W)
@@ -735,7 +765,8 @@ class ParallelSelfAttention(nn.Module):
         dtype = q.dtype
         q = q * jnp.asarray(D ** -0.5, dtype)
         qpos = i + jnp.arange(S, dtype=jnp.int32)          # [S]
-        nblk = (i + S + blk - 1) // blk                    # traced
+        filled = i + (S if count is None else count)
+        nblk = (filled + blk - 1) // blk                   # traced
         neg = jnp.finfo(jnp.float32).min
         m0 = jnp.full((*lead, H, S), neg, jnp.float32)
         l0 = jnp.zeros((*lead, H, S), jnp.float32)
@@ -875,11 +906,12 @@ class ParallelSelfAttention(nn.Module):
             k_scale_pool=ks_pool, v_scale_pool=vs_pool,
             compute_dtype=self.dtype or jnp.float32)
 
-    def _decode_attention(self, q, k, v):
+    def _decode_attention(self, q, k, v, count=None):
         """One decode tick: append k/v at `cache_index`, attend q
-        against the filled prefix. At cache-init time (`model.init` on
-        a [B, max_len] dummy) the cache is shaped from the full-length
-        k/v and a plain causal forward runs instead.
+        against the filled prefix (``count``: see `__call__`). At
+        cache-init time (`model.init` on a [B, max_len] dummy) the
+        cache is shaped from the full-length k/v and a plain causal
+        forward runs instead.
 
         With a ``window``, the cache is a ROLLING buffer of only
         `window` entries (slot = position mod window): cache memory
@@ -954,10 +986,12 @@ class ParallelSelfAttention(nn.Module):
                 return self._kernel_step(plan, q, k, v, cached_k,
                                          cached_v, index, i, i, i + 1)
             self._cache_write(cached_k, cached_v, scale_k, scale_v,
-                              index, k, v, i, S, W)
+                              index, k, v, i,
+                              S if count is None else count, W)
             if prefix:
                 return self._prefix_attention(q, cached_k, cached_v,
-                                              scale_k, scale_v, i, S)
+                                              scale_k, scale_v, i, S,
+                                              count)
             key = self._cache_read(cached_k, scale_k)
             val = self._cache_read(cached_v, scale_v)
             # Valid positions: the prefix plus the causal part of the
@@ -1005,8 +1039,44 @@ class ParallelSelfAttention(nn.Module):
                                     self._repeat_kv(val),
                                     keep[None, None])
         self._cache_write(cached_k, cached_v, scale_k, scale_v,
-                          index, k, v, i, S, W)
+                          index, k, v, i, S if count is None else count,
+                          W)
         return out
+
+
+def append_rows(buf, rows, i, count=None, *, axis):
+    """``rows`` [.., S, ..] into ``buf`` [.., W, ..] (S <= W) at
+    positions i .. i + S - 1 of ``axis``: the append of a cache that
+    grows by position (K/V, their scales, latent rows).
+
+    ``count`` None: every row, one `lax.dynamic_update_slice` - whose
+    start CLAMPS to W - S, so the caller keeps i + S <= W. With a
+    traced ``count`` (a chunk whose tail is pad) only the first
+    ``count`` rows are written and every other row of ``buf`` keeps
+    what it held - also where i + S passes W: the window that is read,
+    merged and written back is the clamped one, and the rows are
+    rolled to where they belong in it, so a prompt that ends within S
+    rows of the cache's end lands on its own positions and not on the
+    real rows below them."""
+    axis %= buf.ndim
+    if count is None:
+        z = jnp.zeros((), i.dtype)
+        return lax.dynamic_update_slice(
+            buf, rows, [i if a == axis else z for a in range(buf.ndim)])
+    S, W = rows.shape[axis], buf.shape[axis]
+    # (`lax` by hand: this runs twice a layer at trace time, and a
+    # `jnp.roll` by a traced shift alone cost a quarter of a chunk
+    # program's trace)
+    start = lax.min(i, np.asarray(W - S, i.dtype))
+    shift = i - start                   # > 0 only in the last S rows
+    r = lax.iota(i.dtype, S) - shift    # window row -> chunk row
+    own = lax.broadcast_in_dim((r >= 0) & (r < count), rows.shape,
+                               (axis,))
+    held = lax.dynamic_slice_in_dim(buf, start, S, axis)
+    rolled = lax.dynamic_slice_in_dim(      # = roll(rows, shift, axis)
+        lax.concatenate([rows, rows], axis), S - shift, S, axis)
+    return lax.dynamic_update_slice_in_dim(
+        buf, lax.select(own, rolled, held), start, axis)
 
 
 def _mesh_is_trivial() -> bool:

@@ -140,6 +140,10 @@ class EngineMetrics:
         self.prefill_first_tokens = 0
         self.prefill_tokens = 0
         self.prefill_chunks = 0    # interleaved prefill chunks streamed
+        # ... of which ran as the padded tail program, and the pad
+        # positions those carried (never counted in prefill_tokens)
+        self.prefill_tail_chunks = 0
+        self.prefill_pad_tokens = 0
         self.ticks = 0             # decode ticks executed
         # Hot-path pipelining counters (the tentpole's evidence):
         # host_syncs counts EXPOSED device->host syncs — reads issued
@@ -325,6 +329,7 @@ class EngineMetrics:
             self._obs_res["requeued"].inc(n)
         elif name in ("prefix_hits", "prefix_misses",
                       "prefix_evictions", "prefill_tokens_skipped",
+                      "prefill_tail_chunks", "prefill_pad_tokens",
                       "spec_proposed", "spec_accepted"):
             self._obs[name].inc(n)
         elif name == "preemptions_swap":
@@ -562,6 +567,8 @@ class EngineMetrics:
                 "prefill_tokens": self.prefill_tokens,
                 "prefill_first_tokens": self.prefill_first_tokens,
                 "prefill_chunks": self.prefill_chunks,
+                "prefill_tail_chunks": self.prefill_tail_chunks,
+                "prefill_pad_tokens": self.prefill_pad_tokens,
                 "ticks": self.ticks,
                 "ticks_overlapped": self.ticks_overlapped,
                 "host_syncs": self.host_syncs,

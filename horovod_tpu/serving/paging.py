@@ -834,13 +834,31 @@ class PagedSlotPool:
                     self._pools, jnp.int32(src), jnp.int32(dst))
                 self._tables = self._tables.at[slot, idx].set(dst)
 
-    def prefill_chunk(self, slot: int, chunk):
+    @staticmethod
+    def chunk_width(max_chunk: Optional[int]) -> Optional[int]:
+        """None under any budget (shared protocol with `SlotPool`):
+        `paged_prefill_chunk` takes no count, so no tail is padded and
+        a chunk's width is its length."""
+        del max_chunk
+        return None
+
+    @staticmethod
+    def prefill_schedule(length: int,
+                         max_chunk: Optional[int] = None) -> List[int]:
+        """THE chunk schedule of a prompt in this pool (shared protocol
+        with `SlotPool`): the binary decomposition, capped at the
+        budget - the fixed pool's padded tail is not taken here."""
+        return prefill_chunks(length, max_chunk, pad_tail=False)
+
+    def prefill_chunk(self, slot: int, chunk,
+                      width: Optional[int] = None):
         """Append one prompt chunk into lane ``slot``'s paged cache;
         returns the chunk's last-position logits (device array). The
-        same binary-decomposition chunk schedule as the slot pool, so
+        binary-decomposition chunk schedule (`prefill_schedule`), so
         the compiled-program set stays log2-bounded; ``slot`` and the
         block table are traced, so every lane and layout shares each
-        size's program."""
+        size's program. ``width`` is `chunk_width`'s None."""
+        del width
         # hvd: disable=HVD001(chunk is host-side prompt tokens from the admission queue, never a device array — no sync)
         chunk = np.asarray(chunk)
         c = int(chunk.shape[0])
@@ -913,8 +931,8 @@ class PagedSlotPool:
         self.begin_prefill(slot)
         logits = None
         off = skipped
-        for c in prefill_chunks(int(prompt.shape[0]) - skipped,
-                                max_chunk):
+        for c in self.prefill_schedule(int(prompt.shape[0]) - skipped,
+                                       max_chunk):
             logits = self.prefill_chunk(slot, prompt[off:off + c])
             off += c
         return self.finish_prefill(slot, logits, temperature, top_p,

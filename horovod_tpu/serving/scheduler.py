@@ -176,6 +176,9 @@ class ContinuousBatchingScheduler:
         self.prefill_chunk_budget = int(prefill_chunk_budget)
         self._max_chunk = (self.prefill_chunk_budget
                            if self.prefill_chunk_budget > 0 else None)
+        # the positions of the pool's chunk programs under the budget:
+        # a shorter chunk (a prompt's tail) is padded to them
+        self._chunk_width = pool.chunk_width(self._max_chunk)
         self.pipeline_depth = max(0, min(1, int(pipeline_depth)))
         self.active: Dict[int, Request] = {}   # slot -> request
         self.prefilling: Dict[int, _PrefillJob] = {}
@@ -577,22 +580,30 @@ class ContinuousBatchingScheduler:
                 continue
             while job.chunks and (left is None
                                   or job.chunks[0] <= left):
+                # c REAL tokens (what the budget is charged and
+                # `prefill_tokens` counts) in a program of `width`
+                # positions: a tail's pads are neither
                 c = job.chunks.pop(0)
+                width = max(c, self._chunk_width or 0)
                 # One site, both records: the chunk in its request's
                 # tree and in the loop's.
                 with _spans.loop_span("sched.prefill_chunk",
-                                      slot=slot, tokens=c):
+                                      slot=slot, tokens=c, width=width):
                     csid = _spans.begin_span(
                         "serving.prefill_chunk",
                         trace_id=job.req.trace_id,
                         parent_id=job.req.span_ids.get("prefill", ""),
                         tokens=c, off=job.off)
                     job.logits = self.pool.prefill_chunk(
-                        slot, job.prompt[job.off:job.off + c])
+                        slot, job.prompt[job.off:job.off + c],
+                        self._chunk_width)
                     job.off += c
                     _spans.end_span(csid)
                 self.metrics.count("prefill_chunks")
                 self.metrics.count("prefill_tokens", c)
+                if width > c:
+                    self.metrics.count("prefill_tail_chunks")
+                    self.metrics.count("prefill_pad_tokens", width - c)
                 if left is not None:
                     left -= c
                 progressed = True
@@ -653,7 +664,7 @@ class ContinuousBatchingScheduler:
                     slot = adm.slot
                     job = _PrefillJob(
                         req=req, prompt=full,
-                        chunks=prefill_schedule(
+                        chunks=self.pool.prefill_schedule(
                             int(full.shape[0])
                             - adm.skipped, self._max_chunk),
                         off=adm.skipped)
@@ -1144,13 +1155,3 @@ class ContinuousBatchingScheduler:
             self._retire(slot, req, "aborted", now)
         for slot, job in list(self.prefilling.items()):
             self._retire_prefill(slot, job, "aborted")
-
-
-def prefill_schedule(length: int, max_chunk: Optional[int]) -> List[int]:
-    """The chunk schedule for one prompt — `prefill_chunks` with the
-    scheduler's budget cap applied (kept as a named seam so the
-    restart replay path and tests share the exact decomposition the
-    dispatch loop uses: same prompt + same budget => same chunks =>
-    same cache states => token-exact replay)."""
-    from horovod_tpu.models.transformer import prefill_chunks
-    return prefill_chunks(length, max_chunk)

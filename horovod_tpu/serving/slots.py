@@ -51,7 +51,8 @@ import jax.numpy as jnp
 from horovod_tpu.annotations import hot_path
 from horovod_tpu.models.transformer import (
     TransformerLM, decode_attention_plans,
-    init_slot_cache, moe_product_plans, moe_stat_columns, prefill_chunks,
+    chunk_width, init_slot_cache, moe_product_plans, moe_stat_columns,
+    prefill_chunks,
     recurrent_leaf,
     state_step_plans,
     sample_lanes,
@@ -432,22 +433,51 @@ class SlotPool:
         finally:
             self.maybe_compiling = False
 
-    def prefill_chunk(self, slot: int, chunk):
-        """Append one prompt chunk (1-D int tokens, power-of-two
-        length from `prefill_chunks`) into ``slot``'s cache; returns
-        the chunk's last-position logits (a DEVICE array — no host
-        sync). The slot stays non-live, so interleaved decode ticks
-        freeze its fill index and the next chunk lands exactly where
-        this one stopped."""
+    def chunk_width(self, max_chunk: Optional[int]) -> Optional[int]:
+        """The positions of this pool's chunk programs under a budget
+        of ``max_chunk`` prompt tokens a step
+        (`models.transformer.chunk_width`, within the cache's rows);
+        None without a budget."""
+        return chunk_width(max_chunk, self.model.max_len)
+
+    def prefill_schedule(self, length: int,
+                         max_chunk: Optional[int] = None) -> List[int]:
+        """THE chunk schedule of a prompt of ``length`` tokens in this
+        pool: the real tokens of each chunk (`prefill_chunks` at
+        `chunk_width`) - whole chunks and at most one tail under a
+        budget, a binary decomposition without one. The scheduler, a
+        restart's replay, `prefill` and `warmup` all read it here, so
+        the warm-up compiles exactly what the scheduler can emit and a
+        replay streams the chunks the first run did."""
+        return prefill_chunks(length, self.chunk_width(max_chunk))
+
+    def prefill_chunk(self, slot: int, chunk,
+                      width: Optional[int] = None):
+        """Append one prompt chunk (1-D int tokens, a length of
+        `prefill_schedule`) into ``slot``'s cache; returns the
+        logits of the chunk's last token (a DEVICE array — no host
+        sync). A chunk shorter than ``width`` (`chunk_width`: the tail
+        of a prompt under a budget) is padded to it and runs as the
+        tail program, its true length a traced operand, so every tail
+        length is one compiled program; the pads leave the cache as
+        they found it. The slot stays non-live, so interleaved decode
+        ticks freeze its fill index and the next chunk lands exactly
+        where this one stopped."""
         # hvd: disable=HVD001(chunk is host-side prompt tokens from the admission queue, never a device array — no sync)
-        chunk = np.asarray(chunk)
+        chunk = np.asarray(chunk, np.int32)
         c = int(chunk.shape[0])
-        self.maybe_compiling = ("prefill", c) not in self._seen_shapes
+        args, shape = (), ("prefill", c)
+        if width is not None and c < width:
+            chunk = np.pad(chunk, (0, width - c))
+            # (a numpy scalar rides the call's own argument handling:
+            # no device put of its own on the dispatch thread)
+            args, shape = (np.int32(c),), ("prefill", width, "tail")
+        self.maybe_compiling = shape not in self._seen_shapes
         try:
             with self._ctx():
                 self._cache, logits, pairs = slot_prefill_chunk(
                     self.dec_model, self.params, self._cache,
-                    jnp.int32(slot), jnp.asarray(chunk, jnp.int32))
+                    jnp.int32(slot), jnp.asarray(chunk), *args)
                 if pairs.size:
                     # on the device until the next tick's copy takes it
                     self._prefill_pairs.append(pairs)
@@ -459,8 +489,8 @@ class SlotPool:
                     self._drf_cache = slot_prefill_advance(
                         self.drf_model, self.drf_params,
                         self._drf_cache, jnp.int32(slot),
-                        jnp.asarray(chunk, jnp.int32))
-            self._note_shape(("prefill", c))
+                        jnp.asarray(chunk), *args)
+            self._note_shape(shape)
             return logits
         finally:
             self.maybe_compiling = False
@@ -505,16 +535,18 @@ class SlotPool:
         """Stream ``prompt`` (1-D int tokens) into ``slot`` in one
         call and return the request's FIRST generated token — the
         begin/chunks/finish composition for callers that do not
-        interleave (tests, warmup, simple drivers). Chunks follow the
-        binary decomposition (`prefill_chunks`, optionally capped at
-        ``max_chunk``), so the set of compiled prefill programs is
-        bounded by log2(max_len) — never one per prompt length."""
+        interleave (tests, simple drivers). Chunks follow
+        `prefill_schedule`: under ``max_chunk`` whole chunks and one
+        padded tail (two compiled programs), without it the binary
+        decomposition (at most log2(max_len) programs) — never one
+        program per prompt length."""
         prompt = np.asarray(prompt)
         self.begin_prefill(slot)
         logits = None
         off = 0
-        for c in prefill_chunks(int(prompt.shape[0]), max_chunk):
-            logits = self.prefill_chunk(slot, prompt[off:off + c])
+        width = self.chunk_width(max_chunk)
+        for c in self.prefill_schedule(int(prompt.shape[0]), max_chunk):
+            logits = self.prefill_chunk(slot, prompt[off:off + c], width)
             off += c
         return self.finish_prefill(slot, logits, temperature, top_p,
                                    seed)
@@ -632,26 +664,39 @@ class SlotPool:
 
     def warmup(self, max_chunk: Optional[int] = None) -> dict:
         """Precompile the serving hot path before the first request:
-        slot reset, every power-of-two prefill chunk a prompt can
-        decompose into (capped at ``max_chunk`` when the scheduler
-        caps chunks), the first-token sample, and the vmapped decode
-        tick. All programs land in the compile-keyed cache this pool
-        already consults (`_seen_shapes`), so the first request of any
-        prompt shape is a jit-cache hit — no XLA compile in the hot
-        path, nothing for the watchdog's `maybe_compiling` exemption
-        to special-case. Runs on the caller's thread; lane 0 is used
-        as scratch and re-zeroed after."""
+        slot reset, every chunk program `prefill_schedule` can emit,
+        the first-token sample, and the vmapped decode tick. Under a
+        budget (``max_chunk``, as the scheduler has it) the chunk
+        programs are TWO - the whole chunk and the padded tail, warmed
+        once at one count since the count is traced (through
+        `prefill_chunk`, the scheduler's own call: the same jit entry)
+        - where every program costs its trace and lowering in every
+        process, compile cache or not; without one, every power of
+        two up to ``max_len``. All programs land in the compile-keyed
+        cache this pool already consults (`_seen_shapes`), so the first
+        request of any prompt length is a jit-cache hit — no XLA
+        compile in the hot path, nothing for the watchdog's
+        `maybe_compiling` exemption to special-case. Runs on the
+        caller's thread; lane 0 is used as scratch and re-zeroed
+        after. Returns ``compiles``, ``seconds`` and ``prefill_sizes``:
+        the chunk programs warmed, a whole chunk as its tokens and the
+        tail as ``"1..<width - 1>"``."""
         t0 = time.time()
         before = self.compiles
-        cap = self.model.max_len
-        if max_chunk is not None and max_chunk >= 1:
-            cap = min(cap, int(max_chunk))
-        cap = 1 << (max(1, cap).bit_length() - 1)   # pow2 floor
-        sizes = [1 << b for b in range(cap.bit_length())]
+        width = self.chunk_width(max_chunk)
+        if width is None:
+            cap = self.model.max_len
+            sizes = [1 << b for b in range(cap.bit_length())]
+            said = sizes
+        else:
+            # a whole chunk, and one tail: any count below the width
+            sizes = [width] + [width - 1] * (width > 1)
+            said = [width] + [f"1..{width - 1}"] * (width > 1)
         logits = None
         for c in sizes:
             self.begin_prefill(0)
-            logits = self.prefill_chunk(0, np.zeros((c,), np.int32))
+            logits = self.prefill_chunk(0, np.zeros((c,), np.int32),
+                                        width)
         self.finish_prefill(0, logits, 0.0, None, 0)
         if self.spec_on:
             # Spec mode replaces the S=1 tick with the round (the
@@ -670,7 +715,7 @@ class SlotPool:
             self._top_ps = self._top_ps.at[0].set(1.0)
         return {"compiles": self.compiles - before,
                 "seconds": time.time() - t0,
-                "prefill_sizes": sizes}
+                "prefill_sizes": said}
 
     def free(self, slot: int):
         """Retire a slot: zero its rows (cost hygiene + trivially

@@ -14,6 +14,7 @@ jit caches are shared across the whole module (flax modules hash by
 their dataclass fields).
 """
 
+import functools
 import time
 from concurrent.futures import CancelledError
 
@@ -24,7 +25,8 @@ import jax
 import jax.numpy as jnp
 
 from horovod_tpu.models.transformer import (
-    TransformerLM, generate, prefill_chunks,
+    TransformerLM, chunk_width, generate, prefill_chunks,
+    slot_prefill_chunk,
 )
 from horovod_tpu.parallel.tensor import unbox
 from horovod_tpu.serving import (
@@ -54,6 +56,71 @@ def _prompts(n, seed=0, lo=1, hi=8):
     rs = np.random.RandomState(seed)
     return [rs.randint(0, VOCAB, (int(rs.randint(lo, hi)),))
             for _ in range(n)]
+
+
+# ---- the padded prompt tail, by cache kind ---------------------------------
+TAIL_B = 8          # the chunk budget (and width) of these cases
+TAIL_LEN = 28       # no multiple of it: a tail can pass the cache's end
+# P % B in {1, 5, B - 1}; P < B; no tail; a prompt that ends within B
+# rows of max_len (the tail starts at 24: a `dynamic_update_slice` of
+# 8 rows there would be clamped to 20 and shift over real rows)
+TAIL_CASES = {"mod-1": 17, "mod-5": 13, "mod-7": 23, "short": 5,
+              "whole": 16, "clamp": 27}
+
+
+def _tail_fields(kind):
+    from horovod_tpu.models.transformer import AttnSpec
+    from horovod_tpu.parallel.latent_attention import LatentSpec
+    from horovod_tpu.parallel.state_space import SsmSpec
+    experts = dict(moe_every=1, moe_impl="dropless", num_experts=8,
+                   moe_k=2, moe_hidden=32)
+    return {
+        # learned positions: the table's slice clamps as K/V's does
+        "kv-128": dict(num_heads=2, head_dim=128, num_kv_heads=1),
+        # two 64-wide KV heads to a stored row
+        "kv-64x2": dict(num_heads=4, head_dim=64, num_kv_heads=2,
+                        pos_emb="rope"),
+        "kv-int8": dict(num_heads=4, head_dim=8, kv_quant="int8",
+                        pos_emb="rope"),
+        # a ring of 12 slots: the tail's pads would lap live rows
+        "ring": dict(num_heads=4, head_dim=8, pos_emb="rope",
+                     layer_kinds=("swa",),
+                     attn_specs=(("swa", AttnSpec(window=12)),)),
+        "latent": dict(num_heads=4, head_dim=8, pos_emb="rope",
+                       layer_kinds=("mla",),
+                       latent=LatentSpec(q_rank=16, kv_rank=16,
+                                         nope_dim=8, rope_dim=8,
+                                         v_dim=8)),
+        "kda": dict(num_heads=4, head_dim=8, pos_emb="none",
+                    layer_kinds=("kda",)),
+        "ssm": dict(num_heads=4, head_dim=8, pos_emb="none",
+                    layer_kinds=("ssm",),
+                    ssm=SsmSpec(num_heads=4, head_dim=8, state_size=16,
+                                groups=2)),
+        # identity experts: `moe_zero_pairs` / `moe_chosen_pairs`
+        "experts-zero": dict(num_heads=4, head_dim=8, pos_emb="rope",
+                             moe_held=(2, 4), moe_zero_experts=2,
+                             moe_router="softmax", **experts),
+        # a group-limited choice over a share: `moe_token_chips`
+        "experts-groups": dict(num_heads=4, head_dim=8, pos_emb="rope",
+                               moe_held=(0, 4), moe_groups=(4, 2),
+                               moe_shared_hidden=16, **experts),
+    }[kind]
+
+
+TAIL_KINDS = ("kv-128", "kv-64x2", "kv-int8", "ring", "latent", "kda",
+              "ssm", "experts-zero", "experts-groups")
+
+
+@functools.lru_cache(maxsize=None)
+def _tail_model(kind):
+    """(model, params) of one tiny float32 layer holding the kind."""
+    model = TransformerLM(vocab_size=VOCAB, num_layers=1,
+                          max_len=TAIL_LEN, dtype=jnp.float32,
+                          **_tail_fields(kind))
+    params = unbox(model.init(
+        jax.random.PRNGKey(2), jnp.zeros((1, 8), jnp.int32))["params"])
+    return model, params
 
 
 def _wait(cond, timeout=60.0, dt=0.005):
@@ -595,7 +662,12 @@ class TestHotPathPipelining:
         # victim kept decoding through them.
         assert interleaved_steps >= 3, interleaved_steps
         assert victim_gains >= 2, victim_gains
-        assert metrics.prefill_chunks >= 7
+        # 3 tokens = one whole chunk + a tail of 1 padded to 2; 14 = 7
+        # whole chunks: pads are in no token count
+        assert metrics.prefill_chunks == 9
+        assert metrics.prefill_tokens == 17
+        assert metrics.prefill_tail_chunks == 1
+        assert metrics.prefill_pad_tokens == 1
         for prompt, r, steps in ((short, a, 16), (long_p, b, 4)):
             ref = np.asarray(generate(
                 model, params, jnp.asarray(prompt)[None], steps))[0]
@@ -678,6 +750,41 @@ class TestHotPathPipelining:
         # set it pinned).
         assert snap["warmup_compiles"] >= 3
 
+    def test_warmup_is_two_chunk_programs_for_every_prompt_length(
+            self, lm):
+        """Under a budget the warm-up compiles TWO chunk programs -
+        the whole chunk and the padded tail, once, at one count - and
+        they are the scheduler's own: prompts of 1 ... 3 budgets of
+        every remainder then run with `compiles` unchanged (a tail
+        call that missed the warmed jit entry - another dtype, a weak
+        type - would count here, and on the chip re-trace inside the
+        measured window)."""
+        model, params = lm
+        B = 8
+        with ServingEngine(model, params, num_slots=2, max_queue=16,
+                           warmup=True, prefill_chunk_budget=B) as eng:
+            assert eng.warmup_info["prefill_sizes"] == [B, "1..7"]
+            # reset, two chunk programs, first token, tick
+            assert eng.warmup_info["compiles"] == 5
+            entries = slot_prefill_chunk._cache_size()
+            rs = np.random.RandomState(5)
+            prompts = [rs.randint(0, VOCAB, (n,))
+                       for n in (1, 5, 7, 8, 9, 13, 16, 23, 24)]
+            hs = [eng.submit(p, 4) for p in prompts]
+            for p, h in zip(prompts, hs):
+                got = h.result(timeout=300).full_sequence
+                if len(p) in (5, 24):   # (`generate` compiles a length)
+                    np.testing.assert_array_equal(got, np.asarray(
+                        generate(model, params, jnp.asarray(p)[None],
+                                 4))[0])
+            snap = eng.metrics_snapshot()
+        assert snap["compiles"] == 0, snap["compiles"]
+        assert slot_prefill_chunk._cache_size() == entries
+        assert snap["prefill_tokens"] == sum(map(len, prompts))
+        assert snap["prefill_tail_chunks"] == 6     # all but 8, 16, 24
+        assert snap["prefill_pad_tokens"] == 7 + 3 + 1 + 7 + 3 + 1
+        assert snap["prefill_chunks"] == 3 * 1 + 1 + 3 * 2 + 2 * 3
+
     def test_prefill_budget_env_default(self, lm, monkeypatch):
         """HVD_PREFILL_CHUNK_BUDGET reaches the engine through the
         runtime config when no kwarg is passed."""
@@ -691,6 +798,7 @@ class TestHotPathPipelining:
             assert eng.scheduler.prefill_chunk_budget == 3
             # pow2 floor of the budget caps chunk sizes
             assert eng.scheduler._max_chunk == 3
+            assert eng.scheduler._chunk_width == 2
             eng.shutdown()
         finally:
             monkeypatch.delenv("HVD_PREFILL_CHUNK_BUDGET")
@@ -710,20 +818,114 @@ class TestPlumbing:
             prefill_chunks(0)
 
     def test_prefill_chunks_budget_cap(self, hvd):
-        """max_chunk caps chunks at its power-of-two floor while the
-        schedule still sums to the prompt length with power-of-two
-        pieces only (the compile-bounded contract)."""
+        """Under a budget the schedule is whole chunks of the
+        budget's power-of-two floor and at most ONE tail (run padded
+        to that width, its count traced): it sums to the prompt
+        length in ceil(n / width) chunks - two programs, whatever the
+        length. ``pad_tail=False`` (the paged pool) keeps the
+        remainder's binary decomposition."""
         assert prefill_chunks(200, 64) == [64, 64, 64, 8]
         assert prefill_chunks(13, 4) == [4, 4, 4, 1]
         assert prefill_chunks(13, 5) == [4, 4, 4, 1]   # pow2 floor
-        assert prefill_chunks(3, 8) == [2, 1]
+        assert prefill_chunks(3, 8) == [3]
+        assert prefill_chunks(7, 8) == [7]
+        assert prefill_chunks(15, 8) == [8, 7]
         assert prefill_chunks(8, 1) == [1] * 8
+        assert chunk_width(None) is None and chunk_width(0) is None
+        assert chunk_width(5) == 4 and chunk_width(128, 48) == 32
         for n in range(1, 70):
             for cap in (1, 2, 3, 8, 64):
                 cs = prefill_chunks(n, cap)
+                w = chunk_width(cap)
+                assert sum(cs) == n and len(cs) == -(-n // w)
+                assert all(c == w for c in cs[:-1])
+                assert 1 <= cs[-1] <= w
+        assert prefill_chunks(200, 64, pad_tail=False) == [64, 64, 64, 8]
+        assert prefill_chunks(13, 5, pad_tail=False) == [4, 4, 4, 1]
+        assert prefill_chunks(3, 8, pad_tail=False) == [2, 1]
+        assert prefill_chunks(7, 8, pad_tail=False) == [4, 2, 1]
+        for n in range(1, 70):
+            for cap in (1, 2, 3, 8, 64):
+                cs = prefill_chunks(n, cap, pad_tail=False)
                 assert sum(cs) == n
                 assert all(c & (c - 1) == 0 for c in cs)
                 assert max(cs) <= cap
+
+    @pytest.mark.parametrize("kind", TAIL_KINDS)
+    def test_padded_tail_leaves_the_cache_the_binary_schedule_leaves(
+            self, hvd, kind):
+        """A prompt streamed as whole chunks + ONE padded tail (the
+        schedule under a budget, `TAIL_B` here) against the same
+        prompt streamed as whole chunks + the tail's binary
+        decomposition (the schedule before the tail program), for
+        every cache kind a pool can hold and every case of
+        `TAIL_CASES`: every leaf equal - the pads wrote no row, lapped
+        no ring slot, decayed no state, kept no convolution row - the
+        indices advanced by exactly P, the same first token, and the
+        experts' pair counts equal (a pad is routed nowhere). Exact
+        where no tail runs; at float32's rounding where the chunk
+        boundary moves a summation."""
+        from jax.tree_util import keystr, tree_flatten_with_path
+        from horovod_tpu.serving.slots import SlotPool
+        model, params = _tail_model(kind)
+        new, old = (SlotPool(model, params, 2) for _ in range(2))
+        assert new.chunk_width(TAIL_B) == TAIL_B
+        for case, P in TAIL_CASES.items():
+            prompt = np.random.RandomState(P).randint(1, VOCAB, (P,))
+            tail = P % TAIL_B
+            assert new.prefill_schedule(P, TAIL_B) == (
+                [TAIL_B] * (P // TAIL_B) + [tail] * bool(tail)), case
+            t_new = new.prefill(1, prompt, 0.0, None, 0,
+                                max_chunk=TAIL_B)
+            old.begin_prefill(1)
+            off = 0
+            for c in prefill_chunks(P, TAIL_B, pad_tail=False):
+                logits = old.prefill_chunk(1, prompt[off:off + c])
+                off += c
+            assert t_new == old.finish_prefill(1, logits, 0.0, None,
+                                               0), case
+            for (path, a), (_, b) in zip(
+                    tree_flatten_with_path(new._cache)[0],
+                    tree_flatten_with_path(old._cache)[0]):
+                a, b, where = np.asarray(a), np.asarray(b), (
+                    case + keystr(path))
+                if "index" in where:
+                    assert (a == [0, P]).all() and (b == a).all(), where
+                elif not tail or a.dtype == np.int8:
+                    np.testing.assert_array_equal(a, b, err_msg=where)
+                else:
+                    np.testing.assert_allclose(a, b, atol=2e-5,
+                                               rtol=1e-5, err_msg=where)
+            pairs = [sum(np.asarray(x) for x in pool._prefill_pairs)
+                     for pool in (new, old)]
+            if "experts" in kind:
+                assert pairs[0].sum() > 0
+                np.testing.assert_array_equal(*pairs, err_msg=case)
+            else:
+                assert pairs == [0, 0]
+            new._prefill_pairs.clear(), old._prefill_pairs.clear()
+        # every length was TWO chunk programs (beside the reset and the
+        # first token), where the binary schedule took four
+        assert new.compiles == 4 and old.compiles == 6
+
+    def test_pad_tokens_claim_no_capacity_of_a_capacity_layer(self, hvd):
+        """The capacity-bound expert layer under ``count``: what the
+        pad positions hold cannot reach a real position's output - a
+        pad's first choice would otherwise claim an expert's slot
+        ahead of every real token's second choice."""
+        from horovod_tpu.parallel.expert import MoELayer
+        layer = MoELayer(num_experts=4, hidden=16, k=2,
+                         capacity_factor=0.5)
+        # (a draw at which the crowd does take a real token's slot)
+        x = jax.random.normal(jax.random.PRNGKey(7), (1, 8, 8))
+        params = layer.init(jax.random.PRNGKey(1), x)
+        crowd = x.at[0, 3:].set(x[0, 0])    # pads that want row 0's experts
+        y, y_crowd, y_all = (
+            layer.apply(params, inp, *count)
+            for inp, count in ((x, (jnp.int32(3),)),
+                               (crowd, (jnp.int32(3),)), (crowd, ())))
+        np.testing.assert_array_equal(y[0, :3], y_crowd[0, :3])
+        assert not np.array_equal(y_crowd[0, :3], y_all[0, :3])
 
     def test_metrics_snapshot_shape(self, lm):
         model, params = lm

@@ -598,7 +598,25 @@ MOE_CELLS = {
 }
 
 
-@pytest.mark.parametrize("program", ["tick", "chunk-128", "chunk-1"])
+# The programs a serving cell runs: the tick, the whole chunk and the
+# padded tail of the budget's 128 positions (its count a traced
+# operand) - and a chunk of one token, which an engine without a budget
+# still compiles.
+CHUNK_PROGRAMS = ["tick", "chunk-128", "tail-128", "chunk-1"]
+
+
+def _chunk_program(sds, program, dec, params, cache):
+    """`slot_prefill_chunk` compiled as "chunk-<C>" (C real tokens) or
+    "tail-<C>" (C positions of which a traced count are real)."""
+    from horovod_tpu.models.transformer import slot_prefill_chunk
+    kind, width = program.split("-")
+    count = (sds((), jnp.int32),) if kind == "tail" else ()
+    return slot_prefill_chunk.lower(
+        dec, params, cache, sds((), jnp.int32),
+        sds((int(width),), jnp.int32), *count).compile()
+
+
+@pytest.mark.parametrize("program", CHUNK_PROGRAMS)
 @pytest.mark.parametrize("cell", sorted(MOE_CELLS))
 def test_expert_layers_stream_their_weights_through_the_kernel(
         sds, monkeypatch, cell, program):
@@ -613,8 +631,7 @@ def test_expert_layers_stream_their_weights_through_the_kernel(
     than the product: count what arrives WITH a part)."""
     from horovod_tpu.models.transformer import (
         TransformerLM, init_slot_cache, moe_product_plans,
-        serving_params, slot_decode_model, slot_decode_tick,
-        slot_prefill_chunk)
+        serving_params, slot_decode_model, slot_decode_tick)
     from horovod_tpu.ops import flash_attention
     from horovod_tpu.parallel.latent_attention import LatentSpec
     from horovod_tpu.parallel.tensor import unbox
@@ -650,9 +667,7 @@ def test_expert_layers_stream_their_weights_through_the_kernel(
             vec(jnp.float32), sds((lanes, 2), jnp.uint32), vec(bool),
             vec(bool), sds((), jnp.int32)).compile()
     else:
-        compiled = slot_prefill_chunk.lower(
-            dec, params, cache, sds((), jnp.int32),
-            sds((int(program.split("-")[1]),), jnp.int32)).compile()
+        compiled = _chunk_program(sds, program, dec, params, cache)
     text = compiled.as_text()
     assert "ragged-dot" not in text and "ragged_dot" not in text
     calls = [ln for ln in text.splitlines()
@@ -675,7 +690,7 @@ def test_expert_layers_stream_their_weights_through_the_kernel(
     assert mem.temp_size_in_bytes < moe["w_gate"].size * 2
 
 
-@pytest.mark.parametrize("program", ["tick", "chunk-128", "chunk-1"])
+@pytest.mark.parametrize("program", CHUNK_PROGRAMS)
 def test_kda_state_is_stepped_in_place_by_one_call_a_layer(
         sds, monkeypatch, program):
     """solar's KDA layer (64 heads x 128, 128 lanes, float32 state) on
@@ -689,8 +704,7 @@ def test_kda_state_is_stepped_in_place_by_one_call_a_layer(
     a chunk of 128 keeps the chunkwise form."""
     from horovod_tpu.models.transformer import (
         TransformerLM, init_slot_cache, serving_params,
-        slot_decode_model, slot_decode_tick, slot_prefill_chunk,
-        state_step_plans)
+        slot_decode_model, slot_decode_tick, state_step_plans)
     from horovod_tpu.ops import flash_attention
     from horovod_tpu.parallel.tensor import unbox
 
@@ -724,13 +738,11 @@ def test_kda_state_is_stepped_in_place_by_one_call_a_layer(
             vec(jnp.float32), sds((lanes, 2), jnp.uint32), vec(bool),
             vec(bool), sds((), jnp.int32)).compile()
     else:
-        compiled = slot_prefill_chunk.lower(
-            dec, params, cache, sds((), jnp.int32),
-            sds((int(program.split("-")[1]),), jnp.int32)).compile()
+        compiled = _chunk_program(sds, program, dec, params, cache)
     text = compiled.as_text()
     calls = [ln for ln in text.splitlines()
              if "tpu_custom_call" in ln and "%kda_step" in ln.split(" = ")[0]]
-    assert len(calls) == (0 if program == "chunk-128" else 1)
+    assert len(calls) == (0 if program.endswith("-128") else 1)
     assert all("/block_0/kda/" in ln for ln in calls)
     # the pool's leaf is aliased input to output in every program
     assert compiled.memory_analysis().alias_size_in_bytes >= state.size * 4
@@ -743,7 +755,7 @@ def test_kda_state_is_stepped_in_place_by_one_call_a_layer(
     assert compiled.memory_analysis().temp_size_in_bytes < state.size * 4
 
 
-@pytest.mark.parametrize("program", ["tick", "chunk-128", "chunk-1"])
+@pytest.mark.parametrize("program", CHUNK_PROGRAMS)
 def test_ssm_state_is_stepped_in_place_by_one_call_a_layer(
         sds, monkeypatch, program):
     """granite's state-space layer (64 heads x 64, state 128, 64 lanes,
@@ -760,8 +772,7 @@ def test_ssm_state_is_stepped_in_place_by_one_call_a_layer(
     chunkwise form."""
     from horovod_tpu.models.transformer import (
         AttnSpec, TransformerLM, init_slot_cache, serving_params,
-        slot_decode_model, slot_decode_tick, slot_prefill_chunk,
-        state_step_plans)
+        slot_decode_model, slot_decode_tick, state_step_plans)
     from horovod_tpu.ops import flash_attention
     from horovod_tpu.parallel.state_space import SsmSpec
     from horovod_tpu.parallel.tensor import unbox
@@ -802,13 +813,11 @@ def test_ssm_state_is_stepped_in_place_by_one_call_a_layer(
             vec(jnp.float32), sds((lanes, 2), jnp.uint32), vec(bool),
             vec(bool), sds((), jnp.int32)).compile()
     else:
-        compiled = slot_prefill_chunk.lower(
-            dec, params, cache, sds((), jnp.int32),
-            sds((int(program.split("-")[1]),), jnp.int32)).compile()
+        compiled = _chunk_program(sds, program, dec, params, cache)
     text = compiled.as_text()
     calls = [ln for ln in text.splitlines()
              if "tpu_custom_call" in ln and "%ssm_step" in ln.split(" = ")[0]]
-    assert len(calls) == (0 if program == "chunk-128" else 1)
+    assert len(calls) == (0 if program.endswith("-128") else 1)
     assert all("/block_0/ssm/" in ln for ln in calls)
     # the pool's leaf is aliased input to output in every program
     assert compiled.memory_analysis().alias_size_in_bytes >= state.size * 4
