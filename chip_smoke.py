@@ -353,7 +353,7 @@ def phase_resnet(devs, seed):
     from horovod_tpu import models
     from horovod_tpu.models import make_cnn_train_step
     from horovod_tpu.models.train import init_cnn_state
-    from horovod_tpu.ops.fusion import combiner_override_options
+    from horovod_tpu.ops.fusion import step_compiler_options
 
     model = models.ResNet101(num_classes=1000)
     tx = hvd.DistributedOptimizer(optax.sgd(0.1, momentum=0.9))
@@ -372,7 +372,8 @@ def phase_resnet(devs, seed):
     say("resnet", f"ResNet-101 {n_params / 1e6:.1f} M params, batch "
                   f"{gb} @ {RESNET_IMAGE}^2 bf16, init "
                   f"{time.time() - t0:.1f} s; step compiled with "
-                  f"compiler_options={combiner_override_options()}")
+                  f"compiler_options="
+                  f"{step_compiler_options(hvd.mesh(), 'data')}")
     rng = jax.random.PRNGKey(seed + 1)
     state = [state]
 
@@ -381,10 +382,10 @@ def phase_resnet(devs, seed):
         return loss
 
     run_steps("resnet", RESNET_STEPS, one_step)
-    say("resnet", "the combiner override "
-                  "(ops/fusion.combiner_override_options) was "
+    say("resnet", "the step's options "
+                  "(ops/fusion.step_compiler_options) were "
                   "accepted by the TPU compiler: the step compiled "
-                  "with it")
+                  "with them")
     say("resnet", mem(devs[0]))
     del state, xb, yb
     gc.collect()
@@ -596,6 +597,8 @@ def count_all_reduce(text):
 
 
 def phase_dp(devs, seed):
+    import re
+
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -603,8 +606,8 @@ def phase_dp(devs, seed):
 
     import horovod_tpu as hvd
     from horovod_tpu.models.transformer import lm_loss
-    from horovod_tpu.ops.fusion import (combiner_override_options,
-                                        plan_buckets)
+    from horovod_tpu.ops.fusion import (plan_buckets,
+                                        step_compiler_options)
     from horovod_tpu.parallel.mesh import make_mesh, shard_batch
     from horovod_tpu.parallel.tensor import unbox
 
@@ -670,10 +673,14 @@ def phase_dp(devs, seed):
     five = run_steps("dp/five-lines", LM_STEPS, one_step)
     _, text = compiled_text(step.__wrapped__, None, *state, batch)
     n_ar = count_all_reduce(text)
+    n_async = len(re.findall(r"async-collective-start[.\d]* = ", text))
     say("dp/five-lines",
-        f"compiled step holds {n_ar} all-reduce op(s); ops/fusion "
-        f"planned {planned} gradient bucket(s) (+1 for the loss "
-        f"pmean); compiled with {combiner_override_options()} — "
+        f"compiled step holds {n_ar} all-reduce op(s), {n_async} of "
+        f"them asynchronous pairs; plan_buckets fuses the leaves "
+        f"into {planned} bucket(s) under the threshold, and on this "
+        f"mesh a leaf of ALONE_BYTES or more is reduced alone "
+        f"(+1 for the loss pmean); compiled "
+        f"with {step_compiler_options(hvd.mesh(), 'data')} — "
         f"accepted by the TPU compiler")
     check(n_ar >= 1, "the shard_map step holds no all-reduce")
     say("dp", mem(devs[0]))

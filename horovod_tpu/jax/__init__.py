@@ -28,8 +28,8 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.ops import eager
-from horovod_tpu.ops.fusion import (combiner_override_options,
-                                    fused_allreduce_tree)
+from horovod_tpu.ops.fusion import (exchange_for, fused_allreduce_tree,
+                                    step_compiler_options)
 from horovod_tpu.ops.sparse import IndexedSlices
 from horovod_tpu.runtime import state as _state
 from horovod_tpu.runtime.config import config
@@ -374,9 +374,24 @@ def make_train_step(loss_fn: Callable, tx: optax.GradientTransformation,
     loss_fn(params, batch) -> scalar loss over the *per-device* microbatch.
     Returns step(params, opt_state, batch) -> (params, opt_state, loss)
     where `batch` is sharded over the data axis and params/opt_state are
-    replicated. Backprop and the fused psum overlap under XLA's async
-    collectives — the latency hiding the reference builds by hand with
-    its background thread + fusion buffer.
+    replicated.
+
+    The gradient exchange is `ops/fusion.py`'s bucketed `psum`, and
+    what is compiled follows the mesh (`ops/fusion.overlaps`). On a
+    TPU mesh whose data axis is larger than 1 the step is compiled with
+    the keys that make its all-reduces ASYNCHRONOUS collectives
+    (`step_compiler_options`) and its body is traced under
+    `exchange_for(mesh, axis)`, where a leaf of `ALONE_BYTES` or more
+    is reduced alone in its own shape and the small leaves' buckets as
+    [rows, 128]: the compiler then carries a matrix's all-reduce
+    through the weight-gradient matmuls (which it moves behind the
+    backward pass for the purpose) and what is ready last - the
+    embedding, the small leaves' bucket - through the optimizer's
+    update: the latency hiding the reference builds by hand with its
+    background thread + fusion buffer (measured on four v5e chips:
+    PERF.md §5, `gpt2-medium.train-dp4`). On any other platform, and
+    on one chip, the options are the combiner pin alone and the
+    program is what it was.
     """
     st = _state.check_initialized()
     mesh = mesh or st.mesh
@@ -393,13 +408,14 @@ def make_train_step(loss_fn: Callable, tx: optax.GradientTransformation,
             "to DistributedOptimizer(...) instead of the step factory")
 
     def step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        if not already_distributed:
-            grads = allreduce_gradients(
-                grads, axis_name=axis, threshold=fusion_threshold,
-                reduce_dtype=reduce_dtype)
-        loss = lax.pmean(loss, axis)
-        updates, new_opt_state = tx.update(grads, opt_state, params)
+        with exchange_for(mesh, axis):
+            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            if not already_distributed:
+                grads = allreduce_gradients(
+                    grads, axis_name=axis, threshold=fusion_threshold,
+                    reduce_dtype=reduce_dtype)
+            loss = lax.pmean(loss, axis)
+            updates, new_opt_state = tx.update(grads, opt_state, params)
         new_params = optax.apply_updates(params, updates)
         return new_params, new_opt_state, loss
 
@@ -413,7 +429,7 @@ def make_train_step(loss_fn: Callable, tx: optax.GradientTransformation,
     from horovod_tpu.utils.timeline import step_bracket
     jitted = jax.jit(
         sharded, donate_argnums=donate_argnums,
-        compiler_options=combiner_override_options() or None)
+        compiler_options=step_compiler_options(mesh, axis) or None)
 
     def placed(params, opt_state, batch):
         params, opt_state = commit_step_state(

@@ -22,8 +22,8 @@ import optax
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu.ops.fusion import (combiner_override_options,
-                                    fused_allreduce_tree)
+from horovod_tpu.ops.fusion import (exchange_for, fused_allreduce_tree,
+                                    step_compiler_options)
 from horovod_tpu.runtime import state as _state
 
 
@@ -89,14 +89,16 @@ def make_cnn_train_step(model, tx: optax.GradientTransformation,
         (loss, new_stats), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state["params"], state["batch_stats"],
                                    images, labels, rng)
-        if not tx_distributed:
-            grads = fused_allreduce_tree(
-                grads, axis_name=axis, average=True,
-                threshold=fusion_threshold, reduce_dtype=reduce_dtype)
-        loss = lax.pmean(loss, axis)
-        new_stats = jax.tree.map(lambda x: lax.pmean(x, axis), new_stats)
-        updates, new_opt = tx.update(grads, state["opt_state"],
-                                     state["params"])
+        with exchange_for(mesh, axis):
+            if not tx_distributed:
+                grads = fused_allreduce_tree(
+                    grads, axis_name=axis, average=True,
+                    threshold=fusion_threshold, reduce_dtype=reduce_dtype)
+            loss = lax.pmean(loss, axis)
+            new_stats = jax.tree.map(lambda x: lax.pmean(x, axis),
+                                     new_stats)
+            updates, new_opt = tx.update(grads, state["opt_state"],
+                                         state["params"])
         new_params = optax.apply_updates(state["params"], updates)
         return ({"params": new_params, "batch_stats": new_stats,
                  "opt_state": new_opt}, loss)
@@ -112,7 +114,7 @@ def make_cnn_train_step(model, tx: optax.GradientTransformation,
     from horovod_tpu.utils.timeline import step_bracket
     compiled = jax.jit(
         sharded, donate_argnums=donate_argnums,
-        compiler_options=combiner_override_options() or None)
+        compiler_options=step_compiler_options(mesh, axis) or None)
 
     def placed(state, batch, rng):
         return compiled(commit_step_state(mesh, state), batch, rng)

@@ -179,6 +179,30 @@ class TestOverlapStructure:
         txt = self._stablehlo(1 << 26)
         assert len(re.findall(r"stablehlo\.all_reduce", txt)) == 2
 
+    @staticmethod
+    def _mnist_step_text(mesh=None):
+        """Post-optimization HLO of `make_cnn_train_step` on
+        MnistConvNet (4 layers x (kernel, bias) = 8 gradient leaves),
+        one bucket a leaf, compiled as the factory compiles it."""
+        import jax
+        import jax.numpy as jnp
+        import optax
+
+        from horovod_tpu import models
+        from horovod_tpu.models import make_cnn_train_step
+        from horovod_tpu.models.train import init_cnn_state
+
+        model = models.MnistConvNet(dtype=jnp.float32)
+        tx = optax.sgd(0.1)
+        state = init_cnn_state(model, tx, jax.random.PRNGKey(0),
+                               jnp.zeros((1, 28, 28, 1), jnp.float32))
+        step = make_cnn_train_step(model, tx, mesh=mesh,
+                                   fusion_threshold=1)
+        return step.__wrapped__.lower(
+            state, (jnp.zeros((8, 28, 28, 1)),
+                    jnp.zeros((8,), jnp.int32)),
+            jax.random.PRNGKey(1)).compile().as_text()
+
     def test_post_optimization_bucket_structure(self, hvd):
         """Close the overlap-model loophole: the
         backend AllReduceCombiner re-merges our independent bucket
@@ -190,35 +214,18 @@ class TestOverlapStructure:
         the pre-pass IR."""
         import re
 
-        import jax
-        import jax.numpy as jnp
-        import optax
-
-        from horovod_tpu import models
-        from horovod_tpu.models import make_cnn_train_step
-        from horovod_tpu.models.train import init_cnn_state
         from horovod_tpu.ops.fusion import combiner_override_options
 
-        n_grad_leaves = 8  # MnistConvNet: 4 layers x (kernel, bias)
-        model = models.MnistConvNet(dtype=jnp.float32)
-        tx = optax.sgd(0.1)
-        state = init_cnn_state(model, tx, jax.random.PRNGKey(0),
-                               jnp.zeros((1, 28, 28, 1), jnp.float32))
-        step = make_cnn_train_step(model, tx, fusion_threshold=1)
-        x = jnp.zeros((8, 28, 28, 1))
-        y = jnp.zeros((8,), jnp.int32)
-        lowered = step.__wrapped__.lower(
-            state, (x, y), jax.random.PRNGKey(1))
+        n_grad_leaves = 8
 
-        def count_all_reduces(compiled):
-            txt = compiled.as_text()  # post-optimization HLO
-            return len(re.findall(r"= \S+ all-reduce\(", txt)), txt
+        def count_all_reduces(txt):
+            return len(re.findall(r"= \S+ all-reduce\(", txt))
 
         # The factory's jit carries the pin (HOROVOD_XLA_COMBINER
         # defaults to "pin"): 8 per-leaf buckets + the loss pmean
         # survive every backend pass as INDEPENDENT all-reduces.
-        n_pinned, txt = count_all_reduces(lowered.compile())
-        assert n_pinned == n_grad_leaves + 1, txt[:2000]
+        txt = self._mnist_step_text()
+        assert count_all_reduces(txt) == n_grad_leaves + 1, txt[:2000]
         # Independence in the optimized module: no all-reduce operand
         # is another all-reduce's result.
         results = {m.lstrip("%") for m in
@@ -241,13 +248,130 @@ class TestOverlapStructure:
         try:
             hvd_config.xla_combiner = "xla"
             assert combiner_override_options() == {}
-            unpinned = make_cnn_train_step(model, tx,
-                                           fusion_threshold=1)
-            n_merged, _ = count_all_reduces(
-                unpinned.__wrapped__.lower(
-                    state, (x, y), jax.random.PRNGKey(1)).compile())
+            n_merged = count_all_reduces(self._mnist_step_text())
         finally:
             hvd_config.xla_combiner = old
         assert n_merged < n_grad_leaves + 1, (
             f"backend no longer combines ({n_merged}); "
             f"revisit combiner_override_options")
+
+    @pytest.mark.parametrize("combiner", ["pin", "xla"])
+    @pytest.mark.parametrize("data", [8, 1])
+    def test_step_options_off_a_tpu_are_the_combiner_pin(
+            self, hvd, data, combiner):
+        """The step factories' `compiler_options` follow the mesh
+        (`step_compiler_options`): on the CPU backend, and at a data
+        axis of 1 on any backend, they are EXACTLY the combiner pin
+        (`{}` under HOROVOD_XLA_COMBINER=xla) - no TPU key reaches a
+        compiler that would refuse it - and the post-optimization
+        bucket structure is what it was: one independent all-reduce a
+        bucket and the loss's under the pin (the CPU compiler keeps
+        them over a single device too), fewer with XLA's combiner
+        left on."""
+        import re
+
+        import jax
+
+        from horovod_tpu.ops.fusion import (combiner_override_options,
+                                            step_compiler_options)
+        from horovod_tpu.parallel.mesh import make_mesh
+        from horovod_tpu.runtime.config import config as hvd_config
+
+        mesh = make_mesh(devices=jax.devices()[:data], data=data)
+        old = hvd_config.xla_combiner
+        try:
+            hvd_config.xla_combiner = combiner
+            pin = combiner_override_options()
+            assert pin == ({} if combiner == "xla" else {
+                "xla_disable_hlo_passes":
+                    "all-reduce-combiner,cpu-all-reduce-combiner"})
+            assert step_compiler_options(mesh, "data") == pin
+            text = self._mnist_step_text(mesh)
+        finally:
+            hvd_config.xla_combiner = old
+        # (a merged all-reduce's result is a tuple: match the opcode)
+        n = len(re.findall(r" all-reduce\(", text))
+        if combiner == "pin":
+            assert n == 8 + 1, text[:2000]   # 8 leaves + the loss
+        else:
+            assert 0 < n < 8 + 1, text[:2000]
+
+
+def _overlapped_exchange(hvd, monkeypatch, sizes, threshold):
+    """The all-reduced shapes and the results of `fused_allreduce_tree`
+    over float32 leaves of `sizes` numbers, traced as a step factory
+    traces it for a mesh that `overlaps` (here the CPU's, taken for
+    one; `ALONE_BYTES` cut to 4 KiB = 1024 numbers so the leaves stay
+    small)."""
+    import re
+
+    from horovod_tpu.ops import fusion
+
+    monkeypatch.setattr(fusion, "ALONE_BYTES", 4096)
+    monkeypatch.setattr(fusion, "overlaps", lambda mesh, axis: True)
+    rng = np.random.RandomState(3)
+    tree = [rng.randn(hvd.size(), *np.atleast_1d(s)).astype(np.float32)
+            for s in sizes]
+
+    def kernel(t):
+        with fusion.exchange_for(hvd.mesh(), "data"):
+            return fused_allreduce_tree(
+                [x[0] for x in t], axis_name="data", average=False,
+                threshold=threshold)
+
+    fn = jax.jit(jax.shard_map(kernel, mesh=hvd.mesh(),
+                               in_specs=P("data"), out_specs=P()))
+    shapes = re.findall(r"\}\) : \(tensor<([\dx]+)xf32>\) -> tensor<",
+                        fn.lower(tree).as_text())
+    for got, x in zip(fn(tree), tree):
+        np.testing.assert_allclose(np.asarray(got), x.sum(axis=0),
+                                   rtol=1e-5)
+    return sorted(shapes)
+
+
+@pytest.mark.parametrize("sizes, threshold, want", [
+    # a large leaf goes alone, in its own shape; the small ones on both
+    # sides of it fuse into one [rows, 128]
+    ([256, (8, 256), 256, (64, 128), 256], 1 << 26,
+     ["64x128", "6x128", "8x256"]),
+    # exactly ALONE_BYTES is large, just under it is not
+    ([1024, 1023, 1023], 1 << 26, ["1024", "16x128"]),
+    # the threshold still closes the small leaves' bucket
+    ([512, (16, 128), 512, 512], 4096, ["16x128", "8x128", "512"]),
+    # and 0 still means one collective a tensor
+    ([256, (16, 128), 256], 0, ["16x128", "256", "256"]),
+], ids=["around", "edge", "threshold", "disabled"])
+def test_overlapped_exchange_reduces_a_large_leaf_alone(
+        hvd, monkeypatch, sizes, threshold, want):
+    """Where a step's exchange `overlaps` (a TPU mesh, data axis > 1)
+    a leaf of `ALONE_BYTES` or more is no part of a bucket: fusion buys
+    back a collective's latency, which such a leaf outweighs, and the
+    flat bucket costs a matrix a relayout both ways and the compiler's
+    overlap (ops/fusion.py). `plan_buckets` under the threshold still
+    plans the rest."""
+    assert _overlapped_exchange(hvd, monkeypatch, sizes,
+                                threshold) == sorted(want)
+
+
+@pytest.mark.parametrize("sizes", [(128, 256), (100, 27), (384,)],
+                         ids=["whole-rows", "padded", "single"])
+def test_overlapped_bucket_is_reduced_as_rows_of_128(hvd, monkeypatch,
+                                                     sizes):
+    """There a fused bucket crosses the wire as [rows, 128] (what lets
+    a TPU run its all-reduce asynchronously), zero-padded where its
+    size is no multiple of 128, and the leaves come back as they went
+    in; a single leaf keeps its shape. Outside such a step the bucket
+    is the flat array it was."""
+    got = _overlapped_exchange(hvd, monkeypatch, sizes, 1 << 20)
+    rows = -(-sum(sizes) // 128)
+    assert got == ([f"{rows}x128"] if len(sizes) > 1 else ["384"])
+
+    def flat(t):
+        return fused_allreduce_tree([x[0] for x in t], axis_name="data",
+                                    average=False, threshold=1 << 20)
+
+    tree = [np.zeros((hvd.size(), s), np.float32) for s in sizes]
+    text = jax.jit(jax.shard_map(
+        flat, mesh=hvd.mesh(), in_specs=P("data"),
+        out_specs=P())).lower(tree).as_text()
+    assert f"tensor<{sum(sizes)}xf32>) -> tensor<" in text

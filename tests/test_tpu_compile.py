@@ -859,3 +859,77 @@ def test_flash_under_a_four_chip_data_mesh(topo, chip_config,
         text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
             q, q, q).compile().as_text()
     assert text.count("tpu_custom_call") == 3
+
+
+def _scheduled_entry(text):
+    """[(name, opcode)] of the scheduled module's entry computation,
+    in the order the core runs it."""
+    entry = text[text.index("\nENTRY "):].splitlines()
+    found = (re.match(r"\s+(?:ROOT )?%?([\w.\-]+) = .*? ([\w\-]+)\(", ln)
+             for ln in entry)
+    return [m.groups() for m in found if m]
+
+
+def test_data_parallel_step_reduces_its_gradients_asynchronously(
+        hvd, topo, chip_config):
+    """`make_train_step` over all four chips, with the options the
+    factory itself chooses: a matrix's all-reduce (reduced alone, in
+    its own shape) and the small leaves' bucket (a [rows, 128] view)
+    are `async-collective-start` / `-done` pairs in the scheduled
+    module with compute between a start and its done - not the
+    synchronous `all-reduce` instructions the same step compiled to
+    before PR 44, when only the loss's scalar may stay one. Over ONE
+    chip the factory chooses today's options: the combiner pin."""
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from horovod_tpu.ops.fusion import (combiner_override_options,
+                                        step_compiler_options)
+    from horovod_tpu.parallel.mesh import make_mesh
+
+    width, depth = 1024, 4
+
+    def loss_fn(p, x):
+        h = x
+        for layer in p:
+            h = jnp.tanh(h @ layer["w"].astype(jnp.bfloat16)
+                         + layer["b"].astype(jnp.bfloat16))
+        return (h.astype(jnp.float32) ** 2).mean()
+
+    tx = hvd.DistributedOptimizer(optax.adamw(3e-4))
+    params = [{"w": jax.ShapeDtypeStruct((width, width), jnp.float32),
+               "b": jax.ShapeDtypeStruct((width,), jnp.float32)}
+              for _ in range(depth)]
+
+    one = make_mesh(devices=topo.devices[:1], data=1)
+    assert (step_compiler_options(one, "data")
+            == combiner_override_options())
+
+    mesh = make_mesh(devices=topo.devices, data=4)
+    assert (step_compiler_options(mesh, "data").items()
+            > combiner_override_options().items())
+    rep = NamedSharding(mesh, P())
+
+    def place(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=rep), tree)
+
+    x = jax.ShapeDtypeStruct((2048, width), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("data")))
+    low = hvd.make_train_step(loss_fn, tx, mesh=mesh).__wrapped__.lower(
+        place(params), place(jax.eval_shape(tx.init, params)), x)
+    ins = _scheduled_entry(low.compile().as_text())
+    names = [n for n, _ in ins]
+    starts = [k for k, n in enumerate(names)
+              if n.startswith("async-collective-start")]
+    # every matrix at least (the biases' bucket is one more here); the
+    # names are the TPU compiler's own, read on jax 0.9.0 / libtpu
+    # 0.0.34 - a version that renames them fails here, not on a chip
+    assert len(starts) >= depth, names
+    for k in starts:
+        done = names.index(names[k].replace("start", "done"))
+        between = [op for _, op in ins[k + 1:done]
+                   if op in ("fusion", "convolution", "custom-call")]
+        assert done > k and between, (names[k], ins[k:done + 1])
+    sync = [n for n, op in ins if op == "all-reduce"]
+    assert len(sync) <= 1, sync        # the loss's scalar mean
