@@ -15,9 +15,9 @@ and tokens made from `--seed`:
              plain XLA attention
     resnet   3 steps of ResNet-101, batch 128 @ 224^2 bf16, through
              hvd.DistributedOptimizer + make_cnn_train_step
-    server   ServingEngine(num_slots=8, warmup=True): fixed pool,
-             paged pool, paged pool with the Pallas kernels; 12
-             requests each, streams against `models.generate`
+    server   ServingEngine(num_slots=8, warmup=True): fixed pool and
+             paged pool; 12 requests each, streams against
+             `models.generate`
 
   --chips 4 (one host, four chips; no one-chip phase runs)
     dp       the LM at one global batch of 8 x 2048 by both
@@ -546,9 +546,6 @@ def phase_server(devs, seed):
     runs = (
         ("server/fixed", model, {}),
         ("server/paged", model, dict(paged=True)),
-        ("server/paged-pallas",
-         model.clone(decode_prefix_impl="pallas"),
-         dict(paged=True, paged_kernel="pallas")),
     )
     for label, m, kw in runs:
         got, eng = serve(label, m, params, prompts, **kw)
@@ -569,15 +566,6 @@ def phase_server(devs, seed):
                       f"{label}: the default tick took "
                       f"{snap['decode_attn_plan']} with {n_cc} "
                       f"custom calls")
-            if kw.get("paged_kernel") == "pallas":
-                n_cc = tick_text(eng.pool).count("tpu_custom_call")
-                say(label, f"compiled tick holds {n_cc} "
-                           f"tpu_custom_call(s) (paged decode "
-                           f"attention: 1 per layer x "
-                           f"{LM_KW['num_layers']})")
-                check(n_cc >= LM_KW["num_layers"],
-                      f"{label}: the Pallas-mode tick holds {n_cc} "
-                      f"custom calls — it took the lax path")
         finally:
             eng.shutdown()
         say(label, mem(devs[0]))

@@ -1368,8 +1368,7 @@ def decode_attention_plan(model: TransformerLM, lanes: int = 1,
     the ambient mesh: the `ops.flash_attention.DecodePlan` (ragged
     kernel or lax, and why) for ``lanes`` slots - over the linear
     cache of ``max_len``, or over the ring of a kind with a window.
-    `decode_attention_plans` answers every kind; the engine logs them
-    at warm-up and `metrics_snapshot()` carries them."""
+    `kernel_plans` answers every kind."""
     from horovod_tpu.ops import flash_attention
     from horovod_tpu.parallel.tensor import _mesh_is_trivial
     if kind == "mla" or (kind is None and not model.softmax_kinds
@@ -1397,53 +1396,53 @@ def decode_attention_plan(model: TransformerLM, lanes: int = 1,
         trivial_mesh=_mesh_is_trivial(), ring=ring)
 
 
-def decode_attention_plans(model: TransformerLM, lanes: int = 1) -> dict:
-    """{kind: `decode_attention_plan`} over the model's softmax kinds
-    and its latent kind (one entry for a model without any: the plan
-    that says so)."""
+def kernel_plans(model: TransformerLM, lanes: int = 1,
+                 chunk: int = 1) -> dict:
+    """Which program steps each kind of ``model``'s layers over
+    ``lanes`` slots and why, as the layers decide it under the ambient
+    mesh when a tick or a prompt chunk of ``chunk`` tokens is traced.
+    Every family is a dict of plan records (``path``, ``why``,
+    ``describe()``) in the model's order:
+
+    * ``"decode_attn"``: {kind: `decode_attention_plan`} over the
+      softmax kinds and the latent kind (one entry, the plan that says
+      so, for a model without any);
+    * ``"moe_product"``: {"tick" | "prefill": the
+      `ops.grouped_matmul.GroupedPlan` the dropless expert layers
+      multiply their (token, expert) pairs with}; {} without such a
+      layer;
+    * ``"state_step"``: {kind ("kda", "ssm"): the
+      `ops.kda_step.StateStepPlan` of a recurrent layer's S = 1 step,
+      which the slot tick's freeze obeys}; {} without such a layer.
+
+    The pools enter their mesh and call this, the engine logs it at
+    warm-up and `metrics_snapshot()` carries it, so a run that fell
+    back to a lax path says so."""
+    from horovod_tpu.parallel import linear_attention, state_space
+    from horovod_tpu.parallel.expert import product_plan
     kinds = model.softmax_kinds + (
         ("mla",) if model.has_latent_cache else ())
-    return {kind: decode_attention_plan(model, lanes, kind)
-            for kind in kinds or ("attn",)}
-
-
-def moe_product_plans(model: TransformerLM, lanes: int = 1,
-                      chunk: int = 1) -> dict:
-    """The way ``model``'s dropless expert layers multiply their
-    (token, expert) pairs, as `parallel.expert.grouped_experts`
-    decides it under the ambient mesh: {"tick": the
-    `ops.grouped_matmul.GroupedPlan` (kernel or `lax.ragged_dot`, and
-    why) of a tick over ``lanes`` slots, "prefill": that of a prompt
-    chunk of ``chunk`` tokens}; {} for a model without such a layer.
-    The engine logs them at warm-up and `metrics_snapshot()` carries
-    them, so a run that fell back to the lax product says so."""
-    from horovod_tpu.parallel.expert import product_plan
-    if model.moe_every <= 0 or model.moe_impl != "dropless":
-        return {}
-    d = model.hidden_size or model.num_heads * model.head_dim
-    held = (model.moe_held or (0, model.num_experts))[1]
-    w_gate = jax.ShapeDtypeStruct(
-        (held, d, model.moe_hidden or model.mlp_ratio * d),
-        jnp.dtype(model.dtype or jnp.float32))
-    routed = model.num_experts + model.moe_zero_experts
-    return {name: product_plan(tokens, model.moe_k, routed, w_gate)
+    products = {}
+    if model.moe_every > 0 and model.moe_impl == "dropless":
+        d = model.hidden_size or model.num_heads * model.head_dim
+        held = (model.moe_held or (0, model.num_experts))[1]
+        w_gate = jax.ShapeDtypeStruct(
+            (held, d, model.moe_hidden or model.mlp_ratio * d),
+            jnp.dtype(model.dtype or jnp.float32))
+        routed = model.num_experts + model.moe_zero_experts
+        products = {
+            name: product_plan(tokens, model.moe_k, routed, w_gate)
             for name, tokens in (("tick", lanes), ("prefill", chunk))}
-
-
-def state_step_plans(model: TransformerLM, lanes: int = 1) -> dict:
-    """The way ``model``'s recurrent layers step their state in an
-    S = 1 tick over ``lanes`` slots, as the layer decides it under the
-    ambient mesh: {kind ("kda", "ssm"): the
-    `ops.kda_step.StateStepPlan` (the in-place kernel or the step as
-    XLA compiles it, and why)}; {} for a model without such a layer.
-    The slot tick's freeze obeys the same plan, the engine logs it at
-    warm-up and `metrics_snapshot()` carries it."""
-    from horovod_tpu.parallel import linear_attention, state_space
-    rules = {
+    steps = {
         "kda": lambda: linear_attention.state_step_plan(
             lanes, model.num_heads, model.head_dim),
         "ssm": lambda: state_space.state_step_plan(lanes, model.ssm)}
-    return {kind: rules[kind]() for kind in model.recurrent_kinds}
+    return {
+        "decode_attn": {kind: decode_attention_plan(model, lanes, kind)
+                        for kind in kinds or ("attn",)},
+        "moe_product": products,
+        "state_step": {kind: steps[kind]()
+                       for kind in model.recurrent_kinds}}
 
 
 def init_slot_cache(model: TransformerLM, num_slots: int):
@@ -1823,7 +1822,7 @@ def slot_decode_tick(dec_model, params, cache, toks, temps, top_ps,
     * ``live`` [S] bool — host-known active lanes. Non-live lanes
       (FREE or mid-prefill slots) still ride the vmapped step but
       their cache fill indices, and a recurrent layer's state, are
-      FROZEN (`_freeze_cache_indices`; where `state_step_plans` says
+      FROZEN (`_freeze_cache_indices`; where `kernel_plans` says
       "kernel" the layer is told which lanes advance and its in-place
       step keeps the state itself), so an idle lane never creeps
       its index — and with it the shared prefix-attention trip count
@@ -1849,8 +1848,9 @@ def slot_decode_tick(dec_model, params, cache, toks, temps, top_ps,
 
     # the recurrent kinds whose step keeps its state itself for a lane
     # that does not advance (the in-place kernel): never selected after
-    kept = tuple(kind for kind, plan in state_step_plans(
-        dec_model, toks.shape[0]).items() if plan.path == "kernel")
+    kept = tuple(kind for kind, plan in kernel_plans(
+        dec_model, toks.shape[0])["state_step"].items()
+        if plan.path == "kernel")
 
     def one(sub, tok, rng, lv, dn):
         told = {"advance": lv & ~dn} if kept else {}
@@ -2033,7 +2033,7 @@ def _paged_view(spec: PagedCacheSpec, pools, table, fill):
 
 
 # Cache-leaf name -> the "paged" collection name its pool rides under
-# (read by `ParallelSelfAttention._paged_decode_attention`).
+# (read by `ParallelSelfAttention._paged_attention`).
 _POOL_NAMES = {"cached_key": "key_pool", "cached_value": "value_pool",
                "cached_key_scale": "key_scale_pool",
                "cached_value_scale": "value_scale_pool"}

@@ -840,8 +840,8 @@ class ParallelSelfAttention(nn.Module):
             q, cached_k.value, cached_v.value, length,
             block_k=plan.block_k)
 
-    def _paged_decode_attention(self, q, k, v, cached_k, cached_v,
-                                scale_k, scale_v, index, i, S, W):
+    def _paged_attention(self, q, k, v, cached_k, cached_v,
+                         scale_k, scale_v, index, i, S, W):
         """Decode/prefill attention against a PAGED cache: the block
         pools + this lane's table/fill arrive via the read-only
         "paged" collection (`models.transformer._paged_collection`),
@@ -850,11 +850,8 @@ class ParallelSelfAttention(nn.Module):
         afterwards), and the attention walks only the FILLED blocks
         (`ops.paged_attention`). RoPE rotates at the TRUE fill (the
         staging index is always 0). The walk at
-        ``decode_prefix_block`` granularity is bitwise the legacy
-        gathered-view path; ``decode_prefix_impl="pallas"`` swaps in
-        the fused S=1 kernel under the same gating the linear cache
-        uses (trivial mesh, un-quantized), falling back to the walk
-        otherwise."""
+        ``decode_prefix_block`` granularity is bitwise the
+        gathered-view path."""
         k_pool = self.get_variable("paged", "key_pool")
         v_pool = self.get_variable("paged", "value_pool")
         ks_pool = (self.get_variable("paged", "key_scale_pool")
@@ -891,14 +888,7 @@ class ParallelSelfAttention(nn.Module):
                 f"({blk}) to be a multiple of the KV block size "
                 f"({bs}) and to divide max_len ({span})")
         from horovod_tpu.ops.paged_attention import (
-            paged_decode_attention, paged_prefix_attention)
-        if (self.decode_prefix_impl == "pallas" and scale_k is None
-                and q.ndim == 4 and S == 1 and _mesh_is_trivial()):
-            # Same gating as the linear flash-decode kernel: a bare
-            # pallas_call is opaque to GSPMD, and int8 KV keeps the
-            # walk's per-block dequant.
-            return paged_decode_attention(q, k_ins, v_ins, k_pool,
-                                          v_pool, table, fill)
+            paged_prefix_attention)
         reps = self.num_heads // (self.num_kv_heads or self.num_heads)
         return paged_prefix_attention(
             q, k_ins, v_ins, k_pool, v_pool, table, fill,
@@ -943,7 +933,7 @@ class ParallelSelfAttention(nn.Module):
             # collection carries — attention walks the pools through
             # the lane's block table, touching only filled blocks,
             # instead of reading a gathered [max_len] view.
-            return self._paged_decode_attention(
+            return self._paged_attention(
                 q, k, v, cached_k, cached_v, scale_k, scale_v,
                 index, i, S, W)
         # Rotate at the ABSOLUTE position; keys enter the cache

@@ -187,13 +187,6 @@ register_knob(
     "disables matching/publishing; blocks then free eagerly), "
     "docs/serving.md")
 register_knob(
-    "HVD_PAGED_KERNEL", "str", "auto", "runtime/config.py",
-    "Serving: paged-attention dispatch — 'auto'/'lax' walk only the "
-    "FILLED blocks of each lane's table (bitwise-equal to the "
-    "legacy gather), 'pallas' adds the fused Pallas decode kernel, "
-    "'off' keeps the full-span gather (the fallback oracle), "
-    "docs/serving.md 'Decode fast path'")
-register_knob(
     "HVD_SPEC_K", "int", str(DEFAULT_SPEC_K), "runtime/config.py",
     "Serving: speculative-decode proposals per round when "
     "ServingEngine(spec_draft=...) doesn't pass spec_k (1..k tokens "
@@ -510,10 +503,8 @@ class Config:
     kv_block_size: int = DEFAULT_KV_BLOCK_SIZE
     kv_blocks: int = 0
     prefix_cache: bool = True
-    # Decode fast path (docs/serving.md): paged-attention dispatch
-    # mode, draft-verify depth, and the construction-time weight
-    # quantization default ("" = off).
-    paged_kernel: str = "auto"
+    # Decode fast path (docs/serving.md): draft-verify depth, and the
+    # construction-time weight quantization default ("" = off).
     spec_k: int = DEFAULT_SPEC_K
     weight_quant: str = ""
     # Sharded serving (docs/serving.md "Sharded serving"): the default
@@ -571,7 +562,6 @@ class Config:
                                       DEFAULT_KV_BLOCK_SIZE)
         self.kv_blocks = _env_int("HVD_KV_BLOCKS", 0)
         self.prefix_cache = _env_int("HVD_PREFIX_CACHE", 1) != 0
-        self.paged_kernel = env_str("HVD_PAGED_KERNEL", "auto")
         self.spec_k = _env_int("HVD_SPEC_K", DEFAULT_SPEC_K)
         self.weight_quant = env_str("HVD_WEIGHT_QUANT")
         self.serve_mesh = env_str("HVD_SERVE_MESH")

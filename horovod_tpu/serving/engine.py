@@ -81,6 +81,12 @@ _IDLE_WAIT_S = 0.05
 # engines can coexist; each reports its own dispatch generation).
 _ENGINE_IDS = itertools.count()
 
+# What the warm-up log line calls each family of the pool's
+# `kernel_plans` (operators and PERF.md quote the line).
+_PLAN_WORDS = {"decode_attn": "decode attention",
+               "moe_product": "expert products",
+               "state_step": "state step"}
+
 
 # What each option does with K/V blocks, and what it would therefore
 # need of a cache that is not K/V rows a step only appends to: a
@@ -363,12 +369,6 @@ class ServingEngine:
         pool at the same num_slots.
     prefix_cache : shared-prefix caching over the paged pool; None
         reads HVD_PREFIX_CACHE (default on). Ignored unless paged.
-    paged_kernel : paged-attention dispatch (docs/serving.md "Decode
-        fast path"): "auto"/"lax" walk only the FILLED blocks of each
-        lane's block table (bitwise the legacy gather), "pallas" adds
-        the fused Pallas decode kernel, "off" keeps the full-span
-        gather (the oracle/fallback). None reads HVD_PAGED_KERNEL.
-        Ignored unless paged.
     spec_draft : (draft_model, draft_params) arming SPECULATIVE
         decoding (docs/serving.md "Decode fast path"): the slot tick
         becomes a batched draft-verify round retiring 1..spec_k+1
@@ -408,7 +408,6 @@ class ServingEngine:
                  kv_block_size: Optional[int] = None,
                  kv_blocks: Optional[int] = None,
                  prefix_cache: Optional[bool] = None,
-                 paged_kernel: Optional[str] = None,
                  spec_draft=None, spec_k: Optional[int] = None,
                  weight_quant: Optional[str] = None,
                  slo=None,
@@ -524,7 +523,7 @@ class ServingEngine:
                 num_blocks=(int(kv_blocks) if kv_blocks
                             and int(kv_blocks) > 0 else None),
                 block_size=kv_block_size, mesh=mesh, eos_id=eos_id,
-                prefix_cache=prefix_cache, kernel=paged_kernel,
+                prefix_cache=prefix_cache,
                 # Evictions are operator-visible cache pressure: the
                 # allocator reports each one straight into this
                 # engine's metrics (and the shared
@@ -539,34 +538,22 @@ class ServingEngine:
         # Warmup runs on the constructor thread BEFORE the dispatch
         # thread exists, so the single-jax-thread contract holds.
         self.warmup_info = None
-        plans = self.pool.decode_attention_plans()
-        self.metrics.observe_decode_attn(plans)
-        said = "; ".join(f"{kind}: {plan.describe()}"
-                         for kind, plan in plans.items())
-        products = self.pool.moe_product_plans(
+        plans = self.pool.kernel_plans(
             self.prefill_chunk_budget if self.prefill_chunk_budget > 0
             else model.max_len)
-        self.metrics.observe_moe_products(products)
-        if products:
-            said += "; expert products: " + "; ".join(
-                f"{name}: {plan.describe()}"
-                for name, plan in products.items())
-        # a recurrent state lives in the fixed pool alone (the paged
-        # pool refuses it)
-        steps = {} if self.paged else self.pool.state_step_plans()
-        self.metrics.observe_state_steps(steps)
-        if steps:
-            said += "; state step: " + "; ".join(
-                f"{kind}: {plan.describe()}"
-                for kind, plan in steps.items())
+        self.metrics.observe_kernel_plans(plans)
+        said = "; ".join(
+            f"{_PLAN_WORDS[family]}: " + "; ".join(
+                f"{key}: {plan.describe()}" for key, plan in of.items())
+            for family, of in plans.items() if of)
         if warmup:
             self.warmup_info = self.pool.warmup(
                 max_chunk=(self.prefill_chunk_budget
                            if self.prefill_chunk_budget > 0 else None))
             self.metrics.observe_warmup(self.warmup_info["seconds"])
             logging.getLogger("horovod_tpu").info(
-                "serving warm-up: %d programs in %.1f s; decode "
-                "attention: %s", self.warmup_info["compiles"],
+                "serving warm-up: %d programs in %.1f s; %s",
+                self.warmup_info["compiles"],
                 self.warmup_info["seconds"], said)
         # Hot-path compiles = pool compiles past this baseline.
         self._compile_baseline = self.pool.compiles

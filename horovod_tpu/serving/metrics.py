@@ -249,23 +249,10 @@ class EngineMetrics:
         self.mesh_devices = 1
         self.mesh_shape = None
         self.warmup_s = None       # startup precompile cost, if run
-        # How the pool's S = 1 ticks attend ("kernel" | "lax" |
-        # "paged") and the plan in words, {kind of softmax layer: ...}
-        # in the model's order; set once by the engine.
-        self.decode_attn_paths = {}
-        self.decode_attn_plans = {}
-        # How the dropless expert layers multiply their (token,
-        # expert) pairs ("kernel" | "lax") and the plan in words,
-        # {"tick" | "prefill": ...}; {} for a model without such a
-        # layer; set once by the engine.
-        self.moe_product_paths = {}
-        self.moe_product_plans = {}
-        # How the recurrent layers' S = 1 ticks step their state
-        # ("kernel" | "lax") and the plan in words, {kind of layer:
-        # ...}; {} for a model without such a layer; set once by the
-        # engine.
-        self.state_step_paths = {}
-        self.state_step_plans = {}
+        # What the pool's `kernel_plans` say, set once by the engine:
+        # each family's paths ("kernel" | "lax" | "paged") and plans
+        # in words, under the snapshot's keys.
+        self.kernel_plans_said = {}
         # Latency series (seconds).
         self.queue_wait_s = Series()
         self.ttft_s = Series()
@@ -288,31 +275,21 @@ class EngineMetrics:
         with self._lock:
             self.warmup_s = seconds
 
-    def observe_decode_attn(self, plans: dict):
-        """{kind of softmax layer: `DecodePlan`}, in the model's
-        order."""
+    def observe_kernel_plans(self, plans: dict):
+        """The pool's `kernel_plans`, said once as the snapshot's plan
+        keys: ``<family>_paths`` and ``<family>_plans`` ({key: path}
+        and {key: the plan in words}) for each family, and before them
+        ``decode_attn_path`` / ``decode_attn_plan``, the first kind's,
+        as before there were kinds."""
+        first = next(iter(plans.get("decode_attn", {}).values()), None)
+        said = {"decode_attn_path": first and first.path,
+                "decode_attn_plan": first and first.describe()}
+        for family, of in plans.items():
+            said[f"{family}_paths"] = {k: p.path for k, p in of.items()}
+            said[f"{family}_plans"] = {k: p.describe()
+                                       for k, p in of.items()}
         with self._lock:
-            self.decode_attn_paths = {k: p.path
-                                      for k, p in plans.items()}
-            self.decode_attn_plans = {k: p.describe()
-                                      for k, p in plans.items()}
-
-    def observe_moe_products(self, plans: dict):
-        """{"tick" | "prefill": `ops.grouped_matmul.GroupedPlan`}."""
-        with self._lock:
-            self.moe_product_paths = {k: p.path
-                                      for k, p in plans.items()}
-            self.moe_product_plans = {k: p.describe()
-                                      for k, p in plans.items()}
-
-    def observe_state_steps(self, plans: dict):
-        """{kind of recurrent layer ("kda", "ssm"):
-        `ops.kda_step.StateStepPlan`}."""
-        with self._lock:
-            self.state_step_paths = {k: p.path
-                                     for k, p in plans.items()}
-            self.state_step_plans = {k: p.describe()
-                                     for k, p in plans.items()}
+            self.kernel_plans_said = said
 
     def count(self, name: str, n: int = 1):
         with self._lock:
@@ -598,17 +575,7 @@ class EngineMetrics:
                 "mesh": self.mesh_shape,
                 "warmup_s": (round(self.warmup_s, 3)
                              if self.warmup_s is not None else None),
-                # the first kind's, as before there were kinds
-                "decode_attn_path": next(
-                    iter(self.decode_attn_paths.values()), None),
-                "decode_attn_plan": next(
-                    iter(self.decode_attn_plans.values()), None),
-                "decode_attn_paths": dict(self.decode_attn_paths),
-                "decode_attn_plans": dict(self.decode_attn_plans),
-                "moe_product_paths": dict(self.moe_product_paths),
-                "moe_product_plans": dict(self.moe_product_plans),
-                "state_step_paths": dict(self.state_step_paths),
-                "state_step_plans": dict(self.state_step_plans),
+                **self.kernel_plans_said,
                 "restarts": self.restarts,
                 "requeued": self.requeued,
                 "faults_injected": self.faults_injected,

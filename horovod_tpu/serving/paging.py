@@ -60,57 +60,43 @@ import jax.numpy as jnp
 
 from horovod_tpu.annotations import hot_path
 from horovod_tpu.models.transformer import (
-    TransformerLM, init_paged_pools, init_slot_cache,
+    TransformerLM, init_paged_pools, init_slot_cache, kernel_plans,
     paged_cache_spec, paged_copy_block, paged_decode_tick,
     paged_prefill_chunk, paged_spec_round, prefill_chunks,
     shard_paged_pools, shard_slot_cache, slot_decode_model,
     slot_prefill_advance, slot_reset,
 )
+from horovod_tpu.ops.flash_attention import DecodePlan
 from horovod_tpu.parallel.mesh import replicate, use
 from horovod_tpu.serving.slots import (
     Admission, TickHandle, _first_token, validate_spec_draft,
 )
 
 
-def _resolve_paged_kernel(mode: Optional[str],
-                          model: TransformerLM,
-                          block_size: int) -> str:
-    """Normalize the paged-attention dispatch mode ("off" | "lax" |
-    "pallas"; docs/serving.md "Decode fast path"). None reads
-    HVD_PAGED_KERNEL. "auto" picks the lax block-table walk — bitwise
-    the legacy gathered-view program, so flipping it on perturbs no
-    pinned stream — falling back to "off" (the full-span gather, the
-    runtime-fallback oracle) when the geometry can't walk: the walk
-    accumulates at ``decode_prefix_block`` granularity, which must be
-    a multiple of the KV block size and divide max_len (the same
-    divisibility `_prefix_attention` requires of the view). Explicit
-    modes raise instead of silently degrading."""
-    if mode is None:
-        from horovod_tpu.runtime.config import config as _cfg
-        mode = _cfg.paged_kernel or "auto"
-    mode = {"0": "off", "1": "lax"}.get(str(mode), str(mode))
-    if mode not in ("auto", "off", "lax", "pallas"):
-        raise ValueError(
-            f"paged kernel mode must be auto|off|lax|pallas "
-            f"(HVD_PAGED_KERNEL), got {mode!r}")
-    if mode == "off":
-        return "off"
-    if mode == "pallas":
-        # The pool aligns its decode model's walk granularity to the
-        # block size (always legal — the spec guarantees block_size
-        # divides max_len), so nothing gates it.
-        return "pallas"
+def _paged_attention_way(model: TransformerLM,
+                         block_size: int) -> Tuple[str, str]:
+    """How a paged pool's programs attend through the block tables
+    (docs/serving.md "Decode fast path"), chosen from the geometry
+    alone: ("lax", why) - the block-table walk over the FILLED blocks,
+    bitwise the gathered-view program - where the walk's granularity
+    ``decode_prefix_block`` is a multiple of the KV block size and
+    divides max_len (the same divisibility `_prefix_attention` requires
+    of the view); ("off", why) - the full-span gather
+    (`models.transformer._paged_view`), the reference the walk is held
+    to - where it is not."""
     blk = model.decode_prefix_block
     wb = min(int(blk), model.max_len) if blk else 0
-    ok = bool(wb) and wb % block_size == 0 and model.max_len % wb == 0
-    if not ok:
-        if mode == "auto":
-            return "off"
-        raise ValueError(
-            f"paged kernel mode {mode!r} needs decode_prefix_block "
-            f"({blk}) to be a multiple of kv_block_size "
-            f"({block_size}) and divide max_len ({model.max_len})")
-    return "lax" if mode == "auto" else mode
+    if not wb:
+        unfit = "decode_prefix_block is off"
+    elif wb % block_size:
+        unfit = (f"decode_prefix_block {blk} is no multiple of the "
+                 f"block size {block_size}")
+    elif model.max_len % wb:
+        unfit = (f"decode_prefix_block {blk} does not divide max_len "
+                 f"{model.max_len}")
+    else:
+        return "lax", f"walks filled blocks, {wb} tokens a step"
+    return "off", f"gathers the lane's span: {unfit}"
 
 
 class BlockPool:
@@ -553,7 +539,6 @@ class PagedSlotPool:
                  eos_id: Optional[int] = None,
                  prefix_cache: Optional[bool] = None,
                  on_evict: Optional[Callable[[], None]] = None,
-                 kernel: Optional[str] = None,
                  spec_draft=None, spec_k: int = 0):
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
@@ -572,18 +557,12 @@ class PagedSlotPool:
         self.spec = paged_cache_spec(model, block_size)
         self.block_size = self.spec.block_size
         # Paged-attention dispatch (docs/serving.md "Decode fast
-        # path"): "lax"/"pallas" walk only the FILLED blocks of each
-        # lane's table (the gathered-view program stays the oracle
-        # and the "off" fallback). "pallas" additionally aligns the
-        # walk granularity to the block size so the fused kernel and
-        # its in-module lax fallback agree bitwise with each other.
-        self.kernel_mode = _resolve_paged_kernel(kernel, model,
-                                                 self.block_size)
+        # path"): "lax" walks only the FILLED blocks of each lane's
+        # table, "off" gathers the whole span (the reference, and the
+        # fallback of a geometry that cannot walk).
+        self.kernel_mode, self._kernel_why = _paged_attention_way(
+            model, self.block_size)
         self._fused = self.kernel_mode != "off"
-        if self.kernel_mode == "pallas":
-            self.dec_model = self.dec_model.clone(
-                decode_prefix_impl="pallas",
-                decode_prefix_block=self.block_size)
         # Speculative decoding: the draft rides a LINEAR slot cache
         # (it is small — the paging win is the target's); prefix
         # caching is disabled in spec mode so ONE chunk schedule
@@ -664,24 +643,22 @@ class PagedSlotPool:
     def spec_on(self) -> bool:
         return self.spec_draft is not None and self.spec_k > 0
 
-    def decode_attention_plans(self) -> dict:
-        """`SlotPool.decode_attention_plans`' twin: this pool's ticks
-        attend through the block tables, never the linear cache (and
-        it takes no model with a second kind of softmax layer)."""
-        from horovod_tpu.ops.flash_attention import DecodePlan
-        return {"attn": DecodePlan(
-            "paged",
-            f"block tables, HVD_PAGED_KERNEL={self.kernel_mode}")}
-
     def _ctx(self):
         return use(self.mesh) if self.mesh is not None \
             else contextlib.nullcontext()
 
-    def moe_product_plans(self, chunk: int = 1) -> dict:
-        """`SlotPool.moe_product_plans`' twin."""
-        from horovod_tpu.models.transformer import moe_product_plans
+    def kernel_plans(self, chunk: int = 1) -> dict:
+        """`SlotPool.kernel_plans`' twin. This pool's programs attend
+        through the block tables, never the linear cache (and it takes
+        no model with a second kind of softmax layer): the one
+        ``"decode_attn"`` plan says which way and why. A recurrent
+        state lives in the fixed pool alone (this pool refuses it)."""
         with self._ctx():
-            return moe_product_plans(self.model, self.num_slots, chunk)
+            plans = kernel_plans(self.model, self.num_slots, chunk)
+        return {**plans,
+                "decode_attn": {
+                    "attn": DecodePlan("paged", self._kernel_why)},
+                "state_step": {}}
 
     def _note_shape(self, key):
         if key not in self._seen_shapes:
@@ -706,7 +683,7 @@ class PagedSlotPool:
             num_blocks=self.num_blocks, block_size=self.block_size,
             mesh=self.mesh, eos_id=self.eos_id,
             prefix_cache=self.blocks.prefix_cache,
-            on_evict=self._on_evict, kernel=self.kernel_mode,
+            on_evict=self._on_evict,
             spec_draft=self.spec_draft, spec_k=self.spec_k)
         fresh._seen_shapes = set(self._seen_shapes)
         fresh.compiles = self.compiles

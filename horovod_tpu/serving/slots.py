@@ -50,12 +50,8 @@ import jax.numpy as jnp
 
 from horovod_tpu.annotations import hot_path
 from horovod_tpu.models.transformer import (
-    TransformerLM, decode_attention_plans,
-    chunk_width, init_slot_cache, moe_product_plans, moe_stat_columns,
-    prefill_chunks,
-    recurrent_leaf,
-    state_step_plans,
-    sample_lanes,
+    TransformerLM, chunk_width, init_slot_cache, kernel_plans,
+    moe_stat_columns, prefill_chunks, recurrent_leaf, sample_lanes,
     shard_slot_cache, slot_decode_model, slot_decode_tick,
     slot_prefill_advance, slot_prefill_chunk, slot_reset,
     slot_spec_round,
@@ -266,32 +262,12 @@ class SlotPool:
         return use(self.mesh) if self.mesh is not None \
             else contextlib.nullcontext()
 
-    def decode_attention_plans(self) -> dict:
-        """{kind: the plan this pool's ticks compile with (kernel or
-        lax, and why)}, one entry for each kind of softmax layer the
-        model has, and one for its latent layers:
-        `models.transformer.decode_attention_plans` under the pool's
-        mesh."""
+    def kernel_plans(self, chunk: int = 1) -> dict:
+        """Which program this pool's ticks, and its prompt chunks of
+        ``chunk`` tokens, step each kind of layer with and why:
+        `models.transformer.kernel_plans` under the pool's mesh."""
         with self._ctx():
-            return decode_attention_plans(self.model, self.num_slots)
-
-    def moe_product_plans(self, chunk: int = 1) -> dict:
-        """{"tick" | "prefill": the plan this pool's ticks and its
-        prompt chunks of ``chunk`` tokens multiply their expert pairs
-        with (kernel or `lax.ragged_dot`, and why)}; {} for a model
-        without a dropless expert layer:
-        `models.transformer.moe_product_plans` under the pool's mesh."""
-        with self._ctx():
-            return moe_product_plans(self.model, self.num_slots, chunk)
-
-    def state_step_plans(self) -> dict:
-        """{kind ("kda", "ssm"): the plan this pool's ticks step that
-        kind of recurrent layer's state with (the in-place kernel or
-        the step as XLA compiles it, and why)}; {} for a model without
-        such a layer:
-        `models.transformer.state_step_plans` under the pool's mesh."""
-        with self._ctx():
-            return state_step_plans(self.model, self.num_slots)
+            return kernel_plans(self.model, self.num_slots, chunk)
 
     def _note_shape(self, key):
         if key not in self._seen_shapes:

@@ -30,16 +30,25 @@ def _tokens(B=8, S=16, seed=0):
 
 def _oracle_greedy(model, params, prompt, steps):
     """Full-prefix recompute: the O(S²)-per-token reference decoder.
-    The forward is jitted - one program a length - because run op by
-    op every length compiled every primitive again (the 40 lengths of
-    the rolling-window case took 270 s of the suite's 1470)."""
-    forward = jax.jit(lambda p, s: model.apply({"params": p}, s))
-    seq = jnp.asarray(prompt)
-    for _ in range(steps):
-        logits = forward(params, seq)
-        nxt = jnp.argmax(logits[:, -1].astype(jnp.float32), axis=-1)
-        seq = jnp.concatenate([seq, nxt[:, None].astype(seq.dtype)],
-                              axis=1)
+    ONE program for every length: the sequence stands padded to its
+    final length and the token after position n - 1 is read from that
+    row, which in a causal model sees nothing of what follows it (a
+    program a length compiles the same forward 40 times over in each
+    rolling-window case)."""
+    B, P = prompt.shape
+
+    @jax.jit
+    def extend(p, seq, n):
+        logits = model.apply({"params": p}, seq)
+        row = jax.lax.dynamic_index_in_dim(logits, n - 1, axis=1,
+                                           keepdims=False)
+        nxt = jnp.argmax(row.astype(jnp.float32), axis=-1)
+        return jax.lax.dynamic_update_slice_in_dim(
+            seq, nxt[:, None].astype(seq.dtype), n, axis=1)
+
+    seq = jnp.pad(jnp.asarray(prompt), ((0, 0), (0, steps)))
+    for n in range(P, P + steps):
+        seq = extend(params, seq, n)
     return seq
 
 
@@ -898,9 +907,10 @@ class TestGenerate:
         toks = _tokens(B=2, S=16, seed=29)
         dot_model = _tiny_model("dot", window=5)
         flash_model = _tiny_model("flash", window=5)
-        variables = dot_model.init(jax.random.PRNGKey(30), toks)
-        a = dot_model.apply(variables, toks)
-        b = flash_model.apply(variables, toks)
+        # every side one program, not a compile a primitive
+        variables = jax.jit(dot_model.init)(jax.random.PRNGKey(30), toks)
+        a = jax.jit(dot_model.apply)(variables, toks)
+        b = jax.jit(flash_model.apply)(variables, toks)
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32),
                                    atol=2e-5)
@@ -908,10 +918,10 @@ class TestGenerate:
         from horovod_tpu.models.transformer import lm_loss
         from horovod_tpu.parallel.tensor import unbox as _unbox
         params = _unbox(variables["params"])
-        g_dot = jax.grad(lambda p: lm_loss(
-            dot_model.apply({"params": p}, toks), toks))(params)
-        g_fla = jax.grad(lambda p: lm_loss(
-            flash_model.apply({"params": p}, toks), toks))(params)
+        g_dot = jax.jit(jax.grad(lambda p: lm_loss(
+            dot_model.apply({"params": p}, toks), toks)))(params)
+        g_fla = jax.jit(jax.grad(lambda p: lm_loss(
+            flash_model.apply({"params": p}, toks), toks)))(params)
         jax.tree.map(
             lambda x, y: np.testing.assert_allclose(
                 np.asarray(x), np.asarray(y), rtol=2e-4, atol=2e-5),

@@ -13,6 +13,7 @@ there"), any other absolute path, and patterns (`*`, `<...>`, `{...}`,
 """
 
 import glob
+import json
 import os
 import re
 
@@ -79,3 +80,25 @@ def test_no_cpu_record_outside_the_benchmark():
                     records.append(
                         os.path.relpath(os.path.join(top, name), REPO))
     assert not records, sorted(records)
+
+
+def test_readme_lists_the_benchmarks_cells():
+    """`README.md`'s table of cells is `BENCHMARK.json`'s `workloads`,
+    each with its chips and its end-to-end metric, and the reverse; it
+    carries no reading beside them (the ledger has the numbers)."""
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    metric = {cell: m["name"] for m in bench["end_to_end"]
+              for cell in m.get("workloads", ())}
+    cells = {(w["name"], str(w["chips"]), metric[w["name"]])
+             for w in bench["workloads"]}
+    with open(os.path.join(REPO, "README.md")) as f:
+        text = f.read()
+    rows = set(re.findall(
+        r"^\| `([\w.-]+\.[\w-]+)` \| (\d+) \| `(\w+)` \|$", text, re.M))
+    assert rows == cells
+    # and no span names a cell of a listed configuration that is gone
+    configs = {w["config"] for w in bench["workloads"]}
+    named = {span for span in re.findall(r"`([^`\n]+)`", text)
+             if any(span.startswith(c + ".") for c in configs)}
+    assert named == {name for name, _, _ in cells}

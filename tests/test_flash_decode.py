@@ -181,8 +181,8 @@ def test_tick_with_the_kernel_forced_equals_the_default_tick():
     fill 0 and a done lane: tokens equal, every lane's logits within
     the flash-decode tolerance, the caches equal."""
     model = tiny_lm()
-    params = unbox(model.init(jax.random.PRNGKey(1),
-                              jnp.zeros((1, 8), jnp.int32))["params"])
+    params = unbox(jax.jit(model.init)(
+        jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32))["params"])
     r = np.random.RandomState(2)
     fills = [130, 7, 64, 0, 33]           # slot 3 free
     live = jnp.asarray([True, True, False, False, True])
@@ -201,13 +201,15 @@ def test_tick_with_the_kernel_forced_equals_the_default_tick():
                     dec, params, cache, jnp.int32(slot),
                     jnp.asarray(r2.randint(0, 96, c), jnp.int32))
                 done_ += c
-        logits = []
-        for slot in range(5):
+        @jax.jit        # one program, not a compile a primitive a slot
+        def slot_logits(cache, slot):
             sub = jax.tree.map(lambda leaf: leaf[slot], cache)
             (h, emb), _ = dec.apply(
                 {"params": params, "cache": sub}, toks[slot][None, None],
                 return_hidden=True, mutable=["cache"])
-            logits.append(jnp.einsum("d,vd->v", h[0, -1], emb))
+            return jnp.einsum("d,vd->v", h[0, -1], emb)
+
+        logits = [slot_logits(cache, jnp.int32(slot)) for slot in range(5)]
         out = []
         for _ in range(3):
             cache, emit, *_ = slot_decode_tick(

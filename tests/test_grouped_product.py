@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from horovod_tpu.models.transformer import (
-    TransformerLM, generate, moe_product_plans,
+    TransformerLM, generate, kernel_plans,
 )
 from horovod_tpu.ops import grouped_matmul
 from horovod_tpu.ops.grouped_matmul import (
@@ -365,13 +365,14 @@ def small_model(moe_impl="dropless"):
 
 
 def test_model_plans_name_the_tick_and_the_chunk():
-    plans = moe_product_plans(small_model(), lanes=4, chunk=16)
+    plans = kernel_plans(small_model(), lanes=4,
+                         chunk=16)["moe_product"]
     assert {k: p.path for k, p in plans.items()} == {
         "tick": "lax", "prefill": "lax"}
-    assert moe_product_plans(TransformerLM(
+    assert kernel_plans(TransformerLM(
         vocab_size=64, num_layers=1, num_heads=2, head_dim=64,
-        max_len=64)) == {}
-    assert moe_product_plans(small_model("gshard")) == {}
+        max_len=64))["moe_product"] == {}
+    assert kernel_plans(small_model("gshard"))["moe_product"] == {}
 
 
 def test_engine_says_which_product_its_expert_layers_took(monkeypatch):

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from benchmarks.harness.cells import load_module
 from horovod_tpu.models.transformer import (
     generate, init_slot_cache, slot_decode_model, slot_decode_tick,
-    slot_prefill_chunk, state_step_plans,
+    kernel_plans, slot_prefill_chunk,
 )
 from horovod_tpu.parallel.linear_attention import (
     kda_chunked, kda_recurrent,
@@ -43,6 +43,12 @@ ARCH128 = dict(ARCH, head_dim=128, num_layers=2,
 def f32_model(arch=ARCH, **kw):
     return A.program_model(arch, max_len=MAX_LEN, attn_impl="dot",
                            dtype="float32", **kw)
+
+
+def ref_logits(arch, params, toks):
+    """The reference's full forward, `A.logits`, as ONE program: run
+    op by op it compiled a primitive at a time, seconds a call."""
+    return jax.jit(lambda p, t: A.logits(arch, p, t))(params, toks)
 
 
 @pytest.fixture(scope="module")
@@ -121,16 +127,18 @@ def test_kda_layer_equals_reference(params, split):
         num_heads=ARCH["num_heads"], head_dim=ARCH["head_dim"],
         out_features=ARCH["hidden_size"], dtype=jnp.float32,
         decode=split is not None)
+    # one program a length, not a compile a primitive
     if split is None:
-        got = layer.apply({"params": p}, x[None])[0]
+        got = jax.jit(layer.apply)({"params": p}, x[None])[0]
     else:
         cache = jax.tree.map(
-            jnp.zeros_like, layer.init(jax.random.PRNGKey(0),
-                                       x[None])["cache"])
+            jnp.zeros_like, jax.jit(layer.init)(
+                jax.random.PRNGKey(0), x[None])["cache"])
+        step = jax.jit(lambda cache, part: layer.apply(
+            {"params": p, "cache": cache}, part, mutable=["cache"]))
         parts = []
         for part in (x[:split], x[split:-1], x[-1:]):   # S > 1, then S = 1
-            y, mut = layer.apply({"params": p, "cache": cache},
-                                 part[None], mutable=["cache"])
+            y, mut = step(cache, part[None])
             cache = mut["cache"]
             parts.append(y[0])
         got = jnp.concatenate(parts)
@@ -206,8 +214,8 @@ def test_a_vmap_over_lanes_is_one_grouped_product(params):
 # ---- the whole model ------------------------------------------------------
 def test_program_equals_reference_full_forward(params):
     toks = tokens(40)
-    want = A.logits(ARCH, params, jnp.asarray(toks))
-    got = f32_model().apply({"params": params}, toks[None])[0]
+    want = ref_logits(ARCH, params, jnp.asarray(toks))
+    got = jax.jit(f32_model().apply)({"params": params}, toks[None])[0]
     np.testing.assert_allclose(got, want, atol=2e-5)
     A.check_layout(ARCH, MAX_LEN, f32_model())
 
@@ -230,11 +238,11 @@ def test_slot_chunks_and_ticks_equal_reference_with_an_interleaved_tick(
         "params" if path == "lax" else "params128")
     model = f32_model(arch)
     dec = slot_decode_model(model)
-    assert state_step_plans(dec, 3)["kda"].path == path
+    assert kernel_plans(dec, 3)["state_step"]["kda"].path == path
     cache = init_slot_cache(model, 3)
     a, b = tokens(21, 1), tokens(30, 2)
-    ref_a = A.logits(arch, params, jnp.asarray(a))
-    ref_b = A.logits(arch, params, jnp.asarray(b))
+    ref_a = ref_logits(arch, params, jnp.asarray(a))
+    ref_b = ref_logits(arch, params, jnp.asarray(b))
     # the tick as traced: the kernel's call a KDA layer and no select
     # of a state leaf on its path, the select and no call on the other
     tick_args = (jnp.zeros(3, jnp.int32), jnp.zeros(3), jnp.ones(3),
@@ -462,7 +470,7 @@ def test_tick_without_a_recurrent_layer_lowers_to_the_select_on_indices(
         before)
     model = make()
     assert not model.has_recurrent_state
-    assert state_step_plans(model, 3) == {}
+    assert kernel_plans(model, 3)["state_step"] == {}
     dec = slot_decode_model(model)
     shapes = jax.eval_shape(
         lambda: (dec.init(jax.random.PRNGKey(0),

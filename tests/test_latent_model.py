@@ -20,8 +20,8 @@ import jax.numpy as jnp
 
 from benchmarks.harness.cells import load_module
 from horovod_tpu.models.transformer import (
-    LAYER_KINDS, MOE_ROUTED_COLUMNS, TransformerLM, decode_attention_plans,
-    generate, init_slot_cache, slot_decode_model, slot_decode_tick,
+    LAYER_KINDS, MOE_ROUTED_COLUMNS, TransformerLM, generate,
+    init_slot_cache, kernel_plans, slot_decode_model, slot_decode_tick,
     slot_prefill_chunk, slot_reset,
 )
 from horovod_tpu.serving import ServingEngine
@@ -39,6 +39,12 @@ STORED = 128                        # a row of 16 + 8, padded to the lanes
 def f32_model(**kw):
     return A.program_model(ARCH, max_len=MAX_LEN, attn_impl="dot",
                            dtype="float32", **kw)
+
+
+def ref_logits(arch, params, toks):
+    """The reference's full forward, `A.logits`, as ONE program: run
+    op by op it compiled a primitive at a time, seconds a call."""
+    return jax.jit(lambda p, t: A.logits(arch, p, t))(params, toks)
 
 
 @pytest.fixture(scope="module")
@@ -65,10 +71,11 @@ def tokens(n, seed=0):
 # ---- the model = the reference ---------------------------------------------
 def test_full_forward_equals_the_reference(params):
     toks = tokens(96, 3)
-    got, mut = f32_model().apply({"params": params},
-                                 jnp.asarray(toks)[None],
-                                 mutable=["intermediates"])
-    want = A.logits(ARCH, params, jnp.asarray(toks))
+    # the model's forward one program, not a compile a primitive
+    got, mut = jax.jit(lambda p, t: f32_model().apply(
+        {"params": p}, t, mutable=["intermediates"]))(
+            params, jnp.asarray(toks)[None])
+    want = ref_logits(ARCH, params, jnp.asarray(toks))
     np.testing.assert_allclose(got[0], want, atol=3e-5)
     # in blocks, as a served request is checked
     served = A.served_logits(ARCH, params, toks[:50], toks[50:80],
@@ -97,10 +104,10 @@ def test_the_model_says_what_it_is(params):
     assert params["block_1"]["moe"]["w_gate"].shape == (4, 64, 32)
     A.check_layout(ARCH, MAX_LEN, model)
     assert A.count(ARCH) == sum(a.size for a in jax.tree.leaves(params))
-    plans = decode_attention_plans(model, 4)
+    plans = kernel_plans(model, 4)["decode_attn"]
     assert list(plans) == ["mla"] and plans["mla"].path == "lax"
-    forced = decode_attention_plans(
-        model.clone(decode_prefix_impl="pallas"), 4)["mla"]
+    forced = kernel_plans(model.clone(decode_prefix_impl="pallas"),
+                          4)["decode_attn"]["mla"]
     assert (forced.path, forced.grid, forced.write) == (
         "kernel", (4, 1), "kernel")
     assert "latent rows of 128 read once" in forced.why
@@ -124,8 +131,8 @@ def test_chunks_then_ticks_through_the_slot_pool_equal_the_reference(
     dec = slot_decode_model(model)
     cache = init_slot_cache(model, 3)
     a, b = tokens(60, 1), tokens(100, 2)
-    ref_a = A.logits(ARCH, params, jnp.asarray(a))
-    ref_b = A.logits(ARCH, params, jnp.asarray(np.pad(b, (0, 28))))
+    ref_a = ref_logits(ARCH, params, jnp.asarray(a))
+    ref_b = ref_logits(ARCH, params, jnp.asarray(np.pad(b, (0, 28))))
 
     def chunk(cache, slot, toks):
         cache, lg, pairs = slot_prefill_chunk(

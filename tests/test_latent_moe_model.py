@@ -158,12 +158,15 @@ def full_forward(served, pool_logits):
     so that the reference's operations compile for one length."""
     seqs = pool_logits[0]
     longest = max(len(seq) for seq in seqs.values())
+    # one program for the lanes (all padded to one length): op by op
+    # the reference compiled a primitive at a time
+    logits = jax.jit(lambda p, t: A.logits(ARCH, p, t))
     want = {}
     for lane, seq in seqs.items():
         padded = np.zeros(longest, np.int32)
         padded[:len(seq)] = seq
-        want[lane] = np.asarray(A.logits(
-            ARCH, served["params"], jnp.asarray(padded)))[:len(seq)]
+        want[lane] = np.asarray(logits(
+            served["params"], jnp.asarray(padded)))[:len(seq)]
     return want
 
 

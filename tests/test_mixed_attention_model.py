@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from benchmarks.harness.cells import load_module
 from horovod_tpu.models.transformer import (
-    AttnSpec, TransformerLM, decode_attention_plans, generate,
+    AttnSpec, TransformerLM, kernel_plans, generate,
     init_slot_cache, slot_decode_model, slot_decode_tick,
     slot_prefill_chunk,
 )
@@ -46,6 +46,12 @@ YARN = RopeSpec(theta=500000, fraction=0.5, yarn_factor=128,
 def f32_model(arch=ARCH, **kw):
     return A.program_model(arch, max_len=MAX_LEN, attn_impl="dot",
                            dtype="float32", **kw)
+
+
+def ref_logits(arch, params, toks):
+    """The reference's full forward, `A.logits`, as ONE program: run
+    op by op it compiled a primitive at a time, seconds a call."""
+    return jax.jit(lambda p, t: A.logits(arch, p, t))(params, toks)
 
 
 @pytest.fixture(scope="module")
@@ -189,11 +195,11 @@ def test_ring_tick_through_the_kernel_equals_the_dense_oracle(H, Hkv):
 
 def test_plans_answer_a_kind():
     model = f32_model()
-    plans = decode_attention_plans(model, 4)
+    plans = kernel_plans(model, 4)["decode_attn"]
     assert list(plans) == ["attn", "swa"]
     assert all(p.path == "lax" for p in plans.values())      # the CPU
-    forced = decode_attention_plans(
-        model.clone(decode_prefix_impl="pallas"), 4)
+    forced = kernel_plans(model.clone(decode_prefix_impl="pallas"),
+                          4)["decode_attn"]
     assert forced["attn"].grid == (4, 1) and forced["swa"].grid == (4, 1)
     assert f"ring of {WINDOW} slots" in forced["swa"].why
     # on the chip, at the published shape: 48 and 72 heads over 8
@@ -209,8 +215,10 @@ def test_plans_answer_a_kind():
 # ---- (c) the model = the reference -----------------------------------------
 def test_full_forward_equals_the_reference(params):
     toks = tokens(96, 3)                # six windows long
-    got = f32_model().apply({"params": params}, jnp.asarray(toks)[None])[0]
-    want = A.logits(ARCH, params, jnp.asarray(toks))
+    # the model's forward one program, not a compile a primitive
+    got = jax.jit(f32_model().apply)(
+        {"params": params}, jnp.asarray(toks)[None])[0]
+    want = ref_logits(ARCH, params, jnp.asarray(toks))
     np.testing.assert_allclose(got, want, atol=3e-5)
     # and in blocks, as a served request is checked
     served = A.served_logits(ARCH, params, toks[:50], toks[50:80],
@@ -259,8 +267,8 @@ def test_chunks_then_ticks_through_the_slot_pool_equal_the_reference(
     dec = slot_decode_model(model)
     cache = init_slot_cache(model, 3)
     a, b = tokens(60, 1), tokens(100, 2)
-    ref_a = A.logits(ARCH, params, jnp.asarray(a))
-    ref_b = A.logits(ARCH, params, jnp.asarray(np.pad(b, (0, 28))))
+    ref_a = ref_logits(ARCH, params, jnp.asarray(a))
+    ref_b = ref_logits(ARCH, params, jnp.asarray(np.pad(b, (0, 28))))
 
     def chunk(cache, slot, toks):
         cache, lg, _ = slot_prefill_chunk(dec, params, cache,

@@ -16,14 +16,23 @@ def test_mnist_convnet_forward(hvd):
     assert out.shape == (4, 10)
 
 
+def _forward(m, x, key=0):
+    """(variables, eval-mode output) of a convolutional model, its
+    init and its forward each ONE program: run op by op, every one of
+    a deep model's convolutions compiled alone, twice over."""
+    vars_ = jax.jit(lambda key, x: m.init(key, x, train=False))(
+        jax.random.PRNGKey(key), x)
+    return vars_, jax.jit(lambda v, x: m.apply(v, x, train=False))(
+        vars_, x)
+
+
 @pytest.mark.parametrize("cls_name,depth", [("ResNet50", 50)])
 def test_resnet_forward(hvd, cls_name, depth):
     from horovod_tpu import models
     m = getattr(models, cls_name)(num_classes=10, dtype=jnp.float32,
                                   width=16)
     x = jnp.zeros((2, 64, 64, 3))
-    vars_ = m.init(jax.random.PRNGKey(0), x, train=False)
-    out = m.apply(vars_, x, train=False)
+    vars_, out = _forward(m, x)
     assert out.shape == (2, 10)
     assert "batch_stats" in vars_
 
@@ -122,18 +131,20 @@ def test_s2d_stem_matches_plain_stem(hvd):
                           width=16, dtype=jnp.float32)
     s2d = models.ResNet(stage_sizes=[1, 1], num_classes=10,
                         width=16, dtype=jnp.float32, s2d_stem=True)
-    vars_ = plain.init(jax.random.PRNGKey(3), x, train=False)
+    # every forward one program, not a compile a primitive
+    vars_, a = _forward(plain, x, key=3)
     # Identical param trees: the s2d stem declares the same
     # stem_conv/kernel [7,7,3,F] under the same name.
-    vars_s2d = s2d.init(jax.random.PRNGKey(4), x, train=False)
+    vars_s2d = jax.eval_shape(
+        lambda: s2d.init(jax.random.PRNGKey(4), x, train=False))
     assert (jax.tree.structure(vars_) == jax.tree.structure(vars_s2d))
-    a = plain.apply(vars_, x, train=False)
-    b = s2d.apply(vars_, x, train=False)
+    b = jax.jit(lambda v, x: s2d.apply(v, x, train=False))(vars_, x)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=1e-5, atol=1e-5)
     # Training mode too (BatchNorm batch stats follow the stem output).
-    at, _ = plain.apply(vars_, x, train=True, mutable=["batch_stats"])
-    bt, _ = s2d.apply(vars_, x, train=True, mutable=["batch_stats"])
+    at, bt = (jax.jit(lambda v, x, m=m: m.apply(
+        v, x, train=True, mutable=["batch_stats"]))(vars_, x)[0]
+        for m in (plain, s2d))
     np.testing.assert_allclose(np.asarray(at), np.asarray(bt),
                                rtol=1e-5, atol=1e-5)
     # Non-multiple-of-4 inputs are a clear error, not silent wrongness.
@@ -150,11 +161,14 @@ def test_inception_s2d_stem_matches_plain(hvd, hw):
     x = jnp.asarray(rng.randn(1, hw, hw, 3), jnp.float32)
     plain = InceptionV3(num_classes=10, dtype=jnp.float32)
     s2d = InceptionV3(num_classes=10, dtype=jnp.float32, s2d_stem=True)
-    vars_ = plain.init(jax.random.PRNGKey(0), x, train=False)
+    # jitted (`_forward`): op by op the 94 convolutions of each forward
+    # compiled one by one, four forwards over; the tree of the second
+    # model needs no forward at all
+    vars_, a = _forward(plain, x)
     assert (jax.tree.structure(vars_) == jax.tree.structure(
-        s2d.init(jax.random.PRNGKey(1), x, train=False)))
-    a = plain.apply(vars_, x, train=False)
-    b = s2d.apply(vars_, x, train=False)
+        jax.eval_shape(
+            lambda: s2d.init(jax.random.PRNGKey(1), x, train=False))))
+    b = jax.jit(lambda v, x: s2d.apply(v, x, train=False))(vars_, x)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=1e-5, atol=1e-5)
 
@@ -163,8 +177,7 @@ def test_vgg16_forward(hvd):
     from horovod_tpu.models import VGG16
     m = VGG16(num_classes=10, dtype=jnp.float32)
     x = jnp.zeros((2, 32, 32, 3))
-    vars_ = m.init(jax.random.PRNGKey(0), x, train=False)
-    out = m.apply(vars_, x, train=False)
+    _, out = _forward(m, x)
     assert out.shape == (2, 10)
 
 
@@ -172,8 +185,7 @@ def test_inception_v3_forward(hvd):
     from horovod_tpu.models import InceptionV3
     m = InceptionV3(num_classes=10, dtype=jnp.float32)
     x = jnp.zeros((1, 299, 299, 3))
-    vars_ = m.init(jax.random.PRNGKey(0), x, train=False)
-    out = m.apply(vars_, x, train=False)
+    _, out = _forward(m, x)
     assert out.shape == (1, 10)
 
 
@@ -484,7 +496,8 @@ def test_chunked_mlm_loss_matches_plain(hvd, chunk):
     model = BertMLM(vocab_size=48, num_layers=1, num_heads=2,
                     head_dim=8, max_len=16, dtype=jnp.float32)
     toks = jnp.asarray(np.random.RandomState(7).randint(0, 48, (4, 16)))
-    params = unbox(model.init(jax.random.PRNGKey(7), toks)["params"])
+    params = unbox(jax.jit(model.init)(
+        jax.random.PRNGKey(7), toks)["params"])
     corrupted, sel = make_mlm_batch(jax.random.PRNGKey(8), toks,
                                     vocab_size=48, mask_id=47)
 
@@ -497,8 +510,9 @@ def test_chunked_mlm_loss_matches_plain(hvd, chunk):
                                     return_hidden=True)
         return chunked_mlm_loss(hidden, embed, toks, sel, chunk=chunk)
 
-    la, ga = jax.value_and_grad(plain)(params)
-    lb, gb = jax.value_and_grad(chunked)(params)
+    # one program a side, not a compile a primitive
+    la, ga = jax.jit(jax.value_and_grad(plain))(params)
+    lb, gb = jax.jit(jax.value_and_grad(chunked))(params)
     np.testing.assert_allclose(float(la), float(lb), rtol=1e-6)
     jax.tree.map(lambda x, y: np.testing.assert_allclose(
         np.asarray(x), np.asarray(y), rtol=2e-5, atol=2e-5), ga, gb)

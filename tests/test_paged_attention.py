@@ -3,16 +3,14 @@
 The contract stack:
 
 * **Walk == gather, bitwise.** The lax block-table walk
-  (`ops.paged_attention.paged_prefix_attention`, the `kernel="lax"`
-  pool mode) reads the same bytes in the same accumulation order as
-  the legacy gathered-view program, so prefill logits and token
-  streams are BITWISE the `kernel="off"` pool's — across fill
-  patterns, block sizes, prompt lengths, eos stops, and int8-KV
-  scale pools.
-* **Pallas == walk, bitwise (interpret).** The fused Pallas decode
-  kernel accumulates at block_size granularity; at
-  ``decode_prefix_block == block_size`` the walk is its exact oracle,
-  pinned in interpret mode on CPU CI.
+  (`ops.paged_attention.paged_prefix_attention`, what a pool takes
+  wherever its geometry allows: `kernel_mode == "lax"`) reads the same
+  bytes in the same accumulation order as the gathered-view program,
+  so prefill logits and token streams are BITWISE those of a pool
+  that gathers (`kernel_mode == "off"`) — across fill patterns, block
+  sizes, prompt lengths, eos stops, and int8-KV scale pools. The
+  geometry here walks; the reference is had by steering the pool's
+  rule in the test (`gathers`): the program has no option for it.
 * **No full-span gather.** The fused tick's traced jaxpr contains no
   gather whose output covers the whole table span — the kernel path
   walks only filled blocks. The same detector FINDS the full-span
@@ -31,8 +29,9 @@ from horovod_tpu.models.transformer import (
 )
 from horovod_tpu.parallel.tensor import unbox
 from horovod_tpu.serving import ServingEngine
+from horovod_tpu.serving import paging
 from horovod_tpu.serving.paging import (
-    PagedSlotPool, _resolve_paged_kernel,
+    PagedSlotPool, _paged_attention_way,
 )
 
 VOCAB = 64
@@ -53,22 +52,39 @@ def lm(hvd):
     return model, params
 
 
+@pytest.fixture
+def steer(monkeypatch):
+    """steer("off") makes every pool built after it gather its lanes'
+    whole spans, the reference, at a geometry the rule would walk;
+    steer("lax") gives the rule back."""
+    def to(kernel):
+        if kernel == "off":
+            monkeypatch.setattr(
+                paging, "_paged_attention_way",
+                lambda model, block_size: ("off", "the test's reference"))
+        else:
+            monkeypatch.setattr(paging, "_paged_attention_way",
+                                _paged_attention_way)
+    return to
+
+
 def _prompts(n, seed=0, lo=1, hi=12):
     rs = np.random.RandomState(seed)
     return [rs.randint(0, VOCAB, (int(rs.randint(lo, hi)),))
             for _ in range(n)]
 
 
-def _pool_streams(model, params, kernel, prompts, steps, *,
+def _pool_streams(steer, model, params, kernel, prompts, steps, *,
                   block_size=8, eos_id=None, num_slots=3,
                   collect_logits=False):
     """Drive a PagedSlotPool directly (interleaved admissions so fill
-    patterns differ per lane) and return per-prompt token streams
-    (and optionally each prefill's final logits)."""
+    patterns differ per lane), walking ("lax") or steered to the
+    gather ("off"), and return per-prompt token streams (and
+    optionally each prefill's final logits)."""
+    steer(kernel)
     pool = PagedSlotPool(model, params, num_slots,
-                         block_size=block_size, eos_id=eos_id,
-                         kernel=kernel)
-    assert pool.kernel_mode == ("off" if kernel == "off" else kernel)
+                         block_size=block_size, eos_id=eos_id)
+    assert pool.kernel_mode == kernel
     streams, logits_out = [], []
     for p in prompts:
         adm = pool.admit(np.asarray(p), steps)
@@ -91,16 +107,16 @@ def _pool_streams(model, params, kernel, prompts, steps, *,
 
 class TestWalkVsGather:
     @pytest.mark.parametrize("block_size", [4, 8, 16])
-    def test_streams_and_logits_bitwise(self, lm, block_size):
-        """kernel="lax" == kernel="off", bitwise, across block sizes
+    def test_streams_and_logits_bitwise(self, lm, steer, block_size):
+        """The walk == the gather, bitwise, across block sizes
         and mixed fill patterns — and both equal `generate`."""
         model, params = lm
         prompts = _prompts(5, seed=0)
         steps = 6
-        off, lo = _pool_streams(model, params, "off", prompts, steps,
+        off, lo = _pool_streams(steer, model, params, "off", prompts, steps,
                                 block_size=block_size,
                                 collect_logits=True)
-        lax_, ll = _pool_streams(model, params, "lax", prompts, steps,
+        lax_, ll = _pool_streams(steer, model, params, "lax", prompts, steps,
                                  block_size=block_size,
                                  collect_logits=True)
         assert off == lax_
@@ -111,33 +127,33 @@ class TestWalkVsGather:
                 model, params, jnp.asarray(p)[None], steps))[0]
             np.testing.assert_array_equal(ref[len(p):], s)
 
-    def test_eos_stop_bitwise(self, lm):
+    def test_eos_stop_bitwise(self, lm, steer):
         model, params = lm
         prompt = _prompts(1, seed=3)[0]
-        probe = _pool_streams(model, params, "off", [prompt], 10)[0]
+        probe = _pool_streams(steer, model, params, "off", [prompt], 10)[0]
         eos = probe[len(probe) // 2]
-        a = _pool_streams(model, params, "off", [prompt], 10, eos_id=eos)
-        b = _pool_streams(model, params, "lax", [prompt], 10, eos_id=eos)
+        a = _pool_streams(steer, model, params, "off", [prompt], 10, eos_id=eos)
+        b = _pool_streams(steer, model, params, "lax", [prompt], 10, eos_id=eos)
         assert a == b
 
-    def test_int8_kv_scale_pools_walk(self, lm):
+    def test_int8_kv_scale_pools_walk(self, lm, steer):
         """int8 KV: the scale pools ride the paged collection and the
         walk's per-block dequant matches the gathered view's."""
         model, params = lm
         kvm = model.clone(kv_quant="int8")
         prompts = _prompts(3, seed=5)
-        a = _pool_streams(kvm, params, "off", prompts, 6)
-        b = _pool_streams(kvm, params, "lax", prompts, 6)
+        a = _pool_streams(steer, kvm, params, "off", prompts, 6)
+        b = _pool_streams(steer, kvm, params, "lax", prompts, 6)
         assert a == b
 
     def test_engine_kernel_token_exact(self, lm):
-        """ServingEngine(paged, kernel) end to end == generate."""
+        """ServingEngine(paged) on the walk end to end == generate."""
         model, params = lm
         prompts = _prompts(6, seed=7)
         steps = 6
         with ServingEngine(model, params, num_slots=3, paged=True,
-                           kv_block_size=8,
-                           paged_kernel="lax") as eng:
+                           kv_block_size=8) as eng:
+            assert eng.pool.kernel_mode == "lax"
             out = [list(eng.submit(p, steps).result(timeout=300)
                         .tokens) for p in prompts]
         for p, s in zip(prompts, out):
@@ -145,7 +161,7 @@ class TestWalkVsGather:
                 model, params, jnp.asarray(p)[None], steps))[0]
             np.testing.assert_array_equal(ref[len(p):], s)
 
-    def test_prefix_hit_fill_pattern_bitwise(self, lm):
+    def test_prefix_hit_fill_pattern_bitwise(self, lm, steer):
         """A prefix-cache hit starts the lane's fill mid-table — the
         walk must be bitwise the gather from that offset too."""
         model, params = lm
@@ -155,40 +171,16 @@ class TestWalkVsGather:
                    for _ in range(2)]
         outs = {}
         for kern in ("off", "lax"):
+            steer(kern)
             with ServingEngine(model, params, num_slots=2, paged=True,
-                               kv_block_size=8, paged_kernel=kern) as e:
+                               kv_block_size=8) as e:
+                assert e.pool.kernel_mode == kern
                 outs[kern] = [
                     list(e.submit(p, 5).result(timeout=300).tokens)
                     for p in prompts]
                 snap = e.metrics_snapshot()
                 assert snap["prefill_tokens_skipped"] > 0  # hit path
         assert outs["off"] == outs["lax"]
-
-
-class TestPallasKernel:
-    def test_pallas_bitwise_vs_walk_at_bs(self, lm):
-        """The fused kernel accumulates at block_size granularity; the
-        walk at decode_prefix_block == block_size is its bitwise
-        oracle (interpret mode)."""
-        model, params = lm
-        aligned = model.clone(decode_prefix_block=8)
-        prompts = _prompts(4, seed=2)
-        a = _pool_streams(aligned, params, "lax", prompts, 8)
-        b = _pool_streams(model, params, "pallas", prompts, 8)
-        assert a == b
-
-    def test_pallas_engine_token_exact(self, lm):
-        model, params = lm
-        prompts = _prompts(4, seed=9)
-        with ServingEngine(model, params, num_slots=2, paged=True,
-                           kv_block_size=8,
-                           paged_kernel="pallas") as eng:
-            out = [list(eng.submit(p, 6).result(timeout=300).tokens)
-                   for p in prompts]
-        for p, s in zip(prompts, out):
-            ref = np.asarray(generate(
-                model, params, jnp.asarray(p)[None], 6))[0]
-            np.testing.assert_array_equal(ref[len(p):], s)
 
 
 def _gather_ops(jaxpr, acc):
@@ -269,27 +261,13 @@ class TestNoFullSpanGather:
 
 
 class TestKernelModeResolution:
-    def test_explicit_mode_raises_on_bad_geometry(self, lm):
-        model, _ = lm
-        bad = model.clone(decode_prefix_block=0)
-        with pytest.raises(ValueError, match="decode_prefix_block"):
-            _resolve_paged_kernel("lax", bad, 8)
-        assert _resolve_paged_kernel("auto", bad, 8) == "off"
-
     def test_auto_defaults_to_walk(self, lm):
-        model, _ = lm
-        assert _resolve_paged_kernel(None, model, 8) in ("lax", "off")
-        assert _resolve_paged_kernel("auto", model, 8) == "lax"
-        assert _resolve_paged_kernel("off", model, 8) == "off"
-
-    def test_env_knob_reaches_pool(self, lm, monkeypatch):
+        """The geometry alone decides: the walk where
+        decode_prefix_block fits the block size and max_len, the
+        gather where it does not, each with its reason in words."""
         model, params = lm
-        monkeypatch.setenv("HVD_PAGED_KERNEL", "off")
-        from horovod_tpu.runtime.config import config
-        config.refresh()
-        try:
-            pool = PagedSlotPool(model, params, 1, block_size=8)
-            assert pool.kernel_mode == "off"
-        finally:
-            monkeypatch.delenv("HVD_PAGED_KERNEL")
-            config.refresh()
+        assert _paged_attention_way(model, 8)[0] == "lax"
+        assert _paged_attention_way(
+            model.clone(decode_prefix_block=0), 8)[0] == "off"
+        assert PagedSlotPool(model, params, 1,
+                             block_size=8).kernel_mode == "lax"

@@ -303,7 +303,8 @@ class TestSequenceParallel:
                                window=window, block_impl="flash")
         sm = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                            out_specs=spec)
-        got = sm(q, k, v)
+        # jitted: an eager shard_map compiles a primitive at a time
+        got = jax.jit(sm)(q, k, v)
         ref = par.dot_product_attention(q, k, v, mask)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
@@ -330,8 +331,9 @@ class TestSequenceParallel:
         spec = P("data", "seq", None, None)
         fn = functools.partial(par.ring_attention, causal=True,
                                block_impl="flash")
-        got = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                            out_specs=spec)(q, k, v)
+        got = jax.jit(jax.shard_map(
+            fn, mesh=mesh, in_specs=(spec, spec, spec),
+            out_specs=spec))(q, k, v)
         assert got.dtype == jnp.bfloat16
         S = q.shape[1]
         mask = jnp.tril(jnp.ones((S, S), bool))[None, None]

@@ -165,17 +165,19 @@ class TestKVCacheInt8:
         quant = small_lm(window=window, pos_emb="rope",
                          kv_quant="int8").clone(decode=True)
         toks16 = jnp.zeros((2, 16), jnp.int32)
-        params = unbox(plain.init(jax.random.PRNGKey(0),
-                                  toks16)["params"])
-        cache_p = plain.init(jax.random.PRNGKey(0), toks16)["cache"]
-        cache_q = quant.init(jax.random.PRNGKey(0), toks16)["cache"]
+        # one program a model, not a compile a primitive a tick
+        init_p = jax.jit(plain.init)(jax.random.PRNGKey(0), toks16)
+        params, cache_p = unbox(init_p["params"]), init_p["cache"]
+        cache_q = jax.jit(quant.init)(jax.random.PRNGKey(0),
+                                      toks16)["cache"]
+        tick_p, tick_q = (jax.jit(lambda p, c, tok, m=m: m.apply(
+            {"params": p, "cache": c}, tok, mutable=["cache"]))
+            for m in (plain, quant))
         rng = np.random.RandomState(4)
         for t in range(8):
             tok = jnp.asarray(rng.randint(0, 64, (2, 1)))
-            lp, mp = plain.apply({"params": params, "cache": cache_p},
-                                 tok, mutable=["cache"])
-            lq, mq = quant.apply({"params": params, "cache": cache_q},
-                                 tok, mutable=["cache"])
+            lp, mp = tick_p(params, cache_p, tok)
+            lq, mq = tick_q(params, cache_q, tok)
             cache_p, cache_q = mp["cache"], mq["cache"]
             denom = float(np.abs(np.asarray(lp)).max())
             err = float(np.abs(np.asarray(lq) - np.asarray(lp)).max())

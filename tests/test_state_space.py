@@ -19,8 +19,8 @@ import jax.numpy as jnp
 from benchmarks.harness.cells import load_module
 from horovod_tpu.models.transformer import (
     RECURRENT_KINDS, TransformerLM, decode_attention_plan, generate,
-    init_slot_cache, slot_decode_model, slot_decode_tick,
-    slot_prefill_chunk, state_step_plans,
+    init_slot_cache, kernel_plans, slot_decode_model, slot_decode_tick,
+    slot_prefill_chunk,
 )
 from horovod_tpu.ops.ssm_step import ssm_state_step, ssm_step_plan
 from horovod_tpu.parallel.state_space import (
@@ -53,6 +53,12 @@ ATOL = 2e-6
 def f32_model(arch=ARCH, **kw):
     return A.program_model(arch, max_len=MAX_LEN, attn_impl="dot",
                            dtype="float32", **kw)
+
+
+def ref_logits(arch, params, toks):
+    """The reference's full forward, `A.logits`, as ONE program: run
+    op by op it compiled a primitive at a time, seconds a call."""
+    return jax.jit(lambda p, t: A.logits(arch, p, t))(params, toks)
 
 
 @pytest.fixture(scope="module")
@@ -161,18 +167,20 @@ def test_layer_equals_reference(params, split):
     layer = Mamba2Mixer(spec=f32_model().ssm,
                         out_features=ARCH["hidden_size"],
                         dtype=jnp.float32, decode=split is not None)
+    # one program a length, not a compile a primitive
     if split is None:
-        got = layer.apply({"params": p}, x[None])[0]
+        got = jax.jit(layer.apply)({"params": p}, x[None])[0]
     else:
         cache = jax.tree.map(
-            jnp.zeros_like, layer.init(jax.random.PRNGKey(0),
-                                       x[None])["cache"])
+            jnp.zeros_like, jax.jit(layer.init)(
+                jax.random.PRNGKey(0), x[None])["cache"])
         assert cache["state"].shape == (1, 1, 16, 128)
         assert cache["conv_tail"].shape == (1, 3, 128 + 2 * 16)
+        step = jax.jit(lambda cache, part: layer.apply(
+            {"params": p, "cache": cache}, part, mutable=["cache"]))
         parts = []
         for part in (x[:split], x[split:-1], x[-1:]):
-            y, mut = layer.apply({"params": p, "cache": cache},
-                                 part[None], mutable=["cache"])
+            y, mut = step(cache, part[None])
             cache = mut["cache"]
             parts.append(y[0])
         got = jnp.concatenate(parts)
@@ -227,7 +235,7 @@ def test_the_plans_rule():
 # ---- the whole model --------------------------------------------------------
 def test_program_equals_reference_full_forward(params):
     toks = tokens(40)
-    want = A.logits(ARCH, params, jnp.asarray(toks))
+    want = ref_logits(ARCH, params, jnp.asarray(toks))
     got = f32_model().apply({"params": params}, toks[None])[0]
     np.testing.assert_allclose(got, want, atol=ATOL)
     A.check_layout(ARCH, MAX_LEN, f32_model())
@@ -245,8 +253,8 @@ def test_each_multiplier_reaches_the_logits(params, scalar, other):
     any published scale: the attention's is tried at 32.)"""
     arch = dict(ARCH, **{scalar: other})
     toks = tokens(24, 3)
-    base = A.logits(ARCH, params, jnp.asarray(toks))
-    want = A.logits(arch, params, jnp.asarray(toks))
+    base = ref_logits(ARCH, params, jnp.asarray(toks))
+    want = ref_logits(arch, params, jnp.asarray(toks))
     got = f32_model(arch).apply({"params": params}, toks[None])[0]
     assert float(jnp.abs(want - base).max()) > 100 * ATOL
     np.testing.assert_allclose(got, want, atol=ATOL)
@@ -297,11 +305,11 @@ def test_slot_chunks_and_ticks_equal_reference_with_an_interleaved_tick(
         "params" if path == "lax" else "params_k")
     model = f32_model(arch)
     dec = slot_decode_model(model)
-    assert state_step_plans(dec, 3)["ssm"].path == path
+    assert kernel_plans(dec, 3)["state_step"]["ssm"].path == path
     cache = init_slot_cache(model, 3)
     a, b = tokens(21, 1), tokens(30, 2)
-    ref_a = A.logits(arch, params, jnp.asarray(a))
-    ref_b = A.logits(arch, params, jnp.asarray(b))
+    ref_a = ref_logits(arch, params, jnp.asarray(a))
+    ref_b = ref_logits(arch, params, jnp.asarray(b))
     tick_args = (jnp.zeros(3, jnp.int32), jnp.zeros(3), jnp.ones(3),
                  jnp.stack([jax.random.PRNGKey(i) for i in range(3)]),
                  jnp.ones(3, bool), jnp.zeros(3, bool), jnp.int32(-1))
@@ -436,7 +444,7 @@ def test_head_of_64_is_served_from_packed_rows_on_every_path():
 
         last = [next_logits(pool._cache, slot, jnp.asarray(tok))
                 for slot, tok in zip(slots, streams[-1])]
-        return streams.T, np.stack(last), pool.decode_attention_plans()
+        return streams.T, np.stack(last), pool.kernel_plans()["decode_attn"]
 
     want, want_l, plans = serve(model)
     assert plans["attn"].path == "lax"
@@ -450,7 +458,7 @@ def test_head_of_64_is_served_from_packed_rows_on_every_path():
     np.testing.assert_array_equal(out[len(prompts[1]):], want[1])
     # and the stream is the reference's: a full forward pass a token
     seq = np.concatenate([prompts[0], want[0]])
-    ref = A.logits(ARCH_64, params, jnp.asarray(seq[:-1]))
+    ref = ref_logits(ARCH_64, params, jnp.asarray(seq[:-1]))
     np.testing.assert_array_equal(
         np.argmax(np.asarray(ref[len(prompts[0]) - 1:]), -1), want[0])
 

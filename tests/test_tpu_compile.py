@@ -179,77 +179,6 @@ def test_flash_decode_attention(sds, hkv):
         q, kv, kv, sds((), jnp.int32)) == 1
 
 
-@pytest.mark.parametrize("block_size", [16, 128])
-def test_paged_decode_attention_vmapped(sds, block_size):
-    """vmap over the lane axis dispatches ONE kernel with the lanes
-    as its leading grid dim (pools unbatched)."""
-    from horovod_tpu.ops.paged_attention import paged_decode_attention
-    per_seq = S // block_size
-    q = sds((LANES, 1, 1, H, D))
-    pool = sds((LANES * per_seq + 1, 1, block_size, H, D))
-
-    def tick(q, k_new, v_new, k_pool, v_pool, tables, fills):
-        return jax.vmap(
-            lambda q, kn, vn, t, f: paged_decode_attention(
-                q, kn, vn, k_pool, v_pool, t, f, interpret=False)
-        )(q, k_new, v_new, tables, fills)
-
-    assert custom_calls(
-        tick, q, q, q, pool, pool, sds((LANES, per_seq), jnp.int32),
-        sds((LANES,), jnp.int32)) == 1
-
-
-def test_paged_decode_tick_whole_program(sds, monkeypatch):
-    """The serving engine's Pallas-mode tick as `PagedSlotPool`
-    dispatches it: 8 lanes x max_len 2048, KV block 16, 12 layers —
-    one paged-decode kernel per layer inside vmap, sampling and the
-    block scatter around it."""
-    from horovod_tpu.models.transformer import (
-        TransformerLM, init_paged_pools, paged_cache_spec,
-        paged_decode_tick, serving_params, slot_decode_model)
-    from horovod_tpu.ops import flash_attention, paged_attention
-    from horovod_tpu.parallel.tensor import unbox
-
-    # The model reaches the kernels through `_auto_interpret()`, which
-    # asks for the default backend — the CPU here. Steer it in the
-    # test; the program grows no option for this.
-    monkeypatch.setattr(flash_attention, "_auto_interpret",
-                        lambda: False)
-    monkeypatch.setattr(paged_attention, "_auto_interpret",
-                        lambda: False)
-
-    layers, bs = 12, 16
-    model = TransformerLM(vocab_size=32768, num_layers=layers,
-                          num_heads=H, head_dim=D, max_len=S,
-                          dtype=jnp.bfloat16, attn_impl="flash")
-    dec = slot_decode_model(model).clone(decode_prefix_impl="pallas",
-                                         decode_prefix_block=bs)
-    spec = paged_cache_spec(model, bs)
-
-    def place(tree):
-        return jax.tree.map(lambda s: sds(s.shape, s.dtype), tree)
-
-    params = place(jax.eval_shape(
-        lambda r: serving_params(unbox(model.init(
-            r, jnp.zeros((1, 64), jnp.int32))["params"])),
-        jax.random.PRNGKey(0)))
-    pools = place(jax.eval_shape(
-        lambda: init_paged_pools(model, spec,
-                                 LANES * spec.blocks_per_seq + 1)))
-    vec = lambda dt: sds((LANES,), dt)  # noqa: E731
-    compiled = paged_decode_tick.lower(
-        dec, spec, pools, params,
-        sds((LANES, spec.blocks_per_seq), jnp.int32), vec(jnp.int32),
-        vec(jnp.int32), vec(jnp.float32), vec(jnp.float32),
-        sds((LANES, 2), jnp.uint32), vec(bool), vec(bool),
-        sds((), jnp.int32), fused=True).compile()
-    assert compiled.as_text().count("tpu_custom_call") == layers
-    mem = compiled.memory_analysis()
-    need = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
-            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
-    assert need < 16 * 2 ** 30, f"tick needs {need / 2**30:.1f} GiB"
-
-
 # The serving cells' attention shapes: (lanes, cache positions, model
 # fields). Two layers, a narrow MLP and a small vocabulary: the tick's
 # attention, cache write and aliasing do not depend on the rest.
@@ -310,8 +239,9 @@ def test_slot_decode_tick_attends_through_the_ragged_kernel(
     from horovod_tpu.ops import flash_attention
     from horovod_tpu.parallel.tensor import unbox
 
-    # the rule asks for the default backend - the CPU here (see
-    # test_paged_decode_tick_whole_program)
+    # The rule reaches the kernels through `_auto_interpret()`, which
+    # asks for the default backend - the CPU here. Steer it in the
+    # test; the program grows no option for this.
     monkeypatch.setattr(flash_attention, "_auto_interpret",
                         lambda: False)
     lanes, W, fields = TICK_SHAPES[cell]
@@ -371,7 +301,7 @@ def test_mixed_tick_attends_both_kinds_through_the_ragged_kernel(
     in one program - no `while` under either, and both caches still
     aliased input to output with no leaf copied."""
     from horovod_tpu.models.transformer import (
-        AttnSpec, TransformerLM, decode_attention_plans, init_slot_cache,
+        AttnSpec, TransformerLM, init_slot_cache, kernel_plans,
         serving_params, slot_decode_model, slot_decode_tick)
     from horovod_tpu.ops import flash_attention
     from horovod_tpu.parallel.tensor import RopeSpec, unbox
@@ -389,7 +319,7 @@ def test_mixed_tick_attends_both_kinds_through_the_ragged_kernel(
             theta=5e5, fraction=0.5, yarn_factor=128,
             yarn_original_len=8192, scale=1.4852030263919618))),
             ("swa", AttnSpec(72, 512, RopeSpec(theta=1e4)))))
-    plans = decode_attention_plans(model, lanes)
+    plans = kernel_plans(model, lanes)["decode_attn"]
     assert plans["attn"].path == plans["swa"].path == "kernel", plans
     assert plans["attn"].grid == (lanes, 48)
     assert plans["swa"].grid == (lanes, 2)
@@ -434,8 +364,8 @@ def test_latent_tick_reads_each_row_once_through_the_ragged_kernel(
     relayout copies of the whole leaf a sublayer and tick:
     `parallel.latent_attention`)."""
     from horovod_tpu.models.transformer import (
-        TransformerLM, decode_attention_plans, init_slot_cache,
-        serving_params, slot_decode_model, slot_decode_tick)
+        TransformerLM, init_slot_cache, kernel_plans, serving_params,
+        slot_decode_model, slot_decode_tick)
     from horovod_tpu.ops import flash_attention
     from horovod_tpu.parallel.latent_attention import LatentSpec
     from horovod_tpu.parallel.tensor import unbox
@@ -455,7 +385,7 @@ def test_latent_tick_reads_each_row_once_through_the_ragged_kernel(
         num_experts=512, moe_zero_experts=256, moe_k=12, moe_hidden=256,
         moe_held=(0, 4), moe_router="softmax", moe_router_bias=True,
         moe_normalize=False, moe_scale=6.0)
-    plan = decode_attention_plans(model, lanes)["mla"]
+    plan = kernel_plans(model, lanes)["decode_attn"]["mla"]
     assert (plan.path, plan.grid, plan.write) == (
         "kernel", (lanes, 16), "kernel"), plan
     dec = slot_decode_model(model)
@@ -509,9 +439,8 @@ def test_plain_latent_block_beside_held_experts_lowers_for_the_chip(
     `ragged-dot`, and the group-limited choice and its chips count
     compiled beside them."""
     from horovod_tpu.models.transformer import (
-        TransformerLM, decode_attention_plans, init_slot_cache,
-        moe_product_plans, serving_params, slot_decode_model,
-        slot_decode_tick)
+        TransformerLM, init_slot_cache, kernel_plans, serving_params,
+        slot_decode_model, slot_decode_tick)
     from horovod_tpu.ops import flash_attention
     from horovod_tpu.parallel.latent_attention import LatentSpec
     from horovod_tpu.parallel.tensor import RopeSpec, unbox
@@ -533,10 +462,10 @@ def test_plain_latent_block_beside_held_experts_lowers_for_the_chip(
         num_experts=192, moe_k=8, moe_hidden=2048, moe_held=(0, 12),
         moe_shared_hidden=2048, moe_router="sigmoid",
         moe_router_bias=False, moe_scale=2.5, moe_groups=(8, 4))
-    plan = decode_attention_plans(model, lanes)["mla"]
+    plan = kernel_plans(model, lanes)["decode_attn"]["mla"]
     assert (plan.path, plan.grid, plan.write) == (
         "kernel", (lanes, 32), "kernel"), plan
-    product = moe_product_plans(model, lanes, 128)["tick"]
+    product = kernel_plans(model, lanes, 128)["moe_product"]["tick"]
     assert (product.path, product.rows) == ("kernel", 64), product
     dec = slot_decode_model(model)
 
@@ -630,8 +559,8 @@ def test_expert_layers_stream_their_weights_through_the_kernel(
     on its way into a call (a relayout of the experts would cost more
     than the product: count what arrives WITH a part)."""
     from horovod_tpu.models.transformer import (
-        TransformerLM, init_slot_cache, moe_product_plans,
-        serving_params, slot_decode_model, slot_decode_tick)
+        TransformerLM, init_slot_cache, kernel_plans, serving_params,
+        slot_decode_model, slot_decode_tick)
     from horovod_tpu.ops import flash_attention
     from horovod_tpu.parallel.latent_attention import LatentSpec
     from horovod_tpu.parallel.tensor import unbox
@@ -647,7 +576,7 @@ def test_expert_layers_stream_their_weights_through_the_kernel(
         vocab_size=128, num_layers=1, max_len=W, norm="rmsnorm",
         mlp_impl="swiglu", mlp_hidden=1024, dtype=jnp.bfloat16,
         attn_impl="flash", moe_every=1, moe_impl="dropless", **fields)
-    plans = moe_product_plans(model, lanes, 128)
+    plans = kernel_plans(model, lanes, 128)["moe_product"]
     assert {p.path for p in plans.values()} == {"kernel"}, plans
     assert plans["tick"].rows == tile
     dec = slot_decode_model(model)
@@ -703,8 +632,8 @@ def test_kda_state_is_stepped_in_place_by_one_call_a_layer(
     token is an S = 1 step too (the call, over the chunk's one lane);
     a chunk of 128 keeps the chunkwise form."""
     from horovod_tpu.models.transformer import (
-        TransformerLM, init_slot_cache, serving_params,
-        slot_decode_model, slot_decode_tick, state_step_plans)
+        TransformerLM, init_slot_cache, kernel_plans, serving_params,
+        slot_decode_model, slot_decode_tick)
     from horovod_tpu.ops import flash_attention
     from horovod_tpu.parallel.tensor import unbox
 
@@ -716,7 +645,7 @@ def test_kda_state_is_stepped_in_place_by_one_call_a_layer(
         mlp_impl="swiglu", mlp_hidden=1024, dtype=jnp.bfloat16,
         attn_impl="flash", moe_every=1, moe_impl="dropless",
         layer_kinds=("kda",), **fields)
-    plan = state_step_plans(model, lanes)["kda"]
+    plan = kernel_plans(model, lanes)["state_step"]["kda"]
     assert (plan.path, plan.block, plan.grid) == ("kernel", 32,
                                                   (lanes, 2)), plan
     dec = slot_decode_model(model)
@@ -771,8 +700,8 @@ def test_ssm_state_is_stepped_in_place_by_one_call_a_layer(
     chunk of one token is an S = 1 step too; a chunk of 128 keeps the
     chunkwise form."""
     from horovod_tpu.models.transformer import (
-        AttnSpec, TransformerLM, init_slot_cache, serving_params,
-        slot_decode_model, slot_decode_tick, state_step_plans)
+        AttnSpec, TransformerLM, init_slot_cache, kernel_plans,
+        serving_params, slot_decode_model, slot_decode_tick)
     from horovod_tpu.ops import flash_attention
     from horovod_tpu.parallel.state_space import SsmSpec
     from horovod_tpu.parallel.tensor import unbox
@@ -789,7 +718,7 @@ def test_ssm_state_is_stepped_in_place_by_one_call_a_layer(
         ssm=SsmSpec(num_heads=64, head_dim=64, state_size=128),
         attn_specs=(("attn", AttnSpec(scale=1 / 64)),),
         embed_scale=12, residual_scale=0.22, logits_divisor=8)
-    plan = state_step_plans(model, lanes)["ssm"]
+    plan = kernel_plans(model, lanes)["state_step"]["ssm"]
     assert (plan.path, plan.block, plan.grid) == (
         "kernel", 4096, (lanes, 1, 1)), plan
     dec = slot_decode_model(model)

@@ -259,19 +259,20 @@ class TestTransformerLM:
                   num_kv_heads=2, max_len=32, dtype=jnp.float32)
         dot_model = TransformerLM(attn_impl="dot", **kw)
         fla_model = TransformerLM(attn_impl="flash", **kw)
-        variables = dot_model.init(jax.random.PRNGKey(12), toks)
-        a = dot_model.apply(variables, toks)
-        b = fla_model.apply(variables, toks)
+        # every side one program, not a compile a primitive
+        variables = jax.jit(dot_model.init)(jax.random.PRNGKey(12), toks)
+        a = jax.jit(dot_model.apply)(variables, toks)
+        b = jax.jit(fla_model.apply)(variables, toks)
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32),
                                    atol=2e-4)
 
         from horovod_tpu.parallel.tensor import unbox
         params = unbox(variables["params"])
-        g1 = jax.grad(lambda p: lm_loss(
-            dot_model.apply({"params": p}, toks), toks))(params)
-        g2 = jax.grad(lambda p: lm_loss(
-            fla_model.apply({"params": p}, toks), toks))(params)
+        g1 = jax.jit(jax.grad(lambda p: lm_loss(
+            dot_model.apply({"params": p}, toks), toks)))(params)
+        g2 = jax.jit(jax.grad(lambda p: lm_loss(
+            fla_model.apply({"params": p}, toks), toks)))(params)
         jax.tree.map(
             lambda x, y: np.testing.assert_allclose(
                 np.asarray(x), np.asarray(y), atol=2e-4, rtol=2e-3),
@@ -285,7 +286,7 @@ class TestTransformerLM:
         from horovod_tpu.models.transformer import chunked_lm_loss
         toks = _tokens(B=4, S=16, seed=3)
         model = _tiny_model("dot")
-        variables = model.init(jax.random.PRNGKey(1), toks)
+        variables = jax.jit(model.init)(jax.random.PRNGKey(1), toks)
         from horovod_tpu.parallel.tensor import unbox
         params = unbox(variables["params"])
 
@@ -296,8 +297,10 @@ class TestTransformerLM:
             h, e = model.apply({"params": p}, toks, return_hidden=True)
             return chunked_lm_loss(h, e, toks, chunk=chunk)
 
-        l1, g1 = jax.value_and_grad(plain)(params)
-        l2, g2 = jax.value_and_grad(chunked)(params)
+        # one program a side: op by op, each primitive of the forward
+        # and of its transpose compiled alone (28 s a case)
+        l1, g1 = jax.jit(jax.value_and_grad(plain))(params)
+        l2, g2 = jax.jit(jax.value_and_grad(chunked))(params)
         np.testing.assert_allclose(float(l1), float(l2), rtol=1e-5)
         jax.tree.map(
             lambda a, b: np.testing.assert_allclose(
@@ -396,10 +399,11 @@ class TestTransformerLM:
         tx = optax.sgd(0.1)
 
         # Single-device oracle.
-        variables = model.init(jax.random.PRNGKey(0), toks)
+        variables = jax.jit(model.init)(jax.random.PRNGKey(0), toks)
         from horovod_tpu.parallel.tensor import unbox
         ref_params = unbox(variables["params"])
 
+        @jax.jit        # one program, not a compile a primitive
         def ref_step(params, toks):
             loss, grads = jax.value_and_grad(
                 lambda p: lm_loss(model.apply({"params": p}, toks),
