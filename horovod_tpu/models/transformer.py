@@ -303,6 +303,7 @@ class TransformerBlock(nn.Module):
     moe_router_bias: Optional[bool] = None
     moe_groups: Optional[Tuple[int, int]] = None  # HeldExpertsMoE.groups
     latent: Optional[LatentSpec] = None  # the widths of an "mla" mixer
+    kda_neg_eigval: bool = True      # KDAAttention.allow_neg_eigval
     ssm: Optional[SsmSpec] = None        # the widths of an "ssm" mixer
     shortcut_moe: bool = False           # see the docstring
     softmax_scale: Optional[float] = None    # see `AttnSpec.scale`
@@ -351,7 +352,8 @@ class TransformerBlock(nn.Module):
                 return KDAAttention(
                     num_heads=self.num_heads, head_dim=self.head_dim,
                     out_features=d, norm_eps=self.ln_eps,
-                    dtype=self.dtype, decode=self.decode, name=name)(
+                    dtype=self.dtype, decode=self.decode,
+                    allow_neg_eigval=self.kda_neg_eigval, name=name)(
                     h, advance, count)
             if self.mixer == "ssm":
                 if self.ssm is None:
@@ -547,6 +549,9 @@ class TransformerLM(nn.Module):
     layer_kinds: Optional[Tuple[str, ...]] = None
     latent: Optional[LatentSpec] = None
     ssm: Optional[SsmSpec] = None
+    # A "kda" layer's beta: 2 sigmoid in (0, 2) (the published
+    # `allow_neg_eigval`), or with False sigmoid in (0, 1).
+    kda_neg_eigval: bool = True
     # What a kind of softmax layer has of its own: ((kind, AttnSpec),
     # ...). A kind without an entry takes the model-wide ``num_heads``
     # / ``window`` / ``rope_theta``.
@@ -765,6 +770,7 @@ class TransformerLM(nn.Module):
                 moe_router_bias=self.moe_router_bias,
                 moe_groups=self.moe_groups,
                 latent=self.latent, ssm=self.ssm,
+                kda_neg_eigval=self.kda_neg_eigval,
                 shortcut_moe=self.moe_shortcut,
                 name=f"block_{i}")(
                 # which lanes a decode step may move: a recurrent

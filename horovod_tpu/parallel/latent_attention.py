@@ -1,17 +1,22 @@
 """Multi-head latent attention (MLA, DeepSeek-V2 2024): queries and
 keys/values go through low-rank bottlenecks, and the decode cache holds
-the bottleneck, not the heads. Two published users: LongCat-Flash (the
-two scale factors, plain rotation, softmax_factor 1) and A.X-K1 (the
+the bottleneck, not the heads. Three published users: LongCat-Flash (the
+two scale factors, plain rotation, softmax_factor 1), A.X-K1 (the
 DeepSeek-V3 family's: no scale factors, YaRN's frequencies on the rope
-part, softmax_factor = mscale^2 = 1.81326).
+part, softmax_factor = mscale^2 = 1.81326) and Kimi Linear's full
+layers (``q_rank`` None: the queries one projection with no norm;
+``rotate`` False: no position enters, the "rope" part of q and the
+shared key go into the score as projected).
 
 On its normed input u at position t, with H heads::
 
     c_q = RMSNorm(W_qa u)                        in R^q_rank
     q   = q_scale * (W_qb c_q)                   in [H, nope + rope]
+          (q_rank None: q = q_scale * (W_q u), no bottleneck, no norm)
     [c_kv ; k_r] = W_kva u                       in R^kv_rank + R^rope
     c   = kv_scale * RMSNorm(c_kv)               in R^kv_rank
     k_rope = RoPE_t(k_r)   (ONE head, shared);   q_rope = RoPE_t(q[:, nope:])
+          (rotate False: k_rope = k_r, q_rope = q[:, nope:])
     k_nope_h = W_UK,h c,   v_h = W_UV,h c        in R^nope, R^v
     score_h(t, j) = (q_nope_h . k_nope_h(j) + q_rope_h . k_rope(j))
                     * softmax_factor / sqrt(nope + rope),  causal
@@ -88,8 +93,14 @@ class LatentSpec:
     (None: plain frequencies at the layer's ``rope_theta``; a
     `RopeSpec` with ``yarn_factor`` is the DeepSeek family's YaRN),
     ``softmax_factor`` what multiplies 1 / sqrt(nope + rope) (that
-    family's mscale(factor, mscale_all_dim)^2; 1.0 = none)."""
-    q_rank: int
+    family's mscale(factor, mscale_all_dim)^2; 1.0 = none).
+    ``q_rank`` None (`q_lora_rank: null`): the queries are ONE
+    projection ``q`` from the hidden width, with no norm. ``rotate``
+    False (`mla_use_nope: true`): there is NO rotary rule - ``rope`` is
+    not read, the rope part of q and the shared key stay as projected,
+    and the row, the absorbed query, the walk and the kernel keep their
+    shapes."""
+    q_rank: Optional[int]
     kv_rank: int
     nope_dim: int
     rope_dim: int
@@ -98,6 +109,7 @@ class LatentSpec:
     kv_scale: float = 1.0
     rope: Optional[RopeSpec] = None
     softmax_factor: float = 1.0
+    rotate: bool = True
 
     @property
     def row(self) -> int:
@@ -244,10 +256,14 @@ class LatentAttention(nn.Module):
             return nn.RMSNorm(dtype=self.dtype, epsilon=self.norm_eps,
                               name=name)
 
-        cq = norm("q_a_norm")(
-            ColumnParallelDense(sp.q_rank, name="q_a", **dense)(u))
-        q = ColumnParallelDense(H * (sp.nope_dim + sp.rope_dim),
-                                name="q_b", **dense)(cq)
+        if sp.q_rank is None:
+            q = ColumnParallelDense(H * (sp.nope_dim + sp.rope_dim),
+                                    name="q", **dense)(u)
+        else:
+            cq = norm("q_a_norm")(
+                ColumnParallelDense(sp.q_rank, name="q_a", **dense)(u))
+            q = ColumnParallelDense(H * (sp.nope_dim + sp.rope_dim),
+                                    name="q_b", **dense)(cq)
         q = q.reshape(*q.shape[:-1], H, sp.nope_dim + sp.rope_dim)
         if sp.q_scale != 1.0:
             q = q * jnp.asarray(sp.q_scale, q.dtype)
@@ -273,14 +289,19 @@ class LatentAttention(nn.Module):
 
     def _rotate(self, q, c, kr, offset):
         """(q with its rope part rotated, the cache rows [c ; k_rope ;
-        0] as stored) at absolute positions offset + arange(S)."""
-        n = self.spec.nope_dim
-        pos = offset + jnp.arange(q.shape[-3])
-        rope = self.spec.rope or RopeSpec(theta=self.rope_theta)
-        rule = dict(rope.rotation(self.spec.rope_dim), interleaved=True)
-        q = jnp.concatenate(
-            [q[..., :n], apply_rope(q[..., n:], pos, **rule)], axis=-1)
-        kr = apply_rope(kr[..., None, :], pos, **rule)[..., 0, :]
+        0] as stored) at absolute positions offset + arange(S); with
+        `LatentSpec.rotate` off nothing turns and the offset is not
+        read."""
+        if self.spec.rotate:
+            n = self.spec.nope_dim
+            pos = offset + jnp.arange(q.shape[-3])
+            rope = self.spec.rope or RopeSpec(theta=self.rope_theta)
+            rule = dict(rope.rotation(self.spec.rope_dim),
+                        interleaved=True)
+            q = jnp.concatenate(
+                [q[..., :n], apply_rope(q[..., n:], pos, **rule)],
+                axis=-1)
+            kr = apply_rope(kr[..., None, :], pos, **rule)[..., 0, :]
         return q, jnp.concatenate(
             [c, kr.astype(c.dtype), self._pad(c)], axis=-1)
 

@@ -8,8 +8,10 @@ keys and values:
     S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T
     o_t = S_t^T q_t
 
-with alpha_t = exp(g_t) in (0, 1]^{Dk} a per-channel decay and beta_t in
-(0, 2) (negative eigenvalues allowed). Two forms of the same arithmetic:
+with alpha_t = exp(g_t) in (0, 1]^{Dk} a per-channel decay and beta_t =
+sigmoid(.) in (0, 1) or, with `KDAAttention.allow_neg_eigval`, twice
+that in (0, 2) (negative eigenvalues allowed). Two forms of the same
+arithmetic:
 
 * `kda_recurrent` - one position at a time (the S = 1 decode tick, and
   the oracle of the tests);
@@ -200,7 +202,12 @@ class KDAAttention(nn.Module):
     pad): the positions past the first ``count`` neither decay the
     state nor write to it (g = 0 and beta = 0 there, as `kda_chunked`
     pads its own tail), and the convolution's tail kept for the next
-    chunk is the last real positions', not the pads'."""
+    chunk is the last real positions', not the pads'.
+
+    ``allow_neg_eigval`` (the published key of the same name): beta =
+    2 sigmoid(.) in (0, 2), so that I - beta k k^T may have a negative
+    eigenvalue (Solar-Open2); False, or the key absent (Kimi Linear):
+    beta = sigmoid(.) in (0, 1)."""
 
     # The cache variables a step overwrites: whoever steps a lane that
     # must not advance has to put the old values back.
@@ -216,6 +223,7 @@ class KDAAttention(nn.Module):
     norm_eps: float = 1e-5
     dtype: Optional[Dtype] = None
     decode: bool = False
+    allow_neg_eigval: bool = True
 
     @nn.compact
     def __call__(self, x: jax.Array,
@@ -236,7 +244,9 @@ class KDAAttention(nn.Module):
         decay = dense(F, "f_b")(dense(D, "f_a")(x))
         a_log = self.param("A_log", nn.initializers.zeros, (H,), f32)
         dt_bias = self.param("dt_bias", nn.initializers.zeros, (F,), f32)
-        beta = 2.0 * jax.nn.sigmoid(dense(H, "b_proj")(x).astype(f32))
+        beta = jax.nn.sigmoid(dense(H, "b_proj")(x).astype(f32))
+        if self.allow_neg_eigval:
+            beta = 2.0 * beta
         gate = jax.nn.sigmoid(
             dense(F, "g_b")(dense(D, "g_a")(x)).astype(f32))
         g = (-jnp.exp(a_log)[:, None]

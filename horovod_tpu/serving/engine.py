@@ -153,9 +153,11 @@ def _refuse_overwritten_cache(model, **options):
     grafts, exports, pages, shelves or shards K/V blocks - and, for
     the first two, rewinds them - would need a snapshot form of that
     state, a block form of that ring or of that row, and none exists:
-    refuse loudly and by name rather than run it wrongly
+    refuse loudly and by name rather than run it wrongly, naming EVERY
+    kind of cache that stands in the way (a model may keep a state
+    beside latent rows: what is refused is the union of the tables)
     (docs/serving.md "Hybrid models", "Mixed attention", "Latent
-    attention")."""
+    attention", "Recurrent state beside latent rows")."""
     recurrent, rolling, latent = (model.has_recurrent_state,
                                   model.has_rolling_cache,
                                   model.has_latent_cache)
@@ -165,26 +167,28 @@ def _refuse_overwritten_cache(model, **options):
         if not on:
             continue
         does, no_state, no_ring, no_latent = _NEEDS_APPENDED_KV[name]
+        has = []        # every kind in the way, not the first
         if recurrent:
-            raise ValueError(
-                f"{name}: this model has recurrent (linear-attention "
-                f"or state-space) layers, and {does}; {no_state} - missing snapshot "
-                f"form of the recurrent state; serve it from the "
-                f"fixed slot pool (ServingEngine defaults)")
+            has.append(
+                f"recurrent (linear-attention or state-space) layers; "
+                f"{no_state} - missing snapshot form of the recurrent "
+                f"state")
         if rolling:
+            has.append(
+                f"sliding-window layers whose cache is a rolling buffer "
+                f"(ring) of {model.rolling_window} slots; {no_ring} - "
+                f"missing block form of the ring")
+        if latent and no_latent is not None:
+            has.append(
+                f"latent-attention layers whose cache holds rows of "
+                f"{model.latent.row} numbers without a head axis; "
+                f"{no_latent} - missing block form of the latent row")
+        if has:
             raise ValueError(
-                f"{name}: this model has sliding-window layers whose "
-                f"cache is a rolling buffer (ring) of "
-                f"{model.rolling_window} slots, and {does}; {no_ring} "
-                f"- missing block form of the ring; serve it from the "
-                f"fixed slot pool (ServingEngine defaults)")
-        if no_latent is not None:
-            raise ValueError(
-                f"{name}: this model has latent-attention layers "
-                f"whose cache holds rows of {model.latent.row} numbers "
-                f"without a head axis, and {does}; {no_latent} - "
-                f"missing block form of the latent row; serve it from "
-                f"the fixed slot pool (ServingEngine defaults)")
+                f"{name}: {does}, and this model has "
+                + "; and ".join(has)
+                + "; serve it from the fixed slot pool (ServingEngine "
+                "defaults)")
 
 
 def _resolve_serving_mesh(mesh):

@@ -504,6 +504,107 @@ def test_plain_latent_block_beside_held_experts_lowers_for_the_chip(
     _cache_stays_in_place(compiled, text, cache)
 
 
+@pytest.mark.parametrize("program", ["tick", "tail-128"])
+def test_recurrent_state_beside_latent_rows_lowers_for_the_chip(
+        sds, monkeypatch, program):
+    """One pool with BOTH kinds of cache at `kimi-linear-48b-a3b`'s real
+    widths (hidden 2304 = 18 x 128, 32 heads x 128, 128 lanes of 8192
+    positions; a KDA layer with the dense FFN and an unrotated,
+    unranked latent layer with 64 of 256 experts held; a vocabulary of
+    128) on the DEFAULT rules. The tick: `kda_step` in place under
+    `block_0/kda`, the in-place append and `latent_decode` under
+    `block_1/mla`, `grouped_swiglu` + `grouped_matmul` under
+    `block_1/moe` - five Mosaic calls, the scopes `kda_share_of_tick`,
+    `latent_layer_share_of_tick` and `moe_share_of_tick` match -
+    neither the state nor the latent leaf copied. The padded prompt
+    tail: `kda_chunked` from the cached state and the absorbed walk
+    over the cached rows in ONE program, both leaves aliased."""
+    from horovod_tpu.models.transformer import (
+        TransformerLM, init_slot_cache, kernel_plans, serving_params,
+        slot_decode_model, slot_decode_tick)
+    from horovod_tpu.ops import flash_attention
+    from horovod_tpu.parallel.latent_attention import LatentSpec
+    from horovod_tpu.parallel.tensor import unbox
+
+    monkeypatch.setattr(flash_attention, "_auto_interpret",
+                        lambda: False)
+    lanes, W = 128, 8192
+    model = TransformerLM(
+        vocab_size=128, num_layers=2, max_len=W, norm="rmsnorm",
+        mlp_impl="swiglu", mlp_hidden=9216, dtype=jnp.bfloat16,
+        attn_impl="flash", hidden_size=2304, num_heads=32, head_dim=128,
+        pos_emb="none", tied_head=False, layer_kinds=("kda", "mla"),
+        kda_neg_eigval=False, mlp_only_layers=(0,),
+        latent=LatentSpec(q_rank=None, kv_rank=512, nope_dim=128,
+                          rope_dim=64, v_dim=128, rotate=False),
+        moe_every=1, moe_impl="dropless",
+        num_experts=256, moe_k=8, moe_hidden=1024, moe_held=(0, 64),
+        moe_shared_hidden=1024, moe_router="sigmoid",
+        moe_router_bias=True, moe_scale=2.446, moe_groups=(1, 1))
+    plans = kernel_plans(model, lanes, 128)
+    assert (plans["state_step"]["kda"].path,
+            plans["state_step"]["kda"].grid) == ("kernel", (lanes, 1))
+    attn = plans["decode_attn"]["mla"]
+    assert (attn.path, attn.grid, attn.write) == (
+        "kernel", (lanes, 32), "kernel"), attn
+    # 128 x 8 / 256 = 4 rows an expert expected, under the ridge
+    assert {k: (p.path, p.rows) for k, p in
+            plans["moe_product"].items()} == {
+        "tick": ("kernel", 64), "prefill": ("kernel", 64)}
+    dec = slot_decode_model(model)
+
+    def place(tree):
+        return jax.tree.map(lambda s: sds(s.shape, s.dtype), tree)
+
+    params = place(jax.eval_shape(
+        lambda r: serving_params(unbox(model.init(
+            r, jnp.zeros((1, 64), jnp.int32))["params"])),
+        jax.random.PRNGKey(0)))
+    assert "q" in params["block_1"]["mla"]
+    assert "q_a" not in params["block_1"]["mla"]
+    cache = place(jax.eval_shape(lambda: init_slot_cache(model, lanes)))
+    state = cache["block_0"]["kda"]["state"]
+    rows = cache["block_1"]["mla"]["cached_latent"]
+    assert state.shape == (lanes, 1, 32, 128, 128)
+    assert rows.shape == (lanes, 1, W, 640)
+    both = state.size * 4 + rows.size * 2
+    if program == "tick":
+        vec = lambda dt: sds((lanes,), dt)  # noqa: E731
+        compiled = slot_decode_tick.lower(
+            dec, params, cache, vec(jnp.int32), vec(jnp.float32),
+            vec(jnp.float32), sds((lanes, 2), jnp.uint32), vec(bool),
+            vec(bool), sds((), jnp.int32)).compile()
+    else:
+        compiled = _chunk_program(sds, program, dec, params, cache)
+    text = compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes >= both
+    _cache_stays_in_place(compiled, text, cache)
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    named = lambda name, scope: [  # noqa: E731
+        ln for ln in calls if "%" + name in ln.split(" = ")[0]
+        and scope in ln]
+    assert len(named("grouped_swiglu", "/block_1/moe/")) == 1
+    assert len(named("grouped_matmul", "/block_1/moe/")) == 1
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    if program != "tick":
+        # the chunkwise form and the walk: no step kernel of either kind
+        assert len(calls) == 2, [ln[:120] for ln in calls]
+        return
+    assert len(named("kda_step", "/block_0/kda/")) == 1
+    assert len(named("latent_decode",
+                     "/block_1/mla/mla._decode_attention/")) == 1
+    assert len([ln for ln in calls if "/block_1/mla/" in ln]) == 2
+    assert len(calls) == 5, [ln[:120] for ln in calls]
+    loops = re.findall(r' while\(.*op_name="([^"]*)"', text)
+    assert [n for n in loops if "/mla/" in n or "/kda/" in n] == []
+    made = [ln for ln in text.splitlines() if re.search(
+        r"= f32\[128,(1,)?32,128,128\]\S* "
+        r"(?!parameter\(|bitcast\(|get-tuple-element\()", ln)]
+    assert made == [], [ln[:160] for ln in made]
+    # the tick's last output: a row of 64 held experts' pairs + the chips
+    assert "s32[1,65]" in text
+
+
 # The serving cells' expert layers at their published widths (one
 # layer, a small vocabulary): lanes, cache positions, the tick's row
 # tile, model fields.
